@@ -194,10 +194,6 @@ def main():
                     help="print a tuning_configs 'decode' section with "
                          "each shape's winner on stdout")
     args = ap.parse_args()
-    if not args.smoke:
-        from flashinfer_tpu.env import apply_platform_from_env
-
-        apply_platform_from_env()
     winners = sweep(args.smoke, args.repeats)
     if args.emit_config:
         print(json.dumps({"decode": {

@@ -36,7 +36,7 @@ jax.config.update("jax_platforms", "cpu")
 
 import jax.numpy as jnp
 import numpy as np
-from flashinfer_tpu.utils import jax_shard_map as shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

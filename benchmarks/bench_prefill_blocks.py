@@ -280,10 +280,6 @@ def main():
                     help="also A/B the fused prefill ingest "
                          "(prefill.fused_ingest) per shape")
     args = ap.parse_args()
-    if not args.smoke:
-        from flashinfer_tpu.env import apply_platform_from_env
-
-        apply_platform_from_env()
     winners = sweep(args.smoke, args.repeats)
     ingest_winners = (sweep_ingest(args.smoke, args.repeats)
                       if args.sweep_ingest else {})

@@ -227,9 +227,6 @@ def main():
             os.environ.get("XLA_FLAGS", "")
             + " --xla_force_host_platform_device_count=8")
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    from flashinfer_tpu.env import apply_platform_from_env
-
-    apply_platform_from_env()
     sweep(args.smoke, args.emit_config)
     return 0
 

@@ -11,7 +11,8 @@ canonical serving lifecycle —
    (plan once per geometry bucket, run per layer per step);
 4. sample with the logits pipeline.
 
-Run: ``python examples/generate.py`` (CPU or TPU; tiny random model).
+Run: ``python examples/generate.py`` (tiny random model).  It runs on the
+devices JAX has — ``JAX_PLATFORMS=cpu`` for the CPU — and prints which.
 """
 
 import functools
@@ -23,19 +24,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 import numpy as np
 
 import jax
-
-# decide the platform BEFORE any jax API touches a backend (a
-# default_backend() probe would initialize the TPU plugin first)
-if "cpu" in sys.argv or not os.environ.get("EXAMPLE_USE_TPU"):
-    jax.config.update("jax_platforms", "cpu")
-    # opt OUT of any inherited persistent XLA cache: this example's
-    # fused-vs-per-op parity assert compares two programs bit-for-bit,
-    # and this host's LLVM has a documented cache flake class
-    # (tests/conftest.py) where a cached executable's numerics differ
-    # from a fresh compile of the same key — everything here compiles
-    # in seconds, so fresh-compile determinism wins
-    jax.config.update("jax_enable_compilation_cache", False)
-
 import jax.numpy as jnp
 
 # the example demonstrates the metered serving lifecycle (ISSUE 10):
@@ -378,6 +366,8 @@ def generate_stepwise(model: str, prompt_lens, max_new_tokens=8, seed=0):
 
 
 if __name__ == "__main__":
+    print(f"# running on {jax.devices()[0].platform} "
+          f"({jax.devices()[0].device_kind})")
     int8 = "int8" in sys.argv
     fused = "--fused-step" in sys.argv
     model = next((a for a in sys.argv[1:] if a in ("mixtral", "deepseek")),

@@ -2,8 +2,8 @@
 
 TPU re-design of the reference CLI (``flashinfer/__main__.py:63-462``).
 Command mapping: cubin/jit-cache management collapses into the XLA
-persistent compilation cache + native-planner cache under
-``FLASHINFER_TPU_CACHE_DIR``.
+persistent compilation cache (``env.xla_cache_dir()``) + the library's
+own state (native planner, tactics) under ``FLASHINFER_TPU_CACHE_DIR``.
 
 Commands: collect-env | show-config | clear-cache | module-status |
 list-modules | tuner-status.
@@ -28,6 +28,7 @@ def cmd_show_config(_args) -> int:
     from flashinfer_tpu import env
 
     print(f"cache_dir        : {env.cache_dir()}")
+    print(f"xla_cache_dir    : {env.xla_cache_dir()}")
     print(f"dump_dir         : {env.dump_dir()}")
     print(f"log_level        : {env.log_level()}")
     print(f"backend_override : {env.backend_override()}")
@@ -59,7 +60,7 @@ def cmd_module_status(_args) -> int:
     print(f"native planner  : {'built+loaded' if lib else 'numpy fallback'}")
     from flashinfer_tpu import env
 
-    xc = env.cache_dir() / "xla_cache"
+    xc = env.xla_cache_dir()
     n = sum(1 for _ in xc.rglob("*") if _.is_file()) if xc.exists() else 0
     print(f"xla compile cache: {n} entries ({xc})")
     from flashinfer_tpu import compile_guard

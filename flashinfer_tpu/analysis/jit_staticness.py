@@ -136,7 +136,7 @@ def _collect_functions(sf: SourceFile) -> List[FnInfo]:
     def _callable_names(expr: ast.expr) -> Set[str]:
         """Bare Names that plausibly name the traced callable inside a
         jit argument — the name itself, or the FIRST positional arg of
-        a composing call, recursively (`jax.jit(jax_shard_map(step,
+        a composing call, recursively (`jax.jit(jax.shard_map(step,
         ...))` traces `step`; `jax.jit(partial(f, x))` traces `f`).
         Later positional args are data/callback operands, not the
         traced body — marking them too would false-positive L003 on

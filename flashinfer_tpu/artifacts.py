@@ -62,10 +62,21 @@ def _sha256(p: Path) -> str:
     return h.hexdigest()
 
 
-def _bundle_members(cache_root: Path):
+def _roots(cache_dir: Optional[str]) -> Tuple[Path, Path]:
+    """(library state root, XLA executable directory).  An explicit
+    ``cache_dir`` is a self-contained bundle root with the executables
+    under it; the live places are ``env.cache_dir()`` and
+    ``env.xla_cache_dir()`` (JAX's own ``JAX_COMPILATION_CACHE_DIR``
+    or the checkout's fixed directory — env.py)."""
+    if cache_dir:
+        return Path(cache_dir), Path(cache_dir) / ArtifactPath.XLA_CACHE
+    return env.cache_dir(), env.xla_cache_dir()
+
+
+def _bundle_members(cache_root: Path, xla_root: Path):
     """Yield (arcname, path) for every file the bundle carries."""
     for sub, root in (
-        (ArtifactPath.XLA_CACHE, cache_root / ArtifactPath.XLA_CACHE),
+        (ArtifactPath.XLA_CACHE, xla_root),
         (ArtifactPath.TACTICS, cache_root / ArtifactPath.TACTICS),
     ):
         if not root.is_dir():
@@ -103,11 +114,10 @@ def pack_artifacts(out_path: str, cache_dir: Optional[str] = None) -> Path:
     version (the autotuner additionally validates device_kind metadata on
     load, so a mismatched bundle degrades to defaults, never misapplies).
     """
-    root = Path(cache_dir) if cache_dir else env.cache_dir()
     out = Path(out_path)
     manifest = {}
     with tarfile.open(out, "w:gz") as tar:
-        for arcname, p in _bundle_members(root):
+        for arcname, p in _bundle_members(*_roots(cache_dir)):
             manifest[arcname] = _sha256(p)
             tar.add(p, arcname=arcname)
         mbytes = json.dumps(manifest, indent=1, sort_keys=True).encode()
@@ -132,7 +142,7 @@ def unpack_artifacts(bundle_path: str,
     the bundle can re-sign it; distribute bundles over channels with
     their own authenticity guarantees.)
     """
-    root = Path(cache_dir) if cache_dir else env.cache_dir()
+    root, xla_root = _roots(cache_dir)
 
     def _members(tar, manifest):
         for member in tar.getmembers():
@@ -176,7 +186,10 @@ def unpack_artifacts(bundle_path: str,
     with tarfile.open(bundle_path, "r:gz") as tar:
         manifest = json.loads(tar.extractfile(_MANIFEST).read().decode())
         for member, rel in _members(tar, manifest):
-            dest = root / rel
+            if rel.parts[0] == ArtifactPath.XLA_CACHE:
+                dest = xla_root.joinpath(*rel.parts[1:])
+            else:
+                dest = root / rel
             dest.parent.mkdir(parents=True, exist_ok=True)
             f = tar.extractfile(member)
             with open(dest, "wb") as out:
@@ -193,11 +206,10 @@ def get_artifacts_status() -> Tuple[Tuple[str, bool], ...]:
     accelerator is absent or wedged (it is part of the recovery
     tooling), so the tuning-config rows list each available stem rather
     than resolving the current chip."""
-    root = env.cache_dir()
+    root, xla_root = _roots(None)
     status = [
         (ArtifactPath.XLA_CACHE,
-         any((root / ArtifactPath.XLA_CACHE).rglob("*"))
-         if (root / ArtifactPath.XLA_CACHE).is_dir() else False),
+         any(xla_root.rglob("*")) if xla_root.is_dir() else False),
         (ArtifactPath.TACTICS,
          (root / ArtifactPath.TACTICS / "tactics.json").is_file()),
     ]
@@ -221,9 +233,8 @@ def clear_artifacts(cache_dir: Optional[str] = None) -> None:
     NOT touched."""
     import shutil
 
-    root = Path(cache_dir) if cache_dir else env.cache_dir()
-    for sub in (ArtifactPath.XLA_CACHE, ArtifactPath.TACTICS):
-        d = root / sub
+    root, xla_root = _roots(cache_dir)
+    for d in (xla_root, root / ArtifactPath.TACTICS):
         if d.is_dir():
             shutil.rmtree(d)
 
@@ -293,7 +304,7 @@ def temp_env_var(key: str, value: str):
 
 def get_subdir_file_list():
     """(subdir, file) pairs the bundle would carry (artifacts.py:227)."""
-    for arcname, _ in _bundle_members(env.cache_dir()):
+    for arcname, _ in _bundle_members(*_roots(None)):
         sub, _, rest = arcname.partition("/")
         yield sub, rest
 
@@ -301,7 +312,7 @@ def get_subdir_file_list():
 def get_available_cubin_files(*_a, **_k) -> Tuple[str, ...]:
     """Reference lists cubins present for a path (artifacts.py:58); here:
     serialized XLA executables in the local cache."""
-    d = env.cache_dir() / ArtifactPath.XLA_CACHE
+    d = env.xla_cache_dir()
     if not d.is_dir():
         return ()
     return tuple(sorted(p.name for p in d.rglob("*") if p.is_file()))
@@ -325,7 +336,7 @@ def get_checksums(subdirs=None):
     """Live checksums of the local artifact set (artifacts.py:198)."""
     want = set(subdirs) if subdirs else None
     out = {}
-    for arcname, p in _bundle_members(env.cache_dir()):
+    for arcname, p in _bundle_members(*_roots(None)):
         sub = arcname.partition("/")[0]
         if want is None or sub in want:
             out[arcname] = _sha256(p)

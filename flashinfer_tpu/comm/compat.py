@@ -29,7 +29,6 @@ from typing import Any, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from flashinfer_tpu.utils import lax_axis_size
 
 from flashinfer_tpu.comm.allreduce import allreduce, allreduce_fusion
 
@@ -330,7 +329,7 @@ def moe_a2a_dispatch(hidden, topk_ids, topk_weights, num_experts: int,
     or raise capacity_factor.  See docs/migration.md deviation table."""
     from flashinfer_tpu.fused_moe.core import _route_buckets
 
-    ep = lax_axis_size(axis)
+    ep = jax.lax.axis_size(axis)
     e_local = num_experts // ep
     T, K = topk_ids.shape
     H = hidden.shape[1]
@@ -355,7 +354,7 @@ def moe_a2a_combine(expert_output, topk_ids, topk_weights,
     zero to the weighted sum (docs/migration.md)."""
     from flashinfer_tpu.fused_moe.core import _route_buckets
 
-    ep = lax_axis_size(axis)
+    ep = jax.lax.axis_size(axis)
     e_local = num_experts // ep
     T, K = topk_ids.shape
     H = expert_output.shape[-1]

@@ -473,43 +473,24 @@ class BatchDecodeWithPagedKVCacheWrapper:
             # units + merge_states reduction (plan-time cost-model
             # choice; the arrays were built by build_decode_split_units
             # in plan())
-            from flashinfer_tpu import compile_guard
-            from flashinfer_tpu.ops import paged_decode as _pd_module
             from flashinfer_tpu.ops.paged_decode import (
                 paged_decode_attention_split)
 
-            def _run_split():
-                return paged_decode_attention_split(
-                    q, k_cache, v_cache, plan.split_arrays,
-                    num_units=plan.split_units,
-                    num_splits=plan.num_splits,
-                    single_chunk=plan.split_single_chunk,
-                    pages_per_chunk=plan.split_ppc,
-                    sm_scale=sm_scale,
-                    logits_soft_cap=plan.logits_soft_cap,
-                    window_left=plan.window_left,
-                    return_lse=return_lse,
-                )
-
-            try:
-                out = compile_guard.guarded(
-                    "paged_decode_split",
-                    (plan.split_units, plan.num_splits,
-                     plan.split_single_chunk, plan.split_ppc,
-                     plan.num_qo_heads, plan.num_kv_heads,
-                     plan.head_dim, plan.page_size, str(q.dtype),
-                     str(k_cache.dtype), float(sm_scale),
-                     float(plan.logits_soft_cap),
-                     int(plan.window_left), return_lse),
-                    _run_split, module=_pd_module,
-                )
-            except compile_guard.KernelQuarantined:
-                backend = "xla"
+            out = paged_decode_attention_split(
+                q, k_cache, v_cache, plan.split_arrays,
+                num_units=plan.split_units,
+                num_splits=plan.num_splits,
+                single_chunk=plan.split_single_chunk,
+                pages_per_chunk=plan.split_ppc,
+                sm_scale=sm_scale,
+                logits_soft_cap=plan.logits_soft_cap,
+                window_left=plan.window_left,
+                return_lse=return_lse,
+            )
         elif backend == "pallas":
             # autotuned pages-per-chunk (reference AutoTuner.choose_one role;
             # zero overhead outside an autotune() context — cached/default)
             from flashinfer_tpu.autotuner import AutoTuner
-            from flashinfer_tpu import compile_guard
             from flashinfer_tpu.ops import paged_decode as _pd_module
 
             ppc_default = max(1, min(512 // plan.page_size, 16))
@@ -518,7 +499,7 @@ class BatchDecodeWithPagedKVCacheWrapper:
                 for c in (128, 256, 512, 1024)
             })
             # one shape key + one runner shared by both tactic tuners and
-            # the final guarded call — a plan field added to
+            # the final call — a plan field added to
             # decode_tactic_key reaches all three AND the model decode
             # paths identically
             from flashinfer_tpu.ops.paged_decode import decode_tactic_key
@@ -561,23 +542,7 @@ class BatchDecodeWithPagedKVCacheWrapper:
             ) if self._kv_layout == "HND" else "off"
             csp = "static" if pf == "static" else False
 
-            try:
-                out = compile_guard.guarded(
-                    "paged_decode",
-                    (plan.page_table.shape, plan.num_qo_heads,
-                     plan.num_kv_heads, plan.head_dim, plan.page_size,
-                     str(q.dtype), str(k_cache.dtype), int(ppc),
-                     self._kv_layout, return_lse,
-                     # every jit static that forces a fresh Mosaic compile
-                     # must be in the fingerprint, or the recompile runs
-                     # outside the guarded window
-                     float(sm_scale), float(plan.logits_soft_cap),
-                     int(plan.window_left), str(csp)),
-                    lambda: _run(int(ppc), csp),
-                    module=_pd_module,
-                )
-            except compile_guard.KernelQuarantined:
-                backend = "xla"
+            out = _run(int(ppc), csp)
         if backend != "pallas":
             out = xla_paged_decode(
                 q, k_cache, v_cache, plan.page_table, plan.kv_lens,

@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 
 from flashinfer_tpu.activation import silu_and_mul
-from flashinfer_tpu.utils import lax_axis_size
 
 
 def _act(h1: jax.Array, activation: str) -> jax.Array:
@@ -371,7 +370,7 @@ def fused_moe_ep(
     (those modes never drop).
     """
     if dispatch == "allgather":
-        ep = lax_axis_size(axis)
+        ep = jax.lax.axis_size(axis)
         rank = jax.lax.axis_index(axis)
         e_local = w_gate_up.shape[0]
 
@@ -432,7 +431,7 @@ def _record_ep_a2a_bytes(hidden, topk_ids, axis, capacity_factor,
 
     if not obs.metrics_enabled():
         return
-    ep = lax_axis_size(axis)
+    ep = jax.lax.axis_size(axis)
     if not isinstance(ep, int):  # outside shard_map (tests call eager)
         return
     T, K = topk_ids.shape
@@ -481,7 +480,7 @@ def _fused_moe_ep_alltoall(
     hidden, w_gate_up, w_down, topk_weights, topk_ids, num_experts,
     axis, activation, capacity_factor,
 ):
-    ep = lax_axis_size(axis)
+    ep = jax.lax.axis_size(axis)
     e_local = w_gate_up.shape[0]
     T, K = topk_ids.shape
     H = hidden.shape[1]
@@ -548,7 +547,7 @@ def _fused_moe_ep_alltoall_exact(
     order-free); at K>2 the K-way addition order can differ from the
     oracle's expert-sorted scatter-add by an ulp.
     """
-    ep = lax_axis_size(axis)
+    ep = jax.lax.axis_size(axis)
     e_local = w_gate_up.shape[0]
     T, K = topk_ids.shape
     H = hidden.shape[1]

@@ -368,7 +368,7 @@ def make_ep_sharded_decode_step(
     first-k layers stay fully local.
 
     Returns (step_fn, mesh, specs)."""
-    from flashinfer_tpu.utils import jax_shard_map as shard_map
+    from jax import shard_map
 
     mesh = mesh or mapping.make_mesh()
     ep_ax, dp = Mapping.AXIS_TP, Mapping.AXIS_DP

@@ -31,7 +31,7 @@ from flashinfer_tpu.norm import rmsnorm
 from flashinfer_tpu.ops.paged_decode import paged_decode_attention
 from flashinfer_tpu.ops.xla_ref import xla_paged_decode
 from flashinfer_tpu.rope import apply_rope_pos_ids
-from flashinfer_tpu.utils import is_tpu, jax_shard_map
+from flashinfer_tpu.utils import is_tpu
 
 
 @dataclass(frozen=True)
@@ -366,7 +366,7 @@ def make_sharded_decode_step(mapping: Mapping, cfg: LlamaConfig, mesh=None,
         return logits, new_caches
 
     sharded = jax.jit(
-        jax_shard_map(
+        jax.shard_map(
             step, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=False,
         )
@@ -457,7 +457,7 @@ def make_cp_prefill_step(mapping: Mapping, cfg: LlamaConfig, mesh=None):
         return logits, kvs
 
     sharded = jax.jit(
-        jax_shard_map(
+        jax.shard_map(
             step, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=False,
         )
@@ -573,7 +573,7 @@ def make_pp_sharded_decode_step(mapping: Mapping, cfg: LlamaConfig, mesh=None):
         return logits, (kcs[:, None], vcs[:, None])
 
     sharded = jax.jit(
-        jax_shard_map(
+        jax.shard_map(
             step, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=False,
         )
@@ -683,7 +683,7 @@ def make_pp_microbatch_decode_step(
         return logits, (kcs[:, None], vcs[:, None])
 
     sharded = jax.jit(
-        jax_shard_map(
+        jax.shard_map(
             step, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=False,
         )

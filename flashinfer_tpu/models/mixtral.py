@@ -33,7 +33,7 @@ from flashinfer_tpu.models.llama import (
     _mm,
 )
 from flashinfer_tpu.norm import rmsnorm
-from flashinfer_tpu.utils import is_tpu, jax_shard_map
+from flashinfer_tpu.utils import is_tpu
 
 
 @dataclass(frozen=True)
@@ -196,7 +196,7 @@ def make_ep_sharded_decode_step(
         return logits, new_caches
 
     sharded = jax.jit(
-        jax_shard_map(
+        jax.shard_map(
             step, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=False,
         )

@@ -58,9 +58,6 @@ def _workload() -> None:
     """A tier-1-sized pass over the instrumented surface: stateless
     decorated ops plus one full plan/run lifecycle per batch wrapper
     family (small shapes; runs in seconds on CPU)."""
-    from flashinfer_tpu.env import apply_platform_from_env
-
-    apply_platform_from_env()
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -114,9 +111,6 @@ def _serving_workload(steps: int, perturb: bool) -> dict:
     ``perturb``, one extra run afterwards moves EXACTLY ONE run-state
     static (the carried logits dtype) so the retrace-cause attribution
     has a known answer.  Returns the selftest facts."""
-    from flashinfer_tpu.env import apply_platform_from_env
-
-    apply_platform_from_env()
     import jax
     import jax.numpy as jnp
 
@@ -209,9 +203,6 @@ def _engine_workload(num_requests: int,
     selftest replays the SAME seeded workload on both attention
     backends and fails on any token divergence (the kernel tier's
     parity gate)."""
-    from flashinfer_tpu.env import apply_platform_from_env
-
-    apply_platform_from_env()
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -269,9 +260,6 @@ def _engine_spill_workload(spill: bool) -> dict:
     RESTORE); ``spill=False`` runs the never-preempted oracle (big
     pool, no tier) over the SAME seeded requests, so the selftest can
     pin token equality.  Returns the tier facts the gates read."""
-    from flashinfer_tpu.env import apply_platform_from_env
-
-    apply_platform_from_env()
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -852,17 +840,23 @@ def cmd_perf(args) -> int:
 
     path = args.banked
     if path is None:
-        path = os.path.join(os.path.dirname(os.path.dirname(
+        # a history kept at the root of the checkout; where none was
+        # asked for and none is kept, the report still carries every
+        # section computed from shapes alone
+        kept = os.path.join(os.path.dirname(os.path.dirname(
             os.path.dirname(os.path.abspath(__file__)))),
             "BENCH_BANKED.md")
-    try:
-        rows = bench_audit.load_banked_history(path, strict=True)
-    except ValueError as e:
-        print(f"error: {e}", file=sys.stderr)
-        return 2
-    if not rows:
-        print(f"error: no bench rows found in {path}", file=sys.stderr)
-        return 2
+        path = kept if os.path.exists(kept) else None
+    rows = []
+    if path is not None:
+        try:
+            rows = bench_audit.load_banked_history(path, strict=True)
+        except ValueError as e:
+            print(f"error: {e}", file=sys.stderr)
+            return 2
+        if not rows:
+            print(f"error: no bench rows found in {path}", file=sys.stderr)
+            return 2
     report = roofline.build_perf_report(rows, chip=args.chip)
     if args.json:
         print(json.dumps(report, indent=1, sort_keys=True))

@@ -11,7 +11,10 @@ env vars and touches no backend at import time — ``analysis/
 vmem_budget.py`` imports ``VMEM_CAPS`` from here and must stay usable
 in a lint process with no accelerator.  Detection (:func:`detect_chip`
 / :func:`current_spec`) reads ``FLASHINFER_TPU_CHIP`` and the jax
-device kind lazily, per call.
+device kind lazily, per call.  A chip that is not in the table is an
+error, never a default: plan-time choosers read :func:`current_spec`
+for the VMEM budget and the peaks, so a wrong spec is wrong program
+behaviour, not just wrong reporting.
 
 Provenance of the numbers:
 
@@ -24,10 +27,10 @@ Provenance of the numbers:
   1836).  ``fp8`` maps to the int8 rate where no native fp8 mode
   exists — same MXU width.
 - VMEM bytes: compile-budget ceilings, not datasheet capacities —
-  v5e 64 MiB is on-chip-validated by this repo's own kernels (they
-  request vmem_limit_bytes=64 MiB and compile, HW_TIER_LOG); v5p
-  carries 2x per tuning_configs/v5p.json; v4/v6e conservatively
-  inherit the v5e bound.
+  v5e 64 MiB is validated by this repo's own kernels (they request
+  vmem_limit_bytes=64 MiB and compile for a v5e); v5p carries 2x per
+  tuning_configs/v5p.json; v4/v6e conservatively inherit the v5e
+  bound.
 - ICI GB/s: per-chip aggregate interconnect bandwidth (v4 2400 Gbps,
   v5e 1600, v5p 4800, v6e 3584 — /8 to bytes), for sizing the
   all-reduce terms the single-chip bench excludes.
@@ -106,7 +109,9 @@ CHIP_ALIASES: Dict[str, str] = {
     "trillium": "v6e",
 }
 
-DEFAULT_CHIP = "v5e"  # the chip every banked row so far was measured on
+# The chip a process with NO accelerator plans and prices for: the CPU
+# test suite, lint, and reports read off-chip.  Never given to a TPU.
+DEFAULT_CHIP = "v5e"
 
 # Plain per-generation VMEM compile-budget dict: what analysis/
 # vmem_budget.py (L009) imports.  Kept as a dict of ints (not specs) so
@@ -118,9 +123,9 @@ VMEM_CAPS: Dict[str, int] = {
 
 def spec(name: str) -> ChipSpec:
     """Spec by canonical name, alias, or device-kind-ish string
-    (``"TPU v5 lite"`` -> v5e).  Unknown names fall back to the
-    DEFAULT_CHIP spec — a bench row must never die on a new chip
-    string, it just attributes against the conservative default."""
+    (``"TPU v5 lite"`` -> v5e).  An unknown name raises KeyError:
+    giving a chip we do not know another chip's peaks would make every
+    number attributed against it wrong in silence."""
     key = str(name).lower().replace(" ", "")
     if key in CHIP_SPECS:
         return CHIP_SPECS[key]
@@ -131,7 +136,10 @@ def spec(name: str) -> ChipSpec:
                                key=lambda kv: -len(kv[0])):
         if alias in key:
             return CHIP_SPECS[canon]
-    return CHIP_SPECS[DEFAULT_CHIP]
+    raise KeyError(
+        f"unknown chip {name!r}: not in hwspec.CHIP_SPECS / CHIP_ALIASES "
+        f"({sorted(CHIP_SPECS)}) — add its spec, with the source of its "
+        "peaks, before running on it")
 
 
 def spec_for_peak_tbps(peak: float,
@@ -151,21 +159,20 @@ def spec_for_peak_tbps(peak: float,
 
 def detect_chip(device_kind: Optional[str] = None) -> str:
     """Canonical chip name: ``FLASHINFER_TPU_CHIP`` env override first
-    (works off-accelerator and in CI), else the jax device kind, else
-    DEFAULT_CHIP.  Env/read and backend touch happen HERE, per call —
-    never at import."""
+    (works off-accelerator and in CI), else the jax device kind.  A
+    device that is no TPU (the CPU the tests run on) plans for
+    DEFAULT_CHIP; a TPU whose kind is not in the table raises, and so
+    does a backend that fails to start.  Env read and backend touch
+    happen HERE, per call — never at import."""
     import os
 
     override = os.environ.get("FLASHINFER_TPU_CHIP")
     if override:
         return spec(override).name
     if device_kind is None:
-        try:
-            import jax
+        import jax
 
-            device_kind = jax.devices()[0].device_kind
-        except Exception:  # no backend (lint/CI process) -> default
-            return DEFAULT_CHIP
+        device_kind = jax.devices()[0].device_kind
     key = str(device_kind).lower().replace(" ", "")
     if "tpu" not in key and not any(a in key for a in CHIP_ALIASES):
         return DEFAULT_CHIP

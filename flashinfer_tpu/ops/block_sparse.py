@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from flashinfer_tpu.utils import cdiv, tpu_compiler_params, use_interpret
+from flashinfer_tpu.utils import cdiv, use_interpret
 
 _NEG_INF = -1e30
 
@@ -125,7 +125,7 @@ def bsr_attention(
         functools.partial(_bsr_kernel, max_nnz=max_nnz, sm_scale=sm_scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((H, M, D), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024
         ),
         interpret=use_interpret(),
@@ -273,7 +273,7 @@ def bsr_attention_token_select(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((H, M, D), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024
         ),
         interpret=use_interpret(),
@@ -454,7 +454,7 @@ def vbsr_attention(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((H, M, D), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024
         ),
         interpret=use_interpret(),

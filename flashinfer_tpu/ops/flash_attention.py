@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from flashinfer_tpu.utils import round_up, tpu_compiler_params, use_interpret
+from flashinfer_tpu.utils import round_up, use_interpret
 
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_KV = 512
@@ -321,7 +321,7 @@ def flash_attention(
         # enable megacore grid partitioning on dual-core chips (v4/v5p), but
         # is a suspect in a Mosaic compile hang under investigation on v5e;
         # reintroduce once cleared.
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024,
         ),
         interpret=use_interpret(),

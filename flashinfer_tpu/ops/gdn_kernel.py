@@ -52,7 +52,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from flashinfer_tpu.utils import tpu_compiler_params, use_interpret
+from flashinfer_tpu.utils import use_interpret
 
 _CHUNK = 128  # lane-aligned [Q, Q] matrices; log2(Q) = 7 doubling rounds
 
@@ -275,7 +275,7 @@ def gdn_chunk_prefill_pallas(
             jax.ShapeDtypeStruct((B, H, nC, Q, dv), q.dtype),
             jax.ShapeDtypeStruct((B, H, dk, dv), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=100 * 1024 * 1024,
         ),
@@ -526,7 +526,7 @@ def kda_chunk_prefill_pallas(
             jax.ShapeDtypeStruct((B, H, nC, Q, dv), q.dtype),
             jax.ShapeDtypeStruct((B, H, dk, dv), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=100 * 1024 * 1024,
         ),

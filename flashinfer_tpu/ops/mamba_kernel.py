@@ -32,7 +32,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from flashinfer_tpu.utils import tpu_compiler_params, use_interpret
+from flashinfer_tpu.utils import use_interpret
 
 _CHUNK = 128
 
@@ -226,7 +226,7 @@ def mamba_chunk_scan_pallas(
             jax.ShapeDtypeStruct((Bsz, H, nC, Q, dim), x.dtype),
             jax.ShapeDtypeStruct((Bsz, H, dim, ds), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=100 * 1024 * 1024,
         ),

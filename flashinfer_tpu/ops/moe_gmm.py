@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from flashinfer_tpu.utils import round_up, tpu_compiler_params, use_interpret
+from flashinfer_tpu.utils import round_up, use_interpret
 
 
 def _pick_tk(tk: int, k: int) -> int:
@@ -353,7 +353,7 @@ def gmm(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((m_pad, n), out_dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")
         ),
         interpret=use_interpret(),
@@ -622,7 +622,7 @@ def _gather_gmm_impl(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((m_pad, n), out_dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=semantics,
         ),
         interpret=use_interpret(),

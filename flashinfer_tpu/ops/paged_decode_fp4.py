@@ -42,7 +42,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from flashinfer_tpu.utils import round_up, tpu_compiler_params, use_interpret
+from flashinfer_tpu.utils import round_up, use_interpret
 
 _NEG_INF = -1e30
 
@@ -330,7 +330,7 @@ def fp4_paged_decode_attention(
             ),
             jax.ShapeDtypeStruct((batch, num_kv_heads, gp, 128), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024,
         ),
         interpret=use_interpret(),

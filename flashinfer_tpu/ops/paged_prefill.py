@@ -71,7 +71,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from flashinfer_tpu.utils import cdiv, next_power_of_two, round_up, tpu_compiler_params, use_interpret
+from flashinfer_tpu.utils import (cdiv, next_power_of_two, round_up, use_interpret)
 
 _NEG_INF = -1e30
 
@@ -85,7 +85,23 @@ CODE_WRITE_ONLY = 3  # ingest mode only: quantize-append the chunk, no
 #                      attention (empty row span; the chunk was pruned
 #                      from every tile but its K/V must reach the cache)
 
+# Mosaic slices a tiled dimension at a dynamic offset only when it can
+# prove the offset a multiple of the tile's sublane rows (8 of f32, 16
+# of bf16).  The ingest kernel DMAs each chunk of RAW rows from
+# ``[kvbase + kvstart, +chunk)`` of the flat axis, so a compiled launch
+# needs every request's flat-axis base (and the chunk width) on this
+# alignment; the planner reports it as ``stats["ingest_kv_aligned"]``.
+INGEST_KV_ALIGN = 16
+
 _POPCNT = np.array([bin(i).count("1") for i in range(256)], np.int64)
+
+
+def ingest_rows_aligned(kv_bases, chunk_tokens: int) -> bool:
+    """Whether every raw-row DMA source of an ingest plan over these
+    per-request flat-axis bases starts on :data:`INGEST_KV_ALIGN` — the
+    fact every fused-ingest adopter gates the compiled launch on."""
+    return chunk_tokens % INGEST_KV_ALIGN == 0 and not np.any(
+        np.asarray(kv_bases, np.int64) % INGEST_KV_ALIGN)
 
 
 def mask_lane_bytes(chunk_tokens: int) -> int:
@@ -446,6 +462,8 @@ def build_prefill_work_units(
         # chunks the ingest launch writes back (== the append traffic
         # the cost model prices): one owner unit per (request, chunk)
         stats["ingest_chunks"] = int(sum(wkv))
+        stats["ingest_kv_aligned"] = ingest_rows_aligned(
+            [kv_bases[u[10]] for u in units if u[10] >= 0], chunk_tokens)
     # pad units: first=0 (no q fetch/wait), wout=0 (never write), empty
     # row span + kvlen 0 (identity online-softmax steps)
     pad_unit = [0, 0, 0, 0, 0, 0, CODE_PARTIAL,
@@ -629,6 +647,7 @@ def _fused_prefill_ingest_kernel(
     kv_quant: str,
     k_scale: float,
     v_scale: float,
+    kv_aligned: bool,
 ):
     """The fused-INGEST work-unit mainloop (ISSUE 14 tentpole): the same
     pipelined online-softmax walk as :func:`_fused_prefill_kernel`, but
@@ -687,10 +706,11 @@ def _fused_prefill_ingest_kernel(
 
     # trace-time constant inverse frequencies — the _rope_freqs formula
     # verbatim (so the in-kernel rotation is bitwise rotate_at_positions)
-    # on a [1, half] 2-D iota (Mosaic has no 1-D iota)
+    # on a [1, half] 2-D iota (Mosaic has no 1-D iota, and only an
+    # integer one: tpu.iota refuses a float result, so cast after)
     inv = 1.0 / (rope_scale * rope_theta ** (
-        2.0 * jax.lax.broadcasted_iota(jnp.float32, (1, half), 1)
-        / head_dim))
+        2.0 * jax.lax.broadcasted_iota(jnp.int32, (1, half), 1)
+        .astype(jnp.float32) / head_dim))
 
     def _rot(x, pos):
         """RoPE x [rows, head_dim] at integer positions [rows, 1] —
@@ -729,6 +749,8 @@ def _fused_prefill_ingest_kernel(
         # ONE contiguous DMA per chunk and tensor: raw rows live at
         # [kvbase + kvstart, +chunk) of the flat axis — no page walk
         src = kvbase_ref[unit] + kvstart_ref[unit]
+        if kv_aligned:  # the plan's promise; Mosaic cannot prove it
+            src = pl.multiple_of(src, INGEST_KV_ALIGN)
         return [
             pltpu.make_async_copy(
                 k_hbm.at[hkv, pl.ds(src, chunk_tokens)], kbuf.at[slot],
@@ -1311,7 +1333,7 @@ def fused_paged_prefill(
         ),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024,
             has_side_effects=True,
         ),
@@ -1352,7 +1374,7 @@ def fused_paged_prefill(
         "num_units", "block_q", "pages_per_chunk", "sm_scale",
         "logits_soft_cap", "window_left", "causal", "return_lse",
         "attend", "rope_scale", "rope_theta", "rope_interleave",
-        "kv_quant", "k_scale", "v_scale",
+        "kv_quant", "k_scale", "v_scale", "kv_aligned",
     ),
 )
 def fused_paged_prefill_ingest(
@@ -1378,6 +1400,7 @@ def fused_paged_prefill_ingest(
     kv_quant: str = "none",  # "none" | "int8" | "fp8"
     k_scale: float = 1.0,  # quant-append scales: high_precision = code * scale
     v_scale: float = 1.0,
+    kv_aligned: bool = False,  # the plan's stats["ingest_kv_aligned"]
 ):
     """Fused prefill INGEST launch: RoPE + KV-quantize-append folded
     into the work-unit prefill mainloop (ISSUE 14 tentpole; the TPU
@@ -1396,7 +1419,19 @@ def fused_paged_prefill_ingest(
     output — returns just the updated ``(k_cache, v_cache)``.
 
     Rotation covers the FULL head_dim (``rotary_dim == head_dim``);
-    partial-rotary callers stay on the separate-op composition."""
+    partial-rotary callers stay on the separate-op composition.
+
+    ``kv_aligned`` is the plan's ``stats["ingest_kv_aligned"]``: every
+    raw-row DMA source starts on :data:`INGEST_KV_ALIGN`.  The compiled
+    kernel needs it (see the constant); interpret mode does not."""
+    if not kv_aligned and not use_interpret():
+        raise ValueError(
+            "fused_paged_prefill_ingest: this plan's raw K/V rows do not "
+            f"all start on a multiple of {INGEST_KV_ALIGN} rows of the "
+            "flat axis (stats['ingest_kv_aligned'] is False), and Mosaic "
+            "cannot slice the tiled row axis at an unaligned dynamic "
+            "offset — run the composed rope -> append -> attention ops "
+            "for this layout")
     total_kv, Hkv, D = k_new.shape
     page_size = k_cache.shape[2]
     chunk_tokens = pages_per_chunk * page_size
@@ -1503,10 +1538,11 @@ def fused_paged_prefill_ingest(
             rope_scale=rope_scale, rope_theta=rope_theta,
             rope_interleave=rope_interleave, kv_quant=kv_quant,
             k_scale=float(k_scale), v_scale=float(v_scale),
+            kv_aligned=kv_aligned,
         ),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024,
             has_side_effects=True,
         ),

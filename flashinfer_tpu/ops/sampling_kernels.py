@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from flashinfer_tpu.utils import round_up, tpu_compiler_params, use_interpret
+from flashinfer_tpu.utils import round_up, use_interpret
 
 _BISECT_ITERS = 32
 _NEG_INF = -1e30
@@ -233,7 +233,7 @@ def _launch_bisect(kernel, x, scalars, out_cols, block_rows):
         ],
         out_specs=pl.BlockSpec((block_rows, oc), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rpad, oc), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024,
         ),
         interpret=use_interpret(),

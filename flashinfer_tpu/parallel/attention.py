@@ -32,8 +32,7 @@ from jax.sharding import PartitionSpec as P
 from flashinfer_tpu.ops.flash_attention import flash_attention
 from flashinfer_tpu.ops.merge import merge_state
 from flashinfer_tpu.ops.xla_ref import xla_ragged_attention
-from flashinfer_tpu.utils import (get_sm_scale, is_tpu, jax_shard_map,
-                                  lax_axis_size)
+from flashinfer_tpu.utils import get_sm_scale, is_tpu
 
 
 def _attn(q, k, v, q_pos, kv_pos, *, causal, sm_scale, use_pallas):
@@ -60,7 +59,7 @@ def ulysses_attention(
     """All-to-all sequence parallel attention (call inside shard_map).
 
     Requires num heads divisible by the axis size."""
-    cp = lax_axis_size(axis)
+    cp = jax.lax.axis_size(axis)
     if q.shape[1] % cp or k.shape[1] % cp:
         raise ValueError(
             f"ulysses needs qo heads ({q.shape[1]}) and kv heads "
@@ -100,7 +99,7 @@ def ring_attention(
     ``[r*chunk, (r+1)*chunk)``.  Each of the cp steps computes a partial
     against the currently-held KV chunk and rotates KV to the next rank
     (bidirectional-bandwidth zigzag scheduling is a later optimization)."""
-    cp = lax_axis_size(axis)
+    cp = jax.lax.axis_size(axis)
     me = jax.lax.axis_index(axis)
     chunk = q.shape[0]
     sm_scale = get_sm_scale(q.shape[-1], sm_scale)
@@ -158,7 +157,7 @@ class ParallelAttention:
 
         spec = P(axis, None, None)
         self._call = jax.jit(
-            jax_shard_map(
+            jax.shard_map(
                 local, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
                 check_vma=False,
             )

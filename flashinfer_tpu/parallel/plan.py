@@ -590,9 +590,8 @@ def build_sharded_fused_step(spec: Int8ShardSpec, plan: ShardingPlan, *,
                              "in_specs pytree is built up front)")
         comm = _ShardMapComm(spec, plan)
         in_sh, out_sh = plan.shard_step_shardings(num_layers)
-        from flashinfer_tpu.utils import jax_shard_map
 
-        mapped = jax_shard_map(
+        mapped = jax.shard_map(
             lambda *a: _body(*a, comm), mesh=plan.mesh,
             in_specs=plan.spec_tree(in_sh),
             out_specs=plan.spec_tree(out_sh), check_vma=False,

@@ -138,25 +138,10 @@ def _tuned_flash(
         default=_FLASH_BLOCK_CANDIDATES[0],
         module=_fa_module,
     )
-    from flashinfer_tpu import compile_guard
-
-    try:
-        return compile_guard.guarded(
-            "flash_attention",
-            # key buckets shapes; the remaining jit statics must also be in
-            # the fingerprint so their recompiles stay inside the guard
-            (key, int(bq), int(bkv), float(sm_scale),
-             float(logits_soft_cap), int(window_left), bool(return_lse)),
-            lambda: flash_attention(
-                q, k, v, q_seg, kv_seg, q_pos, kv_pos,
-                block_q=int(bq), block_kv=int(bkv), **kwargs,
-            ),
-            module=_fa_module,
-        )
-    except compile_guard.KernelQuarantined:
-        return xla_ragged_attention(
-            q, k, v, q_seg, kv_seg, q_pos, kv_pos, **kwargs
-        )
+    return flash_attention(
+        q, k, v, q_seg, kv_seg, q_pos, kv_pos,
+        block_q=int(bq), block_kv=int(bkv), **kwargs,
+    )
 
 
 @flashinfer_api
@@ -1119,7 +1104,6 @@ class BatchPrefillWithPagedKVCacheWrapper:
             k_cache, v_cache = paged_kv_cache[:, 0], paged_kv_cache[:, 1]
         if self._fused_plan is not None and not return_lse:
             # fused work-unit kernel: KV pages DMA'd straight from the cache
-            from flashinfer_tpu import compile_guard
             from flashinfer_tpu.ops import paged_prefill as _pp_module
             from flashinfer_tpu.ops.paged_prefill import fused_paged_prefill
 
@@ -1193,26 +1177,14 @@ class BatchPrefillWithPagedKVCacheWrapper:
                     unit_plan, statics, self._fused_stats = _build(best)
                     self._fused_plan = (unit_plan, statics)
 
-            try:
-                out = compile_guard.guarded(
-                    "fused_paged_prefill",
-                    (q.shape, k_hnd.shape, str(q.dtype), plan.causal,
-                     plan.window_left, float(plan.sm_scale),
-                     float(plan.logits_soft_cap),
-                     "mask_bytes" in unit_plan,  # masked kernel variant
-                     tuple(sorted(statics.items()))),
-                    lambda: fused_paged_prefill(
-                        q, k_hnd, v_hnd, unit_plan,
-                        sm_scale=plan.sm_scale,
-                        logits_soft_cap=plan.logits_soft_cap,
-                        window_left=plan.window_left, causal=plan.causal,
-                        **statics,
-                    ),
-                    module=_pp_module,
-                )
-                return out[:total_q]
-            except compile_guard.KernelQuarantined:
-                pass  # fall through to the gather + flash path below
+            out = fused_paged_prefill(
+                q, k_hnd, v_hnd, unit_plan,
+                sm_scale=plan.sm_scale,
+                logits_soft_cap=plan.logits_soft_cap,
+                window_left=plan.window_left, causal=plan.causal,
+                **statics,
+            )
+            return out[:total_q]
         if plan.kv_gather_rows is None:
             # fused plan was active but this call needs the gather path
             # (return_lse): materialize the deferred plan once, rebinds
@@ -1272,8 +1244,17 @@ class BatchPrefillWithPagedKVCacheWrapper:
         recorder's replan diffs see which mode served."""
         plan = self._plan
         if plan.fused_ingest is None:
+            from flashinfer_tpu.ops.paged_prefill import (
+                ingest_rows_aligned)
+
+            # the raw rows are request-contiguous: bases = cumsum(kv);
+            # the compiled kernel needs them aligned (INGEST_KV_ALIGN)
+            kvl = np.asarray(self._fused_raw[3], np.int64)
             fkey = self._fused_raw[5]
-            resolved = resolve_prefill_ingest(
+            resolved = ingest_rows_aligned(
+                np.cumsum(kvl)[:-1],
+                self._fused_plan[1]["pages_per_chunk"] * self._fused_raw[4],
+            ) and resolve_prefill_ingest(
                 fkey, total_q=plan.total_q, total_kv=plan.total_kv,
                 num_qo_heads=plan.num_qo_heads,
                 num_kv_heads=plan.num_kv_heads, head_dim=plan.head_dim)
@@ -1358,8 +1339,6 @@ class BatchPrefillWithPagedKVCacheWrapper:
                 "the WHOLE planned KV — from-scratch prefill)")
 
         if self._resolve_ingest():
-            from flashinfer_tpu import compile_guard
-            from flashinfer_tpu.ops import paged_prefill as _pp_module
             from flashinfer_tpu.ops.paged_prefill import (
                 build_prefill_ingest_units, fused_paged_prefill_ingest,
             )
@@ -1388,36 +1367,22 @@ class BatchPrefillWithPagedKVCacheWrapper:
             if total_q != plan.tq_pad:
                 q = jnp.pad(q, ((0, plan.tq_pad - total_q), (0, 0),
                                 (0, 0)))
-            try:
-                res = compile_guard.guarded(
-                    "fused_paged_prefill_ingest",
-                    (q.shape, k_new.shape, str(q.dtype),
-                     str(k_cache.dtype), plan.causal, plan.window_left,
-                     float(plan.sm_scale), float(plan.logits_soft_cap),
-                     rope_scale, rope_theta, rope_interleave, kv_quant,
-                     ks, vs, return_lse,
-                     "mask_bytes" in unit_plan,
-                     tuple(sorted(ist.items()))),
-                    lambda: fused_paged_prefill_ingest(
-                        q, k_new, v_new, k_cache, v_cache, unit_plan,
-                        sm_scale=plan.sm_scale,
-                        logits_soft_cap=plan.logits_soft_cap,
-                        window_left=plan.window_left, causal=plan.causal,
-                        return_lse=return_lse, rope_scale=rope_scale,
-                        rope_theta=rope_theta,
-                        rope_interleave=rope_interleave,
-                        kv_quant=kv_quant, k_scale=ks, v_scale=vs,
-                        **ist,
-                    ),
-                    module=_pp_module,
-                )
-                if return_lse:
-                    out, lse, caches = res
-                    return out[:total_q], lse[:total_q], caches
-                out, caches = res
-                return out[:total_q], caches
-            except compile_guard.KernelQuarantined:
-                q = q[:total_q]  # fall through to the composed oracle
+            res = fused_paged_prefill_ingest(
+                q, k_new, v_new, k_cache, v_cache, unit_plan,
+                sm_scale=plan.sm_scale,
+                logits_soft_cap=plan.logits_soft_cap,
+                window_left=plan.window_left, causal=plan.causal,
+                return_lse=return_lse, rope_scale=rope_scale,
+                rope_theta=rope_theta, rope_interleave=rope_interleave,
+                kv_quant=kv_quant, k_scale=ks, v_scale=vs,
+                kv_aligned=self._ingest_stats["ingest_kv_aligned"],
+                **ist,
+            )
+            if return_lse:
+                out, lse, caches = res
+                return out[:total_q], lse[:total_q], caches
+            out, caches = res
+            return out[:total_q], caches
 
         # ---- the separate-op composition (the oracle tier) ----
         from flashinfer_tpu.page import (

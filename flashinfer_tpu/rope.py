@@ -518,12 +518,14 @@ def _fused_ingest_append(
             num_units=plan_np.pop("num_units"),
             block_q=plan_np.pop("block_q"),
             pages_per_chunk=plan_np.pop("pages_per_chunk"),
+            kv_aligned=plan_np.pop("stats")["ingest_kv_aligned"],
         )
-        plan_np.pop("stats")
         plan = {k: jnp.asarray(a) for k, a in plan_np.items()}
         _INGEST_PLAN_CACHE[plan_key] = (plan, statics)
         if len(_INGEST_PLAN_CACHE) > _INGEST_PLAN_CAP:
             _INGEST_PLAN_CACHE.popitem(last=False)
+    if not statics["kv_aligned"]:
+        return None  # whole-page runs of a page narrower than the tile
     scale = 1.0 / max(quant_scale_kv, 1e-12)
     return fused_paged_prefill_ingest(
         None, k_rope, v, k_cache, v_cache, plan,

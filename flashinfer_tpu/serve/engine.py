@@ -603,6 +603,10 @@ class ServingEngine:
                 num_splits=config.decode_num_splits,
                 fused_ingest=self._ingest,
             )
+        # each rung is one whole-model compile: keep it across processes
+        from flashinfer_tpu import env
+
+        env.enable_compilation_cache()
         self._build_step()
 
     # -- public surface ---------------------------------------------------

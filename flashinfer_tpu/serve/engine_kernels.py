@@ -313,17 +313,21 @@ def build_engine_work_units(
     # irrelevant here — it merely PARTITIONS the causal attention the
     # single launch computes whole (merge is associative), and no
     # cross-request page is shared on a from-scratch step (fresh pages
-    # per request).  Non-qualifying steps carry the padding-only
+    # per request).  Each request's rows must also start on
+    # INGEST_KV_ALIGN of the flat axis — the compiled kernel's raw-row
+    # DMA needs it.  Non-qualifying steps carry the padding-only
     # degenerate plan with ``ingest_on = 0`` — the body's lax.cond
     # keeps the composed tier.
     ingest_plan = None
     ingest_on = 0
     if geom.fused_ingest:
         from flashinfer_tpu.ops.paged_prefill import (
-            build_prefill_ingest_units)
+            build_prefill_ingest_units, ingest_rows_aligned)
 
         eligible = bool(segs) and all(
-            s.kv_after == s.n and not s.decoding for s in segs)
+            s.kv_after == s.n and not s.decoding for s in segs
+        ) and ingest_rows_aligned([s.row0 for s in segs],
+                                  geom.prefill_ppc * ps)
         if eligible:
             qoI = [0]
             kvI: List[int] = []
@@ -366,6 +370,7 @@ def build_engine_work_units(
             (ingest_plan["qstart"].shape, U)
         assert ingest_plan["stats"].get("ingest_write_only_units",
                                         0) == 0
+        assert ingest_plan["stats"]["ingest_kv_aligned"]
 
     chunk_tokens = geom.prefill_ppc * ps
     stats = {
@@ -493,5 +498,6 @@ def engine_kernel_ingest_attention(q, k, v, k_cache, v_cache, kplans, *,
         causal=True, rope_theta=float(rope_theta),
         kv_quant=kv_quant, k_scale=float(k_scale),
         v_scale=float(v_scale),
+        kv_aligned=True,  # build_engine_work_units' eligibility rule
     )
     return attn, kc, vc

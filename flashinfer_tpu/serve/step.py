@@ -592,7 +592,15 @@ class MixedServingStep:
         # single resolution point the wrapper uses (prefill.py)
         if fused_ingest is None:
             use_ingest = False
-            if ingest_eligible and resolved == "pallas":
+            from flashinfer_tpu.ops.paged_prefill import (
+                ingest_pages_per_chunk, ingest_rows_aligned)
+
+            # the raw rows are the flat token axis: bases = qo_indptr;
+            # the compiled kernel needs them aligned (INGEST_KV_ALIGN)
+            if ingest_eligible and resolved == "pallas" \
+                    and ingest_rows_aligned(
+                        qo_indptr[:-1],
+                        ingest_pages_per_chunk(page_size) * page_size):
                 from flashinfer_tpu.prefill import resolve_prefill_ingest
 
                 fkey = (B, int(arrays["tq_pad"]), cfg.num_qo_heads,
@@ -630,8 +638,8 @@ class MixedServingStep:
                 num_units=iplan_np.pop("num_units"),
                 block_q=iplan_np.pop("block_q"),
                 pages_per_chunk=iplan_np.pop("pages_per_chunk"),
+                kv_aligned=iplan_np.pop("stats")["ingest_kv_aligned"],
             )
-            iplan_np.pop("stats")
             ingest_plan = {k2: jnp.asarray(v2)
                            for k2, v2 in iplan_np.items()}
         self._plan = _MixedPlan(

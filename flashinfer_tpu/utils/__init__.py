@@ -156,74 +156,6 @@ def use_interpret() -> bool:
     return env.force_interpret() or not is_tpu()
 
 
-_dropped_compiler_params: set = set()
-
-
-def tpu_compiler_params(**kw):
-    """Version-portable ``pltpu.CompilerParams``: JAX renamed
-    ``TPUCompilerParams`` -> ``CompilerParams`` across the versions this
-    library supports, and a hard reference to either name makes every
-    Pallas launch raise AttributeError on the other side of the rename.
-
-    Fields the installed version's dataclass doesn't declare are dropped
-    WITH a once-per-field warning, not fatally: on the old side of the
-    rename there is no way to express them at all, and a crashed launch
-    is strictly worse than a missing hint.  The drop is not always
-    numerics-neutral — losing ``has_side_effects`` un-marks an
-    effectful kernel and lets XLA DCE it when its outputs go unused —
-    so the warning names the field and the risk instead of hiding it."""
-    import dataclasses
-    import logging
-
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    known = {f.name for f in dataclasses.fields(cls)}
-    for k in kw:
-        if k not in known and (cls.__name__, k) not in \
-                _dropped_compiler_params:
-            _dropped_compiler_params.add((cls.__name__, k))
-            logging.getLogger("flashinfer_tpu").warning(
-                "dropping Pallas compiler param %r: this JAX's %s does "
-                "not declare it (known: %s). If this is "
-                "'has_side_effects', ensure every launch's outputs are "
-                "consumed or the kernel may be dead-code-eliminated.",
-                k, cls.__name__, sorted(known))
-    return cls(**{k: v for k, v in kw.items() if k in known})
-
-
-def jax_shard_map(f, **kw):
-    """Version-portable ``jax.shard_map``: the API graduated from
-    ``jax.experimental.shard_map.shard_map`` (where the replication
-    check is spelled ``check_rep``) to ``jax.shard_map`` (spelled
-    ``check_vma``).  Callers use the graduated spelling; this adapter
-    translates when running on the experimental version."""
-    import inspect
-
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        params = inspect.signature(sm).parameters
-    except (TypeError, ValueError):
-        params = {}
-    if "check_vma" in kw and "check_vma" not in params \
-            and "check_rep" in params:
-        kw["check_rep"] = kw.pop("check_vma")
-    return sm(f, **kw)
-
-
-def lax_axis_size(axis_name: str) -> int:
-    """Static size of a named mesh axis from inside shard_map/pmap —
-    ``jax.lax.axis_size`` where it exists, else the classic
-    ``psum(1, axis)`` spelling (which constant-folds to a Python int)."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 # Reference attention-backend names (``flashinfer/utils.py:522``
 # determine_attention_backend picks fa2/fa3/trtllm-gen/... per CUDA arch;
 # wrapper ctors accept them verbatim, e.g. mla/_core.py:1397 backend=).
@@ -558,15 +490,11 @@ def get_device_sm_count(device=None) -> int:
 
 def get_gpu_memory_bandwidth(device=None) -> float:
     """HBM peak in GB/s for the attached chip (reference queries CUDA;
-    here the bench table in bench.py is the source of truth)."""
-    import jax
+    here obs/hwspec.py's table is the one source, and a TPU that is not
+    in it raises)."""
+    from flashinfer_tpu.obs import hwspec
 
-    kind = jax.devices()[0].device_kind.lower().replace(" ", "")
-    peaks = {"v5p": 2765.0, "v6e": 1640.0, "v4": 1228.0}
-    for key, val in peaks.items():
-        if key in kind:
-            return val
-    return 819.0  # v5e / default
+    return hwspec.current_spec().hbm_tbps * 1000.0
 
 
 def get_cuda_python_version():

@@ -23,20 +23,16 @@ if not _ON_TPU:
         + " --xla_force_host_platform_device_count=8"
     )
     os.environ["JAX_PLATFORMS"] = "cpu"
-    # Persistent XLA cache for the CPU suite: a full run compiles
-    # thousands of executables per worker, and this host's LLVM has
-    # produced one SEGFAULT class and one unreproducible single-test
-    # numerical flake in exactly that regime (ROUND_NOTES suite-scale
-    # note).  Env-var form on purpose: no package import at collection
-    # time, and env._CACHE_ENABLED stays False so tests that monkeypatch
-    # FLASHINFER_TPU_CACHE_DIR + call enable_compilation_cache() keep
-    # their hermetic behavior.  SUITE-scoped directory on purpose: if a
-    # miscompile of the flake class ever lands in the cache, deleting
-    # this dir is consequence-free (the production cache is untouched).
+    # Persistent XLA cache for the CPU suite (a full run compiles
+    # thousands of executables per worker), by the program's own rule
+    # (flashinfer_tpu/env.py xla_cache_dir): JAX_COMPILATION_CACHE_DIR
+    # where it is set, else the checkout's one fixed git-ignored
+    # directory.  Env-var form on purpose: no package import at
+    # collection time.
     os.environ.setdefault(
         "JAX_COMPILATION_CACHE_DIR",
-        os.path.join(os.path.expanduser("~"), ".cache", "flashinfer_tpu",
-                     "xla_cache_cpu_suite"))
+        os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), ".xla_cache"))
     os.environ.setdefault(
         "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.5")
 

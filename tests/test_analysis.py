@@ -459,7 +459,7 @@ def test_l003_composed_jit_wrap_marks_inner_callable():
                 if os.environ.get("FLAG"):
                     return x
                 return x + 1
-            return jax.jit(jax_shard_map(step, mesh=mesh, **specs))
+            return jax.jit(jax.shard_map(step, mesh=mesh, **specs))
     """
     findings = jit_staticness.run(_project(("m.py", src)))
     assert [f.func for f in findings] == ["step"], findings

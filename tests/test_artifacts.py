@@ -23,7 +23,11 @@ def fake_cache(tmp_path, monkeypatch):
         json.dumps({"meta": {}, "tactics": {"k": 1}})
     )
     monkeypatch.setenv("FLASHINFER_TPU_CACHE_DIR", str(root))
+    # the XLA executables live where JAX's own variable says, not under
+    # FLASHINFER_TPU_CACHE_DIR
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(root / "xla_cache"))
     assert env.cache_dir() == root
+    assert env.xla_cache_dir() == root / "xla_cache"
     return root
 
 
@@ -129,6 +133,27 @@ def test_clear_artifacts(fake_cache):
     assert not (fake_cache / "autotuner").exists()
     # shipped tuning configs untouched
     assert artifacts.get_available_header_files()
+
+
+def test_xla_cache_is_placed_from_outside(monkeypatch):
+    """Where JAX_COMPILATION_CACHE_DIR is set the program uses it and
+    sets no other in code; unset, one fixed git-ignored directory of the
+    checkout (the path is part of the cache key)."""
+    from pathlib import Path
+
+    import jax
+
+    from flashinfer_tpu import env
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/from/outside")
+    before = jax.config.jax_compilation_cache_dir
+    env.enable_compilation_cache()
+    assert env.xla_cache_dir() == Path("/placed/from/outside")
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    repo = Path(env.__file__).resolve().parent.parent
+    assert env.xla_cache_dir() == repo / ".xla_cache"
+    assert ".xla_cache/" in (repo / ".gitignore").read_text().split()
 
 
 def test_temp_env_var(monkeypatch):

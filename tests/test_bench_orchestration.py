@@ -27,8 +27,8 @@ def bench_mod():
 
 def test_phase_rows_survive_timeout(bench_mod, monkeypatch):
     monkeypatch.setenv("BENCH_SELFTEST_HANG", "1")
-    # window must cover phase-subprocess startup (apply_platform_from_env
-    # imports jax, ~2-5s) before the rows land and the hang begins
+    # window must cover phase-subprocess startup (importing jax, ~2-5s)
+    # before the rows land and the hang begins
     rows, ok, detail = bench_mod._run_phase("selftest", False, timeout_s=20)
     assert not ok
     assert "timed out" in detail

@@ -132,7 +132,7 @@ def test_sim_wedge_subprocess_times_out(tmp_path):
     """The one real-subprocess pin: the sim rung sleeps past its
     timeout and _spawn_rung must kill it and report a wedge."""
     res = bringup._spawn_rung({"rung_id": bringup.SIM_WEDGE_RUNG},
-                              timeout_s=3.0, interpret=True)
+                              timeout_s=1.0, interpret=True)
     assert res["outcome"] == "wedge"
     assert "timed out" in res["detail"]
 

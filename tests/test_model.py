@@ -238,7 +238,7 @@ def test_generate_example_all_families(mode):
 
     env = dict(os.environ)
     env.pop("PYTHONPATH", None)
-    args = [sys.executable, "examples/generate.py", "cpu"]
+    args = [sys.executable, "examples/generate.py"]  # env: JAX_PLATFORMS=cpu
     if mode:
         args.append(mode)
     r = subprocess.run(

@@ -4,7 +4,6 @@ per-expert loop, EP vs single-device (mirrors reference tests/moe strategy)."""
 import jax
 import jax.numpy as jnp
 
-from flashinfer_tpu.utils import jax_shard_map
 import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
@@ -120,7 +119,7 @@ def test_fused_moe_ep_alltoall_matches_single_device():
         )
 
     out = jax.jit(
-        jax_shard_map(
+        jax.shard_map(
             fn, mesh=mesh,
             in_specs=(P("tp"), P("tp"), P("tp"), P("tp"), P("tp")),
             out_specs=P("tp"),
@@ -150,7 +149,7 @@ def test_fused_moe_ep_matches_single_device():
         return moe.fused_moe_ep(x, w1, w2, wts, ids, E, axis="tp")
 
     out = jax.jit(
-        jax_shard_map(
+        jax.shard_map(
             fn, mesh=mesh,
             in_specs=(P("tp"), P("tp"), P("tp"), P("tp"), P("tp")),
             out_specs=P("tp"),
@@ -310,7 +309,7 @@ def test_fused_moe_ep_alltoall_capacity_drops():
         )
 
     out, dropped = jax.jit(
-        jax_shard_map(
+        jax.shard_map(
             fn, mesh=mesh,
             in_specs=(P("tp"), P("tp"), P("tp"), P("tp"), P("tp")),
             out_specs=(P("tp"), P("tp")),
@@ -378,7 +377,7 @@ def test_fused_moe_ep_alltoall_exact_no_drop_under_overflow():
         )
 
     out, dropped = jax.jit(
-        jax_shard_map(
+        jax.shard_map(
             fn, mesh=mesh,
             in_specs=(P("tp"), P("tp"), P("tp"), P("tp"), P("tp")),
             out_specs=(P("tp"), P("tp")),
@@ -413,7 +412,7 @@ def test_fused_moe_ep_alltoall_exact_balanced_routing():
         )
 
     out = jax.jit(
-        jax_shard_map(
+        jax.shard_map(
             fn, mesh=mesh,
             in_specs=(P("tp"), P("tp"), P("tp"), P("tp"), P("tp")),
             out_specs=P("tp"),
@@ -460,7 +459,7 @@ def test_fused_moe_ep_alltoall_exact_fuzz(seed, ep):
         )
 
     out, dropped = jax.jit(
-        jax_shard_map(
+        jax.shard_map(
             fn, mesh=mesh,
             in_specs=(P("tp"),) * 5, out_specs=(P("tp"), P("tp")),
             check_vma=False,

@@ -1,0 +1,268 @@
+"""The main path's kernels, compiled for a v5e that is described and not
+attached (guide `on-chip-measurement` section 2).
+
+Interpret mode checks what a kernel computes; only the TPU compiler says
+whether Mosaic accepts it.  The compiler is installed here, so every
+tier-1 run asks it — at Llama-3-8B widths, for the kernels the serving
+engine launches and for the whole engine step on a prefill rung and on the
+decode rung.  A pass is a compile, never a chip run.
+
+Only one process may load the TPU's library, so the topology is described
+inside a module-scoped fixture of THIS file (never at import, in a
+``skipif``, in ``parametrize`` or in conftest.py), every compile happens in
+the test's own process, and all such tests live in this one file.  The
+persistent compilation cache is off around them: a TPU executable written
+to it cannot be read back without a chip.
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+HQ, HKV, D, PS, VOCAB = 32, 8, 128, 16, 128256
+SM = D ** -0.5
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever keeps it from describing
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """One described v5e chip, with the compilation cache off and no
+    trace of this process's interpret-mode runs (or, after, of ours) in
+    JAX's own caches: an inner jit does not key on ``is_tpu``."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    jax.clear_caches()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.clear_caches()
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _as_on_a_v5e(mp):
+    """Steer ``use_interpret()`` / ``resolve_backend()`` onto the branch a
+    TPU takes (``utils.is_tpu`` is a ``functools.cache``: replace the
+    name, do not call it) and load the shipped v5e tuning table."""
+    from flashinfer_tpu import autotuner, utils
+
+    mp.setattr(utils, "is_tpu", lambda: True)
+    mp.setattr(autotuner, "_device_config_key", lambda: "v5e")
+    mp.setattr(autotuner.AutoTuner, "_instance", None)
+
+
+@pytest.fixture
+def compiled_branch(monkeypatch):
+    _as_on_a_v5e(monkeypatch)
+
+
+def _sds(chip, shape, dtype):
+    return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype), sharding=chip)
+
+
+def _like(chip, tree):
+    return jax.tree.map(lambda a: _sds(chip, a.shape, a.dtype), tree)
+
+
+def _compile(fn, *args):
+    """Lower + compile ``fn`` for the described chip; the program must
+    hold a Mosaic kernel.  Raises what the chip's compiler would."""
+    lowered = jax.jit(fn).lower(*args)
+    assert "tpu_custom_call" in lowered.as_text(), "no Pallas kernel lowered"
+    return lowered.compile()
+
+
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+def test_paged_decode(chip, compiled_branch, kv_dtype):
+    from flashinfer_tpu.ops import paged_decode_attention
+
+    B, ppr = 64, 128
+    cache = _sds(chip, (B * ppr, HKV, PS, D), kv_dtype)
+    _compile(
+        lambda q, kc, vc, pt, lens: paged_decode_attention(
+            q, kc, vc, pt, lens, sm_scale=SM, kv_layout="HND"),
+        _sds(chip, (B, HQ, D), jnp.bfloat16), cache, cache,
+        _sds(chip, (B, ppr), jnp.int32), _sds(chip, (B,), jnp.int32))
+
+
+def test_split_kv_decode(chip, compiled_branch):
+    from flashinfer_tpu.ops.paged_decode import (
+        build_decode_split_units, paged_decode_attention_split,
+        split_pages_per_chunk)
+
+    B, ppr, S = 64, 32, 2
+    ppc = split_pages_per_chunk(PS, HKV, D, 2)
+    plan = build_decode_split_units(
+        np.arange(B * ppr, dtype=np.int32).reshape(B, ppr),
+        np.full(B, ppr * PS, np.int64), num_splits=S, page_size=PS,
+        pages_per_chunk=ppc)
+    arrays = {k: plan[k] for k in
+              ("pages", "kvlen", "wu_req", "wu_page0", "wu_kvlen")}
+    cache = _sds(chip, (B * ppr, HKV, PS, D), jnp.bfloat16)
+    _compile(
+        lambda q, kc, vc, p: paged_decode_attention_split(
+            q, kc, vc, p, num_units=int(plan["wu_req"].shape[0]),
+            num_splits=S, single_chunk=bool(plan["single_chunk"]),
+            pages_per_chunk=ppc, sm_scale=SM),
+        _sds(chip, (B, HQ, D), jnp.bfloat16), cache, cache,
+        _like(chip, arrays))
+
+
+def test_flash_attention(chip, compiled_branch):
+    from flashinfer_tpu.ops import flash_attention
+
+    T = 2048
+    q = _sds(chip, (T, HQ, D), jnp.bfloat16)
+    kv = _sds(chip, (T, HKV, D), jnp.bfloat16)
+    i32 = _sds(chip, (T,), jnp.int32)
+    _compile(
+        lambda q, k, v, seg, pos: flash_attention(
+            q, k, v, seg, seg, pos, pos, causal=True, sm_scale=SM),
+        q, kv, kv, i32, i32)
+
+
+def _prefill_geometry():
+    qo = np.asarray([0, 512, 640, 1664, 1701], np.int64)
+    kv_lens = np.asarray([1024, 512, 2048, 333], np.int64)
+    pages = -(-kv_lens // PS)
+    return qo, np.concatenate([[0], np.cumsum(pages)]), \
+        np.arange(int(pages.sum()), dtype=np.int64), kv_lens
+
+
+def test_work_unit_paged_prefill(chip, compiled_branch):
+    from flashinfer_tpu.ops.paged_prefill import (build_prefill_work_units,
+                                                  fused_paged_prefill)
+
+    qo, kvp, kvi, kv_lens = _prefill_geometry()
+    plan = build_prefill_work_units(qo, kvp, kvi, kv_lens, block_q=128,
+                                    pages_per_chunk=8, page_size=PS)
+    statics = {k: plan.pop(k)
+               for k in ("num_units", "block_q", "pages_per_chunk")}
+    plan.pop("stats")
+    cache = _sds(chip, (len(kvi), HKV, PS, D), jnp.bfloat16)
+    _compile(
+        lambda q, kc, vc, p: fused_paged_prefill(
+            q, kc, vc, p, sm_scale=SM, causal=True, return_lse=True,
+            **statics),
+        _sds(chip, (int(qo[-1]), HQ, D), jnp.bfloat16), cache, cache,
+        _like(chip, plan))
+
+
+@pytest.mark.parametrize("kv_dtype,kv_quant",
+                         [("bfloat16", "none"), ("int8", "int8")])
+def test_fused_ingest_prefill(chip, compiled_branch, kv_dtype, kv_quant):
+    """Refused until PR 21: a float ``tpu.iota``, then a dynamic slice of
+    the tiled raw-row axis that Mosaic could not prove aligned."""
+    from flashinfer_tpu.ops.paged_prefill import (
+        build_prefill_ingest_units, fused_paged_prefill_ingest,
+        ingest_block_q, ingest_pages_per_chunk)
+
+    # from-scratch prefill of three requests whose rows start aligned
+    lens = np.asarray([512, 256, 48], np.int64)
+    qo = np.concatenate([[0], np.cumsum(lens)])
+    pages = -(-lens // PS)
+    T = int(qo[-1])
+    plan = build_prefill_ingest_units(
+        qo, np.concatenate([[0], np.cumsum(pages)]),
+        np.arange(int(pages.sum()), dtype=np.int64), lens,
+        ingest_block_q(T), ingest_pages_per_chunk(PS), PS, causal=True)
+    statics = {k: plan.pop(k)
+               for k in ("num_units", "block_q", "pages_per_chunk")}
+    assert plan.pop("stats")["ingest_kv_aligned"]
+    cache = _sds(chip, (int(pages.sum()) + 1, HKV, PS, D), kv_dtype)
+    q = _sds(chip, (T, HQ, D), jnp.bfloat16)
+    kv = _sds(chip, (T, HKV, D), jnp.bfloat16)
+    _compile(
+        lambda q, k, v, kc, vc, p: fused_paged_prefill_ingest(
+            q, k, v, kc, vc, p, sm_scale=SM, causal=True, rope_theta=5e5,
+            kv_quant=kv_quant, k_scale=0.05, v_scale=0.05, kv_aligned=True,
+            **statics),
+        q, kv, kv, cache, cache, _like(chip, plan))
+    # rows that do not start aligned never reach the compiler
+    with pytest.raises(ValueError, match="unaligned dynamic"):
+        jax.jit(lambda q, k, v, kc, vc, p: fused_paged_prefill_ingest(
+            q, k, v, kc, vc, p, sm_scale=SM, **statics)).lower(
+            q, kv, kv, cache, cache, _like(chip, plan))
+
+
+def test_sampling_kernels_at_vocab(chip, compiled_branch):
+    from flashinfer_tpu import sampling as S
+
+    probs = _sds(chip, (64, VOCAB), jnp.float32)
+    _compile(lambda p: S.top_p_renorm_probs(S.top_k_renorm_probs(p, 40),
+                                            0.95), probs)
+
+
+@pytest.fixture(scope="module")
+def engine_step(chip):
+    """(engine, its config, rung -> argument shapes) of a 1-layer
+    Llama-3-8B engine as ``EngineConfig.from_knobs`` builds it on a v5e."""
+    from flashinfer_tpu.models.llama import LlamaConfig, init_llama_params
+    from flashinfer_tpu.serve import (EngineConfig, SamplingConfig,
+                                      ServingEngine, engine_kernels as ek)
+
+    mp = pytest.MonkeyPatch()  # module scope: no function-scoped fixture
+    _as_on_a_v5e(mp)
+    try:
+        mcfg = LlamaConfig.llama3_8b(num_layers=1)
+        ecfg = EngineConfig.from_knobs(
+            mcfg, num_pages=512, max_seq_tokens=2048,
+            sampling=SamplingConfig(temperature=0.8, top_k=40, top_p=0.95))
+        params = jax.eval_shape(
+            lambda: init_llama_params(jax.random.PRNGKey(0), mcfg))
+        eng = ServingEngine(mcfg, params, dataclasses.replace(
+            ecfg, num_pages=2))  # shapes only: no 512-page pool on the host
+    finally:
+        mp.undo()
+    caches = [(_sds(chip, (ecfg.num_pages, HKV, ecfg.page_size, D),
+                    eng.kv_dtype),) * 2 for _ in range(mcfg.num_layers)]
+
+    def args(rung):
+        n = -(-rung // ecfg.page_size)
+        plans = ek.build_engine_work_units(
+            [ek.SchedSeg(row0=0, n=rung, pages=tuple(range(1, n + 1)),
+                         split=0, kv_after=rung, decoding=False, slot=0,
+                         group=0)], rung=rung, geom=eng._geom)
+        B, ppr = ecfg.max_batch, eng._ppr
+        host = (params, *(np.zeros(rung, np.int32),) * 5,
+                *(np.zeros((B, ppr), np.int32),) * 2,
+                *(np.zeros(rung, np.int32),) * 2,
+                *(np.zeros(B, np.int32),) * 2, ek.plans_to_device(plans))
+        return _like(chip, host) + (caches,)
+
+    return eng, ecfg, args
+
+
+def test_from_knobs_on_v5e_is_the_kernel_tier(engine_step):
+    _, ecfg, _ = engine_step
+    assert ecfg.attention_backend == "kernel"
+    assert ecfg.fused_ingest == "on"
+    assert ecfg.rungs() == (64, 128, 256, 512, 1024)
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_engine_step(engine_step, compiled_branch, which):
+    """The whole donated step, as from_knobs configures it (fused ingest
+    included), on the decode rung and on a prefill rung."""
+    eng, ecfg, args = engine_step
+    rung = min(ecfg.rungs()) if which == "decode" else 512
+    lowered = eng._step.lower(*args(rung))
+    assert lowered.as_text().count("tpu_custom_call") >= 5
+    mem = lowered.compile().memory_analysis()
+    # one layer + embed + lm_head in bf16, and the KV pool
+    assert 2.0 * 2**30 < mem.argument_size_in_bytes < 3.0 * 2**30

@@ -10,7 +10,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from flashinfer_tpu.utils import jax_shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -177,9 +176,9 @@ def test_moe_ep_fleet_matches_fused_moe_ep():
     specs = dict(
         in_specs=(P("tp"),) * 5, out_specs=P("tp"), check_vma=False,
     )
-    out = jax.jit(jax_shard_map(layer_fn, mesh=mesh, **specs))(
+    out = jax.jit(jax.shard_map(layer_fn, mesh=mesh, **specs))(
         x, w1, w2, wts, ids)
-    ref = jax.jit(jax_shard_map(direct_fn, mesh=mesh, **specs))(
+    ref = jax.jit(jax.shard_map(direct_fn, mesh=mesh, **specs))(
         x, w1, w2, wts, ids)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
     # validators catch real misconfigurations
@@ -211,7 +210,7 @@ def test_comm_moe_a2a_dispatch_combine_roundtrip():
         return moe_a2a_combine(flat, ids, wts, E, axis="tp",
                                capacity_factor=float(ep))
 
-    out = jax.jit(jax_shard_map(
+    out = jax.jit(jax.shard_map(
         fn, mesh=mesh, in_specs=(P("tp"),) * 3, out_specs=P("tp"),
         check_vma=False,
     ))(x, ids, wts)

@@ -386,10 +386,19 @@ def test_row_auditor_never_raises_on_garbage():
     assert a.stamp({"phase": "y", "weird": object()}) is not None
 
 
-def test_load_banked_history_parses_real_bank():
-    rows = bench_audit.load_banked_history(
-        os.path.join(REPO_ROOT, "BENCH_BANKED.md"))
-    assert rows, "committed BENCH_BANKED.md should yield history rows"
+def test_load_banked_history_parses_run_records(tmp_path):
+    # the banked-file format: prose around ```json run records, each
+    # with a "rows" list (bench.py --bank writes it)
+    bank = tmp_path / "BANK.md"
+    bank.write_text(
+        "## run 1\n```json\n" + json.dumps({"rows": [
+            dict(phase="decode", bs=64, ctx=4096, us=1443.0, tbps=0.7448),
+            dict(phase="mla", bs=64, ctx=4096, us=1328.2, tbps=0.2526),
+        ]}) + "\n```\nnotes\n```json\n" + json.dumps({"rows": [
+            dict(phase="decode", bs=1, ctx=512, us=30.0, tbps=0.27),
+        ]}) + "\n```\n")
+    rows = bench_audit.load_banked_history(str(bank))
+    assert len(rows) == 3
     assert any(r.get("phase") == "decode" for r in rows)
     assert bench_audit.load_banked_history("/nonexistent") == []
 

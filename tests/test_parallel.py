@@ -4,7 +4,6 @@
 import jax
 import jax.numpy as jnp
 
-from flashinfer_tpu.utils import jax_shard_map
 import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
@@ -70,7 +69,7 @@ def test_dcp_decode_matches_full():
         return dcp_decode(q, kc, vc, table[0], lens[0], axis="cp", kv_layout="NHD")
 
     out = jax.jit(
-        jax_shard_map(
+        jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(P(), P(), P(), P("cp"), P("cp")),
             out_specs=P(),
@@ -100,7 +99,7 @@ def test_allreduce_fusion_patterns(mesh8):
         return normed, new_res
 
     normed, new_res = jax.jit(
-        jax_shard_map(
+        jax.shard_map(
             fn, mesh=mesh8,
             in_specs=(P("tp"), P(), P()), out_specs=(P(), P()),
             check_vma=False,

@@ -415,8 +415,10 @@ def test_wrapper_run_ingest_errors(monkeypatch):
 def _reroute_args(seed=0):
     # whole-page runs: page-aligned start AND end (the gate's contract
     # — a partial last page would zero rows the composed tier keeps)
-    lens = np.array([24, 8, 16])
-    pos0 = np.array([0, 8, 0])
+    # and each run's flat-axis base on INGEST_KV_ALIGN (the compiled
+    # kernel's raw-row DMA alignment, a second gate of the reroute)
+    lens = np.array([32, 16, 16])
+    pos0 = np.array([0, 16, 0])
     kv_indptr = np.array([0, 4, 8, 12], np.int32)
     kv_indices = np.arange(12, dtype=np.int32)
     bi = np.repeat(np.arange(3), lens).astype(np.int32)
@@ -677,7 +679,9 @@ def test_engine_ingest_token_parity_and_trace_budget():
                 attention_backend=backend, fused_ingest=fused,
                 enable_prefix_cache=False)
             eng = ServingEngine(cfg, params, ec)
-            for i, n in enumerate([11, 5, 19]):
+            # 16 + 16 fill the first step's budget from scratch with
+            # both requests' rows on INGEST_KV_ALIGN: the ingest step
+            for i, n in enumerate([16, 16, 19]):
                 eng.submit(EngineRequest(f"r{i}", list(range(1, n + 1)),
                                          max_new_tokens=4))
             out = eng.run()
@@ -785,7 +789,7 @@ def test_perf_report_prefill_ingest_section():
         assert cell["avoided_fraction"] >= 0.20
         assert cell["chips"]
     report = roofline.build_perf_report([])
-    assert report["schema"].endswith("/5")
+    assert report["schema"].endswith("/6")
     assert "prefill_ingest" in report
     text = roofline.render_perf_report(report)
     assert "prefill-ingest" in text

@@ -619,13 +619,12 @@ def test_allreduce_bytes_counter_and_zero_overhead(monkeypatch):
 
     from flashinfer_tpu import obs
     from flashinfer_tpu.comm.allreduce import allreduce
-    from flashinfer_tpu.utils import jax_shard_map
 
     mesh = Mesh(np.array(jax.devices()[:4]), ("tp",))
     x = jnp.ones((4, 64), jnp.float32)
 
     def run():
-        return jax.jit(jax_shard_map(
+        return jax.jit(jax.shard_map(
             lambda x: allreduce(x, "tp"), mesh=mesh,
             in_specs=P(None, "tp"), out_specs=P(None, "tp"),
             check_vma=False))(x)
@@ -650,7 +649,6 @@ def test_ep_a2a_bytes_counter(monkeypatch):
 
     from flashinfer_tpu import obs
     from flashinfer_tpu.fused_moe import fused_moe_ep
-    from flashinfer_tpu.utils import jax_shard_map
 
     mesh = Mesh(np.array(jax.devices()[:2]), ("tp",))
     T, K, H, E, I = 4, 2, 32, 4, 64
@@ -666,7 +664,7 @@ def test_ep_a2a_bytes_counter(monkeypatch):
                              0, E, jnp.int32)
 
     def run():
-        fn = jax_shard_map(
+        fn = jax.shard_map(
             lambda h, w, wk, tw, ti: fused_moe_ep(
                 h, w, wk, tw, ti, E, axis="tp", dispatch="alltoall"),
             mesh=mesh,

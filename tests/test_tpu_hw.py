@@ -4,8 +4,9 @@
 The smoke tier (test_tpu_smoke.py) proves each kernel Mosaic-compiles;
 this tier is the TPU analogue of the reference's GPU-correctness tests
 (tests/attention/test_batch_prefill_kernels.py): oracle comparison at the
-shapes the benchmarks run.  Auto-skips off-TPU.  Run each test in its own
-process under a timeout — a Mosaic hang must cost one slot, not the chip.
+shapes the benchmarks run.  Auto-skips off-TPU; on the chip it runs in
+ONE process without xdist (test_tpu_smoke.py's header has the command),
+under the caller's timeout.
 """
 
 import numpy as np
