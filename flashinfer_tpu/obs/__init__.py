@@ -277,20 +277,21 @@ def lifecycle_snapshot():
 # ---------------------------------------------------------------------------
 
 
-def steploop_begin(surface: str):
+def steploop_begin(surface: str, **attrs):
     """Open a step-loop ticket for one serving-step dispatch, or None
     when the gate is off — call sites keep the ticket local and guard
-    every stamp with ``if tick is not None`` (see serve/step.py)."""
+    every stamp with ``if tick is not None`` (see serve/step.py).
+    ``attrs`` label the step's profiler span and its ledger record."""
     if not steploop_enabled():
         return None
     from flashinfer_tpu.obs import steploop as _steploop
 
-    return _steploop.begin(surface)
+    return _steploop.begin(surface, **attrs)
 
 
 def steploop_summary():
     """The aggregated host-loop report over the retained ledger window
-    (host_frac, worst sub-phase, drift tails — obs.steploop.summarize),
+    (host_frac, worst sub-phase — obs.steploop.summarize),
     or None when the gate is off."""
     if not steploop_enabled():
         return None

@@ -40,7 +40,7 @@ Commands:
   compile-once fused serving loop with the ``FLASHINFER_TPU_STEPLOOP``
   gate on, write the unified trace with the host/device step lanes
   merged in, and print the ledger summary (host_frac, Amdahl ceiling,
-  sub-phase decomposition, drift).  ``--selftest`` exits non-zero on a
+  sub-phase decomposition).  ``--selftest`` exits non-zero on a
   missing device lane, any negative gap (clock-base skew), host time
   the named sub-phases did not attribute, or a ledger decomposition
   that does not sum to the measured loop wall within 5% — the CI gate.
@@ -784,8 +784,7 @@ def cmd_doctor(args) -> int:
     # ledger summary — looked up via sys.modules, never imported, so
     # doctor itself cannot defeat the zero-overhead default (the same
     # rule roofline's live join follows); zeros/absent in a fresh
-    # process, live host_frac / worst sub-phase / drift tails in the
-    # serving one
+    # process, live host_frac / worst sub-phase in the serving one
     try:
         report["host_loop"] = {"enabled": obs.steploop_enabled()}
         _sl = sys.modules.get("flashinfer_tpu.obs.steploop")
@@ -801,8 +800,7 @@ def cmd_doctor(args) -> int:
                 phases_us=s["phases"],
                 unattributed_frac=s["unattributed_frac"],
                 negative_gaps=s["negative_gaps"],
-                missing_device_lane=s["missing_device_lane"],
-                drift=s["drift"])
+                missing_device_lane=s["missing_device_lane"])
     except Exception as e:  # doctor must never crash on a broken tree
         report["host_loop"] = f"<unavailable: {type(e).__name__}>"
 

@@ -172,10 +172,12 @@ METRICS: Dict[str, Tuple[str, Tuple[str, ...], str]] = {
         "(the sum of the named sub-phases)"),
     "steploop.phase_us": (
         "histogram", ("surface", "phase"),
-        "named host sub-phase durations per step (engine: admit / "
-        "schedule / assemble / lower / dispatch; fused step wrappers: "
-        "signature / dispatch) — the host-gap decomposition ROADMAP "
-        "item 4's pipeline refactor is judged against"),
+        "named sub-phase durations per step (engine host window: admit "
+        "/ schedule / assemble / lower / dispatch, their children "
+        "lower.build / lower.upload / dispatch.upload / dispatch.call, "
+        "then fetch / post; fused step wrappers: signature / dispatch) "
+        "— the host-gap decomposition ROADMAP item 4's pipeline "
+        "refactor is judged against"),
     "steploop.device_us": (
         "histogram", ("surface",),
         "per-step device execution window: async-dispatch return to "
@@ -186,12 +188,6 @@ METRICS: Dict[str, Tuple[str, Tuple[str, ...], str]] = {
         "device idle between step N completion and step N+1 dispatch "
         "per (surface, thread) lane — the host gap; host_frac = "
         "gap / (gap + device), Amdahl ceiling = 1/(1-host_frac)"),
-    "steploop.pred_vs_measured": (
-        "histogram", ("surface",),
-        "online predicted-vs-measured drift: costmodel."
-        "predict_step_seconds over the measured step wall (ratio; "
-        "explicit DRIFT_RATIO_BUCKETS around the perfect-model 1.0) — "
-        "the automatic form of the bench pred_step_ratio join"),
     # -- tiered KV: host offload + disaggregated handoff (serve/kv_tier.py)
     "engine.kv_tier.spills": (
         "counter", (),
@@ -318,25 +314,12 @@ _LIFECYCLE_BUCKETS = {
     # lifecycle.queue_us keeps DEFAULT_BUCKETS_US (host-latency scale)
 }
 
-# Drift-ratio boundaries for steploop.pred_vs_measured (predicted /
-# measured step wall): log-spaced around the perfect-model 1.0 so both
-# "model optimistic" (<1) and "model pessimistic" (>1) tails resolve.
-# Defined HERE (not in obs.steploop) so declaring buckets never imports
-# the ledger machinery — the zero-overhead pin covers catalog.declare.
-DRIFT_RATIO_BUCKETS: Tuple[float, ...] = (
-    0.01, 0.02, 0.05, 0.1, 0.2, 0.5, 0.8, 0.9, 1.0,
-    1.1, 1.25, 2.0, 5.0, 10.0, 20.0, 100.0,
-)
-
-
 def declare(registry) -> None:
     """Pin non-default bucket boundaries on `registry`."""
     for name in PERCENT_HISTOGRAMS:
         registry.declare_histogram(name, PERCENT_BUCKETS)
     for name, buckets in _LIFECYCLE_BUCKETS.items():
         registry.declare_histogram(name, buckets)
-    registry.declare_histogram("steploop.pred_vs_measured",
-                               DRIFT_RATIO_BUCKETS)
 
 
 # Decorated public-API op names (decorator name= or f.__qualname__).

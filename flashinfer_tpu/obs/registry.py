@@ -72,7 +72,7 @@ def spans_enabled() -> bool:
 def steploop_enabled() -> bool:
     """The ``FLASHINFER_TPU_STEPLOOP`` gate (default off) for the
     step-loop flight deck (obs.steploop): per-step host/device overlap
-    ledger + predicted-vs-measured drift join.  Same placement rule as
+    ledger, its phases also profiler spans.  Same placement rule as
     :func:`spans_enabled` — the gate lives HERE so checking it never
     imports the steploop machinery (the zero-overhead subprocess pin in
     tests/test_steploop.py).  Gate-ON steps pay a completion probe

@@ -602,7 +602,6 @@ def _host_loop(attributed: Sequence[Mapping]) -> dict:
                 "amdahl_ceiling": s["amdahl_ceiling"],
                 "worst_phase": s["worst_phase"],
                 "phases_us": s["phases"],
-                "drift": s["drift"],
             }
     return out
 
@@ -939,15 +938,12 @@ def render_perf_report(report: Mapping) -> str:
                    if m.get("pred_step_ratio") is not None else ""))
         live = hl.get("live")
         if live:
-            drift = live.get("drift") or {}
             lines.append(
                 f"  live ledger: {live['steps']} steps "
                 f"({live['idle_ticks']} idle), host_frac "
                 f"{live['host_frac']:.3f}, ceiling "
                 f"{live['amdahl_ceiling']:.2f}x, worst sub-phase "
-                f"{live['worst_phase']}"
-                + (f", drift p50 {drift.get('p50', 0):.3f}"
-                   if drift else ""))
+                f"{live['worst_phase']}")
     grad = report.get("graduation")
     if grad and grad.get("sections"):
         lines.append("")

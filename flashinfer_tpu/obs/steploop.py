@@ -1,4 +1,4 @@
-"""Step-loop flight deck: host/device overlap profiler + drift watchdog.
+"""Step-loop flight deck: host/device overlap profiler on two clocks.
 
 The obs stack meters ops (PR 2/5) and requests (PR 10) but was blind to
 the STEP LOOP itself — the host work between device steps that ROADMAP
@@ -9,8 +9,13 @@ every serving-step dispatch (``ServingEngine.step`` / ``ServingStep`` /
 - named host sub-phase durations (the engine decomposes into ``admit``
   / ``schedule`` / ``assemble`` (schedule-array assembly) / ``lower``
   (kernel-plan lowering via ``build_engine_work_units``) / ``dispatch``
-  (signature + host→device upload + the jitted call); the fused step
-  wrappers record ``signature`` + ``dispatch``);
+  (signature + host→device upload + the jitted call), then ``fetch``
+  (the wait for the sampled tokens) and ``post`` (trie inserts, token
+  scatter, page release); the fused step wrappers record ``signature``
+  + ``dispatch``).  A phase opened with :meth:`StepTicket.enter` /
+  :meth:`StepTicket.phase` is ALSO a ``jax.profiler.TraceAnnotation``
+  (``engine.<phase>`` inside one ``engine.step``), so a device trace
+  attributes each idle gap to the phase the host was in;
 - the device execution window: JAX async dispatch returns before the
   device finishes, so the gate-ON path adds a completion probe
   (``block_until_ready`` — the measurement tax this mode pays) and
@@ -21,12 +26,7 @@ every serving-step dispatch (``ServingEngine.step`` / ``ServingStep`` /
   step N+1's dispatch return, per (surface, thread) lane — from which
   :func:`summarize` computes ``host_frac``, overlap efficiency, and the
   Amdahl projection ``1 / (1 - host_frac)``: the speedup CEILING the
-  item-4 two-stage pipeline can buy by hiding host work;
-- an online join against ``costmodel.predict_step_seconds``: call sites
-  that can price their step pass ``predicted_s`` and the ledger keeps
-  ``ratio = predicted_s / measured step wall`` — the
-  ``predicted_vs_measured`` drift histogram that used to be a
-  hand-driven bench join.
+  item-4 two-stage pipeline can buy by hiding host work.
 
 Zero-overhead-by-default: the ``FLASHINFER_TPU_STEPLOOP`` gate lives in
 ``registry.steploop_enabled`` and the ``obs.steploop_begin`` facade
@@ -46,8 +46,7 @@ import threading
 import time
 from typing import Dict, List, Optional
 
-# Engine sub-phases in loop order (the docs/observability.md table).
-ENGINE_PHASES = ("admit", "schedule", "assemble", "lower", "dispatch")
+from jax.profiler import TraceAnnotation
 
 # Synthetic chrome-trace lanes: host sub-phases and the device window
 # ride dedicated tids so they never collide with the per-thread span
@@ -70,61 +69,132 @@ def _default_capacity() -> int:
         return 2048
 
 
+def _clock(now: Optional[float]) -> float:
+    return time.perf_counter() if now is None else float(now)
+
+
+class _Span:
+    """One open phase: a clock window plus the profiler annotation of
+    the same interval.  ``close`` books the duration into the ticket."""
+
+    __slots__ = ("tick", "name", "t0", "ann")
+
+    def __init__(self, tick: "StepTicket", name: str, t0: float):
+        self.tick, self.name, self.t0 = tick, name, t0
+        self.ann = TraceAnnotation(f"{tick.span_prefix}.{name}")
+        self.ann.__enter__()
+
+    def close(self, now: Optional[float] = None) -> None:
+        self.tick._book(self.name, _clock(now) - self.t0)
+        self.ann.__exit__(None, None, None)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self.close()
+
+
 class StepTicket:
     """One in-flight step measurement.
 
     Stamp protocol (all perf_counter seconds; contiguous — each
-    ``mark`` closes the window since the previous stamp):
+    ``mark`` closes the window since the previous stamp, each ``enter``
+    closes the phase still open):
 
-    ``begin() -> mark(phase)* -> dispatched() -> done() -> commit()``
+    ``begin() -> (mark(phase) | enter(phase))* -> dispatched() -> done()
+    -> commit()``
 
+    ``enter(name)`` opens a top-level phase on the clock AND as a
+    profiler span; ``phase(name)`` is a context manager for a child
+    span inside it, booked under its dotted name (``lower.build``).
+    ``mark`` names a window after the fact, so it has no profiler span.
     ``dispatched()`` closes the ``dispatch`` sub-phase and ends the
     host window; ``done()`` is the completion probe's return (the
     device-window end).  Idle ticks (``commit(idle=True)``) skip
     dispatched/done — an empty-schedule poll has no device lane, and
     the gap math must not mis-attribute it as device time.
+
+    Two books, so that nothing is counted twice: ``phases`` holds the
+    top-level phases of the HOST window (before ``dispatched()``) — the
+    only ones ``summarize``'s host accounting adds up — and ``spans``
+    holds what overlaps or follows them: dotted children, and the
+    phases after the dispatch (``fetch``, ``post``).
     """
 
-    __slots__ = ("surface", "tid", "t_begin", "_t_mark", "phases",
-                 "t_dispatch", "t_done")
+    __slots__ = ("surface", "tid", "t_begin", "_t_mark", "phases", "spans",
+                 "t_dispatch", "t_done", "attrs", "span_prefix", "_open",
+                 "_step_ann")
 
-    def __init__(self, surface: str, now: Optional[float] = None):
-        t = time.perf_counter() if now is None else float(now)
+    def __init__(self, surface: str, now: Optional[float] = None,
+                 **attrs):
+        t = _clock(now)
         self.surface = surface
         self.tid = threading.get_ident()
         self.t_begin = t
         self._t_mark = t
         self.phases: Dict[str, float] = {}
+        self.spans: Dict[str, float] = {}
         self.t_dispatch: Optional[float] = None
         self.t_done: Optional[float] = None
+        self.attrs = attrs
+        self.span_prefix = "engine" if surface == "ServingEngine" \
+            else surface
+        self._open: Optional[_Span] = None
+        self._step_ann = TraceAnnotation(f"{self.span_prefix}.step",
+                                         **attrs)
+        self._step_ann.__enter__()
+
+    def _book(self, name: str, dur: float) -> None:
+        book = self.phases if "." not in name and self.t_dispatch is None \
+            else self.spans
+        book[name] = book.get(name, 0.0) + dur
+
+    def _close_open(self, t: float) -> None:
+        if self._open is not None:
+            self._open.close(t)
+            self._open = None
+            self._t_mark = t
 
     def mark(self, phase: str, now: Optional[float] = None) -> None:
         """Attribute the window since the previous stamp to ``phase``."""
-        t = time.perf_counter() if now is None else float(now)
-        self.phases[phase] = self.phases.get(phase, 0.0) \
-            + (t - self._t_mark)
+        t = _clock(now)
+        self._book(phase, t - self._t_mark)
         self._t_mark = t
+
+    def enter(self, phase: str, now: Optional[float] = None) -> None:
+        """Close the top-level phase still open and open ``phase``."""
+        t = _clock(now)
+        self._close_open(t)
+        self._open = _Span(self, phase, t)
+
+    def phase(self, name: str, now: Optional[float] = None) -> _Span:
+        """A child span (``with tick.phase("lower.build"): ...``)."""
+        return _Span(self, name, _clock(now))
 
     def dispatched(self, now: Optional[float] = None) -> None:
         """Async dispatch returned: close the ``dispatch`` sub-phase,
         end the host window, open the device window."""
-        t = time.perf_counter() if now is None else float(now)
-        self.phases["dispatch"] = self.phases.get("dispatch", 0.0) \
-            + (t - self._t_mark)
-        self._t_mark = t
+        t = _clock(now)
+        if self._open is not None:
+            self._close_open(t)
+        else:
+            self.mark("dispatch", t)
         self.t_dispatch = t
 
     def done(self, now: Optional[float] = None) -> None:
         """Completion probe returned: the device window's end."""
-        self.t_done = time.perf_counter() if now is None else float(now)
+        self.t_done = _clock(now)
+        self._close_open(self.t_done)
 
-    def commit(self, *, tokens: int = 0,
-               predicted_s: Optional[float] = None,
-               idle: bool = False, **attrs) -> dict:
-        """Seal the ticket into the global ledger; returns the record."""
-        return ledger().commit(self, tokens=tokens,
-                               predicted_s=predicted_s, idle=idle,
-                               attrs=attrs)
+    def commit(self, *, tokens: int = 0, idle: bool = False,
+               now: Optional[float] = None, **attrs) -> dict:
+        """Close what is open (the last phase, the step's span) and seal
+        the ticket into the global ledger; returns the record."""
+        self._close_open(_clock(now))
+        self._step_ann.__exit__(None, None, None)
+        return ledger().commit(self, tokens=tokens, idle=idle,
+                               attrs={**self.attrs, **attrs})
 
 
 class StepLedger:
@@ -156,8 +226,7 @@ class StepLedger:
         return max(0, self._total - self.capacity)
 
     def commit(self, ticket: StepTicket, *, tokens: int = 0,
-               predicted_s: Optional[float] = None, idle: bool = False,
-               attrs: Optional[dict] = None) -> dict:
+               idle: bool = False, attrs: Optional[dict] = None) -> dict:
         host_end = ticket.t_dispatch if ticket.t_dispatch is not None \
             else ticket._t_mark
         rec = {
@@ -169,19 +238,14 @@ class StepLedger:
             "t_dispatch": ticket.t_dispatch,
             "t_done": ticket.t_done,
             "phases": dict(ticket.phases),
+            "spans": dict(ticket.spans),
             "host_us": (host_end - ticket.t_begin) * 1e6,
             "device_us": None,
             "gap_us": None,
-            "predicted_s": predicted_s,
-            "pred_vs_measured": None,
             "attrs": dict(attrs or {}),
         }
         if ticket.t_done is not None and ticket.t_dispatch is not None:
             rec["device_us"] = (ticket.t_done - ticket.t_dispatch) * 1e6
-            if predicted_s is not None:
-                wall = ticket.t_done - ticket.t_begin
-                if wall > 0:
-                    rec["pred_vs_measured"] = float(predicted_s) / wall
         with self._lock:
             if idle:
                 self._idle_total += 1
@@ -219,7 +283,7 @@ def _observe_record(rec: dict) -> None:
         return
     reg.counter_inc("steploop.steps", surface=surface)
     reg.observe("steploop.host_us", rec["host_us"], surface=surface)
-    for phase, dur in rec["phases"].items():
+    for phase, dur in (*rec["phases"].items(), *rec["spans"].items()):
         reg.observe("steploop.phase_us", dur * 1e6, surface=surface,
                     phase=phase)
     if rec["device_us"] is not None:
@@ -227,9 +291,6 @@ def _observe_record(rec: dict) -> None:
                     surface=surface)
     if rec["gap_us"] is not None:
         reg.observe("steploop.gap_us", max(rec["gap_us"], 0.0),
-                    surface=surface)
-    if rec["pred_vs_measured"] is not None:
-        reg.observe("steploop.pred_vs_measured", rec["pred_vs_measured"],
                     surface=surface)
 
 
@@ -253,10 +314,12 @@ def reset(capacity: Optional[int] = None) -> None:
                              else _default_capacity())
 
 
-def begin(surface: str, now: Optional[float] = None) -> StepTicket:
+def begin(surface: str, now: Optional[float] = None,
+          **attrs) -> StepTicket:
     """Open a ticket (callers reach this through ``obs.steploop_begin``,
-    which owns the gate check)."""
-    return StepTicket(surface, now=now)
+    which owns the gate check).  ``attrs`` (the engine's ``step`` index)
+    go onto the step's profiler span and into the record."""
+    return StepTicket(surface, now=now, **attrs)
 
 
 # ---------------------------------------------------------------------------
@@ -297,6 +360,11 @@ def summarize(records: Optional[List[dict]] = None) -> dict:
     ``1 / (1 - host_frac)`` is the speedup CEILING a perfect host/
     device pipeline (ROADMAP item 4) can reach; real wins land below
     it (the host work still exists, it just overlaps).
+
+    The host accounting (``host_us``, ``phases``, ``worst_phase``,
+    ``unattributed_frac``) adds up a record's ``phases`` only: its
+    ``spans`` (dotted children, ``fetch``, ``post``) lie inside those
+    phases or after the host window, and would be counted twice.
     """
     led = ledger()
     recs = led.records() if records is None else list(records)
@@ -313,7 +381,7 @@ def summarize(records: Optional[List[dict]] = None) -> dict:
         out.update(host_frac=None, overlap_efficiency=None,
                    amdahl_ceiling=None, negative_gaps=0,
                    missing_device_lane=0, phases={}, worst_phase=None,
-                   unattributed_frac=None, drift=None)
+                   unattributed_frac=None)
         return out
 
     host = [r["host_us"] for r in steps]
@@ -354,10 +422,6 @@ def summarize(records: Optional[List[dict]] = None) -> dict:
     unattr = sum(r["host_us"] for r in steps) - sum(phases.values())
     total_host = max(sum(r["host_us"] for r in steps), 1e-9)
     out["unattributed_frac"] = max(unattr, 0.0) / total_host
-
-    ratios = [r["pred_vs_measured"] for r in steps
-              if r["pred_vs_measured"] is not None]
-    out["drift"] = _dist(ratios) if ratios else None
     return out
 
 

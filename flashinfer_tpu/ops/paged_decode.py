@@ -995,6 +995,7 @@ def paged_decode_attention_split(
                 (num_units, num_kv_heads, gp, 128), jnp.float32),
         ],
         interpret=use_interpret(),
+        name="paged_decode_attention_split",  # the device-event name
     )(
         plan["pages"], plan["kvlen"], plan["wu_req"], plan["wu_page0"],
         plan["wu_kvlen"], qg, k_cache, v_cache,

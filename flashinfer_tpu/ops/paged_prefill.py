@@ -1207,7 +1207,7 @@ def _fused_prefill_kernel(
     static_argnames=(
         "num_units", "block_q", "pages_per_chunk", "sm_scale",
         "logits_soft_cap", "window_left", "causal", "return_lse",
-        "trace_events",
+        "trace_events", "name",
     ),
 )
 def fused_paged_prefill(
@@ -1225,6 +1225,7 @@ def fused_paged_prefill(
     causal: bool = True,
     return_lse: bool = False,
     trace_events: bool = False,
+    name: str = "fused_paged_prefill",  # the launch's device-event name
 ):
     total_q, H, D = q.shape
     _, Hkv, page_size, _ = k_cache.shape
@@ -1338,6 +1339,7 @@ def fused_paged_prefill(
             has_side_effects=True,
         ),
         interpret=use_interpret(),
+        name=name,
     )(
         plan["qstart"], plan["rowlo"], plan["rowhi"], plan["qpos0"],
         plan["kvstart"], plan["kvlen"], plan["first"], plan["wout"],
@@ -1548,6 +1550,7 @@ def fused_paged_prefill_ingest(
         ),
         interpret=use_interpret(),
         input_output_aliases=aliases,
+        name="fused_paged_prefill_ingest",  # the device-event name
     )(
         plan["qstart"], plan["rowlo"], plan["rowhi"], plan["qpos0"],
         plan["kvstart"], plan["kvlen"], plan["first"], plan["wout"],

@@ -70,6 +70,7 @@ semantics, scheduler knobs, and the retrace-budget contract.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -80,6 +81,15 @@ import numpy as np
 
 from flashinfer_tpu.api_logging import flashinfer_api
 from flashinfer_tpu.serve.step import SamplingConfig
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _span(tick, name: str):
+    """A child span of the step ticket's open phase (a profiler span
+    and a ledger entry), or nothing when the step-loop gate is off."""
+    return _NO_SPAN if tick is None else tick.phase(name)
+
 
 _NEG_INF = -1e30  # matches ops/merge.py and ops/xla_ref.py
 
@@ -333,6 +343,8 @@ class EngineRequest:
     #                          zero-token mid-prefill vacate counts
     arrival: int = -1
     enqueue_t: float = 0.0
+    first_sched_t: Optional[float] = None  # start of the step that ran
+    #                          its first prompt chunk (kept over preemption)
     deadline: float = float("inf")
     preemptions: int = 0
 
@@ -497,6 +509,12 @@ class ServingEngine:
                     "token_page", "token_slot", "page_table", "grp_pages",
                     "tok_grp", "split", "last_rows", "sample_seeds",
                     "kplans", "caches")
+    # what the kernel tier's planner counts per step (useful against
+    # launched): summed into ``unit_stats``, and each step's own values
+    # go into the facts ``step()`` returns (0 on the reference tier)
+    _UNIT_FACTS = ("prefill_units", "prefill_units_launched",
+                   "prefill_cells_valid", "prefill_cells_launched",
+                   "decode_pages_real", "decode_pages_launched")
 
     def __init__(self, model_cfg, params, config: EngineConfig):
         if config.attention_backend not in ("reference", "kernel"):
@@ -1042,6 +1060,10 @@ class ServingEngine:
         def _body(params, flat_tokens, positions, tok_req, token_page,
                   token_slot, page_table, grp_pages, tok_grp, split,
                   last_rows, sample_seeds, kplans, caches):
+            # The parts of the program carry names (jax.named_scope ->
+            # the HLO op_name of every operation under them), so a
+            # device trace splits by part: embed; per layer attn.qkv,
+            # attn.kv_write, attn.core, attn.out, mlp; head; sample.
             from flashinfer_tpu.activation import silu_and_mul
             from flashinfer_tpu.cascade import compose_cascade_levels
             from flashinfer_tpu.models.llama import _mm, _pre_quant
@@ -1050,26 +1072,15 @@ class ServingEngine:
 
             engine_self._traces += 1
             T = flat_tokens.shape[0]
-            x = params["embed"][flat_tokens].astype(mcfg.dtype)
-            new_caches = []
-            for li, layer in enumerate(params["layers"]):
-                h = rmsnorm(x, layer["input_norm"], mcfg.rms_eps)
-                pre = _pre_quant(h, layer)
-                q = _mm(h, layer, "q_proj", pre).reshape(
-                    T, mcfg.num_qo_heads, mcfg.head_dim)
-                k = _mm(h, layer, "k_proj", pre).reshape(
-                    T, mcfg.num_kv_heads, mcfg.head_dim)
-                v = _mm(h, layer, "v_proj", pre).reshape(
-                    T, mcfg.num_kv_heads, mcfg.head_dim)
-                kc, vc = caches[li]
 
-                def _composed_attn(q, k, v, kc, vc):
-                    """The ONE composed sequence — rope -> quantize ->
-                    scatter-append -> attend -> v-scale epilogue.  The
-                    non-ingest path and the ingest cond's false branch
-                    run exactly this function, so a fix to the
-                    quantize/scale/scatter logic can never reach one
-                    fused_ingest setting and miss the other."""
+            def _composed_attn(q, k, v, kc, vc):
+                """The ONE composed sequence — rope -> quantize ->
+                scatter-append -> attend -> v-scale epilogue.  The
+                non-ingest path and the ingest cond's false branch run
+                exactly this function, so a fix to the
+                quantize/scale/scatter logic can never reach one
+                fused_ingest setting and miss the other."""
+                with jax.named_scope("attn.kv_write"):
                     q, k = apply_rope_pos_ids(q, k, positions,
                                               rope_theta=mcfg.rope_theta)
                     if int8_kv:
@@ -1085,6 +1096,7 @@ class ServingEngine:
                     # page 0)
                     kc = kc.at[token_page, :, token_slot, :].set(k_w)
                     vc = vc.at[token_page, :, token_slot, :].set(v_w)
+                with jax.named_scope("attn.core"):
                     if kernel_backend:
                         # the graduated path: the same two-level
                         # cascade, but level 1 rides the work-unit
@@ -1127,6 +1139,32 @@ class ServingEngine:
                         o = o * mcfg.kv_v_scale
                     return o.astype(mcfg.dtype), kc, vc
 
+            @jax.named_scope("attn.core")
+            def _ingest_branch(q, k, v, kc, vc):
+                from flashinfer_tpu.serve.engine_kernels import (
+                    engine_kernel_ingest_attention)
+
+                return engine_kernel_ingest_attention(
+                    q, k, v, kc, vc, kplans, geom=geom,
+                    sm_scale=sm_plain, rope_theta=mcfg.rope_theta,
+                    kv_quant="int8" if int8_kv else "none",
+                    k_scale=mcfg.kv_k_scale if int8_kv else 1.0,
+                    v_scale=mcfg.kv_v_scale if int8_kv else 1.0)
+
+            with jax.named_scope("embed"):
+                x = params["embed"][flat_tokens].astype(mcfg.dtype)
+            new_caches = []
+            for li, layer in enumerate(params["layers"]):
+                with jax.named_scope("attn.qkv"):
+                    h = rmsnorm(x, layer["input_norm"], mcfg.rms_eps)
+                    pre = _pre_quant(h, layer)
+                    q = _mm(h, layer, "q_proj", pre).reshape(
+                        T, mcfg.num_qo_heads, mcfg.head_dim)
+                    k = _mm(h, layer, "k_proj", pre).reshape(
+                        T, mcfg.num_kv_heads, mcfg.head_dim)
+                    v = _mm(h, layer, "v_proj", pre).reshape(
+                        T, mcfg.num_kv_heads, mcfg.head_dim)
+                kc, vc = caches[li]
                 if use_ingest:
                     # ISSUE 14: per-step VALUE dispatch between the
                     # fused-ingest launch and the composed cascade —
@@ -1134,57 +1172,55 @@ class ServingEngine:
                     # per-rung program and the one-trace-per-rung
                     # budget is untouched.  ingest_on certifies a
                     # from-scratch prefill schedule (level 0 + decode
-                    # structurally empty) at plan-build time.
-                    from flashinfer_tpu.serve.engine_kernels import (
-                        engine_kernel_ingest_attention)
-
-                    def _ingest_branch(q, k, v, kc, vc):
-                        return engine_kernel_ingest_attention(
-                            q, k, v, kc, vc, kplans, geom=geom,
-                            sm_scale=sm_plain,
-                            rope_theta=mcfg.rope_theta,
-                            kv_quant="int8" if int8_kv else "none",
-                            k_scale=mcfg.kv_k_scale if int8_kv else 1.0,
-                            v_scale=mcfg.kv_v_scale if int8_kv else 1.0)
-
-                    attn, kc, vc = jax.lax.cond(
-                        kplans["ingest_on"] > 0, _ingest_branch,
-                        _composed_attn, q, k, v, kc, vc)
+                    # structurally empty) at plan-build time.  The
+                    # cond, and whatever XLA copies around it, is part
+                    # of writing the K/V: its own operations read
+                    # attn.kv_write, the branches' their inner scopes.
+                    with jax.named_scope("attn.kv_write"):
+                        attn, kc, vc = jax.lax.cond(
+                            kplans["ingest_on"] > 0, _ingest_branch,
+                            _composed_attn, q, k, v, kc, vc)
                 else:
                     attn, kc, vc = _composed_attn(q, k, v, kc, vc)
                 new_caches.append((kc, vc))
-                x = x + _mm(attn.reshape(T, -1), layer,
-                            "o_proj").astype(mcfg.dtype)
-                h2 = rmsnorm(x, layer["post_norm"], mcfg.rms_eps)
-                pre2 = _pre_quant(h2, layer, "gate_proj")
-                mlp = jnp.concatenate(
-                    [_mm(h2, layer, "gate_proj", pre2),
-                     _mm(h2, layer, "up_proj", pre2)], -1)
-                x = x + _mm(silu_and_mul(mlp), layer,
-                            "down_proj").astype(mcfg.dtype)
-            x_last = x[last_rows]
-            xf = rmsnorm(x_last, params["final_norm"], mcfg.rms_eps)
-            logits = _mm(xf, params, "lm_head").astype(jnp.float32)
+                with jax.named_scope("attn.out"):
+                    x = x + _mm(attn.reshape(T, -1), layer,
+                                "o_proj").astype(mcfg.dtype)
+                with jax.named_scope("mlp"):
+                    h2 = rmsnorm(x, layer["post_norm"], mcfg.rms_eps)
+                    pre2 = _pre_quant(h2, layer, "gate_proj")
+                    mlp = jnp.concatenate(
+                        [_mm(h2, layer, "gate_proj", pre2),
+                         _mm(h2, layer, "up_proj", pre2)], -1)
+                    x = x + _mm(silu_and_mul(mlp), layer,
+                                "down_proj").astype(mcfg.dtype)
+            with jax.named_scope("head"):
+                x_last = x[last_rows]
+                xf = rmsnorm(x_last, params["final_norm"], mcfg.rms_eps)
+                logits = _mm(xf, params, "lm_head").astype(jnp.float32)
             # per-lane deterministic sampling: the key depends only on
             # (request arrival id, token index), never on scheduling —
             # the same request samples the same stream under any
             # packing, preemption, or sharing mode
-            t = jnp.maximum(jnp.asarray(sampling.temperature, jnp.float32),
-                            1e-6)
-            probs = jax.nn.softmax((logits / t).astype(jnp.float32), -1)
-            if sampling.top_k:
-                from flashinfer_tpu import sampling as S
+            with jax.named_scope("sample"):
+                t = jnp.maximum(
+                    jnp.asarray(sampling.temperature, jnp.float32), 1e-6)
+                probs = jax.nn.softmax((logits / t).astype(jnp.float32),
+                                       -1)
+                if sampling.top_k:
+                    from flashinfer_tpu import sampling as S
 
-                probs = S.top_k_renorm_probs(probs, sampling.top_k)
-            if sampling.top_p < 1.0:
-                from flashinfer_tpu import sampling as S
+                    probs = S.top_k_renorm_probs(probs, sampling.top_k)
+                if sampling.top_p < 1.0:
+                    from flashinfer_tpu import sampling as S
 
-                probs = S.top_p_renorm_probs(probs, sampling.top_p)
-            keys = jax.vmap(lambda s: jax.random.fold_in(base_key, s))(
-                sample_seeds)
-            tokens = jax.vmap(
-                lambda p, kk: jax.random.categorical(
-                    kk, jnp.log(jnp.maximum(p, 1e-30))))(probs, keys)
+                    probs = S.top_p_renorm_probs(probs, sampling.top_p)
+                keys = jax.vmap(
+                    lambda s: jax.random.fold_in(base_key, s))(
+                        sample_seeds)
+                tokens = jax.vmap(
+                    lambda p, kk: jax.random.categorical(
+                        kk, jnp.log(jnp.maximum(p, 1e-30))))(probs, keys)
             return tokens.astype(jnp.int32), new_caches
 
         donate = (13,) if cfg.donate else ()
@@ -1206,17 +1242,21 @@ class ServingEngine:
     @flashinfer_api(name="engine.step")
     def step(self) -> dict:
         """One engine step: admit, schedule, run the compiled rung,
-        scatter results.  Returns step facts (rung, tokens scheduled,
-        requests sampled/finished)."""
+        scatter results.  Returns this step's facts: its index, rung,
+        tokens scheduled (and how many were prompt / decode tokens),
+        requests sampled / finished, the kernel tier's work units and
+        cells useful against launched (``_UNIT_FACTS``), and the queue
+        wait of the requests whose first prompt chunk ran in it."""
         from flashinfer_tpu import obs
 
-        tick = obs.steploop_begin("ServingEngine")
+        t_step = time.perf_counter()
+        tick = obs.steploop_begin("ServingEngine", step=self._steps)
+        if tick is not None:
+            tick.enter("admit")
         self._admit()
         if tick is not None:
-            tick.mark("admit")
+            tick.enter("schedule")
         sched = self._schedule()
-        if tick is not None:
-            tick.mark("schedule")
         if not sched:
             if self._waiting and not self._running:
                 r = min(self._waiting, key=self._order_key)
@@ -1233,13 +1273,13 @@ class ServingEngine:
             if tick is not None:
                 tick.commit(idle=True)
             return {"rung": 0, "tokens": 0, "sampled": 0, "finished": 0}
+        if tick is not None:
+            tick.enter("assemble")
         cfg, mcfg = self.config, self.cfg
         ps, ppr = cfg.page_size, self._ppr
         Bpad = cfg.max_batch
         total = sum(n for _, n in sched)
         rung = self._rung_for(total)
-        kv_pairs_before = self.kv_pairs_total
-
         flat = np.zeros(rung, np.int32)
         pos = np.zeros(rung, np.int32)
         tok_req = np.zeros(rung, np.int32)
@@ -1252,6 +1292,11 @@ class ServingEngine:
         last_rows = np.zeros(Bpad, np.int32)
         sample_seeds = np.zeros(Bpad, np.int32)
         samplers: List[EngineRequest] = []
+        facts = dict.fromkeys(
+            ("prefill_tokens", "decode_tokens", "first_scheduled",
+             *self._UNIT_FACTS), 0)
+        facts.update(step=self._steps, rung=rung, tokens=total,
+                     queue_wait_s=0.0)
 
         # group slots: one per distinct shared page-run prefix this
         # step (sharing mode: every full hit of one cached run lands in
@@ -1283,6 +1328,11 @@ class ServingEngine:
         for r, n in sched:
             g = groups[_grp_key(r)]
             decoding = r.kv_len >= len(r.prompt)
+            facts["decode_tokens" if decoding else "prefill_tokens"] += n
+            if r.first_sched_t is None and not decoding:
+                r.first_sched_t = t_step
+                facts["first_scheduled"] += 1
+                facts["queue_wait_s"] += t_step - r.enqueue_t
             if self._kernel_backend:
                 from flashinfer_tpu.serve.engine_kernels import SchedSeg
 
@@ -1322,44 +1372,47 @@ class ServingEngine:
                 self.kv_rows_total += len(run_key) * ps
         self.tokens_total += total
         self.sampled_total += len(samplers)
+        facts["sampled"] = len(samplers)
         if tick is not None:
-            tick.mark("assemble")
+            tick.enter("lower")
 
         kplans: dict = {}
         if self._kernel_backend:
             from flashinfer_tpu.serve import engine_kernels as _ek
 
-            plans = _ek.build_engine_work_units(segs, rung=rung,
-                                                geom=self._geom)
+            with _span(tick, "lower.build"):
+                plans = _ek.build_engine_work_units(segs, rung=rung,
+                                                    geom=self._geom)
             st = plans["stats"]
             us = self.unit_stats
-            us["prefill_units"] += st["prefill_units"]
-            us["prefill_units_launched"] += st["prefill_units_launched"]
-            us["prefill_cells_launched"] += st["prefill_cells_launched"]
-            us["prefill_cells_valid"] += st["prefill_cells_valid"]
-            us["decode_pages_real"] += st["decode_pages_real"]
-            us["decode_pages_launched"] += st["decode_pages_launched"]
+            for k in self._UNIT_FACTS:
+                facts[k] = st[k]
+                us[k] += st[k]
             us["kv_pairs_launched"] += (st["prefill_cells_launched"]
                                         + st["decode_cells_launched"])
             us["kv_rows_launched"] += (st["prefill_rows_launched"]
                                        + st["decode_rows_launched"])
-            kplans = _ek.plans_to_device(plans)
+            with _span(tick, "lower.upload"):
+                kplans = _ek.plans_to_device(plans)
         if tick is not None:
-            tick.mark("lower")
+            tick.enter("dispatch")
 
-        full_args = (self.params, jnp.asarray(flat), jnp.asarray(pos),
-                     jnp.asarray(tok_req), jnp.asarray(token_page),
-                     jnp.asarray(token_slot), jnp.asarray(page_table),
-                     jnp.asarray(grp_pages), jnp.asarray(tok_grp),
-                     jnp.asarray(split), jnp.asarray(last_rows),
-                     jnp.asarray(sample_seeds), kplans, self.caches)
+        with _span(tick, "dispatch.upload"):
+            full_args = (self.params, jnp.asarray(flat), jnp.asarray(pos),
+                         jnp.asarray(tok_req), jnp.asarray(token_page),
+                         jnp.asarray(token_slot), jnp.asarray(page_table),
+                         jnp.asarray(grp_pages), jnp.asarray(tok_grp),
+                         jnp.asarray(split), jnp.asarray(last_rows),
+                         jnp.asarray(sample_seeds), kplans, self.caches)
         sig = obs.state_signature(full_args, names=self._STATE_NAMES)
         seen = self._rung_traced.get(rung, 0)
         before = self._traces
         t0 = time.perf_counter() if sig is not None else 0.0
-        tokens_dev, self.caches = self._step(*full_args)
+        with _span(tick, "dispatch.call"):
+            tokens_dev, self.caches = self._step(*full_args)
         if tick is not None:
             tick.dispatched()
+            tick.enter("fetch")
         if self._traces > before:
             self._rung_traced[rung] = seen + 1
             if sig is not None:
@@ -1382,14 +1435,10 @@ class ServingEngine:
         tokens = np.asarray(tokens_dev)
         if tick is not None:
             # np.asarray above IS the completion probe (tokens cross to
-            # host); join the predicted step time online — the drift
-            # histogram ROADMAP items 1/7 wanted automated
+            # host): the ``fetch`` phase is the device window as the
+            # host sees it
             tick.done()
-            tick.commit(
-                tokens=total, rung=rung,
-                predicted_s=self._predict_step_seconds(
-                    total, self.kv_pairs_total - kv_pairs_before,
-                    len(self._running)))
+            tick.enter("post")
 
         # register freshly-completed full pages of each shareable span
         # FIRST (post-run: the page KV is materialized now, and a
@@ -1414,8 +1463,13 @@ class ServingEngine:
         obs.counter_inc("engine.step_tokens", total)
         obs.gauge_set("engine.pool_pages_in_use", self.pool.used_pages)
         obs.gauge_set("engine.pool_pages_free", self.pool.free_pages)
-        return {"rung": rung, "tokens": total, "sampled": len(samplers),
-                "finished": finished}
+        facts["finished"] = finished
+        if tick is not None:
+            # a step whose call traced (and compiled) says so in the
+            # ledger, with no need of the SPANS gate
+            tick.commit(tokens=total, rung=rung,
+                        traced=self._traces > before)
+        return facts
 
     def _finish(self, r: EngineRequest) -> None:
         from flashinfer_tpu import obs

@@ -443,12 +443,12 @@ def engine_kernel_attention(q, k_cache, v_cache, kplans, *,
         q, k_cache, v_cache, p0,
         num_units=p0["qstart"].shape[0], block_q=geom.block_q,
         pages_per_chunk=geom.prefill_ppc, sm_scale=sm_scale,
-        causal=False, return_lse=True)
+        causal=False, return_lse=True, name="fused_paged_prefill_l0")
     o1, lse1 = fused_paged_prefill(
         q, k_cache, v_cache, p1,
         num_units=p1["qstart"].shape[0], block_q=geom.block_q,
         pages_per_chunk=geom.prefill_ppc, sm_scale=sm_scale,
-        causal=True, return_lse=True)
+        causal=True, return_lse=True, name="fused_paged_prefill_l1")
     dec_rows = kplans["dec_rows"]
     # idle lanes carry row == rung: the gather clips to a real row whose
     # q is then attended against a kv_len=0 table (empty state), and

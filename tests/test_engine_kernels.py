@@ -368,3 +368,53 @@ def test_kernel_tier_cost_is_launched_vs_effective(params):
     # the reference tier keeps the launched == effective convention
     _, ref_eng = _serve(params, prompts, "reference")
     assert ref_eng.aggregate_cost().flops_effective is None
+
+
+# ---------------------------------------------------------------------------
+# The facts step() returns (ISSUE 24): each step's own counts
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("backend", ["reference", "kernel"])
+def test_step_facts_add_up_and_queue_wait_counts_once(params, backend):
+    """Every step's facts are that step's own values: over a run they
+    add up to the engine's running totals (``tokens_total``,
+    ``sampled_total``, ``unit_stats``; the unit facts are 0 on the
+    reference tier), prompt and decode tokens split the tokens
+    scheduled, and a request's queue wait is counted in the step that
+    ran its FIRST prompt chunk only — a preempted request that is
+    admitted again does not count twice."""
+    rng = np.random.default_rng(23)
+    pA = [int(t) for t in rng.integers(1, CFG.vocab_size, 20)]
+    pB = [int(t) for t in rng.integers(1, CFG.vocab_size, 20)]
+    eng = _mk_engine(params, backend, num_pages=7, max_batch=2,
+                     max_seq_tokens=48)   # 6 usable pages: B preempts A
+    a = EngineRequest("A", list(pA), max_new_tokens=8, priority=5)
+    eng.submit(a)
+    facts = [eng.step() for _ in range(6)]  # A is mid-decode ...
+    b = EngineRequest("B", list(pB), max_new_tokens=4, priority=0)
+    eng.submit(b)                           # ... when B arrives
+    while eng.has_work():
+        facts.append(eng.step())
+    assert a.preemptions == 1 and b.preemptions == 0
+    busy = [f for f in facts if f["tokens"]]
+    assert [f["step"] for f in busy] == list(range(len(busy)))
+    assert sum(f["tokens"] for f in busy) == eng.tokens_total
+    assert sum(f["sampled"] for f in busy) == eng.sampled_total
+    assert sum(f["finished"] for f in busy) == 2
+    for f in busy:
+        assert f["prefill_tokens"] + f["decode_tokens"] == f["tokens"]
+    # A's rows are computed again on resume, less what the trie kept
+    assert sum(f["prefill_tokens"] for f in busy) > len(pA) + len(pB)
+    for k in ServingEngine._UNIT_FACTS:
+        assert sum(f[k] for f in busy) == pytest.approx(eng.unit_stats[k])
+        assert (eng.unit_stats[k] > 0) == (backend == "kernel"), k
+    # two requests, two first chunks, whatever happened to them later
+    assert sum(f["first_scheduled"] for f in busy) == 2
+    firsts = [f for f in busy if f["first_scheduled"]]
+    assert len(firsts) == 2 and firsts[0]["step"] == 0
+    assert a.first_sched_t is not None and b.first_sched_t is not None
+    assert sum(f["queue_wait_s"] for f in busy) == pytest.approx(
+        (a.first_sched_t - a.enqueue_t) + (b.first_sched_t - b.enqueue_t))
+    assert all(f["queue_wait_s"] == 0.0 for f in busy
+               if not f["first_scheduled"])

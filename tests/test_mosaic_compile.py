@@ -16,6 +16,7 @@ to it cannot be read back without a chip.
 """
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -263,6 +264,22 @@ def test_engine_step(engine_step, compiled_branch, which):
     rung = min(ecfg.rungs()) if which == "decode" else 512
     lowered = eng._step.lower(*args(rung))
     assert lowered.as_text().count("tpu_custom_call") >= 5
-    mem = lowered.compile().memory_analysis()
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
     # one layer + embed + lm_head in bf16, and the KV pool
     assert 2.0 * 2**30 < mem.argument_size_in_bytes < 3.0 * 2**30
+    # What a device trace is read by (perfbench/layers/kernel.*.json,
+    # readers/xplane_scopes.py): each attention launch is an instruction
+    # of its own name (name= on the pallas_call), and the step's parts
+    # are components of the operations' op_name (jax.named_scope).
+    hlo = compiled.as_text()
+    for kernel in ("fused_paged_prefill_l0", "fused_paged_prefill_l1",
+                   "fused_paged_prefill_ingest",
+                   "paged_decode_attention_split"):
+        assert re.search(rf"%{kernel}(\.\d+)? = .*custom-call\(", hlo), kernel
+    for scope in ("embed", "attn.qkv", "attn.kv_write", "attn.core",
+                  "attn.out", "mlp", "head", "sample"):
+        assert re.search(rf'op_name="jit\(_body\)/(\S+/)?{scope}/', hlo), scope
+    assert ("jit(_body)/attn.kv_write/cond/branch_0_fun/attn.core/"
+            "jit(fused_paged_prefill)/fused_paged_prefill_l0/pallas_call"
+            ) in hlo

@@ -1,13 +1,14 @@
-"""Step-loop flight deck (ISSUE 17): host/device overlap ledger +
-predicted-vs-measured drift watchdog.
+"""Step-loop flight deck (ISSUE 17): host/device overlap ledger, its
+phases also spans on the profiler's clock (ISSUE 24).
 
 Covers the zero-overhead default (gate-off facade no-op IN-PROCESS plus
 the SUBPROCESS pin that plain library serving never even imports
 ``obs.steploop``), the ticket/ledger math on hand-driven clocks (gap
-chaining, host_frac / overlap efficiency / Amdahl ceiling, the drift
-ratio join), the bounded-ring and thread-safety contracts, negative-gap
-and idle-tick semantics, the unified-trace step lanes, the engine /
-ServingStep wiring (sub-phases, device lane, online drift), the
+chaining, host_frac / overlap efficiency / Amdahl ceiling, phases with
+children, ``fetch`` and ``post``), the bounded-ring and thread-safety
+contracts, negative-gap and idle-tick semantics, the unified-trace step
+lanes, the engine / ServingStep wiring (sub-phases, device lane, the
+``engine.*`` spans in a ``jax.profiler`` trace), the
 ``python -m flashinfer_tpu.obs steploop --selftest`` acceptance gate,
 and the perf/6 report's ``host_loop`` section (banked-row Amdahl projection +
 the live ledger join).
@@ -114,7 +115,7 @@ def _three_step_lane():
     """Three steps on one lane with exact clocks:
 
     s1: host 0.2s (a=0.1 + dispatch=0.1), device 0.4s, no gap (first)
-    s2: host 0.1s, gap 0.2s, device 0.4s, predicted 0.25 / wall 0.5
+    s2: host 0.1s, gap 0.2s, device 0.4s
     s3: host 0.1s, gap 0.2s, device 0.4s
 
     steady-state pairs (s2, s3): Σgap=0.4, Σdevice=0.8 ->
@@ -129,7 +130,7 @@ def _three_step_lane():
     t2 = steploop.begin("Lane", now=0.7)
     t2.dispatched(now=0.8)
     t2.done(now=1.2)
-    r2 = t2.commit(tokens=4, predicted_s=0.25)
+    r2 = t2.commit(tokens=4)
 
     t3 = steploop.begin("Lane", now=1.3)
     t3.dispatched(now=1.4)
@@ -139,15 +140,16 @@ def _three_step_lane():
 
 
 @pytest.mark.quick
-def test_hand_clock_gap_overlap_and_drift(fresh_ledger):
+def test_hand_clock_gap_and_overlap(fresh_ledger):
     r2, r3 = _three_step_lane()
     assert r2["gap_us"] == pytest.approx(0.2e6)
     assert r3["gap_us"] == pytest.approx(0.2e6)
     assert r2["device_us"] == pytest.approx(0.4e6)
     assert r2["host_us"] == pytest.approx(0.1e6)
-    # drift: predicted 0.25s over a 0.5s step wall (begin -> done)
-    assert r2["pred_vs_measured"] == pytest.approx(0.5)
-    assert r3["pred_vs_measured"] is None
+    # the mark protocol books host phases only: nothing in ``spans``
+    assert r2["spans"] == {} and r2["phases"] == {
+        "dispatch": pytest.approx(0.1)}
+    assert "pred_vs_measured" not in r2 and "predicted_s" not in r2
 
     s = steploop.summarize()
     assert s["steps"] == 3 and s["idle_ticks"] == 0
@@ -161,8 +163,60 @@ def test_hand_clock_gap_overlap_and_drift(fresh_ledger):
     assert s["phases"]["a"] == pytest.approx(0.1e6, abs=0.1)
     assert s["phases"]["dispatch"] == pytest.approx(0.3e6, abs=0.1)
     assert s["worst_phase"] == "dispatch"
-    assert s["drift"]["count"] == 1
-    assert s["drift"]["p50"] == pytest.approx(0.5)
+    assert "drift" not in s
+
+
+@pytest.mark.quick
+def test_hand_clock_phases_children_fetch_and_post(fresh_ledger):
+    """The engine's protocol on a hand clock: ``enter`` chains the
+    top-level phases, ``phase`` books dotted children INSIDE the open
+    one, and what follows the dispatch (``fetch``, ``post``) lies
+    outside the host window.  The five host phases add up to what they
+    added up to before the children existed."""
+    t = steploop.begin("ServingEngine", now=10.0, step=7)
+    t.enter("admit", now=10.0)
+    t.enter("schedule", now=10.1)
+    t.enter("assemble", now=10.3)
+    t.enter("lower", now=10.6)
+    b = t.phase("lower.build", now=10.6)
+    b.close(now=11.0)
+    u = t.phase("lower.upload", now=11.0)
+    u.close(now=11.5)
+    t.enter("dispatch", now=11.6)
+    with t.phase("dispatch.upload", now=11.6) as du:
+        pass                      # closed on the wall clock by the with
+    c = t.phase("dispatch.call", now=11.8)
+    c.close(now=12.0)
+    t.dispatched(now=12.0)
+    t.enter("fetch", now=12.0)
+    t.done(now=15.0)
+    t.enter("post", now=15.0)
+    rec = t.commit(tokens=3, rung=8, traced=True, now=15.5)
+
+    assert rec["phases"] == {
+        "admit": pytest.approx(0.1), "schedule": pytest.approx(0.2),
+        "assemble": pytest.approx(0.3), "lower": pytest.approx(1.0),
+        "dispatch": pytest.approx(0.4)}
+    assert sum(rec["phases"].values()) * 1e6 \
+        == pytest.approx(rec["host_us"]) == pytest.approx(2.0e6)
+    sp = rec["spans"]
+    assert sp["lower.build"] == pytest.approx(0.4)
+    assert sp["lower.upload"] == pytest.approx(0.5)
+    assert sp["lower.build"] + sp["lower.upload"] \
+        <= rec["phases"]["lower"] + 1e-9
+    assert sp["dispatch.call"] == pytest.approx(0.2)
+    assert sp["fetch"] == pytest.approx(3.0) \
+        == pytest.approx(rec["device_us"] / 1e6)
+    assert sp["post"] == pytest.approx(0.5)
+    assert du.name == "dispatch.upload" and "dispatch.upload" in sp
+    assert rec["t_dispatch"] == 12.0 and rec["t_done"] == 15.0
+    assert rec["attrs"] == {"step": 7, "rung": 8, "traced": True}
+    # the host accounting adds up the five phases and nothing twice
+    s = steploop.summarize()
+    assert set(s["phases"]) == {"admit", "schedule", "assemble", "lower",
+                                "dispatch"}
+    assert s["unattributed_frac"] == pytest.approx(0.0, abs=1e-9)
+    assert s["worst_phase"] == "lower"
 
 
 @pytest.mark.quick
@@ -304,10 +358,19 @@ def test_registry_mirror_from_committed_records(gate_on):
     assert "steploop.host_us" in snap["histograms"]
     assert "steploop.device_us" in snap["histograms"]
     assert "steploop.gap_us" in snap["histograms"]
-    drift = snap["histograms"]["steploop.pred_vs_measured"]
-    assert sum(h["count"] for h in drift.values()) == 1
-    phase_keys = set(snap["histograms"]["steploop.phase_us"])
-    assert any("phase=dispatch" in k for k in phase_keys)
+    assert "steploop.pred_vs_measured" not in snap["histograms"]
+    t = steploop.begin("Lane", now=2.0)
+    t.enter("lower", now=2.0)
+    t.phase("lower.build", now=2.0).close(now=2.1)
+    t.dispatched(now=2.2)
+    t.enter("fetch", now=2.2)
+    t.done(now=2.5)
+    t.commit(now=2.5)
+    phases = obs.snapshot()["histograms"]["steploop.phase_us"]
+    # host phases, children and fetch all mirror under their own label
+    for label in ("dispatch", "lower", "lower.build", "fetch"):
+        assert any(f"phase={label}," in k or k.endswith(f"phase={label}")
+                   for k in phases), (label, sorted(phases))
 
 
 # ------------------------------------------------------- surface wiring --
@@ -329,7 +392,7 @@ def _tiny_engine(jnp):
 
 
 @pytest.mark.quick
-def test_engine_wiring_phases_idle_and_drift(gate_on):
+def test_engine_wiring_phases_idle_and_traced(gate_on):
     import jax.numpy as jnp
     import numpy as np
 
@@ -353,12 +416,75 @@ def test_engine_wiring_phases_idle_and_drift(gate_on):
     assert {"admit", "schedule", "assemble", "lower", "dispatch"} \
         <= set(s["phases"])
     assert s["unattributed_frac"] < 0.01
-    # the online drift join: the engine prices every dispatched step
-    assert s["drift"] and s["drift"]["count"] == s["steps"]
-    assert all(r["pred_vs_measured"] > 0
-               for r in steploop.ledger().records() if not r["idle"])
+    recs = [r for r in steploop.ledger().records() if not r["idle"]]
+    # children, fetch and post are booked beside the five, per step
+    for r in recs:
+        assert {"dispatch.upload", "dispatch.call", "fetch", "post"} \
+            <= set(r["spans"])
+        assert r["spans"]["dispatch.upload"] + r["spans"]["dispatch.call"] \
+            <= r["phases"]["dispatch"]
+        # fetch opens a few microseconds after the dispatch returned
+        assert r["spans"]["fetch"] * 1e6 == pytest.approx(r["device_us"],
+                                                          abs=1e3)
+    # the record carries the engine's step index, and says which steps
+    # traced (compiled): each rung's first, and no other
+    assert [r["attrs"]["step"] for r in recs] == list(range(len(recs)))
+    traced = [r for r in recs if r["attrs"].get("traced")]
+    assert len(traced) == eng.num_traces \
+        == len({r["attrs"]["rung"] for r in recs})
     snap = obs.snapshot()
     assert sum(snap["counters"]["engine.idle_steps"].values()) == 1
+
+
+def test_engine_phases_are_profiler_spans(gate_on, tmp_path):
+    """Three tiny steps inside ``jax.profiler``: the host plane holds
+    one ``engine.step`` span per step, labelled with the engine's step
+    index (the same index as the record's and the facts'), and the
+    phases and their children nested inside it."""
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.profiler import ProfileData
+
+    from flashinfer_tpu.serve import EngineRequest
+
+    cfg, eng = _tiny_engine(jnp)
+    rng = np.random.default_rng(1)
+    eng.submit(EngineRequest(
+        "r0", [int(t) for t in rng.integers(1, cfg.vocab_size, 5)],
+        max_new_tokens=5))
+    eng.step()                     # the compile stays outside the trace
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    facts = [eng.step() for _ in range(3)]
+    jax.profiler.stop_trace()
+    found = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    events = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+               dict(e.stats))
+              for p in (ProfileData.from_file(found[0]).planes
+                        if found else ())
+              if p.name == "/host:CPU" for ln in p.lines
+              for e in ln.events if e.name.startswith("engine.")]
+    if not events:
+        pytest.skip("this profiler writes no host plane with TraceMes")
+    steps = sorted(e for e in events if e[0] == "engine.step")
+    assert [e[3]["step"] for e in steps] == [f["step"] for f in facts] \
+        == [1, 2, 3]
+    recs = [r for r in steploop.ledger().records() if not r["idle"]][-3:]
+    assert [r["attrs"]["step"] for r in recs] == [1, 2, 3]
+    names = ("admit", "schedule", "assemble", "lower", "dispatch",
+             "dispatch.upload", "dispatch.call", "fetch", "post")
+    for _, lo, hi, _ in steps:
+        inside = {e[0] for e in events if lo <= e[1] and e[2] <= hi}
+        assert {f"engine.{n}" for n in names} <= inside
+    # a child lies inside its parent
+    call = sorted(e for e in events if e[0] == "engine.dispatch.call")
+    disp = sorted(e for e in events if e[0] == "engine.dispatch")
+    assert all(d[1] <= c[1] and c[2] <= d[2] for c, d in zip(call, disp))
 
 
 @pytest.mark.quick
@@ -456,23 +582,27 @@ def test_perf5_host_loop_section_and_live_join(fresh_ledger):
     assert hl["live"]["steps"] == 3
     assert hl["live"]["amdahl_ceiling"] == pytest.approx(1.5)
     assert hl["live"]["worst_phase"] == "dispatch"
+    assert "drift" not in hl["live"]
     text = roofline.render_perf_report(rep)
     assert "host loop" in text and "ceiling" in text
+    assert "drift" not in text
 
 
 @pytest.mark.quick
 def test_catalog_and_span_category_coverage():
     """Coverage gates stay closed: the steploop metrics are declared in
-    the catalog (the doc-parity test then forces docs), the drift
-    buckets live in catalog (NOT steploop — importing them must not
-    defeat the subprocess pin), and the span category is registered."""
-    from flashinfer_tpu.obs import spans
-    from flashinfer_tpu.obs.catalog import DRIFT_RATIO_BUCKETS, METRICS
+    the catalog (the doc-parity test then forces docs), the drift join
+    is gone from it, the phase histogram's row names the new phases,
+    and the span category is registered."""
+    from flashinfer_tpu.obs import catalog, spans
 
     for name in ("steploop.steps", "steploop.idle_ticks",
                  "steploop.host_us", "steploop.phase_us",
                  "steploop.device_us", "steploop.gap_us",
-                 "steploop.pred_vs_measured", "engine.idle_steps"):
-        assert name in METRICS, name
-    assert DRIFT_RATIO_BUCKETS[0] < 1.0 < DRIFT_RATIO_BUCKETS[-1]
+                 "engine.idle_steps"):
+        assert name in catalog.METRICS, name
+    assert "steploop.pred_vs_measured" not in catalog.METRICS
+    assert not hasattr(catalog, "DRIFT_RATIO_BUCKETS")
+    for phase in ("lower.build", "dispatch.call", "fetch", "post"):
+        assert phase in catalog.METRICS["steploop.phase_us"][2]
     assert "steploop" in spans.SPAN_CATEGORIES_VALID
