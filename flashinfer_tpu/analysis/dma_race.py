@@ -1601,7 +1601,10 @@ def _static_env(site: PallasCallSite,
     """Partial-bound kernel params evaluated in the launcher's scope:
     literals resolve concretely; a value expr that IS the final grid
     element ties to N_STEPS (the `num_units` coupling every
-    cross-unit-prefetch guard needs); the rest stay opaque statics."""
+    cross-unit-prefetch guard needs; a kernel that reads the bound as
+    `pl.num_programs(<final axis>)` instead, as the work-unit prefill
+    mainloop does for its dynamic trip count, gets the same tie from
+    the evaluator); the rest stay opaque statics."""
     out: Dict[str, object] = {}
     grid_last = None
     if site.grid_exprs:

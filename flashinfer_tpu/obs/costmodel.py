@@ -876,13 +876,15 @@ def engine_step(num_tokens: int, batch: int, layers: int, *, hidden: int,
 
     Launched-vs-effective (the KERNEL attention backend): when the
     engine runs the Pallas work-unit tier, ``kv_pairs_launched`` /
-    ``kv_rows_launched`` carry the REAL unit stats — padded unit
-    grids, chunk-aligned page walks, scratch-page DMAs included
-    (``ServingEngine.unit_stats``).  The attention term then prices
-    ``flops`` from launched pairs and ``bytes`` from launched rows,
-    with ``flops_effective`` holding the exact attended-pair work, so
-    ``obs perf`` exposes the tier's true padding waste instead of the
-    dense window the reference tier attends through.  Left ``None``
+    ``kv_rows_launched`` carry the REAL unit stats — the work units
+    each launch runs (a prefill-level launch runs its plan's real units,
+    never the pad units of the rung's cap), each a whole tile against a
+    whole chunk-aligned page walk (``ServingEngine.unit_stats``).  The
+    attention term then prices ``flops`` from launched pairs and
+    ``bytes`` from launched rows, with ``flops_effective`` holding the
+    exact attended-pair work, so ``obs perf`` exposes the tier's true
+    padding waste instead of the dense window the reference tier
+    attends through.  Left ``None``
     (the reference tier, and every pre-graduation caller) the formula
     is unchanged: launched == effective attended pairs."""
     qdim, kvdim = hq * hd, hkv * hd
@@ -1641,8 +1643,7 @@ register_cost_launch(CostLaunchBinding(
 ))
 
 
-def _fused_prefill_stats(sc):
-    u = sc["num_units"]
+def _fused_prefill_stats(sc, u):
     chunk = sc["ppc"] * sc["page_size"]
     cells = u * sc["bq"] * chunk
     return {"tiles": u, "units": u, "mxu_cells_total": cells,
@@ -1659,11 +1660,12 @@ def _fused_prefill_vmem_shapes(sc):
 
 
 def _fused_prefill_adapter(sc):
+    u = sc["n_units"]  # the launch runs the plan's real units only
     c = fused_prefill_from_stats(
-        _fused_prefill_stats(sc), block_q=sc["bq"],
+        _fused_prefill_stats(sc, u), block_q=sc["bq"],
         pages_per_chunk=sc["ppc"], page_size=sc["page_size"],
         num_qo_heads=sc["num_qo_heads"], num_kv_heads=sc["Hkv"],
-        head_dim=sc["head_dim"], total_q=sc["num_units"] * sc["bq"])
+        head_dim=sc["head_dim"], total_q=u * sc["bq"])
     return {"bytes_read": c.bytes_read,
             "bytes_written": c.bytes_written, "flops": c.flops}
 
@@ -1678,7 +1680,7 @@ def _fused_prefill_implicit(sc):
 register_cost_launch(CostLaunchBinding(
     launcher="fused_paged_prefill",
     family="fused_prefill_from_stats",
-    scenario=dict(Hkv=2, num_units=4, num_qo_heads=16, bq=128,
+    scenario=dict(Hkv=2, n_units=4, num_qo_heads=16, bq=128,
                   group=8, head_dim=128, page_size=16, ppc=8),
     statics=dict(bq=128, ppc=8, page_size=16, group=8, sm_scale=1.0,
                  logits_soft_cap=0.0, window_left=-1, causal=True,
@@ -1691,10 +1693,13 @@ register_cost_launch(CostLaunchBinding(
     compare={"bytes_read": 0.0, "bytes_written": 0.0, "flops": 0.0},
     itemsizes={"acc_ref": 4, "m_ref": 4, "l_ref": 4, "lsebuf": 4},
     implicit_fallback=_fused_prefill_implicit,
-    notes="4 work units, each its own q tile and single full KV "
-          "chunk (first=wout=1, CODE_FULL): the stats adapter's "
-          "tiles/units/cells mirror the plan exactly, so parity is "
-          "exact (tol 0) on reads, writes and MXU FLOPs.",
+    notes="4 real work units, each its own q tile and single full "
+          "KV chunk (first=wout=1, CODE_FULL).  The unit axis's trip "
+          "count is the plan's n_units whatever length the plan "
+          "arrays are padded to, so pad units are not run and the "
+          "family prices none: the stats adapter's tiles/units/cells "
+          "mirror the launch exactly, parity is exact (tol 0) on "
+          "reads, writes and MXU FLOPs.",
 ))
 
 
@@ -1714,7 +1719,7 @@ def _prefill_ingest_adapter(sc):
     total_q, total_kv = u * sc["bq"], u * chunk
     hq, hkv, d = sc["num_qo_heads"], sc["Hkv"], sc["head_dim"]
     c = prefill_ingest(
-        total_q, total_kv, hq, hkv, d, stats=_fused_prefill_stats(sc),
+        total_q, total_kv, hq, hkv, d, stats=_fused_prefill_stats(sc, u),
         block_q=sc["bq"], pages_per_chunk=sc["ppc"],
         page_size=sc["page_size"])
     rope_flops = 6.0 * (total_q * hq + total_kv * hkv) * d

@@ -34,9 +34,12 @@ the unit list sequentially with an explicitly pipelined mainloop:
   Padding waste (idle unit rows / idle MXU cells) is reported through
   the plan's ``stats`` into the obs padding-waste histograms.
 
-Grid is ``(num_kv_heads, num_units)``: each unit computes ALL q heads of
+Grid is ``(num_kv_heads, n_units)``: each unit computes ALL q heads of
 one KV head's GQA group, so every KV page is fetched from HBM exactly once
-per kv head — the same bandwidth discipline as the decode kernel.
+per kv head — the same bandwidth discipline as the decode kernel.  The
+unit axis runs the plan's REAL units only (``plan["n_units"]``, a value
+of the call, not of the trace): the plan arrays keep their padded static
+length, so one trace serves every unit count, and pad units are never run.
 
 vs the gather+flash path (prefill.py): no extra HBM round trip for KV —
 for chunked prefill (small qo vs large kv) the gather pass costs ~50% of
@@ -190,7 +193,15 @@ def build_prefill_work_units(
     re-plan every step against ONE compiled launch — the serving
     engine's rung ladder (serve/engine_kernels.py) — pad every plan of
     a rung to the same cap so the plan-array SHAPES never retrace while
-    the values change freely.
+    the values change freely.  Such a plan must hold at least one real
+    unit (AssertionError otherwise): its launch runs ``n_units`` grid
+    steps, and an empty grid would leave the output unwritten.
+
+    ``n_units`` (an int32 scalar, ``stats["units"]``, or 1 for a plan
+    of no unit at all: one identity pad unit) is the count of real
+    units, which lead the arrays.  :func:`fused_paged_prefill` takes it
+    as the trip count of its unit axis when the plan handed to it
+    carries it, so the pad units cost nothing on the device.
 
     Per-unit fields: ``qstart`` (q-tile token start), ``rowlo``/``rowhi``
     (this unit's request's row span within the tile), ``qpos0``
@@ -439,7 +450,11 @@ def build_prefill_work_units(
                 f"{n_real} work units — the caller's per-rung unit cap "
                 "is undersized (serve/engine_kernels.py computes it "
                 "from the rung statics; a schedule can never exceed it)")
-        U = max(int(num_units_pad), 1)
+        assert n_real >= 1, (
+            "a plan padded to a fixed unit count needs a real unit: the "
+            "caller covers every q row with a segment, so even a step "
+            "with no KV keeps its zero-write fallback units")
+        U = int(num_units_pad)
     else:
         U = max(next_power_of_two(max(n_real, 1)), 8)
     n_mxu = n_real - n_write_only  # write-only units run no MXU dot
@@ -483,6 +498,7 @@ def build_prefill_work_units(
         first=np.asarray(first, np.int32), wout=np.asarray(wout, np.int32),
         qslot=np.asarray(qslot, np.int32), code=arr(6, np.int32),
         pages=np.stack([u[7] for u in units]).astype(np.int32).reshape(-1),
+        n_units=np.int32(max(n_real, 1)),
         num_units=U,
         block_q=block_q,
         pages_per_chunk=pages_per_chunk,
@@ -973,7 +989,6 @@ def _fused_prefill_kernel(
     logits_soft_cap: float,
     window_left: int,
     causal: bool,
-    num_units: int,
     has_mask: bool,
     return_lse: bool,
     trace_events: bool,
@@ -1028,7 +1043,10 @@ def _fused_prefill_kernel(
             qbuf.at[slot], qsem.at[slot],
         )
 
-    # guarded next-unit index (scalar arrays are exactly num_units long)
+    # the unit axis's trip count (the plan's count of real units, or the
+    # static length of its arrays: the launcher's choice).  It guards every
+    # prefetch below, so no DMA is started that no later grid step waits for.
+    num_units = pl.num_programs(1)
     nxt = jnp.minimum(u + 1, num_units - 1)
 
     # warm-up: unit 0's q tile (only if unit 0 opens a tile — an
@@ -1216,7 +1234,7 @@ def fused_paged_prefill(
     v_cache: jax.Array,
     plan: dict,  # jnp arrays from build_prefill_work_units
     *,
-    num_units: int,
+    num_units: int,  # static length of the plan arrays (pad units included)
     block_q: int = 128,
     pages_per_chunk: int = 8,
     sm_scale: float = 1.0,
@@ -1245,6 +1263,11 @@ def fused_paged_prefill(
     q_pad = jnp.transpose(
         q_pad.reshape(total_q + block_q, Hkv, group, D), (1, 0, 2, 3)
     )
+    # run only the units the plan has: real units lead the arrays and pad
+    # units are identity steps, so the plan's own count, where the caller
+    # hands it over, bounds the unit axis (a traced scalar: shapes, and so
+    # the trace, stay functions of ``num_units`` alone)
+    n_units = plan.get("n_units", num_units)
 
     in_specs = [
         pl.BlockSpec(memory_space=pl.ANY),
@@ -1295,7 +1318,7 @@ def fused_paged_prefill(
                      else [out_shape]) + [ev_shape]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=11,
-        grid=(Hkv, num_units),
+        grid=(Hkv, n_units),
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=[
@@ -1328,7 +1351,7 @@ def fused_paged_prefill(
             _fused_prefill_kernel,
             bq=block_q, ppc=pages_per_chunk, page_size=page_size,
             group=group, sm_scale=sm_scale, logits_soft_cap=logits_soft_cap,
-            window_left=window_left, causal=causal, num_units=num_units,
+            window_left=window_left, causal=causal,
             has_mask=has_mask, return_lse=return_lse,
             trace_events=trace_events,
         ),
@@ -1354,8 +1377,11 @@ def fused_paged_prefill(
     elif trace_events:
         out, ev = out
     if trace_events:
-        # [Hkv, ceil(U/8), 8, 128] -> [Hkv, num_units] tags, grid order
+        # [Hkv, ceil(U/8), 8, 128] -> [Hkv, num_units] tags, grid order;
+        # a unit past the trip count was not run and has no tag (0, which
+        # the exporter skips), whatever its never-written slot holds
         events = ev[..., 0].reshape(Hkv, -1)[:, :num_units]
+        events = jnp.where(jnp.arange(num_units) < n_units, events, 0)
     if return_lse:
         # [Hkv, tq_pad, group, 128] -> [tq, H] (lane 0 carries the value)
         lse = jnp.transpose(lse_raw[:, :total_q, :, 0], (1, 0, 2)).reshape(

@@ -692,11 +692,11 @@ class ServingEngine:
         rows with, shared-prefix KV dedup included via kv_rows.
 
         On the KERNEL backend the launched terms come from the REAL
-        work-unit stats (padded unit grids, scratch-page chunk DMAs and
-        all — ``ServingEngine.unit_stats``) while the effective terms
-        stay the exact attended-pair accounting, so the stamped
-        ``flops`` vs ``flops_effective`` gap is the tier's true
-        padding/pruning waste, not the dense-window fiction the
+        work-unit stats (the units each launch runs, whole tiles and
+        chunk-aligned page walks — ``ServingEngine.unit_stats``) while
+        the effective terms stay the exact attended-pair accounting, so
+        the stamped ``flops`` vs ``flops_effective`` gap is the tier's
+        true tile-padding waste, not the dense-window fiction the
         reference tier pays."""
         from flashinfer_tpu.obs import costmodel
 

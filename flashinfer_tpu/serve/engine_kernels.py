@@ -36,7 +36,9 @@ SAME per-step schedule onto the proven work-unit kernels instead:
 Retrace contract (the engine's rung ladder): every plan-array shape is
 a pure function of the RUNG, never the schedule values — prefill plans
 pad to :meth:`EngineKernelGeom.prefill_unit_cap` via the planner's
-``num_units_pad``, the decode plan is ``max_batch x num_splits`` units
+``num_units_pad`` (the two launches run only the plan's real units: its
+``n_units`` leaf is the trip count of their unit axis, a value and not a
+shape), the decode plan is ``max_batch x num_splits`` units
 over a fixed-width page table, and the level-0 custom-mask operand is
 always present (all-ones windows demote to the maskless PARTIAL code
 in-plan, so steady-state decode pays no expansion).  One rung == one
@@ -144,7 +146,8 @@ class EngineKernelGeom:
         1`` qo tiles, each (tile, segment) span walks at most
         ``max_prefill_chunks`` KV chunks, and pruned spans keep one
         zero-write fallback unit.  The cap is a RUNG static, so a rung's
-        plan shape can never vary step to step."""
+        plan shape can never vary step to step.  It sizes the plan
+        arrays only: a launch runs the units a step's plan has."""
         spans = cdiv(rung, self.block_q) + 2 * (self.max_batch + 1)
         return max(next_power_of_two(spans * self.max_prefill_chunks), 8)
 
@@ -153,7 +156,7 @@ class EngineKernelGeom:
 # statics and stats stay host-side so the traced pytree is rung-stable)
 PREFILL_ARRAY_KEYS = ("qstart", "rowlo", "rowhi", "qpos0", "kvstart",
                       "kvlen", "first", "wout", "qslot", "code", "pages",
-                      "mask_bytes")
+                      "n_units", "mask_bytes")
 DECODE_ARRAY_KEYS = ("pages", "kvlen", "wu_req", "wu_page0", "wu_kvlen")
 # the ingest plan's traced arrays (fused_ingest geoms only): the base
 # work-unit arrays plus the ingest extras, no mask (from-scratch causal)
@@ -186,9 +189,10 @@ def build_engine_work_units(
                     (== rung for idle slots: gathers clip to a harmless
                     row, scatters drop)
         stats     — launched-vs-effective unit accounting for the
-                    ``engine_step`` cost family (padding INCLUDED in
-                    launched work: pad prefill units still stream their
-                    scratch-page chunk and run the masked MXU update)
+                    ``engine_step`` cost family (a prefill launch runs
+                    its plan's real units, so launched counts those:
+                    what is launched and not effective is the idle
+                    rows and columns inside a real unit's tile)
 
     Every array shape depends only on ``rung`` and ``geom`` — the
     engine's compile-once contract.
@@ -373,16 +377,17 @@ def build_engine_work_units(
         assert ingest_plan["stats"]["ingest_kv_aligned"]
 
     chunk_tokens = geom.prefill_ppc * ps
+    # what the two prefill launches run: each level's ``n_units``, the
+    # trip count of its unit axis (the pad units up to U are not run)
+    launched = int(plan0["n_units"]) + int(plan1["n_units"])
     stats = {
-        # launched work counts the PADDED unit grid: pad units still
-        # DMA their scratch-page chunk and run the masked MXU update,
-        # which is exactly the waste effective_pct_roofline exposes
         "prefill_units": plan0["stats"]["units"] + plan1["stats"]["units"],
-        "prefill_units_launched": 2 * U,
-        "prefill_cells_launched": 2.0 * U * geom.block_q * chunk_tokens,
+        "prefill_units_launched": launched,
+        "prefill_cells_launched": float(
+            launched * geom.block_q * chunk_tokens),
         "prefill_cells_valid": float(plan0["stats"]["mxu_cells_valid"]
                                      + plan1["stats"]["mxu_cells_valid"]),
-        "prefill_rows_launched": 2.0 * U * chunk_tokens,
+        "prefill_rows_launched": float(launched * chunk_tokens),
         "decode_pages_real": dplan["stats"]["pages_real"],
         "decode_pages_launched": dplan["stats"]["pages_launched"],
         "decode_rows_launched": float(

@@ -182,6 +182,27 @@ def test_l014_wait_deletion_skew_real_fused_prefill():
     assert {"read-before-wait", "dangling-dma"} <= tags, tags
 
 
+def test_l014_unit_bound_skew_real_fused_prefill():
+    """The work-unit mainloop reads its unit axis's trip count through
+    ``pl.num_programs(1)`` (a value of the call: the plan's count of real
+    units), which the model ties to N_STEPS.  Loosen the KV prefetch
+    guard by one and the last unit run starts a chunk that no grid step
+    waits for."""
+    real = _real(OPS_PREFILL)
+    head, kernel = real.split("def _fused_prefill_kernel(", 1)
+    assert "num_units = pl.num_programs(1)" in kernel
+    guard = ("    @pl.when(u + 1 < num_units)\n"
+             "    def _():\n"
+             "        for d in kv_dmas(nxt, jax.lax.rem(u + 1, 2)):")
+    assert kernel.count(guard) == 1
+    skew = head + "def _fused_prefill_kernel(" + kernel.replace(
+        guard, guard.replace("u + 1 < num_units", "u < num_units"))
+    findings = dma_race.run(
+        _project(("flashinfer_tpu/ops/paged_prefill.py", skew)))
+    assert findings and all(f.code == "L014" for f in findings)
+    assert "dangling-dma" in _tags(findings)
+
+
 def test_l014_slot_parity_skew_real_fused_prefill():
     """Prefetching the NEXT unit into the CURRENT slot (rem(u) instead
     of rem(u+1)) overwrites the buffer the mainloop is about to read."""

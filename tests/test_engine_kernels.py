@@ -173,10 +173,20 @@ def test_kernel_retrace_budget_and_steady_state(params):
     eng = _mk_engine(params, "kernel")
     for i, p in enumerate(_prompts(rng, 6)):
         eng.submit(EngineRequest(f"a{i}", list(p), max_new_tokens=3))
-    eng.run()
+    facts = []
+    while eng.has_work():
+        facts.append(eng.step())
     first_wave = eng.num_traces
     assert first_wave == len(eng._rung_traced) <= 9
     assert all(n == 1 for n in eng._rung_traced.values())
+    # the prefill launches' unit count is a VALUE of the step's plan: the
+    # steps of one rung ran different counts (all of them real units) on
+    # that rung's one trace
+    by_rung = {}
+    for f in facts:
+        assert f["prefill_units_launched"] == f["prefill_units"] >= 2
+        by_rung.setdefault(f["rung"], set()).add(f["prefill_units_launched"])
+    assert max(len(counts) for counts in by_rung.values()) >= 3, by_rung
     for i, p in enumerate(_prompts(rng, 6)):
         eng.submit(EngineRequest(f"b{i}", list(p), max_new_tokens=3))
     eng.run()
@@ -256,6 +266,119 @@ def test_planner_covers_every_rung_row():
                 qs = int(p["qstart"][u])
                 covered |= set(range(qs, qs + bq))
         assert covered >= set(range(16)), (lvl, sorted(covered))
+
+
+# one schedule per kind of unit the two prefill-level launches can meet
+# (rung 16, page 8; _geom() gives block_q 16, 8 pages a chunk)
+_UNIT_SCHEDULES = {
+    # decode lanes and prefill chunks in one step, no shared prefix
+    "mixed": [
+        SchedSeg(row0=0, n=1, pages=(1, 2, 3), split=0, kv_after=20,
+                 decoding=True, slot=0, group=0),
+        SchedSeg(row0=1, n=1, pages=(4, 5), split=0, kv_after=11,
+                 decoding=True, slot=1, group=1),
+        SchedSeg(row0=2, n=6, pages=(6, 7, 8), split=0, kv_after=19,
+                 decoding=False, slot=2, group=2),
+        SchedSeg(row0=8, n=5, pages=(9,), split=0, kv_after=5,
+                 decoding=False, slot=3, group=3)],
+    # two requests on one 16-token prefix; the second's chunk starts
+    # inside it, so level 0 has real units whose mask cuts
+    "shared_prefix": [
+        SchedSeg(row0=0, n=1, pages=(1, 2, 3), split=16, kv_after=21,
+                 decoding=True, slot=0, group=0),
+        SchedSeg(row0=1, n=7, pages=(1, 2, 4), split=16, kv_after=19,
+                 decoding=False, slot=1, group=0),
+        SchedSeg(row0=8, n=3, pages=(5,), split=0, kv_after=3,
+                 decoding=False, slot=2, group=1)],
+    # one decode lane with no prefix: neither level has KV to attend,
+    # both plans are zero-write fallback units only
+    "fallback_only": [
+        SchedSeg(row0=0, n=1, pages=(1, 2), split=0, kv_after=9,
+                 decoding=True, slot=0, group=0)],
+}
+
+
+def test_planner_counts_the_units_it_launches():
+    """``n_units`` is the plan's count of real units, at least one per
+    level, and what the step's stats call launched is those units."""
+    from flashinfer_tpu.ops.paged_prefill import build_prefill_work_units
+
+    g = _geom()
+    chunk = g.prefill_ppc * g.page_size
+    seen = set()
+    for segs in _UNIT_SCHEDULES.values():
+        plans = build_engine_work_units(segs, rung=16, geom=g)
+        n = 0
+        for lvl in ("prefill0", "prefill1"):
+            p = plans[lvl]
+            assert p["n_units"].dtype == np.int32 and p["n_units"].shape == ()
+            assert p["n_units"] == p["stats"]["units"] >= 1
+            assert p["qstart"].shape == (g.prefill_unit_cap(16),)
+            n += int(p["n_units"])
+        st = plans["stats"]
+        assert st["prefill_units_launched"] == st["prefill_units"] == n
+        assert st["prefill_cells_launched"] == n * g.block_q * chunk
+        assert st["prefill_rows_launched"] == n * chunk
+        assert st["prefill_cells_valid"] <= st["prefill_cells_launched"]
+        seen.add(n)
+    assert len(seen) == len(_UNIT_SCHEDULES)  # three counts, one shape
+    # a plan padded to a cap with no unit at all would launch an empty grid
+    with pytest.raises(AssertionError, match="real unit"):
+        build_prefill_work_units(
+            np.asarray([0, 0], np.int64), np.asarray([0, 0], np.int64),
+            np.zeros(0, np.int64), np.asarray([0], np.int64),
+            16, 2, 8, causal=True, num_units_pad=8)
+
+
+@pytest.mark.parametrize("schedule", sorted(_UNIT_SCHEDULES))
+def test_dynamic_unit_bound_is_bitwise_the_static_grid(schedule):
+    """Both prefill-level launches with the plan's ``n_units`` as the
+    trip count of the unit axis against the same launches over the whole
+    padded grid (the plan without that leaf): ``o`` and ``lse`` bit for
+    bit, and no further trace for another count at the same shapes."""
+    from flashinfer_tpu.ops.paged_prefill import (CODE_PARTIAL_MASK,
+                                                  fused_paged_prefill)
+    from flashinfer_tpu.serve.engine_kernels import PREFILL_ARRAY_KEYS
+
+    g = _geom()
+    HQ, HKV, D = 4, 2, 64
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(5), 3)
+    q = jax.random.normal(kq, (16, HQ, D), jnp.float32)
+    kc = jax.random.normal(kk, (12, HKV, g.page_size, D), jnp.float32)
+    vc = jax.random.normal(kv, (12, HKV, g.page_size, D), jnp.float32)
+
+    def launch(plans, lvl, dynamic):
+        p = {k: jnp.asarray(plans[lvl][k]) for k in PREFILL_ARRAY_KEYS
+             if k in plans[lvl]}
+        if not dynamic:
+            p = {k: v for k, v in p.items() if k != "n_units"}
+        return fused_paged_prefill(
+            q, kc, vc, p, num_units=p["qstart"].shape[0],
+            block_q=g.block_q, pages_per_chunk=g.prefill_ppc,
+            sm_scale=D ** -0.5, causal=lvl == "prefill1", return_lse=True)
+
+    plans = build_engine_work_units(_UNIT_SCHEDULES[schedule], rung=16,
+                                    geom=g)
+    p0, p1 = plans["prefill0"], plans["prefill1"]
+    real0 = p0["code"][:int(p0["n_units"])]
+    assert (schedule == "shared_prefix") == bool(
+        (real0 == CODE_PARTIAL_MASK).any())
+    if schedule == "fallback_only":
+        for p in (p0, p1):  # the lane's row, and the rung's padding rows
+            assert not p["kvlen"].any() and p["n_units"] == 2
+    for lvl in ("prefill0", "prefill1"):
+        assert plans[lvl]["n_units"] < plans[lvl]["qstart"].shape[0]
+        o_dyn, lse_dyn = launch(plans, lvl, True)
+        o_st, lse_st = launch(plans, lvl, False)
+        np.testing.assert_array_equal(np.asarray(o_dyn), np.asarray(o_st))
+        np.testing.assert_array_equal(np.asarray(lse_dyn),
+                                      np.asarray(lse_st))
+        assert np.isfinite(np.asarray(o_dyn)).all()
+    traced = fused_paged_prefill._cache_size()
+    for other in sorted(set(_UNIT_SCHEDULES) - {schedule}):
+        launch(build_engine_work_units(_UNIT_SCHEDULES[other], rung=16,
+                                       geom=g), "prefill0", True)
+    assert fused_paged_prefill._cache_size() == traced
 
 
 def test_schedule_gap_raises():

@@ -145,20 +145,34 @@ def _prefill_geometry():
         np.arange(int(pages.sum()), dtype=np.int64), kv_lens
 
 
-def test_work_unit_paged_prefill(chip, compiled_branch):
+@pytest.mark.parametrize("masked", [False, True])
+def test_work_unit_paged_prefill(chip, compiled_branch, masked):
+    """The plan carries its count of real units (``n_units``), so the
+    unit axis of the grid has a dynamic bound; ``masked`` adds the
+    pipelined per-unit mask operand, as the engine's level-0 launch has
+    it (the kernel's other operands are manual DMAs)."""
     from flashinfer_tpu.ops.paged_prefill import (build_prefill_work_units,
                                                   fused_paged_prefill)
 
     qo, kvp, kvi, kv_lens = _prefill_geometry()
+    mask = None
+    if masked:  # causal by position, spelled as a custom mask
+        mask = np.concatenate([
+            (np.arange(kv)[None, :]
+             <= (kv - n + np.arange(n))[:, None]).reshape(-1)
+            for n, kv in zip(np.diff(qo), kv_lens)])
     plan = build_prefill_work_units(qo, kvp, kvi, kv_lens, block_q=128,
-                                    pages_per_chunk=8, page_size=PS)
+                                    pages_per_chunk=8, page_size=PS,
+                                    mask_flat=mask, causal=not masked)
     statics = {k: plan.pop(k)
                for k in ("num_units", "block_q", "pages_per_chunk")}
-    plan.pop("stats")
+    stats = plan.pop("stats")
+    assert plan["n_units"] == stats["units"] < statics["num_units"]
+    assert ("mask_bytes" in plan) == masked
     cache = _sds(chip, (len(kvi), HKV, PS, D), jnp.bfloat16)
     _compile(
         lambda q, kc, vc, p: fused_paged_prefill(
-            q, kc, vc, p, sm_scale=SM, causal=True, return_lse=True,
+            q, kc, vc, p, sm_scale=SM, causal=not masked, return_lse=True,
             **statics),
         _sds(chip, (int(qo[-1]), HQ, D), jnp.bfloat16), cache, cache,
         _like(chip, plan))

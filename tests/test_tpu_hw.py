@@ -593,8 +593,12 @@ def test_trace_events_prefill_on_chip():
     )
     ev = np.asarray(events)
     assert ev.shape == (HKV, num_units)
+    # one tag per unit the launch ran (the plan's real units, which lead
+    # the arrays), none for the pad units past its trip count
+    n_units = int(plan["n_units"])
+    assert 0 < n_units < num_units and not ev[:, n_units:].any()
     for h in range(HKV):
-        for u in range(num_units):
+        for u in range(n_units):
             blk, grp, ei, et, sm = profiler.decode_tag(
                 int(ev[h, u]), num_units, 1
             )
