@@ -2,17 +2,16 @@
 
 What is compared is what the timed window itself left behind:
 
-* ``kv_rel_rms`` - the K/V rows that the window's steps wrote into the
-  engine's paged cache, for requests still in flight when the window closed
-  (read out through their page lists by the harness), against the rows the
-  plain float32 reference (``reference.py``) computes for the same token
-  sequence.  Rows of layer l depend on every kernel of the layers before it
-  (prefill launches, decode launch, cascade merge, norm, MLP), so the first
-  ``CHECK_LAYERS`` layers cross them all; deeper rows add only the
-  amplification of rounding by a random-weight network, and are printed but
-  not held to the limit.  The number is the worst, over the sampled requests
-  and those layers, of ``||rows - reference|| / ||reference||`` (Frobenius,
-  K and V together).
+* the rows number (the family names it: ``kv_rel_rms`` for K/V rows) - the
+  rows that the window's steps wrote into the engine's cache, for requests
+  still in flight when the window closed (read out by the family's
+  ``state_rows``), against the rows the family's plain float32 reference
+  computes for the same token sequence.  Rows come per comparable unit (a
+  layer, or one of a layer's attentions) as named kinds (``k`` and ``v``, or
+  a latent row), and the family says how many leading units are held to the
+  limit: deeper ones are printed, not held.  The number is the worst, over
+  the sampled requests and those units, of ``||rows - reference|| /
+  ||reference||`` (Frobenius, all kinds of a unit together).
 * ``token_below_topk`` - every token the window served for the sampled
   requests (in flight and finished), against the reference's logits at the
   position that produced it: how far the served token's logit lies below the
@@ -24,7 +23,11 @@ What is compared is what the timed window itself left behind:
 * exact counts, limit 0: a row that is not finite, a token outside the
   vocabulary, a trace or a compile inside the window.
 
-The limits and the readings they were set from are below, beside the check.
+This file keeps what is common to every block: padding to buckets, the
+relative-RMS rule, ``token_below_topk``, the exact counts, the control's
+plumbing and the verdict.  The block's reference, the number of units held,
+the limits with the readings they were set from, and any further number of
+its own (``more_numbers``) are the family's (``families/<family>.py``).
 """
 
 from __future__ import annotations
@@ -33,38 +36,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-import reference
-import weights
+# Exact comparisons, whatever the block: the limit is 0.
+EXACT = ("rows_not_finite", "tokens_outside_vocab", "traces_in_window",
+         "compiles_in_window")
 
-CHECK_LAYERS = 4
 
-# Limits.  Each stands between the largest reading of sound runs and the
-# smallest of the control (the reference computed in int8, the step below
-# the configurations' bf16, put in the program's place), both read on the
-# chip at the cells' own sizes; PERF.md section 2 lists every reading.
-#
-# kv_rel_rms: sound runs read 0.0168-0.0172 (7B, both cells) and
-# 0.0188-0.0191 (Nemo) over 13 and 12 seeds (bf16 storage and bf16 activations
-# against float32: 0.0026 at layer 0, growing by attention and MLP rounding to
-# layer 3); the int8 control reads 0.0941-0.0946 (7B) and 0.1035-0.1038 (Nemo)
-# on four seeds each (0.0126 at layer 0).  The limit is near the geometric
-# middle: 2.1 times the largest sound reading, and the smallest control
-# reading is 2.4 times it.
-KV_REL_RMS_LIMIT = 0.04
-# token_below_topk: held against the fault it is there to catch, a token
-# altered or drawn outside the reference's top k (a broken sampler, head or
-# last layer reads several logits: the logits' spread is ~1.3, and the top 40
-# of 32,768 start ~3 spreads up).  Sound runs read 0.00-0.144 logits (bf16
-# rounding of 16 layers moves a logit by a few hundredths, and a served token
-# is inside the program's own top 40; a widest gap swings by its nature); the
-# limit is about three times their largest.  The int8 control reads 0.74-1.08,
-# so it fails this number too.
-TOKEN_BELOW_TOPK_LIMIT = 0.4
-
-LIMITS = {"kv_rel_rms": KV_REL_RMS_LIMIT,
-          "token_below_topk": TOKEN_BELOW_TOPK_LIMIT,
-          "rows_not_finite": 0, "tokens_outside_vocab": 0,
-          "traces_in_window": 0, "compiles_in_window": 0}
+def limits(family):
+    """{number: limit}: the family's, and 0 for the exact counts."""
+    return {**family.LIMITS, **dict.fromkeys(EXACT, 0)}
 
 
 def bucket(n, floor=32):
@@ -80,21 +59,18 @@ def _pad(tokens):
     return list(tokens) + [0] * (bucket(len(tokens)) - len(tokens))
 
 
-def _rel(a, ref):
-    return float(jnp.sqrt(jnp.sum((a - ref) ** 2) / jnp.sum(ref ** 2)))
+def _rel(got, ref):
+    """``||got - ref|| / ||ref||`` over all the kinds of one unit's rows."""
+    num = sum(jnp.sum((got[kind] - ref[kind]) ** 2) for kind in ref)
+    return float(jnp.sqrt(num / sum(jnp.sum(r ** 2) for r in ref.values())))
 
 
-def run_reference(samples, m, seed, *, precision, on_rows):
-    """One pass of the reference over the samples' sequences.  Returns the
-    logits at each sample's ``logit_rows``."""
-    ends = weights.ends_params(seed, m)
-    out = reference.forward(
-        [_pad(s["tokens"]) for s in samples], m, ends["embed"],
-        lambda li: weights.layer_params(seed, m, li),
-        layers=m["num_hidden_layers"], on_rows=on_rows, precision=precision,
-        head=(ends["final_norm"], ends["lm_head"]),
-        logit_rows=[s["logit_rows"] for s in samples])
-    return out
+def run_reference(samples, family, m, seed, *, precision, on_rows):
+    """One pass of the family's reference over the samples' sequences.
+    Returns the logits at each sample's ``logit_rows``."""
+    return family.reference_forward(
+        [_pad(s["tokens"]) for s in samples], m, seed, on_rows=on_rows,
+        precision=precision, logit_rows=[s["logit_rows"] for s in samples])
 
 
 def _below_topk(logits, tokens, k):
@@ -105,83 +81,96 @@ def _below_topk(logits, tokens, k):
     return kth - got
 
 
-def compare(samples, m, seed, *, top_k, control=False, say=print):
+def _rows_len(prog_rows):
+    return next(iter(prog_rows.values())).shape[1]
+
+
+def compare(samples, family, m, seed, *, top_k, control=False, say=print):
     """The numbers of one run.  Each sample is a dict:
 
         tokens      the sequence the engine was fed: prompt + served tokens
                     but the last (teacher forcing)
-        prog_rows   None, or the engine's cache rows for it,
-                    [layers, 2, n, Hkv, D] (any float type, host or device)
+        prog_rows   None, or the engine's cache rows for it as the family's
+                    ``state_rows`` gives them, {kind: [units, n, ...]} (any
+                    float type, host or device)
         logit_rows  positions whose logits produced a served token
         served      the tokens served at those positions
 
     Returns ({number: value}, {control number: value} or None)."""
-    layers = m["num_hidden_layers"]
+    held = family.UNITS_HELD
     k = max(int(top_k), 1)
-    per_layer = np.zeros(layers)
-    keep = {}  # (sample, layer) -> reference rows, for the control
+    per_unit = {}
+    keep = {}  # (sample, unit) -> reference rows, for the control
     bad_rows = 0
 
-    def on_ref(si, li, kr, vr):
+    def on_ref(si, unit, rows):
         nonlocal bad_rows
         s = samples[si]
         if s["prog_rows"] is None:
             return
-        n = s["prog_rows"].shape[2]
-        ref = jnp.stack([kr[:n], vr[:n]])
-        got = jnp.asarray(s["prog_rows"][li]).astype(jnp.float32)
-        bad_rows += int(jnp.sum(~jnp.isfinite(got)))
-        per_layer[li] = max(per_layer[li], _rel(got, ref))
-        if control and li < CHECK_LAYERS:
-            keep[si, li] = ref
+        n = _rows_len(s["prog_rows"])
+        ref = {kind: r[:n] for kind, r in rows.items()}
+        got = {kind: jnp.asarray(s["prog_rows"][kind][unit])
+               .astype(jnp.float32) for kind in ref}
+        bad_rows += sum(int(jnp.sum(~jnp.isfinite(g))) for g in got.values())
+        per_unit[unit] = max(per_unit.get(unit, 0.0), _rel(got, ref))
+        if control and unit < held:
+            keep[si, unit] = ref
 
-    logits = run_reference(samples, m, seed, precision="float32",
+    logits = run_reference(samples, family, m, seed, precision="float32",
                            on_rows=on_ref)
     gaps = [np.asarray(_below_topk(lg, s["served"], k))
             for lg, s in zip(logits, samples) if len(s["served"])]
     gap = float(np.max(np.concatenate(gaps))) if gaps else 0.0
     n_tok = int(sum(len(g) for g in gaps))
-    with_rows = [s["prog_rows"].shape[2] for s in samples
+    with_rows = [s["prog_rows"] for s in samples
                  if s["prog_rows"] is not None]
-    say(f"check: K/V rows of {len(with_rows)} request(s), {sum(with_rows)} "
-        f"positions; relative RMS deviation per layer "
-        f"{[round(float(x), 5) for x in per_layer]}")
+    say(f"check: cache rows ({', '.join(with_rows[0]) if with_rows else '-'})"
+        f" of {len(with_rows)} request(s), "
+        f"{sum(_rows_len(r) for r in with_rows)} positions; relative RMS "
+        f"deviation per unit "
+        f"{[round(per_unit[u], 5) for u in sorted(per_unit)]}")
     say(f"check: {n_tok} served tokens of {len(gaps)} request(s); widest gap "
         f"below the reference's top-{k} cutoff {gap:.4f} logits")
-    numbers = {"kv_rel_rms": float(per_layer[:CHECK_LAYERS].max()),
+    numbers = {family.ROWS_NUMBER: max((v for u, v in per_unit.items()
+                                        if u < held), default=0.0),
                "token_below_topk": gap, "rows_not_finite": bad_rows}
+    if hasattr(family, "more_numbers"):
+        numbers.update(family.more_numbers(samples, m, seed, logits))
     ctl = None
     if control:
-        ctl_layer = np.zeros(CHECK_LAYERS)
+        ctl_unit = {}
 
-        def on_ctl(si, li, kr, vr):
-            if (si, li) in keep:
-                ref = keep[si, li]
-                n = ref.shape[1]
-                ctl_layer[li] = max(ctl_layer[li],
-                                    _rel(jnp.stack([kr[:n], vr[:n]]), ref))
+        def on_ctl(si, unit, rows):
+            if (si, unit) in keep:
+                ref = keep[si, unit]
+                got = {kind: rows[kind][:len(r)] for kind, r in ref.items()}
+                ctl_unit[unit] = max(ctl_unit.get(unit, 0.0), _rel(got, ref))
 
-        clogits = run_reference(samples, m, seed, precision="int8",
+        clogits = run_reference(samples, family, m, seed,
+                                precision=family.CONTROL_PRECISION,
                                 on_rows=on_ctl)
         cg = []
         for lg, cl, s in zip(logits, clogits, samples):
             if len(s["served"]):
                 kth_tok = jax.lax.top_k(cl, k)[1][:, -1]
                 cg.append(np.asarray(_below_topk(lg, kth_tok, k)))
-        ctl = {"kv_rel_rms": float(ctl_layer.max()),
+        ctl = {family.ROWS_NUMBER: max(ctl_unit.values(), default=0.0),
                "token_below_topk": float(np.max(np.concatenate(cg)))
                if cg else 0.0}
-        say(f"control (reference in int8 weights): relative RMS deviation "
-            f"per layer {[round(float(x), 5) for x in ctl_layer]}; widest "
+        say(f"control (reference in {family.CONTROL_PRECISION}): relative "
+            f"RMS deviation per unit "
+            f"{[round(ctl_unit[u], 5) for u in sorted(ctl_unit)]}; widest "
             f"gap of its own top-{k} token {ctl['token_below_topk']:.4f}")
     return numbers, ctl
 
 
-def verdict(numbers, say=print):
-    """True when every number is within its limit; prints each beside it."""
+def verdict(numbers, limit_of, say=print):
+    """True when every number is within its limit (``limit_of``:
+    :func:`limits` of the family); prints each beside it."""
     ok = True
     for name, value in numbers.items():
-        limit = LIMITS[name]
+        limit = limit_of[name]
         good = np.isfinite(value) and value <= limit
         ok &= bool(good)
         say(f"compared {name}: {value:.6g} (limit {limit:g}) "

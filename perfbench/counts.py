@@ -5,6 +5,13 @@ These are the benchmark's own yardstick: a later PR cannot change them.
 ``itemsize`` bytes wide (2: bf16 weights and K/V).  Each function counts
 the LEAST the algorithm needs, so a share built on it is a lower bound over
 a measured time and cannot pass 100%.
+
+The functions of ``(m, facts)`` at the end are what readers call by name
+through the family (``readers/roofline.py``: ``count``; ``readers/
+steploop.py``: ``decode_min_bytes``).  ``facts`` is one step's facts as
+``ctx["steps"]`` holds them: what ``step()`` returned (``tokens``,
+``sampled``, ``prefill_tokens``, ...) and what the harness noted beside it
+(``ctx_tokens``, the context tokens scheduled).
 """
 
 from __future__ import annotations
@@ -53,3 +60,22 @@ def step_min_flops(m, tokens, attended_pairs, sampled):
             + 4 * attended_pairs * m["num_hidden_layers"]
             * m["num_attention_heads"] * m["head_dim"]
             + 2 * sampled * head_elems(m))
+
+
+def decode_min_bytes(m, facts):
+    """:func:`decode_step_min_bytes` of one step's facts."""
+    return decode_step_min_bytes(m, facts["ctx_tokens"])
+
+
+def dense_flops(m, facts):
+    """Least operations of a step's projections and MLP (the scopes
+    ``attn.qkv``, ``attn.out``, ``mlp``): 2 per multiply-add of every linear
+    weight, for each of the step's real token rows (not the rung's padded
+    ones)."""
+    return 2 * facts["tokens"] * m["num_hidden_layers"] * layer_weight_elems(m)
+
+
+def dense_bytes(m, facts, itemsize=2):
+    """Least HBM bytes of the same parts: every layer's linear weights read
+    once, however few rows the step has."""
+    return itemsize * m["num_hidden_layers"] * layer_weight_elems(m)

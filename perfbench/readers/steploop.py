@@ -8,16 +8,16 @@ Parameters: ``steps`` (``all`` | ``decode``: scheduled no prompt token |
 ``mixed``: scheduled one), ``value`` (``host_ms`` | ``device_ms`` |
 ``hbm_share``) and ``stat`` (median | mean).
 
-``hbm_share`` is the least bytes the step must read (``counts.py``: the
-layers' weights, the head, and the K/V of the contexts it scheduled) over
-the chip's peak bandwidth (``peaks.json``) over the step's device window,
-in per cent: a lower bound over a measured time, so it cannot pass 100."""
+``hbm_share`` is the least bytes the step must read (the family's
+``counts.decode_min_bytes`` of the configuration and the step's facts: the
+layers' weights, the head, and the cache rows of the contexts it scheduled)
+over the chip's peak bandwidth (``peaks.json``) over the step's device
+window, in per cent: a lower bound over a measured time, so it cannot pass
+100."""
 
 from __future__ import annotations
 
 import statistics
-
-import counts
 
 PHASES = ("admit", "schedule", "assemble", "lower", "dispatch")
 
@@ -33,7 +33,7 @@ def _value(s, what, ctx):
     if what == "hbm_share":
         if ctx["peaks"] is None or "ctx_tokens" not in s:
             return None
-        least = counts.decode_step_min_bytes(ctx["model"], s["ctx_tokens"]) \
+        least = ctx["family"].counts.decode_min_bytes(ctx["model"], s) \
             / ctx["peaks"]["hbm_bytes_per_s"]
         return 100.0 * least / (rec["device_us"] / 1e6)
     raise ValueError(f"unknown value {what!r}")

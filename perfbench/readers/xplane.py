@@ -11,6 +11,17 @@ from __future__ import annotations
 import re
 
 
+def op_seconds(ctx, patterns):
+    """Self seconds, over the traced steps, of the device operations whose
+    name matches one of ``patterns``; None where the trace has none."""
+    t = ctx["trace"]
+    if not t or not t["steps"]:
+        return None
+    pats = [re.compile(p) for p in patterns]
+    hit = [s for n, s in t["op_s"].items() if any(p.search(n) for p in pats)]
+    return sum(hit) if hit else None
+
+
 def read(ctx, spec):
     t = ctx["trace"]
     if not t:
@@ -18,10 +29,6 @@ def read(ctx, spec):
     if spec["value"] == "idle_share":
         return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
     if spec["value"] == "op_ms_per_step":
-        pats = [re.compile(p) for p in spec["patterns"]]
-        hit = [s for n, s in t["op_s"].items()
-               if any(p.search(n) for p in pats)]
-        if not hit or not t["steps"]:
-            return None
-        return 1e3 * sum(hit) / t["steps"]
+        secs = op_seconds(ctx, spec["patterns"])
+        return None if secs is None else 1e3 * secs / t["steps"]
     raise ValueError(f"unknown value {spec['value']!r}")

@@ -176,7 +176,10 @@ def _seconds(path, _mtime, exclude):
     return scope_seconds(*load(path), exclude)
 
 
-def read(ctx, spec):
+def seconds(ctx, spec):
+    """Self seconds, over the traced steps, of the operations under
+    ``spec["scopes"]`` (less ``spec["exclude"]``); None where there is
+    nothing to read."""
     t = ctx["trace"]
     found = glob.glob(str(TRACE_DIR / "plugins" / "profile" / "*"
                           / "*.xplane.pb"))
@@ -186,4 +189,9 @@ def read(ctx, spec):
                     tuple(spec.get("exclude", ())))
     if secs is None:
         return None
-    return 1e3 * sum(secs.get(s, 0.0) for s in spec["scopes"]) / t["steps"]
+    return sum(secs.get(s, 0.0) for s in spec["scopes"])
+
+
+def read(ctx, spec):
+    secs = seconds(ctx, spec)
+    return None if secs is None else 1e3 * secs / ctx["trace"]["steps"]

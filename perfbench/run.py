@@ -5,14 +5,17 @@
 
 One process, one chip, no child process.  The LAST line of standard output
 is one JSON object (``correct``, ``attempted``, ``failed``, ``metrics``,
-``device`` and, in a traced run, ``breakdown``).  Everything else worth
+``device``, in a traced run ``breakdown``, and last ``compared``: each number
+of the check beside its limit, which are also the last lines of standard
+error).  Everything else worth
 reading is on earlier lines that start with ``[perfbench]``.  Where JAX
 finds no TPU the run fails with no result line; ``--tiny`` is the CPU
 rehearsal only, and its last line says ``"rehearsal"``.
 
-This file names no cell, no configuration, no traffic mix and no metric:
-they are data (``BENCHMARK.json``, ``configs/``, ``traffic/``, ``layers/``)
-found by name.  See ``README.md``.
+This file names no cell, no configuration, no traffic mix, no metric and no
+block: they are data (``BENCHMARK.json``, ``configs/``, ``traffic/``,
+``layers/``) and the configuration's family (``families/``), found by name.
+See ``README.md``.
 """
 
 from __future__ import annotations
@@ -33,7 +36,8 @@ for p in (str(ROOT), str(HERE)):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-import loop  # noqa: E402  (perfbench's own, pure Python; found through HERE)
+import family  # noqa: E402  (perfbench's own, pure Python; found through HERE)
+import loop  # noqa: E402
 import metrics  # noqa: E402
 import traffic  # noqa: E402
 
@@ -84,28 +88,12 @@ def load_cell(name):
             mine(bench["end_to_end"]), mine(bench["per_layer"]))
 
 
-def apply_tiny(config, spec):
+def apply_tiny(config, spec, fam):
     with open(HERE / "tiny.json") as f:
         tiny = json.load(f)
-    config = dict(config, **tiny["model"])
+    config = dict(config, **fam.TINY)
     config["engine"] = tiny["engine"]
     return config, dict(spec, **tiny["traffic"])
-
-
-def model_config(config):
-    import jax.numpy as jnp
-    from flashinfer_tpu.models.llama import LlamaConfig
-
-    if config["torch_dtype"] != "bfloat16":
-        raise ValueError("the engine is served in bfloat16 here")
-    return LlamaConfig(
-        vocab_size=config["vocab_size"], hidden_size=config["hidden_size"],
-        intermediate_size=config["intermediate_size"],
-        num_layers=config["num_hidden_layers"],
-        num_qo_heads=config["num_attention_heads"],
-        num_kv_heads=config["num_key_value_heads"],
-        head_dim=config["head_dim"], rope_theta=float(config["rope_theta"]),
-        rms_eps=float(config["rms_norm_eps"]), dtype=jnp.bfloat16)
 
 
 def warm_rungs(eng, ecfg, vocab, rng, make_request, cache):
@@ -155,25 +143,7 @@ def warm_rungs(eng, ecfg, vocab, rng, make_request, cache):
     return seen
 
 
-def kv_rows(eng, req, n):
-    """[layers, 2, n, Hkv, D]: the first ``n`` K/V rows the engine's paged
-    cache holds for ``req``, read through its page list, on the host."""
-    import jax.numpy as jnp
-    import numpy as np
-
-    pages = jnp.asarray(req.pages, jnp.int32)
-    out = []
-    for kc, vc in eng.caches:
-        pair = []
-        for c in (kc, vc):
-            w = c[pages]  # [npages, Hkv, PS, D]
-            w = jnp.swapaxes(w, 1, 2).reshape(-1, c.shape[1], c.shape[3])
-            pair.append(np.asarray(w[:n]))
-        out.append(np.stack(pair))
-    return np.stack(out)
-
-
-def pick_samples(driver, rng, eng, ended):
+def pick_samples(driver, rng, state_rows, ended):
     """What the check compares: requests in flight at the window's close
     with their cache rows (the longest among them), and finished requests of
     the window with their served tokens (the longest among them); the rest
@@ -200,7 +170,7 @@ def pick_samples(driver, rng, eng, ended):
                 f"prompt and {k} served tokens")
         samples.append({
             "rid": req.rid, "tokens": seq[:n],
-            "prog_rows": kv_rows(eng, req, n),
+            "prog_rows": state_rows(req, n),
             "logit_rows": [plen - 1 + j for j in range(k)],
             "served": list(req.out_tokens)})
     done = [r for r in ended if r["tokens"] and not r.get("refused")]
@@ -244,14 +214,16 @@ def main(argv=None):
                     help="CPU rehearsal sizes; never a result")
     ap.add_argument("--control", type=int, choices=(0, 1), default=0,
                     help="also read the check's numbers for the control "
-                         "(the reference in int8); not used by the driver")
+                         "(the family's reference a precision lower); "
+                         "not used by the driver")
     ap.add_argument("--keep-trace", default="",
                     help="copy the profiler's files into this directory")
     args = ap.parse_args(argv)
 
     cell, config, spec, e2e, per_layer = load_cell(args.workload)
+    fam = family.load(config)
     if args.tiny:
-        config, spec = apply_tiny(config, spec)
+        config, spec = apply_tiny(config, spec, fam)
     if args.trace:
         # read lazily by the program, so setting them here is early enough
         os.environ["FLASHINFER_TPU_STEPLOOP"] = "1"
@@ -298,14 +270,13 @@ def main(argv=None):
         f"window {args.seconds:g} s trace {args.trace}")
 
     import check
-    import weights
 
     # ---- set-up -----------------------------------------------------------
     t0 = time.perf_counter()
-    params = weights.make_params(args.seed, config)
+    params = fam.make_params(args.seed, config)
     jax.block_until_ready(params)
     say(f"weights from seed {args.seed}: {time.perf_counter() - t0:.1f} s")
-    mcfg = model_config(config)
+    mcfg = fam.model_config(config)
     # The engine's own sampling seed stays fixed: the engine bakes it into
     # the compiled step as a constant, so a seed that moved with --seed
     # would recompile every rung in every run.  The weights, the prompts and
@@ -386,7 +357,6 @@ def main(argv=None):
     ended = metrics.ended_in_window(driver.records.values(), t_open, t_close)
     lens = sorted((len(r["prompt"]), r["asked"]) for r in ended)
     say(f"lengths ended (prompt, output): {lens}")
-
     # ---- layers (traced runs) --------------------------------------------
     ctx = None
     if args.trace:
@@ -398,8 +368,8 @@ def main(argv=None):
         for s, rec in zip(busy, ledger):
             s["ledger"] = rec
         ctx = {"steps": steps, "requests": ended, "model": config,
-               "values": values,
-               "peaks": peaks, "trace": tracer.reduce(say),
+               "family": fam, "values": values, "say": say,
+               "peaks": peaks, "trace": tracer.reduce(say, first),
                "admitted": [r for r in driver.records.values()
                             if metrics.in_window(r["t_submit"],
                                                  t_open, t_close)
@@ -410,20 +380,44 @@ def main(argv=None):
                 r["hit_tokens"] = req.hit_tokens
                 r["preemptions"] = req.preemptions
 
+    # A run that reads far off has, so far, lost seconds in a few steps
+    # (PERF.md section 2): say which, and in a traced run in which of the
+    # step's phases, so the next one can be explained.
+    def before(i):
+        return steps[i - 1]["t1"] if i else t_open
+
+    def told(i):
+        s = steps[i]
+        rec = s.get("ledger", {})
+        spans = {**rec.get("phases", {}), **rec.get("spans", {})}
+        top = sorted(spans.items(), key=lambda kv: -kv[1])[:3]
+        return (f"{i}, {1e3 * (s['t1'] - s['t0']):.1f}, "
+                f"{1e3 * (s['t0'] - before(i)):.1f}, {s['rung']}, "
+                f"{s['tokens']}"
+                + "".join(f", {n} {1e3 * t:.1f}" for n, t in top))
+
+    slow = sorted(range(len(steps)), reverse=True,
+                  key=lambda i: steps[i]["t1"] - before(i))[:3]
+    say("longest steps of the window (index, ms in step(), ms in the loop "
+        "before it, rung, tokens; traced: its longest phases, ms): "
+        + "; ".join(map(told, slow)))
+
     # ---- check (outside the window, after the program's state is freed) ---
     samples = pick_samples(driver, np.random.default_rng([args.seed, 0xC4EC]),
-                           eng, ended)
+                           lambda req, n: fam.state_rows(eng, req, n),
+                           ended)
     bad_tokens = sum(not 0 <= t < vocab
                      for r in driver.records.values() for t in r["tokens"])
     eng.caches = None
     del eng, params, driver.eng
     gc.collect()
     t0 = time.perf_counter()
-    numbers, ctl = check.compare(samples, config, args.seed,
+    numbers, ctl = check.compare(samples, fam, config, args.seed,
                                  top_k=config["sampling"]["top_k"],
                                  control=bool(args.control), say=say)
     numbers.update(tokens_outside_vocab=bad_tokens, **in_window)
-    correct = check.verdict(numbers, say)
+    limit_of = check.limits(fam)
+    correct = check.verdict(numbers, limit_of, say)
     if ctl is not None:
         say("control numbers: " + json.dumps(ctl))
     say(f"check took {time.perf_counter() - t0:.1f} s (not in setup_s)")
@@ -449,11 +443,16 @@ def main(argv=None):
                                "idle_gaps": ctx["trace"]["idle_gaps"][:10]}
     if args.keep_trace and tracer:
         tracer.keep(ROOT / args.keep_trace)
+    result["compared"] = {n: {"value": v, "limit": limit_of[n]}
+                          for n, v in numbers.items()}
     if on_tpu:
-        print(json.dumps(result))
+        print(json.dumps(result), flush=True)
     else:
         print(json.dumps({"rehearsal": "passed" if correct else "FAILED",
-                          "would_be": result}))
+                          "would_be": result}), flush=True)
+    for n, c in result["compared"].items():
+        print(f"compared {n}: {c['value']:.6g} (limit {c['limit']:g})",
+              file=sys.stderr)
     return 0 if correct or on_tpu else 1
 
 

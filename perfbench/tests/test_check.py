@@ -3,18 +3,12 @@
 perturbed or a served token is altered; the int8 control reads well above
 the program."""
 
-import json
-import pathlib
-
-import jax
 import numpy as np
 import pytest
 
 import check
-import run
+import family
 import weights
-
-HERE = pathlib.Path(__file__).resolve().parent.parent
 
 
 @pytest.fixture(scope="module")
@@ -24,13 +18,12 @@ def served():
     from flashinfer_tpu.serve import (EngineConfig, EngineRequest,
                                       SamplingConfig, ServingEngine)
 
-    with open(HERE / "tiny.json") as f:
-        tiny = json.load(f)
-    m = dict(tiny["model"], rope_theta=1e6, rms_norm_eps=1e-5,
+    fam = family.load({})
+    m = dict(fam.TINY, rope_theta=1e6, rms_norm_eps=1e-5,
              torch_dtype="bfloat16")
     seed = 2**31 + 5
-    params = weights.make_params(seed, m)
-    mcfg = run.model_config(m)
+    params = fam.make_params(seed, m)
+    mcfg = fam.model_config(m)
     ecfg = EngineConfig(num_pages=96, page_size=16, max_batch=8,
                         prefill_budget_tokens=32, max_seq_tokens=160,
                         attention_backend="reference", role="prefill",
@@ -48,15 +41,15 @@ def served():
         n = len(r.prompt) + len(r.out_tokens) - 1
         samples.append({
             "rid": r.rid, "tokens": (r.prompt + r.out_tokens)[:n],
-            "prog_rows": run.kv_rows(eng, r, n),
+            "prog_rows": fam.state_rows(eng, r, n),
             "logit_rows": [len(r.prompt) - 1 + j
                            for j in range(len(r.out_tokens))],
             "served": list(r.out_tokens)})
-    return samples, m, seed
+    return samples, fam, m, seed
 
 
 def test_layer_params_are_the_bits_make_params_gave(served):
-    _, m, seed = served
+    _, _, m, seed = served
     whole = weights.make_params(seed, m)
     one = weights.layer_params(seed, m, 1)
     for name, a in one.items():
@@ -69,8 +62,8 @@ def test_layer_params_are_the_bits_make_params_gave(served):
 
 
 def test_reference_agrees_with_the_engine_and_int8_does_not(served):
-    samples, m, seed = served
-    numbers, ctl = check.compare(samples, m, seed, top_k=1, control=True)
+    samples, fam, m, seed = served
+    numbers, ctl = check.compare(samples, fam, m, seed, top_k=1, control=True)
     # bf16 storage and bf16 activations against float32: a few 1e-3
     assert 0 < numbers["kv_rel_rms"] < 0.008
     assert numbers["rows_not_finite"] == 0
@@ -81,35 +74,39 @@ def test_reference_agrees_with_the_engine_and_int8_does_not(served):
 
 
 def test_a_perturbed_cache_row_fails(served):
-    samples, m, seed = served
+    samples, fam, m, seed = served
     bad = [dict(s) for s in samples]
-    rows = np.array(bad[0]["prog_rows"]).astype(np.float32)
-    rows[1, 0, 5] *= -1.0            # one K row of layer 1, sign flipped
-    bad[0]["prog_rows"] = rows
-    sound, _ = check.compare(samples, m, seed, top_k=1)
-    numbers, _ = check.compare(bad, m, seed, top_k=1)
+    rows = np.array(bad[0]["prog_rows"]["k"]).astype(np.float32)
+    rows[1, 5] *= -1.0               # one K row of layer 1, sign flipped
+    bad[0]["prog_rows"] = dict(bad[0]["prog_rows"], k=rows)
+    sound, _ = check.compare(samples, fam, m, seed, top_k=1)
+    numbers, _ = check.compare(bad, fam, m, seed, top_k=1)
     assert numbers["kv_rel_rms"] > 10 * sound["kv_rel_rms"]
-    rows[1, 0, 5] = np.nan
-    numbers, _ = check.compare(bad, m, seed, top_k=1)
+    rows[1, 5] = np.nan
+    numbers, _ = check.compare(bad, fam, m, seed, top_k=1)
     assert numbers["rows_not_finite"] > 0
     assert not check.verdict(dict(numbers, tokens_outside_vocab=0,
                                   traces_in_window=0, compiles_in_window=0),
-                             say=lambda s: None)
+                             check.limits(fam), say=lambda s: None)
 
 
 def test_an_altered_token_fails(served):
-    samples, m, seed = served
+    samples, fam, m, seed = served
     bad = [dict(s) for s in samples]
     bad[2]["served"] = [(t + 1) % 512 for t in bad[2]["served"]]
-    numbers, _ = check.compare(bad, m, seed, top_k=1)
+    numbers, _ = check.compare(bad, fam, m, seed, top_k=1)
     assert numbers["token_below_topk"] > 1.0
 
 
 def test_verdict_holds_each_number_to_its_limit():
-    good = {k: 0 for k in check.LIMITS}
-    assert check.verdict(good, say=lambda s: None)
-    for name in check.LIMITS:
-        worse = dict(good, **{name: check.LIMITS[name] + 1})
-        assert not check.verdict(worse, say=lambda s: None)
-    assert not check.verdict(dict(good, kv_rel_rms=float("nan")),
+    limits = check.limits(family.load({}))
+    assert limits == {"kv_rel_rms": 0.04, "token_below_topk": 0.4,
+                      "rows_not_finite": 0, "tokens_outside_vocab": 0,
+                      "traces_in_window": 0, "compiles_in_window": 0}
+    good = {k: 0 for k in limits}
+    assert check.verdict(good, limits, say=lambda s: None)
+    for name in limits:
+        worse = dict(good, **{name: limits[name] + 1})
+        assert not check.verdict(worse, limits, say=lambda s: None)
+    assert not check.verdict(dict(good, kv_rel_rms=float("nan")), limits,
                              say=lambda s: None)

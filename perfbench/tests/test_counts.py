@@ -1,4 +1,5 @@
-"""counts.py against numbers worked by hand for both configurations."""
+"""counts.py against numbers worked by hand for both configurations, and
+its functions of a step's facts, which readers call by name."""
 
 import json
 import pathlib
@@ -46,3 +47,19 @@ def test_mistral_nemo_by_hand():
         + 2 * 5120 * 131072
     assert counts.step_min_flops(m, 512, pairs, 1) == pytest.approx(
         4.5e12, rel=0.02)
+
+
+def test_counts_of_a_steps_facts_by_hand():
+    m = _m("mistral-7b-v0.3")
+    facts = {"tokens": 557, "prefill_tokens": 512, "sampled": 45,
+             "ctx_tokens": 12000}
+    # 557 rows through 16 layers of 218,103,808 multiply-adds, 2 each
+    assert counts.dense_flops(m, facts) == 2 * 557 * 16 * 218_103_808
+    assert counts.dense_flops(m, facts) / 197e12 == pytest.approx(
+        19.733e-3, rel=1e-4)
+    # the 16 layers' weights once: 6.5 GiB, 8.52 ms at 819 GB/s
+    assert counts.dense_bytes(m, facts) == 16 * 416 * 2**20
+    assert counts.dense_bytes(m, facts) / 819e9 == pytest.approx(
+        8.52e-3, rel=1e-3)
+    assert counts.decode_min_bytes(m, facts) == \
+        counts.decode_step_min_bytes(m, 12000)

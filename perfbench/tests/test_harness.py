@@ -11,6 +11,7 @@ import pathlib
 
 import pytest
 
+import family
 import run
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent.parent
@@ -58,7 +59,9 @@ def test_every_metric_of_the_benchmark_has_its_files():
         assert m["moves"] in e2e
         assert set(m.get("workloads", cells)) <= cells
     for c in bench["configs"]:
-        assert (ROOT / c["file"]).exists()
+        with open(ROOT / c["file"]) as f:
+            name = json.load(f).get("family", family.DEFAULT)
+        assert (ROOT / "perfbench" / "families" / f"{name}.py").exists()
     for w in bench["workloads"]:
         assert (ROOT / "perfbench" / "traffic"
                 / f"{w['traffic']}.json").exists()

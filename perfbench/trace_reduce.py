@@ -6,7 +6,9 @@ into plain lists, ``reduce`` is arithmetic over those lists (tested on a
 small recording, ``tests/data/``), and ``Tracer`` is the harness's handle on
 the profiler: it wraps ``steps`` steady steps in the middle of the window,
 each inside a ``perfbench.step`` annotation written from this file, so that
-an idle gap is attributed to "inside step()" or to the harness's own loop.
+an idle gap is attributed to "inside step()" or to the harness's own loop,
+and it notes WHICH steps those were, so that a reader can put the facts of
+the same steps (tokens, lanes, context) beside their device time.
 
 What a v5e trace looks like (PR 23's chip runs): one plane per chip named
 ``/device:TPU:<n>`` whose line ``XLA Ops`` holds one event per executed HLO
@@ -168,11 +170,14 @@ def _host_label(main, spans, g0, g1):
 class Tracer:
     """Wraps ``steps`` engine steps, from ``at`` of the way into the window,
     in the JAX profiler (Python call tracing off: a step makes thousands of
-    calls), each step inside a ``perfbench.step`` annotation."""
+    calls), each step inside a ``perfbench.step`` annotation.  Every step
+    of the run goes through :meth:`around_step`, from the first, so the count
+    of its calls is the step's index in the loop's list of steps, and
+    ``traced`` holds the indices of the steps that were wrapped."""
 
     def __init__(self, directory, steps, at):
         self.dir, self.steps, self.at = str(directory), steps, at
-        self.state, self.left = "waiting", steps
+        self.state, self.calls, self.traced = "waiting", 0, []
         shutil.rmtree(self.dir, ignore_errors=True)
 
     def tick(self, elapsed, seconds):
@@ -184,19 +189,20 @@ class Tracer:
             opts.host_tracer_level = 2
             jax.profiler.start_trace(self.dir, profiler_options=opts)
             self.state = "tracing"
-        elif self.state == "tracing" and self.left <= 0:
+        elif self.state == "tracing" and len(self.traced) >= self.steps:
             self.close()
 
     @contextlib.contextmanager
     def around_step(self):
         import jax
 
+        index, self.calls = self.calls, self.calls + 1
         if self.state != "tracing":
             yield
             return
         with jax.profiler.TraceAnnotation(STEP):
             yield
-        self.left -= 1
+        self.traced.append(index)
 
     def close(self):
         import jax
@@ -210,7 +216,11 @@ class Tracer:
                                        "*.xplane.pb"))
         return found[0] if found else None
 
-    def reduce(self, say=print):
+    def reduce(self, say=print, first=0):
+        """The reduction of the recording (:func:`reduce`), and under
+        ``step_indices`` which steps it covers, counted from step ``first``
+        of the run (the window's first): None where the recording's
+        ``perfbench.step`` spans are not the steps this object wrapped."""
         path = self.path()
         if self.state != "done" or path is None:
             say("trace: the profiler wrote nothing")
@@ -223,7 +233,10 @@ class Tracer:
         if out is None:
             say("trace: no device plane with operations")
         else:
-            say(f"trace: {out['steps']} steps, window {out['window_s']:.4f} "
+            out["step_indices"] = [i - first for i in self.traced] \
+                if len(self.traced) == out["steps"] else None
+            say(f"trace: {out['steps']} steps (of the window: "
+                f"{out['step_indices']}), window {out['window_s']:.4f} "
                 f"s, device busy {out['busy_s']:.4f} s; "
                 f"{os.path.getsize(path)} bytes at {path}")
         return out
