@@ -1,0 +1,457 @@
+"""The ``granitemoehybrid`` block (granite-4.0-h) for the serving engine:
+Mamba-2 and no-rope attention mixers in a published pattern, a routed expert
+layer with a shared expert in every layer, tied and scaled ends.
+
+This module is the model's side of the engine's seam (``serve/engine.py``,
+"The model's seam"): :class:`GraniteHybridConfig` says which kind each layer
+is and what a recurrent layer keeps, and computes the embedding, each layer
+and the head inside the engine's compiled step.  The engine owns tokens,
+schedule, K/V pages, state slots, the head's row gather and sampling.
+
+Equations (``n`` an RMSNorm with its own weight, no biases but the
+convolution's; bf16 weights and activations, the state ``S`` and the router
+in float32):
+
+- ends: ``x = embedding_multiplier * E[tok]``; ``logits = n(x_L) E^T /
+  logits_scaling`` (tied head);
+- layer: ``x <- x + residual_multiplier * mixer(n(x))``; ``x <- x +
+  residual_multiplier * (MoE(u) + Shared(u))``, ``u = n(x)``;
+- attention: grouped-query over the engine's pages, NO rotation, softmax of
+  ``q . k * attention_multiplier``;
+- Mamba-2: ``[z | xBC | dt] = h W_in``; a causal depthwise convolution of
+  width ``d_conv`` and SiLU over ``xBC``; ``[x | B | C] = xBC``; ``D_t =
+  softplus(dt_t + dt_bias)``, ``a_t = exp(D_t A)``, ``A = -exp(A_log)``;
+  ``S_t = a_t S_{t-1} + D_t x_t (x) B_t``; ``y_t = S_t C_t + D x_t``; ``g_t =
+  RMSNorm(y_t silu(z_t))`` over the whole inner width; output ``g_t W_out``;
+- expert layer: router over ALL the published experts in float32, the
+  ``experts_per_tok`` largest, softmax over those; this chip holds experts
+  ``local_experts = (first, count)`` and adds what they give; routes to
+  absent experts are dropped before the grouped matmul, and nothing stands
+  in for the peer chip.
+
+Mamba-2 under continuous batching: the flat batch of one step holds, per
+scheduled request, one contiguous segment (``serve/recurrent.py``).  The
+convolution reads, for the first ``d_conv - 1`` rows of a segment, the
+slot's tail; one-token segments (decode lanes) advance all slots' ``S`` at
+once under a mask; longer segments are cut into work units of at most
+``scan_chunk`` rows that one ``fori_loop`` with a dynamic bound runs in
+order, each in the chunked (SSD) form, carrying ``S`` from unit to unit of a
+segment, loading the slot at its first unit (zero for a request (re)admitted
+since its last step) and writing it back at its last.  Shapes depend on the
+rung alone: one trace per rung.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+_PUBLISHED_PATTERN = tuple(
+    "attention" if i % 10 == 5 else "mamba" for i in range(40))
+
+
+@dataclasses.dataclass(frozen=True)
+class GraniteHybridConfig:
+    """granite-4.0-h-small as published; ``vocab_size``, ``layer_types``
+    and ``local_experts`` say what is held here."""
+
+    vocab_size: int = 100352
+    hidden_size: int = 4096
+    layer_types: Tuple[str, ...] = _PUBLISHED_PATTERN
+    num_qo_heads: int = 32
+    num_kv_heads: int = 8
+    head_dim: int = 128
+    mamba_n_heads: int = 128
+    mamba_d_head: int = 64
+    mamba_d_state: int = 128
+    mamba_d_conv: int = 4
+    mamba_n_groups: int = 1
+    num_experts: int = 72            # the router's width
+    experts_per_tok: int = 10
+    intermediate_size: int = 768     # one expert's width
+    shared_intermediate_size: int = 1536
+    local_experts: Tuple[int, int] = (0, 72)   # (first, count) held here
+    embedding_multiplier: float = 12.0
+    residual_multiplier: float = 0.22
+    logits_scaling: float = 16.0
+    attention_multiplier: float = 0.0078125
+    rms_eps: float = 1e-5
+    dtype: object = jnp.bfloat16
+    state_dtype: object = jnp.float32
+    scan_chunk: int = 128            # rows of one work unit of the scan
+    attention_rope = False           # position_embedding_type "nope"
+
+    # -- sizes ------------------------------------------------------------
+
+    @property
+    def num_layers(self) -> int:
+        return len(self.layer_types)
+
+    @property
+    def attention_scale(self) -> float:
+        return float(self.attention_multiplier)
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_n_heads * self.mamba_d_head
+
+    @property
+    def conv_dim(self) -> int:
+        return self.mamba_inner + 2 * self.mamba_n_groups * self.mamba_d_state
+
+    # -- the engine's seam --------------------------------------------------
+
+    def layer_kinds(self) -> Tuple[str, ...]:
+        return tuple("attention" if t == "attention" else "recurrent"
+                     for t in self.layer_types)
+
+    def new_recurrent_state(self, slots: int):
+        """(convolution tail ``[slots, d_conv - 1, conv_dim]``, ``S``
+        ``[slots, heads x d_head x d_state]``) of one Mamba layer, zero.
+        ``S`` is kept flat: the step updates it in place and never lays it
+        out anew (``_mamba_mixer``)."""
+        return (jnp.zeros((slots, self.mamba_d_conv - 1, self.conv_dim),
+                          self.dtype),
+                jnp.zeros((slots, self.mamba_n_heads * self.mamba_d_head
+                           * self.mamba_d_state), self.state_dtype))
+
+    def engine_embed(self, params, tokens):
+        e = params["embed"][tokens].astype(jnp.float32)
+        return (self.embedding_multiplier * e).astype(self.dtype)
+
+    def engine_head(self, params, x_last):
+        from flashinfer_tpu.norm import rmsnorm
+
+        xf = rmsnorm(x_last, params["final_norm"], self.rms_eps)
+        logits = jax.lax.dot_general(
+            xf, params["embed"], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return logits / self.logits_scaling
+
+    def engine_layer(self, li, x, layer, cache, ctx):
+        if self.layer_types[li] == "attention":
+            x, cache = _attention_mixer(self, x, layer, cache, ctx)
+        else:
+            x, cache = _mamba_mixer(self, x, layer, cache, ctx)
+        x, rows = _expert_layer(self, x, layer, ctx)
+        held = ctx.aux.setdefault("moe_rows", [])
+        held.append(rows)
+        if li == self.num_layers - 1:
+            ctx.aux["moe_rows"] = jnp.stack(held)
+        return x, cache
+
+    def engine_facts(self, aux, tokens: int) -> dict:
+        """The expert layers' counters of one step, from the rows each held
+        expert got in each layer (``aux["moe_rows"]`` ``[layers, held]``)."""
+        rows = aux["moe_rows"]
+        return {"moe_routes": tokens * self.experts_per_tok
+                * self.num_layers,
+                "moe_routes_local": int(rows.sum()),
+                "moe_expert_rows_max": int(rows.max()),
+                "moe_experts_hit": int((rows > 0).sum())}
+
+
+# ---------------------------------------------------------------------------
+# Parameters
+# ---------------------------------------------------------------------------
+
+
+def layer_shapes(cfg: GraniteHybridConfig, kind: str) -> dict:
+    """{name: shape} of one layer's weights; linear weights ``[in, out]``,
+    the held experts stacked ``[count, in, out]`` with gate and up side by
+    side."""
+    h = cfg.hidden_size
+    held = cfg.local_experts[1]
+    out = {"input_norm": (h,), "post_norm": (h,),
+           "router": (h, cfg.num_experts),
+           "w_gate_up": (held, h, 2 * cfg.intermediate_size),
+           "w_down": (held, cfg.intermediate_size, h),
+           "shared_gate_up": (h, 2 * cfg.shared_intermediate_size),
+           "shared_down": (cfg.shared_intermediate_size, h)}
+    if kind == "attention":
+        q = cfg.num_qo_heads * cfg.head_dim
+        kv = cfg.num_kv_heads * cfg.head_dim
+        out.update(q_proj=(h, q), k_proj=(h, kv), v_proj=(h, kv),
+                   o_proj=(q, h))
+    else:
+        out.update(
+            in_proj=(h, cfg.mamba_inner + cfg.conv_dim + cfg.mamba_n_heads),
+            conv_w=(cfg.mamba_d_conv, cfg.conv_dim), conv_b=(cfg.conv_dim,),
+            dt_bias=(cfg.mamba_n_heads,), A_log=(cfg.mamba_n_heads,),
+            D=(cfg.mamba_n_heads,), ssm_norm=(cfg.mamba_inner,),
+            out_proj=(cfg.mamba_inner, h))
+    return out
+
+
+def init_weight(key, name: str, shape, dtype):
+    """One weight from a key: linear N(0, 0.02^2); norm weights 1 + 0.1 N;
+    the convolution U(-1/2, 1/2) (its fan-in is 4); ``dt_bias`` so that
+    softplus of it lies in [0.001, 0.1]; ``A_log`` = log U(1, 16); D = 1
+    + 0.1 N: Mamba-2's own initialisation, so that the decay per token is
+    what a trained layer's is."""
+    if name.endswith("norm"):
+        w = 1.0 + 0.1 * jax.random.normal(key, shape, jnp.float32)
+    elif name == "conv_w":
+        w = jax.random.uniform(key, shape, jnp.float32, -0.5, 0.5)
+    elif name == "conv_b":
+        w = 0.1 * jax.random.normal(key, shape, jnp.float32)
+    elif name == "dt_bias":
+        dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32,
+                                        np.log(1e-3), np.log(1e-1)))
+        w = dt + jnp.log(-jnp.expm1(-dt))     # softplus^-1(dt)
+    elif name == "A_log":
+        w = jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0))
+    elif name == "D":
+        w = 1.0 + 0.1 * jax.random.normal(key, shape, jnp.float32)
+    else:
+        w = 0.02 * jax.random.normal(key, shape, jnp.float32)
+    return w.astype(dtype)
+
+
+def init_granite_hybrid_params(key, cfg: GraniteHybridConfig) -> dict:
+    """Random parameter tree: ``embed`` (tied head), ``final_norm``,
+    ``layers[i]`` as :func:`layer_shapes` lists."""
+    def tree(key, shapes):
+        names = sorted(shapes)
+        return {n: init_weight(k, n, shapes[n], cfg.dtype)
+                for n, k in zip(names, jax.random.split(key, len(names)))}
+
+    out = tree(jax.random.fold_in(key, 1 << 20),
+               {"embed": (cfg.vocab_size, cfg.hidden_size),
+                "final_norm": (cfg.hidden_size,)})
+    out["layers"] = [tree(jax.random.fold_in(key, li), layer_shapes(cfg, t))
+                     for li, t in enumerate(cfg.layer_types)]
+    return out
+
+
+# ---------------------------------------------------------------------------
+# The layer
+# ---------------------------------------------------------------------------
+
+
+def _residual(cfg, x, delta):
+    return (x.astype(jnp.float32) + cfg.residual_multiplier
+            * delta.astype(jnp.float32)).astype(cfg.dtype)
+
+
+def _attention_mixer(cfg, x, layer, cache, ctx):
+    from flashinfer_tpu.norm import rmsnorm
+
+    t = x.shape[0]
+    with jax.named_scope("attn.qkv"):
+        h = rmsnorm(x, layer["input_norm"], cfg.rms_eps)
+        q = (h @ layer["q_proj"]).reshape(t, cfg.num_qo_heads, cfg.head_dim)
+        k = (h @ layer["k_proj"]).reshape(t, cfg.num_kv_heads, cfg.head_dim)
+        v = (h @ layer["v_proj"]).reshape(t, cfg.num_kv_heads, cfg.head_dim)
+    attn, cache = ctx.attend(q, k, v, cache)
+    with jax.named_scope("attn.out"):
+        x = _residual(cfg, x, jnp.dot(attn.reshape(t, -1), layer["o_proj"],
+                                      preferred_element_type=jnp.float32))
+    return x, cache
+
+
+def _mamba_mixer(cfg, x, layer, cache, ctx):
+    from flashinfer_tpu.norm import rmsnorm
+
+    conv_state, ssm_state = cache
+    t = x.shape[0]
+    nh, dh, ds, g = (cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state,
+                     cfg.mamba_n_groups)
+    rep, inner, taps = nh // g, cfg.mamba_inner, cfg.mamba_d_conv - 1
+    seg = ctx.plan["seg"]
+    row0, n_seg, fresh = seg[:, 0], seg[:, 1], seg[:, 2] > 0
+    f32 = jnp.float32
+
+    with jax.named_scope("ssm.in_proj"):
+        h = rmsnorm(x, layer["input_norm"], cfg.rms_eps)
+        zxd = h @ layer["in_proj"]
+        z, xbc, dt = jnp.split(zxd, [inner, inner + cfg.conv_dim], -1)
+
+    with jax.named_scope("ssm.conv"):
+        # input t - k of a row lies in the flat batch where the row is at
+        # least k rows into its segment, else in the slot's tail
+        off = jnp.arange(t) - row0[ctx.tok_req]
+        tails = jnp.where(fresh[:, None, None], 0, conv_state)
+        tail_tok = tails[ctx.tok_req]                       # [T, taps, C]
+        cw = layer["conv_w"].astype(f32)
+        acc = layer["conv_b"].astype(f32) + cw[taps] * xbc.astype(f32)
+        for k in range(1, taps + 1):
+            shifted = jnp.pad(xbc, ((k, 0), (0, 0)))[:t]
+            idx = jnp.clip(taps + off - k, 0, taps - 1)
+            from_tail = jnp.take_along_axis(
+                tail_tok, idx[:, None, None], 1)[:, 0]
+            prev = jnp.where((off >= k)[:, None], shifted, from_tail)
+            acc = acc + cw[taps - k] * prev.astype(f32)
+        act = jax.nn.silu(acc).astype(cfg.dtype)
+        # each slot's new tail: its last `taps` inputs, of this step's
+        # rows and, where the segment is shorter, of the old tail
+        r = jnp.arange(taps)
+        o = n_seg[:, None] - taps + r[None]                  # [B, taps]
+        from_flat = xbc[jnp.clip(row0[:, None] + o, 0, t - 1)]
+        from_old = jnp.take_along_axis(
+            tails, jnp.clip(n_seg[:, None] + r[None], 0,
+                            taps - 1)[:, :, None], 1)
+        conv_state = jnp.where((o >= 0)[:, :, None], from_flat,
+                               from_old).astype(conv_state.dtype)
+
+    with jax.named_scope("ssm.scan"):
+        xs, bm, cm = jnp.split(act, [inner, inner + g * ds], -1)
+        xs = xs.reshape(t, g, rep, dh)
+        bm = bm.reshape(t, g, ds)
+        cm = cm.reshape(t, g, ds)
+        a_head = -jnp.exp(layer["A_log"].astype(f32)).reshape(g, rep)
+        delta = jax.nn.softplus(
+            dt.astype(f32) + layer["dt_bias"].astype(f32)).reshape(t, g, rep)
+        q = min(cfg.scan_chunk, t)
+        y = jnp.zeros((t + q, g, rep, dh), f32)
+
+        # The slots' array goes through both passes FLAT, [slots, heads x
+        # d_head x d_state], and feeds no matmul: given its axes, the
+        # compiler lays the whole array out to suit the loop's
+        # contractions, two copies of every slot a layer (an off-chip
+        # compile shows them).  Axes are opened on one slot's state at a
+        # time and, in the elementwise pass, by a reshape that is a bitcast.
+
+        # one-token segments: every slot at once, under a mask
+        one = n_seg == 1
+        rows = jnp.clip(row0, 0, t - 1)
+        s0 = jnp.where(fresh[:, None], 0, ssm_state.astype(f32)).reshape(
+            -1, nh, dh, ds)
+        d1 = delta[rows].reshape(-1, nh)                     # [B, heads]
+        b1 = jnp.repeat(bm[rows].astype(f32), rep, 1)        # [B, heads, ds]
+        c1 = jnp.repeat(cm[rows].astype(f32), rep, 1)
+        x1 = xs[rows].astype(f32).reshape(-1, nh, dh)
+        s1 = jnp.exp(d1 * a_head.reshape(nh))[..., None, None] * s0 \
+            + (d1[..., None] * x1)[..., None] * b1[:, :, None, :]
+        # one elementwise pass over the slots, in place under donation; the
+        # lanes' outputs are read from its result (a second consumer of
+        # `s1` would keep a copy of every slot alive)
+        ssm = jnp.where(one[:, None, None, None], s1, s0)
+        y1 = jnp.sum(ssm * c1[:, :, None, :], -1)
+        ssm = ssm.reshape(ssm_state.shape)
+        y = y.at[jnp.where(one, rows, t + q)].set(
+            y1.reshape(-1, g, rep, dh), mode="drop")
+
+        # longer segments: work units of at most q rows, in order
+        work = ctx.plan["work"]
+        xs_p, bm_p, cm_p, dl_p = (
+            jnp.pad(v, ((0, q),) + ((0, 0),) * (v.ndim - 1))
+            for v in (xs, bm, cm, delta))
+        lower = jnp.tril(jnp.ones((q, q), bool))
+
+        def unit(w, carry):
+            s_prev, y, ssm = carry
+            r0, n, slot, first, last, fresh_w = (work[w, i] for i in range(6))
+            s_slot = jax.lax.dynamic_index_in_dim(ssm, slot, 0, False)
+            s_in = jnp.where(first > 0,
+                             jnp.where(fresh_w > 0, 0, s_slot), s_prev)
+            s_g = s_in.reshape(g, rep, dh, ds)
+            xc = jax.lax.dynamic_slice_in_dim(xs_p, r0, q).astype(f32)
+            bc = jax.lax.dynamic_slice_in_dim(bm_p, r0, q).astype(f32)
+            cc = jax.lax.dynamic_slice_in_dim(cm_p, r0, q).astype(f32)
+            live = jnp.arange(q) < n
+            dc = jnp.where(live[:, None, None],
+                           jax.lax.dynamic_slice_in_dim(dl_p, r0, q), 0.0)
+            acum = jnp.cumsum(dc * a_head, 0)                # [q, g, rep]
+            # within the unit: scores[i, j] = (C_i . B_j) exp(acum_i -
+            # acum_j) D_j on the causal triangle, masked inside the exponent
+            decay = jnp.exp(jnp.where(
+                lower[:, :, None, None],
+                acum[:, None] - acum[None, :], -jnp.inf))    # [q, q, g, rep]
+            cb = jnp.einsum("igs,jgs->ijg", cc, bc)
+            scores = cb[..., None] * decay * dc[None]
+            y_c = jnp.einsum("ijgr,jgrd->igrd", scores, xc) \
+                + jnp.exp(acum)[..., None] * jnp.einsum(
+                    "igs,grds->igrd", cc, s_g)
+            # the state after the unit's n rows (rows past n decay by 1
+            # and add nothing)
+            wj = jnp.exp(acum[-1][None] - acum) * dc
+            s_out = (jnp.exp(acum[-1])[..., None, None] * s_g + jnp.einsum(
+                "jgrd,jgs->grds", wj[..., None] * xc, bc)).reshape(s_in.shape)
+            y_old = jax.lax.dynamic_slice_in_dim(y, r0, q)
+            y = jax.lax.dynamic_update_slice_in_dim(
+                y, jnp.where(live[:, None, None, None], y_c, y_old), r0, 0)
+            ssm = jax.lax.dynamic_update_index_in_dim(
+                ssm, jnp.where(last > 0, s_out, s_slot), slot, 0)
+            return s_out, y, ssm
+
+        _, y, ssm = jax.lax.fori_loop(
+            0, ctx.plan["n_work"], unit,
+            (jnp.zeros(ssm.shape[1:], f32), y, ssm))
+        ssm_state = ssm.astype(ssm_state.dtype)
+        y = y[:t] + layer["D"].astype(f32).reshape(g, rep)[..., None] \
+            * xs.astype(f32)
+        gated = (y.reshape(t, inner)
+                 * jax.nn.silu(z.astype(f32))).astype(cfg.dtype)
+        gated = rmsnorm(gated, layer["ssm_norm"], cfg.rms_eps)
+
+    with jax.named_scope("ssm.out"):
+        x = _residual(cfg, x, jnp.dot(gated, layer["out_proj"],
+                                      preferred_element_type=f32))
+    return x, (conv_state, ssm_state)
+
+
+def _grouped(lhs, row_ids, w, group_sizes, gather: bool):
+    """Rows of ``lhs`` (gathered by ``row_ids`` when ``gather``), sorted by
+    expert, times each expert's weight: the Pallas grouped matmul on the
+    chip (its grid covers the groups' rows only, so rows past them are
+    never computed), ``ragged_dot`` where kernels are interpreted or a
+    width is not 128-aligned."""
+    from flashinfer_tpu.utils import use_interpret
+
+    k, n = w.shape[1], w.shape[2]
+    if use_interpret() or k % 128 or n % 128:
+        return jax.lax.ragged_dot(lhs[row_ids] if gather else lhs, w,
+                                  group_sizes)
+    from flashinfer_tpu.fused_moe.core import _heuristic_gmm_tiles
+    from flashinfer_tpu.ops.moe_gmm import gather_gmm, gmm
+
+    tm, tn, tk = _heuristic_gmm_tiles(row_ids.shape[0], k, n,
+                                      w.dtype.itemsize, lhs.dtype.itemsize)
+    if gather:
+        return gather_gmm(lhs, row_ids, w, group_sizes, tm=tm, tn=tn, tk=tk)
+    return gmm(lhs, w, group_sizes, tm=tm, tn=tn, tk=tk)
+
+
+def _expert_layer(cfg, x, layer, ctx):
+    """``x + residual_multiplier * (what the held experts add + the shared
+    expert)``, and the rows each held expert got."""
+    from flashinfer_tpu.activation import silu_and_mul
+    from flashinfer_tpu.fused_moe.routing import route_renormalize
+    from flashinfer_tpu.norm import rmsnorm
+
+    t, top_k = x.shape[0], cfg.experts_per_tok
+    first, held = cfg.local_experts
+    with jax.named_scope("moe.route"):
+        u = rmsnorm(x, layer["post_norm"], cfg.rms_eps)
+        logits = jnp.dot(u, layer["router"],
+                         preferred_element_type=jnp.float32)
+        weights, ids = route_renormalize(logits, top_k)
+        # a route stays when its expert is held here and its row is a
+        # token; the others sort behind every group and are never computed
+        local = (ids >= first) & (ids < first + held) \
+            & (jnp.arange(t) < ctx.plan["n_tokens"])[:, None]
+        key = jnp.where(local, ids - first, held).reshape(-1)
+        order = jnp.argsort(key, stable=True)
+        token_of = order // top_k
+        group_sizes = jnp.bincount(key, length=held).astype(jnp.int32)
+        # where route (t, k) lies among the sorted rows
+        place = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=order.dtype))
+    with jax.named_scope("moe.experts"):
+        h1 = _grouped(u, token_of, layer["w_gate_up"], group_sizes, True)
+        h2 = _grouped(silu_and_mul(h1), token_of, layer["w_down"],
+                      group_sizes, False)
+        per_route = h2[place].reshape(t, top_k, -1).astype(jnp.float32)
+        routed = jnp.sum(jnp.where(local[..., None],
+                                   weights[..., None] * per_route, 0.0), 1)
+    with jax.named_scope("moe.shared"):
+        shared = jnp.dot(silu_and_mul(u @ layer["shared_gate_up"]),
+                         layer["shared_down"],
+                         preferred_element_type=jnp.float32)
+        x = _residual(cfg, x, routed + shared)
+    return x, group_sizes

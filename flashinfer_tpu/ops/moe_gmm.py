@@ -357,6 +357,7 @@ def gmm(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")
         ),
         interpret=use_interpret(),
+        name="moe_gmm",  # the instruction's name in a device trace
     )(offsets, tile_group, tile_m, *operands)
     return out[:m]
 

@@ -1,0 +1,189 @@
+"""The engine's step for a model that brings its own block.
+
+``ServingEngine`` serves two kinds of model.  One without ``engine_layer``
+is the Llama-style dense block, whose step is written inline in
+``engine.py`` ``_build_step`` and is NOT touched by this file.  One with
+``engine_layer`` gets the step built here, chosen once in
+``ServingEngine.__init__`` and imported only then: the engine still owns the
+tokens, the schedule, the caches, the K/V write and the paged attention, the
+head's row gather and the sampling; the model supplies the embedding, each
+layer and the head.
+
+Why a second body, and not a branch in the first: on the chip a rung's first
+step took 0.55 s longer, on a warm compile cache and with a byte-identical
+lowered program, once the Llama ``_body`` held this branch, and 1.6 s longer
+with the Llama layer in a function of its own (PERF.md section 6, PR 28;
+mechanism not found).  So the K/V write, the reference tier's windows and the
+sampling epilogue below are copies of ``_build_step``'s, less what no such
+model runs (int8 K/V, fused ingest); a fix to one belongs in the other.
+
+Such a model object supplies, beside the sizes every model has:
+
+- ``layer_kinds()``: ``"attention"`` or ``"recurrent"`` per layer; an
+  attention layer gets K/V pages, a recurrent one the arrays of
+  ``new_recurrent_state(slots)`` (``[slots, ...]``, one slot a batch slot),
+  both as ``cache`` below and donated through the step; with recurrent
+  layers also ``scan_chunk``, the rows of one work unit;
+- ``attention_rope`` (False: no rotation) and ``attention_scale``;
+- ``engine_embed(params, tokens)``, ``engine_layer(li, x, layer, cache,
+  ctx) -> (x, cache)``, ``engine_head(params, x_last) -> float32 logits``;
+- ``engine_facts(aux, tokens)``: the step's facts from what its layers put
+  into ``ctx.aux`` (small arrays, fetched with the tokens).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+
+_NEG_INF = -1e30
+
+
+@dataclasses.dataclass
+class StepContext:
+    """What the step hands the model's layer, once per trace.
+
+    ``attend(q, k, v, cache) -> (out, cache)`` writes the K/V rows into the
+    pages and attends over them (the engine's tier, the model's rotation
+    and scale); ``plan`` is ``serve/recurrent.py``'s plan of the step:
+    ``n_tokens`` and, with recurrent layers, each slot's segment and the
+    scan's work units; ``aux`` takes what the layers want fetched."""
+
+    positions: jax.Array
+    tok_req: jax.Array
+    attend: object
+    plan: dict
+    aux: dict
+
+
+def _sample(logits, sampling, base_key, sample_seeds):
+    """The engine's sampling epilogue (``_build_step``'s): per-lane
+    deterministic, the key from (request arrival id, token index) alone."""
+    with jax.named_scope("sample"):
+        t = jnp.maximum(
+            jnp.asarray(sampling.temperature, jnp.float32), 1e-6)
+        probs = jax.nn.softmax((logits / t).astype(jnp.float32), -1)
+        if sampling.top_k:
+            from flashinfer_tpu import sampling as S
+
+            probs = S.top_k_renorm_probs(probs, sampling.top_k)
+        if sampling.top_p < 1.0:
+            from flashinfer_tpu import sampling as S
+
+            probs = S.top_p_renorm_probs(probs, sampling.top_p)
+        keys = jax.vmap(
+            lambda s: jax.random.fold_in(base_key, s))(sample_seeds)
+        tokens = jax.vmap(
+            lambda p, kk: jax.random.categorical(
+                kk, jnp.log(jnp.maximum(p, 1e-30))))(probs, keys)
+    return tokens.astype(jnp.int32)
+
+
+def build(eng):
+    """The jitted, donated step of ``eng`` for ``eng.cfg``'s own block:
+    ``_build_step._body``'s arguments and ``mplan`` -> ``(tokens, caches,
+    aux)``."""
+    cfg, mcfg = eng.config, eng.cfg
+    if eng._int8_kv:
+        raise NotImplementedError(
+            "int8 K/V pages are not supported for a model that brings its "
+            "own block (serve/block_step.py)")
+    K = eng._ppr * cfg.page_size   # per-request KV window rows
+    sm_scale = float(getattr(mcfg, "attention_scale", None)
+                     or 1.0 / float(mcfg.head_dim) ** 0.5)
+    use_rope = bool(getattr(mcfg, "attention_rope", True))
+    sampling = cfg.sampling
+    base_key = jax.random.PRNGKey(cfg.seed)
+    kernel_backend, geom = eng._kernel_backend, eng._geom
+    return_logits = cfg.return_logits
+    Hq, Hkv, D = mcfg.num_qo_heads, mcfg.num_kv_heads, mcfg.head_dim
+
+    def _window(c, table):
+        # [pages, Hkv, PS, D] -> [rows, K, Hkv, D]: the row offset of KV
+        # position j is always j
+        w = c[table]
+        return jnp.swapaxes(w, 2, 3).reshape(w.shape[0], K, Hkv, D)
+
+    def _attend(q, kw, vw, lo, hi):
+        # the reference tier's per-token windowed attention: valid rows j in
+        # [lo, hi] per token, masked lanes contribute exact zeros
+        T = q.shape[0]
+        qg = q.reshape(T, Hkv, Hq // Hkv, D).astype(jnp.float32)
+        s = jnp.einsum("tngd,tknd->tngk", qg,
+                       kw.astype(jnp.float32)) * sm_scale
+        j = jnp.arange(kw.shape[1])
+        vm = ((j[None, :] >= lo[:, None])
+              & (j[None, :] <= hi[:, None]))[:, None, None, :]
+        s = jnp.where(vm, s, _NEG_INF)
+        m = jnp.max(s, axis=-1, keepdims=True)
+        p = jnp.where(vm, jnp.exp(s - m), 0.0)
+        l = jnp.sum(p, axis=-1)
+        out = jnp.einsum(
+            "tngk,tknd->tngd", p / jnp.where(l > 0, l, 1.0)[..., None],
+            vw.astype(jnp.float32))
+        lse = jnp.where(l > 0, m[..., 0] + jnp.log(l), _NEG_INF)
+        return out.reshape(T, Hq, D), lse.reshape(T, Hq)
+
+    def _body(params, flat_tokens, positions, tok_req, token_page,
+              token_slot, page_table, grp_pages, tok_grp, split,
+              last_rows, sample_seeds, kplans, caches, mplan):
+        eng._traces += 1
+
+        def _attend_pages(q, k, v, cache):
+            kc, vc = cache
+            with jax.named_scope("attn.kv_write"):
+                if use_rope:
+                    from flashinfer_tpu.rope import apply_rope_pos_ids
+
+                    q, k = apply_rope_pos_ids(
+                        q, k, positions, rope_theta=mcfg.rope_theta)
+                # pad lanes scatter into the scratch page (pool page 0)
+                kc = kc.at[token_page, :, token_slot, :].set(
+                    k.astype(kc.dtype))
+                vc = vc.at[token_page, :, token_slot, :].set(
+                    v.astype(vc.dtype))
+            with jax.named_scope("attn.core"):
+                if kernel_backend:
+                    from flashinfer_tpu.serve.engine_kernels import (
+                        engine_kernel_attention)
+
+                    # graft-lint: ok env read once a trace, pinned per rung
+                    o = engine_kernel_attention(
+                        q, kc, vc, kplans, geom=geom, sm_scale=sm_scale)
+                else:
+                    from flashinfer_tpu.cascade import (
+                        compose_cascade_levels)
+
+                    # level 1: the request's own window, rows [split, pos];
+                    # level 0: the shared prefix run, rows
+                    # [0, min(split, pos + 1))
+                    o1, lse1 = _attend(
+                        q, _window(kc, page_table)[tok_req],
+                        _window(vc, page_table)[tok_req], split, positions)
+                    o0, lse0 = _attend(
+                        q, _window(kc, grp_pages)[tok_grp],
+                        _window(vc, grp_pages)[tok_grp],
+                        jnp.zeros_like(split),
+                        jnp.minimum(split - 1, positions))
+                    o, _ = compose_cascade_levels([(o0, lse0), (o1, lse1)])
+            return o.astype(mcfg.dtype), (kc, vc)
+
+        aux = {}
+        ctx = StepContext(positions=positions, tok_req=tok_req,
+                          attend=_attend_pages, plan=mplan, aux=aux)
+        with jax.named_scope("embed"):
+            x = mcfg.engine_embed(params, flat_tokens)
+        new_caches = []
+        for li, layer in enumerate(params["layers"]):
+            x, cache = mcfg.engine_layer(li, x, layer, caches[li], ctx)
+            new_caches.append(cache)
+        with jax.named_scope("head"):
+            logits = mcfg.engine_head(params, x[last_rows])
+        if return_logits:
+            aux["logits"] = logits
+        return (_sample(logits, sampling, base_key, sample_seeds),
+                new_caches, aux)
+
+    return jax.jit(_body, donate_argnums=(13,) if cfg.donate else ())

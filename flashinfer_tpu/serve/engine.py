@@ -405,6 +405,11 @@ class EngineConfig:
     kv_offload: str = "off"         # engine.kv_offload: off | host
     spill_policy: str = "recompute"  # engine.spill_policy
     host_gib: float = 4.0           # engine.host_gib (host tier GiB)
+    # tests only, and only for a model that brings its own block
+    # (serve/block_step.py): the step also hands back the float32 logits of
+    # the lanes it sampled (``ServingEngine.last_logits``); a static of the
+    # program, off wherever a run is timed
+    return_logits: bool = False
 
     @staticmethod
     def from_knobs(model_cfg, *, num_pages: int, max_seq_tokens: int = 256,
@@ -542,6 +547,21 @@ class ServingEngine:
         self.cfg = model_cfg
         self.params = params
         self.config = config
+        # the model's seam (serve/block_step.py): a model that brings its
+        # own layer says which kind each layer is and what a recurrent
+        # layer keeps; one that does not is the Llama-style dense block
+        self._block = model_cfg if hasattr(model_cfg, "engine_layer") \
+            else None
+        self._kinds = (tuple(model_cfg.layer_kinds())
+                       if self._block is not None
+                       else ("attention",) * model_cfg.num_layers)
+        self._recurrent = "recurrent" in self._kinds
+        if self._recurrent and config.kv_offload != "off":
+            self._refuse_recurrent("kv_offload='host' (host spill)")
+        if self._recurrent and config.role != "unified":
+            self._refuse_recurrent(f"role={config.role!r} (migration)")
+        self.prefix_lookups_refused = 0
+        self.last_logits: Dict[str, np.ndarray] = {}
         self.pool = BlockPool(config.num_pages, config.page_size)
         self.prefix_cache = PrefixCache(self.pool)
         self._waiting: List[EngineRequest] = []
@@ -585,12 +605,20 @@ class ServingEngine:
         self.kv_dtype = kv_dtype
         self._int8_kv = kv_dtype == jnp.int8
         ps, ppr = config.page_size, config.pages_per_req()
+        # one entry a layer, donated through the step together: K/V pages
+        # for an attention layer; for a recurrent layer the model's state
+        # arrays ``[max_batch, ...]``, one SLOT a batch slot
+        # (``EngineRequest.slot``, allocated at admission and freed at
+        # release with it), so a model without recurrent layers allocates
+        # nothing
         self.caches = [
             (jnp.zeros((config.num_pages, model_cfg.num_kv_heads, ps,
                         model_cfg.head_dim), kv_dtype),
              jnp.zeros((config.num_pages, model_cfg.num_kv_heads, ps,
                         model_cfg.head_dim), kv_dtype))
-            for _ in range(model_cfg.num_layers)
+            if kind == "attention"
+            else model_cfg.new_recurrent_state(config.max_batch)
+            for kind in self._kinds
         ]
         self._ppr = ppr
         self._kernel_backend = config.attention_backend == "kernel"
@@ -606,7 +634,9 @@ class ServingEngine:
         }
         # fused ingest is a kernel-tier concept: the reference backend
         # is the composed oracle by contract, so "on" there is inert
-        self._ingest = (self._kernel_backend
+        # ... and it is the Llama step's: inert as well for a model that
+        # brings its own block
+        self._ingest = (self._kernel_backend and self._block is None
                         and config.fused_ingest == "on")
         if self._kernel_backend:
             from flashinfer_tpu.serve.engine_kernels import EngineKernelGeom
@@ -625,7 +655,21 @@ class ServingEngine:
         from flashinfer_tpu import env
 
         env.enable_compilation_cache()
-        self._build_step()
+        if self._block is None:
+            self._build_step()
+        else:
+            from flashinfer_tpu.serve import block_step
+
+            self._step = block_step.build(self)
+
+    def _refuse_recurrent(self, what: str) -> None:
+        """A recurrent layer's state is not in the K/V pages: a path that
+        moves or reuses pages alone would resume from state nobody kept.
+        State snapshots at page boundaries are not there yet."""
+        raise NotImplementedError(
+            f"{what} is not supported for a model with recurrent layers: "
+            "the recurrent state slots are neither snapshotted nor moved "
+            "with the K/V pages")
 
     # -- public surface ---------------------------------------------------
 
@@ -810,8 +854,14 @@ class ServingEngine:
         hit_pages: List[int] = []
         hit_tokens = 0
         if staged is None and cfg.enable_prefix_cache:
-            hit_pages, hit_tokens = self.prefix_cache.lookup(
-                r.prompt, split // cfg.page_size)
+            if self._recurrent:
+                # a hit would skip tokens whose recurrent state nobody
+                # kept: refused, and counted
+                self.prefix_lookups_refused += 1
+                obs.counter_inc("engine.prefix_lookups_refused")
+            else:
+                hit_pages, hit_tokens = self.prefix_cache.lookup(
+                    r.prompt, split // cfg.page_size)
         # adopt the shared run BEFORE any eviction: the hit pages must
         # not be evictable while we make room (refcount >= 2 fences
         # them out of evict()'s cache-only candidate set — otherwise an
@@ -1324,10 +1374,16 @@ class ServingEngine:
         # module-doc contract), so the reference backend is unmoved
         sched.sort(key=lambda e: groups[_grp_key(e[0])])
         segs = []
+        rsegs = []  # recurrent layers: (row0, n, slot, starts from zero)
         row = 0
         for r, n in sched:
             g = groups[_grp_key(r)]
             decoding = r.kv_len >= len(r.prompt)
+            if self._recurrent:
+                # a request (re)admitted since its last step has no
+                # tokens materialized: its slot's state is zero, whatever
+                # the slot's last holder left there
+                rsegs.append((row, n, r.slot, r.kv_len == 0))
             facts["decode_tokens" if decoding else "prefill_tokens"] += n
             if r.first_sched_t is None and not decoding:
                 r.first_sched_t = t_step
@@ -1394,6 +1450,17 @@ class ServingEngine:
                                        + st["decode_rows_launched"])
             with _span(tick, "lower.upload"):
                 kplans = _ek.plans_to_device(plans)
+        if self._block is not None:
+            from flashinfer_tpu.serve import recurrent as _rec
+
+            with _span(tick, "lower.recurrent"):
+                mplan, rfacts = _rec.build_plan(
+                    rsegs, tokens=total, rung=rung, max_batch=Bpad,
+                    chunk=mcfg.scan_chunk if self._recurrent else 0)
+            facts.update(rfacts)
+            if self._recurrent:
+                facts["state_slots_used"] = sum(
+                    s is not None for s in self._slots)
         if tick is not None:
             tick.enter("dispatch")
 
@@ -1404,12 +1471,17 @@ class ServingEngine:
                          jnp.asarray(grp_pages), jnp.asarray(tok_grp),
                          jnp.asarray(split), jnp.asarray(last_rows),
                          jnp.asarray(sample_seeds), kplans, self.caches)
-        sig = obs.state_signature(full_args, names=self._STATE_NAMES)
+        names = self._STATE_NAMES
+        if self._block is not None:
+            full_args += ({k: jnp.asarray(v) for k, v in mplan.items()},)
+            names += ("mplan",)
+        sig = obs.state_signature(full_args, names=names)
         seen = self._rung_traced.get(rung, 0)
         before = self._traces
         t0 = time.perf_counter() if sig is not None else 0.0
         with _span(tick, "dispatch.call"):
-            tokens_dev, self.caches = self._step(*full_args)
+            out = self._step(*full_args)
+        tokens_dev, self.caches = out[:2]
         if tick is not None:
             tick.dispatched()
             tick.enter("fetch")
@@ -1433,6 +1505,16 @@ class ServingEngine:
         if sig is not None:
             self._last_sig[rung] = sig
         tokens = np.asarray(tokens_dev)
+        if self._block is not None:
+            # what the model's layers noted (an expert layer's rows per
+            # expert) and the tests' logits: computed by the step the
+            # tokens came from, so this fetch waits for nothing
+            aux = {k: np.asarray(v) for k, v in out[2].items()}
+            if "logits" in aux:
+                logits = aux.pop("logits")
+                self.last_logits = {r.rid: logits[r.slot]
+                                    for r in samplers}
+            facts.update(self._block.engine_facts(aux, total))
         if tick is not None:
             # np.asarray above IS the completion probe (tokens cross to
             # host): the ``fetch`` phase is the device window as the
@@ -1444,7 +1526,7 @@ class ServingEngine:
         # FIRST (post-run: the page KV is materialized now, and a
         # request finishing this very step must still donate its span
         # to the cache before its own references are released)
-        if cfg.enable_prefix_cache:
+        if cfg.enable_prefix_cache and not self._recurrent:
             for r, _ in sched:
                 upto = min(r.kv_len, r.split) // ps
                 if upto > r.inserted_pages:
@@ -1511,6 +1593,8 @@ class ServingEngine:
         sampling-seed stream) and frozen cascade ``split``."""
         from flashinfer_tpu import obs
 
+        if self._recurrent:
+            self._refuse_recurrent("adopt_migrated (migration)")
         if self.config.role == "prefill":
             raise ValueError("a prefill-role pool cannot adopt "
                              "migrated requests")
@@ -1548,6 +1632,8 @@ class ServingEngine:
         device pages now and restores bit-exactly when it next
         schedules).  The request re-queues as waiting; admission
         pressure decides when it returns."""
+        if self._recurrent:
+            self._refuse_recurrent("offload_idle (host spill)")
         if self.host_store is None:
             raise ValueError("offload_idle needs kv_offload='host'")
         r = next((x for x in self._running if x.rid == rid), None)

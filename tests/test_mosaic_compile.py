@@ -297,3 +297,93 @@ def test_engine_step(engine_step, compiled_branch, which):
     assert ("jit(_body)/attn.kv_write/cond/branch_0_fun/attn.core/"
             "jit(fused_paged_prefill)/fused_paged_prefill_l0/pallas_call"
             ) in hlo
+
+
+# ---------------------------------------------------------------------------
+# granite-4.0-h-small's block (ISSUE 28): the grouped expert matmuls the
+# engine newly reaches, and one whole step of a Mamba layer and an attention
+# layer, at the published widths
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("k, n", [(4096, 1536), (768, 4096)])
+def test_moe_gmm_at_granite_widths(chip, compiled_branch, k, n):
+    """36 held experts, 10 routes of 576 tokens (5,760 sorted rows of which
+    about half are local), the tiles the heuristic picks: gate|up
+    ``4096 -> 2 x 768`` and down ``768 -> 4096``."""
+    from flashinfer_tpu.fused_moe.core import _heuristic_gmm_tiles
+    from flashinfer_tpu.ops.moe_gmm import gmm
+
+    m = 5760
+    tm, tn, tk = _heuristic_gmm_tiles(m, k, n, 2, 2)
+    compiled = _compile(
+        lambda lhs, rhs, sizes: gmm(lhs, rhs, sizes, tm=tm, tn=tn, tk=tk),
+        _sds(chip, (m, k), "bfloat16"), _sds(chip, (36, k, n), "bfloat16"),
+        _sds(chip, (36,), "int32"))
+    assert re.search(r"%moe_gmm(\.\d+)? = .*custom-call\(",
+                     compiled.as_text())
+
+
+def test_granite_hybrid_engine_step(chip):
+    """The donated step of a Mamba-2 layer and an attention layer with
+    their expert layers at granite-4.0-h-small's widths (36 of 72 experts
+    held, half the vocabulary), on the largest rung: the attention kernels
+    without rotation, the grouped matmuls, the scan's loop, and every part
+    under its name."""
+    from flashinfer_tpu.models.granite_hybrid import (
+        GraniteHybridConfig, init_granite_hybrid_params)
+    from flashinfer_tpu.serve import (EngineConfig, SamplingConfig,
+                                      ServingEngine, engine_kernels as ek,
+                                      recurrent)
+
+    mp = pytest.MonkeyPatch()
+    _as_on_a_v5e(mp)
+    try:
+        mcfg = GraniteHybridConfig(vocab_size=50176,
+                                   layer_types=("mamba", "attention"),
+                                   local_experts=(0, 36))
+        ecfg = EngineConfig.from_knobs(
+            mcfg, num_pages=512, max_seq_tokens=2048, fused_ingest="off",
+            kv_offload="off", spill_policy="recompute",
+            sampling=SamplingConfig(temperature=0.8, top_k=40, top_p=0.95))
+        assert ecfg.rungs() == (64, 128, 256, 512, 1024)
+        params = jax.eval_shape(
+            lambda: init_granite_hybrid_params(jax.random.PRNGKey(0), mcfg))
+        eng = ServingEngine(mcfg, params, dataclasses.replace(
+            ecfg, num_pages=2))   # shapes only: no 512-page pool on the host
+        rung, chunk = 1024, 512
+        n = -(-rung // ecfg.page_size)
+        plans = ek.build_engine_work_units(
+            [ek.SchedSeg(row0=0, n=chunk, pages=tuple(range(1, n + 1)),
+                         split=0, kv_after=chunk, decoding=False, slot=0,
+                         group=0)], rung=rung, geom=eng._geom)
+        B, ppr = ecfg.max_batch, eng._ppr
+        mplan, _ = recurrent.build_plan(
+            [(0, chunk, 0, True)], tokens=chunk, rung=rung, max_batch=B,
+            chunk=mcfg.scan_chunk)
+        caches = [
+            jax.eval_shape(lambda: mcfg.new_recurrent_state(B)),
+            (jax.ShapeDtypeStruct((ecfg.num_pages, HKV, ecfg.page_size, D),
+                                  jnp.bfloat16),) * 2]
+        host = (params, *(np.zeros(rung, np.int32),) * 5,
+                *(np.zeros((B, ppr), np.int32),) * 2,
+                *(np.zeros(rung, np.int32),) * 2,
+                *(np.zeros(B, np.int32),) * 2, ek.plans_to_device(plans),
+                caches, mplan)
+        lowered = eng._step.lower(*_like(chip, host))
+        compiled = lowered.compile()
+    finally:
+        mp.undo()
+    hlo = compiled.as_text()
+    for kernel in ("fused_paged_prefill_l0", "fused_paged_prefill_l1",
+                   "paged_decode_attention_split", "moe_gmm"):
+        assert re.search(rf"%{kernel}(\.\d+)? = .*custom-call\(", hlo), kernel
+    for scope in ("embed", "ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.out",
+                  "moe.route", "moe.experts", "moe.shared", "attn.qkv",
+                  "attn.kv_write", "attn.core", "attn.out", "head", "sample"):
+        assert re.search(rf'op_name="jit\(_body\)/(\S+/)?{scope}/', hlo), scope
+    assert "apply_rope" not in hlo and "/rope" not in hlo
+    mem = compiled.memory_analysis()
+    # a Mamba layer and an attention layer with 36 experts each, half the
+    # embedding, 64 slots and 512 pages: 2.1 GB; well under the chip's 16
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 6e9
