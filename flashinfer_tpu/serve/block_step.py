@@ -13,9 +13,10 @@ Why a second body, and not a branch in the first: on the chip a rung's first
 step took 0.55 s longer, on a warm compile cache and with a byte-identical
 lowered program, once the Llama ``_body`` held this branch, and 1.6 s longer
 with the Llama layer in a function of its own (PERF.md section 6, PR 28;
-mechanism not found).  So the K/V write, the reference tier's windows and the
-sampling epilogue below are copies of ``_build_step``'s, less what no such
-model runs (int8 K/V, fused ingest); a fix to one belongs in the other.
+mechanism not found).  So the reference tier's windows and the sampling
+epilogue below are copies of ``_build_step``'s (a fix to one belongs in the
+other); the K/V write is the one both call (``serve/kv_write.py``), here
+without int8 pages, which no such model runs.
 
 Such a model object supplies, beside the sizes every model has:
 
@@ -37,6 +38,9 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+
+from flashinfer_tpu.serve.kv_write import (append_rows, page_cap,
+                                           touched_pages)
 
 _NEG_INF = -1e30
 
@@ -130,6 +134,10 @@ def build(eng):
               token_slot, page_table, grp_pages, tok_grp, split,
               last_rows, sample_seeds, kplans, caches, mplan):
         eng._traces += 1
+        with jax.named_scope("attn.kv_write"):
+            touched = touched_pages(
+                token_page, cap=page_cap(token_page.shape[0], cfg.page_size,
+                                         cfg.max_batch))
 
         def _attend_pages(q, k, v, cache):
             kc, vc = cache
@@ -139,11 +147,9 @@ def build(eng):
 
                     q, k = apply_rope_pos_ids(
                         q, k, positions, rope_theta=mcfg.rope_theta)
-                # pad lanes scatter into the scratch page (pool page 0)
-                kc = kc.at[token_page, :, token_slot, :].set(
-                    k.astype(kc.dtype))
-                vc = vc.at[token_page, :, token_slot, :].set(
-                    v.astype(vc.dtype))
+                # pad lanes go to the scratch page (pool page 0)
+                kc, vc = append_rows(kc, vc, k.astype(kc.dtype),
+                                     v.astype(vc.dtype), touched, token_slot)
             with jax.named_scope("attn.core"):
                 if kernel_backend:
                     from flashinfer_tpu.serve.engine_kernels import (

@@ -383,14 +383,14 @@ class EngineConfig:
     # lowering (serve/engine_kernels.py; interpret-mode off-TPU)
     attention_backend: str = "reference"
     decode_num_splits: int = 1      # kernel tier's split-KV factor
-    # prefill.fused_ingest (ISSUE 14): "on" = kernel-tier from-scratch
-    # prefill steps ride the fused RoPE + quantize-append + attention
-    # ingest launch (serve/engine_kernels.engine_kernel_ingest_
-    # attention; value-level lax.cond dispatch, so one-trace-per-rung
-    # holds); "off" = the composed rope -> scatter -> cascade tier.
-    # Ignored under the reference backend (the oracle tier stays
-    # composed by contract).  from_knobs resolves absent entries via
-    # the costmodel.predict_prefill_ingest_win chooser.
+    # prefill.fused_ingest: accepted ("off" | "on") and IGNORED.  The engine's
+    # step always writes a step's K/V rows and attends over the pages
+    # (``_composed_attn``).  The per-layer ``lax.cond`` onto the fused-ingest
+    # launch went in PR 29: no step of a benchmark cell took the branch, and
+    # its planner held the assertion a lone short prefill tripped.  The field
+    # and "on" stay only because perfbench/configs/mistral-nemo-12b.json
+    # passes it and perfbench/run.py prints it; the benchmark PR that drops
+    # both removes the field (PERF.md section 7.1).
     fused_ingest: str = "off"
     # tiered-KV statics (serve/kv_tier.py): the engine's ROLE in a
     # disaggregated pair ("prefill" keeps finished KV pages alive for
@@ -440,33 +440,8 @@ class EngineConfig:
             host_gib=float(t.lookup("engine.host_gib", key, default=4)),
         )
         knobs.update(over)
-        cfg = EngineConfig(num_pages=num_pages,
-                           max_seq_tokens=max_seq_tokens, **knobs)
-        if "fused_ingest" not in over \
-                and cfg.attention_backend == "kernel":
-            # shape-key the ingest knob the way the prefill wrapper
-            # does (batch, tq_pad, H, Hkv, D, page_size) at the
-            # ladder's top rung — the from-scratch prefill step the
-            # fusion serves; resolve_prefill_ingest is the shared
-            # knob -> cost-model-chooser resolution point
-            from flashinfer_tpu.prefill import resolve_prefill_ingest
-
-            top = max(cfg.rungs())
-            kv_bytes = jnp.dtype(
-                cfg.kv_dtype if cfg.kv_dtype is not None
-                else model_cfg.dtype).itemsize
-            use = resolve_prefill_ingest(
-                (cfg.max_batch, top, model_cfg.num_qo_heads,
-                 model_cfg.num_kv_heads, model_cfg.head_dim,
-                 cfg.page_size),
-                total_q=top, total_kv=top,
-                num_qo_heads=model_cfg.num_qo_heads,
-                num_kv_heads=model_cfg.num_kv_heads,
-                head_dim=model_cfg.head_dim,
-                cache_bytes=int(kv_bytes))
-            cfg = dataclasses.replace(
-                cfg, fused_ingest="on" if use else "off")
-        return cfg
+        return EngineConfig(num_pages=num_pages,
+                            max_seq_tokens=max_seq_tokens, **knobs)
 
     def pages_per_req(self) -> int:
         return -(-self.max_seq_tokens // self.page_size)
@@ -632,12 +607,9 @@ class ServingEngine:
             "decode_pages_real": 0, "decode_pages_launched": 0,
             "kv_pairs_launched": 0.0, "kv_rows_launched": 0.0,
         }
-        # fused ingest is a kernel-tier concept: the reference backend
-        # is the composed oracle by contract, so "on" there is inert
-        # ... and it is the Llama step's: inert as well for a model that
-        # brings its own block
-        self._ingest = (self._kernel_backend and self._block is None
-                        and config.fused_ingest == "on")
+        # the engine adopts no fused ingest, whatever ``config.fused_ingest``
+        # says (see the field): no ``ingest`` plan is built or shipped
+        self._ingest = False
         if self._kernel_backend:
             from flashinfer_tpu.serve.engine_kernels import EngineKernelGeom
 
@@ -649,7 +621,6 @@ class ServingEngine:
                 head_dim=model_cfg.head_dim,
                 kv_itemsize=kv_dtype.itemsize,
                 num_splits=config.decode_num_splits,
-                fused_ingest=self._ingest,
             )
         # each rung is one whole-model compile: keep it across processes
         from flashinfer_tpu import env
@@ -1104,8 +1075,6 @@ class ServingEngine:
 
         kernel_backend = self._kernel_backend
         geom = self._geom
-        use_ingest = self._ingest
-        sm_plain = 1.0 / float(mcfg.head_dim) ** 0.5
 
         def _body(params, flat_tokens, positions, tok_req, token_page,
                   token_slot, page_table, grp_pages, tok_grp, split,
@@ -1119,17 +1088,22 @@ class ServingEngine:
             from flashinfer_tpu.models.llama import _mm, _pre_quant
             from flashinfer_tpu.norm import rmsnorm
             from flashinfer_tpu.rope import apply_rope_pos_ids
+            from flashinfer_tpu.serve.kv_write import (
+                append_rows, page_cap, touched_pages)
 
             engine_self._traces += 1
             T = flat_tokens.shape[0]
+            # the pages this step writes, once for every layer
+            with jax.named_scope("attn.kv_write"):
+                touched = touched_pages(
+                    token_page, cap=page_cap(T, ps, cfg.max_batch))
 
             def _composed_attn(q, k, v, kc, vc):
-                """The ONE composed sequence — rope -> quantize ->
-                scatter-append -> attend -> v-scale epilogue.  The
-                non-ingest path and the ingest cond's false branch run
-                exactly this function, so a fix to the
-                quantize/scale/scatter logic can never reach one
-                fused_ingest setting and miss the other."""
+                """One layer's attention: rope -> quantize -> the step's
+                K/V rows into the pages, in place
+                (``serve/kv_write.py``) -> attend over the pages -> v-scale
+                epilogue.  Both tiers and every ``fused_ingest`` setting
+                run exactly this function."""
                 with jax.named_scope("attn.kv_write"):
                     q, k = apply_rope_pos_ids(q, k, positions,
                                               rope_theta=mcfg.rope_theta)
@@ -1142,10 +1116,9 @@ class ServingEngine:
                     else:
                         k_w = k.astype(kc.dtype)
                         v_w = v.astype(vc.dtype)
-                    # pad lanes scatter into the scratch page (pool
-                    # page 0)
-                    kc = kc.at[token_page, :, token_slot, :].set(k_w)
-                    vc = vc.at[token_page, :, token_slot, :].set(v_w)
+                    # pad lanes go to the scratch page (pool page 0)
+                    kc, vc = append_rows(kc, vc, k_w, v_w, touched,
+                                         token_slot)
                 with jax.named_scope("attn.core"):
                     if kernel_backend:
                         # the graduated path: the same two-level
@@ -1189,18 +1162,6 @@ class ServingEngine:
                         o = o * mcfg.kv_v_scale
                     return o.astype(mcfg.dtype), kc, vc
 
-            @jax.named_scope("attn.core")
-            def _ingest_branch(q, k, v, kc, vc):
-                from flashinfer_tpu.serve.engine_kernels import (
-                    engine_kernel_ingest_attention)
-
-                return engine_kernel_ingest_attention(
-                    q, k, v, kc, vc, kplans, geom=geom,
-                    sm_scale=sm_plain, rope_theta=mcfg.rope_theta,
-                    kv_quant="int8" if int8_kv else "none",
-                    k_scale=mcfg.kv_k_scale if int8_kv else 1.0,
-                    v_scale=mcfg.kv_v_scale if int8_kv else 1.0)
-
             with jax.named_scope("embed"):
                 x = params["embed"][flat_tokens].astype(mcfg.dtype)
             new_caches = []
@@ -1215,23 +1176,7 @@ class ServingEngine:
                     v = _mm(h, layer, "v_proj", pre).reshape(
                         T, mcfg.num_kv_heads, mcfg.head_dim)
                 kc, vc = caches[li]
-                if use_ingest:
-                    # ISSUE 14: per-step VALUE dispatch between the
-                    # fused-ingest launch and the composed cascade —
-                    # lax.cond, so both branches live in the SAME
-                    # per-rung program and the one-trace-per-rung
-                    # budget is untouched.  ingest_on certifies a
-                    # from-scratch prefill schedule (level 0 + decode
-                    # structurally empty) at plan-build time.  The
-                    # cond, and whatever XLA copies around it, is part
-                    # of writing the K/V: its own operations read
-                    # attn.kv_write, the branches' their inner scopes.
-                    with jax.named_scope("attn.kv_write"):
-                        attn, kc, vc = jax.lax.cond(
-                            kplans["ingest_on"] > 0, _ingest_branch,
-                            _composed_attn, q, k, v, kc, vc)
-                else:
-                    attn, kc, vc = _composed_attn(q, k, v, kc, vc)
+                attn, kc, vc = _composed_attn(q, k, v, kc, vc)
                 new_caches.append((kc, vc))
                 with jax.named_scope("attn.out"):
                     x = x + _mm(attn.reshape(T, -1), layer,
@@ -1295,8 +1240,10 @@ class ServingEngine:
         scatter results.  Returns this step's facts: its index, rung,
         tokens scheduled (and how many were prompt / decode tokens),
         requests sampled / finished, the kernel tier's work units and
-        cells useful against launched (``_UNIT_FACTS``), and the queue
-        wait of the requests whose first prompt chunk ran in it."""
+        cells useful against launched (``_UNIT_FACTS``), the pages its
+        K/V rows were written to (``kv_pages_written``, of
+        ``kv_pages_pool`` usable ones) and the queue wait of the requests
+        whose first prompt chunk ran in it."""
         from flashinfer_tpu import obs
 
         t_step = time.perf_counter()
@@ -1344,9 +1291,9 @@ class ServingEngine:
         samplers: List[EngineRequest] = []
         facts = dict.fromkeys(
             ("prefill_tokens", "decode_tokens", "first_scheduled",
-             *self._UNIT_FACTS), 0)
+             "kv_pages_written", *self._UNIT_FACTS), 0)
         facts.update(step=self._steps, rung=rung, tokens=total,
-                     queue_wait_s=0.0)
+                     queue_wait_s=0.0, kv_pages_pool=cfg.num_pages - 1)
 
         # group slots: one per distinct shared page-run prefix this
         # step (sharing mode: every full hit of one cached run lands in
@@ -1385,6 +1332,10 @@ class ServingEngine:
                 # the slot's last holder left there
                 rsegs.append((row, n, r.slot, r.kv_len == 0))
             facts["decode_tokens" if decoding else "prefill_tokens"] += n
+            # the pages its rows land on: what the step's K/V write moves
+            # (serve/kv_write.py), beside the pool it no longer copies
+            facts["kv_pages_written"] += ((r.kv_len + n - 1) // ps
+                                          - r.kv_len // ps + 1)
             if r.first_sched_t is None and not decoding:
                 r.first_sched_t = t_step
                 facts["first_scheduled"] += 1

@@ -98,19 +98,12 @@ class EngineKernelGeom:
     single_chunk: bool   # config-level certificate: every decode unit
     #                      is at most one DMA chunk for EVERY schedule
     dec_width: int       # fixed decode page-table width (chunk-aligned)
-    # ISSUE 14: the fused-ingest adoption static (the resolved
-    # prefill.fused_ingest knob).  When True every plan bundle carries
-    # an ``ingest`` sub-plan (rung-stable shapes; degenerate when the
-    # step's schedule is not a from-scratch prefill) and the body
-    # dispatches on the bundle's ``ingest_on`` VALUE via lax.cond — so
-    # the one-trace-per-rung budget is untouched
-    fused_ingest: bool = False
 
     @staticmethod
     def build(*, page_size: int, pages_per_req: int, max_batch: int,
               max_rung: int, num_kv_heads: int, head_dim: int,
-              kv_itemsize: int, num_splits: int = 1,
-              fused_ingest: bool = False) -> "EngineKernelGeom":
+              kv_itemsize: int, num_splits: int = 1
+              ) -> "EngineKernelGeom":
         from flashinfer_tpu.ops.paged_decode import split_pages_per_chunk
         from flashinfer_tpu.ops.paged_prefill import (
             ingest_block_q, ingest_pages_per_chunk)
@@ -132,7 +125,6 @@ class EngineKernelGeom:
             single_chunk=cdiv(pages_per_req, num_splits) <= decode_ppc,
             dec_width=max(round_up(pages_per_req, decode_ppc),
                           per_unit_pages * num_splits, decode_ppc),
-            fused_ingest=bool(fused_ingest),
         )
 
     @property
@@ -158,11 +150,6 @@ PREFILL_ARRAY_KEYS = ("qstart", "rowlo", "rowhi", "qpos0", "kvstart",
                       "kvlen", "first", "wout", "qslot", "code", "pages",
                       "n_units", "mask_bytes")
 DECODE_ARRAY_KEYS = ("pages", "kvlen", "wu_req", "wu_page0", "wu_kvlen")
-# the ingest plan's traced arrays (fused_ingest geoms only): the base
-# work-unit arrays plus the ingest extras, no mask (from-scratch causal)
-INGEST_ARRAY_KEYS = ("qstart", "rowlo", "rowhi", "qpos0", "kvstart",
-                     "kvlen", "first", "wout", "qslot", "code", "pages",
-                     "kvbase", "posoff", "wkv")
 
 
 def build_engine_work_units(
@@ -307,75 +294,6 @@ def build_engine_work_units(
         (dplan["pages"].shape, geom.dec_width)
     assert not geom.single_chunk or dplan["single_chunk"]
 
-    # ---- fused-ingest plan (ISSUE 14): rung-stable EXTRA plan -------
-    # Present on every bundle of a fused_ingest geom so the traced
-    # pytree never changes shape.  A step qualifies when its WHOLE
-    # schedule is a from-scratch prefill (kv_before == 0, i.e.
-    # kv_after == n, no decode lanes): every attended KV row is one of
-    # THIS step's raw rows, so the ingest launch can rotate +
-    # quantize-append + attend them in one pass.  The cascade split is
-    # irrelevant here — it merely PARTITIONS the causal attention the
-    # single launch computes whole (merge is associative), and no
-    # cross-request page is shared on a from-scratch step (fresh pages
-    # per request).  Each request's rows must also start on
-    # INGEST_KV_ALIGN of the flat axis — the compiled kernel's raw-row
-    # DMA needs it.  Non-qualifying steps carry the padding-only
-    # degenerate plan with ``ingest_on = 0`` — the body's lax.cond
-    # keeps the composed tier.
-    ingest_plan = None
-    ingest_on = 0
-    if geom.fused_ingest:
-        from flashinfer_tpu.ops.paged_prefill import (
-            build_prefill_ingest_units, ingest_rows_aligned)
-
-        eligible = bool(segs) and all(
-            s.kv_after == s.n and not s.decoding for s in segs
-        ) and ingest_rows_aligned([s.row0 for s in segs],
-                                  geom.prefill_ppc * ps)
-        if eligible:
-            qoI = [0]
-            kvI: List[int] = []
-            piI = [0]
-            pagesI: List[int] = []
-            basesI: List[int] = []
-            for s in segs:
-                qoI.append(qoI[-1] + s.n)
-                kvI.append(s.kv_after)
-                pagesI.extend(s.pages[: cdiv(s.kv_after, ps)])
-                piI.append(len(pagesI))
-                basesI.append(s.row0)
-            if total < rung:  # rung padding rows: empty-state segment
-                qoI.append(rung)
-                kvI.append(0)
-                piI.append(len(pagesI))
-                basesI.append(total)
-            ingest_plan = build_prefill_ingest_units(
-                np.asarray(qoI, np.int64), np.asarray(piI, np.int64),
-                np.asarray(pagesI, np.int64), np.asarray(kvI, np.int64),
-                geom.block_q, geom.prefill_ppc, ps,
-                causal=True, window_left=-1,
-                pack_tiles=True, prune=True, num_units_pad=U,
-                fused_ingest={"kv_bases": np.asarray(basesI, np.int64)},
-            )
-            ingest_on = 1
-        else:
-            ingest_plan = build_prefill_ingest_units(
-                np.asarray([0, rung], np.int64),
-                np.asarray([0, 0], np.int64),
-                np.zeros(0, np.int64), np.asarray([0], np.int64),
-                geom.block_q, geom.prefill_ppc, ps,
-                causal=True, window_left=-1,
-                pack_tiles=True, prune=True, num_units_pad=U,
-            )
-        # the rung contract: the cap must hold for the ingest plan too
-        # (causal from-scratch geometry never emits write-only units —
-        # the last tile of each request keeps every chunk)
-        assert ingest_plan["qstart"].shape[0] == U, \
-            (ingest_plan["qstart"].shape, U)
-        assert ingest_plan["stats"].get("ingest_write_only_units",
-                                        0) == 0
-        assert ingest_plan["stats"]["ingest_kv_aligned"]
-
     chunk_tokens = geom.prefill_ppc * ps
     # what the two prefill launches run: each level's ``n_units``, the
     # trip count of its unit axis (the pad units up to U are not run)
@@ -396,16 +314,8 @@ def build_engine_work_units(
             dplan["stats"]["pages_launched"] * ps),
         "decode_cells_valid": float(dec_lens.sum()),
     }
-    out = dict(prefill0=plan0, prefill1=plan1, decode=dplan,
-               dec_rows=dec_rows, stats=stats)
-    if ingest_plan is not None:
-        out["ingest"] = ingest_plan
-        out["ingest_on"] = ingest_on
-        stats["ingest_on"] = ingest_on
-        stats["ingest_chunks"] = (
-            ingest_plan["stats"].get("ingest_chunks", 0) if ingest_on
-            else 0)
-    return out
+    return dict(prefill0=plan0, prefill1=plan1, decode=dplan,
+                dec_rows=dec_rows, stats=stats)
 
 
 def plans_to_device(plans: Dict) -> Dict:
@@ -414,7 +324,7 @@ def plans_to_device(plans: Dict) -> Dict:
     body never sees a structure change."""
     import jax.numpy as jnp
 
-    out = dict(
+    return dict(
         prefill0={k: jnp.asarray(plans["prefill0"][k])
                   for k in PREFILL_ARRAY_KEYS},
         prefill1={k: jnp.asarray(plans["prefill1"][k])
@@ -423,11 +333,6 @@ def plans_to_device(plans: Dict) -> Dict:
                 for k in DECODE_ARRAY_KEYS},
         dec_rows=jnp.asarray(plans["dec_rows"]),
     )
-    if "ingest" in plans:  # fused_ingest geoms: structurally ALWAYS on
-        out["ingest"] = {k: jnp.asarray(plans["ingest"][k])
-                         for k in INGEST_ARRAY_KEYS}
-        out["ingest_on"] = jnp.asarray(plans["ingest_on"], jnp.int32)
-    return out
 
 
 def engine_kernel_attention(q, k_cache, v_cache, kplans, *,
@@ -469,40 +374,3 @@ def engine_kernel_attention(q, k_cache, v_cache, kplans, *,
     lse1 = lse1.at[dec_rows].set(lsed.astype(lse1.dtype), mode="drop")
     out, _ = compose_cascade_levels([(o0, lse0), (o1, lse1)])
     return out
-
-
-def engine_kernel_ingest_attention(q, k, v, k_cache, v_cache, kplans, *,
-                                   geom: EngineKernelGeom,
-                                   sm_scale: float, rope_theta: float,
-                                   kv_quant: str, k_scale: float,
-                                   v_scale: float):
-    """The fused-ingest form of one layer's engine attention (ISSUE
-    14, traced inside the engine body's ``lax.cond`` TRUE branch): the
-    step's RAW pre-RoPE q/k/v rows ride ONE
-    :func:`~flashinfer_tpu.ops.paged_prefill.fused_paged_prefill_ingest`
-    launch that rotates in-register, quantize-appends the finished
-    pages, and attends the in-register values — replacing the
-    rope -> scatter-append -> (level-0 + level-1 + decode + merge)
-    composition for the from-scratch prefill step the plan bundle
-    certified (``ingest_on``; level 0 and decode are structurally
-    empty there, so the cascade fold is the identity).
-
-    ``sm_scale`` is the PLAIN softmax scale — the launcher owns the
-    quantized-cache scale folding, so the output lands already
-    v-scaled in ``q.dtype`` (matching the composed tier's
-    ``(compose * v_scale).astype`` epilogue bit-for-bit for int8, see
-    tests/test_prefill_ingest.py).  Returns ``(attn, k_cache,
-    v_cache)`` with the caches updated by the launch."""
-    from flashinfer_tpu.ops.paged_prefill import fused_paged_prefill_ingest
-
-    plan = kplans["ingest"]
-    attn, (kc, vc) = fused_paged_prefill_ingest(
-        q, k, v, k_cache, v_cache, plan,
-        num_units=plan["qstart"].shape[0], block_q=geom.block_q,
-        pages_per_chunk=geom.prefill_ppc, sm_scale=sm_scale,
-        causal=True, rope_theta=float(rope_theta),
-        kv_quant=kv_quant, k_scale=float(k_scale),
-        v_scale=float(v_scale),
-        kv_aligned=True,  # build_engine_work_units' eligibility rule
-    )
-    return attn, kc, vc

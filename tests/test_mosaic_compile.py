@@ -235,13 +235,15 @@ def engine_step(chip):
     _as_on_a_v5e(mp)
     try:
         mcfg = LlamaConfig.llama3_8b(num_layers=1)
+        # 4,096 pages: a pool (128 MiB a K or V) that the compiler cannot
+        # keep in fast memory, as no cell's can be
         ecfg = EngineConfig.from_knobs(
-            mcfg, num_pages=512, max_seq_tokens=2048,
+            mcfg, num_pages=4096, max_seq_tokens=2048,
             sampling=SamplingConfig(temperature=0.8, top_k=40, top_p=0.95))
         params = jax.eval_shape(
             lambda: init_llama_params(jax.random.PRNGKey(0), mcfg))
         eng = ServingEngine(mcfg, params, dataclasses.replace(
-            ecfg, num_pages=2))  # shapes only: no 512-page pool on the host
+            ecfg, num_pages=2))  # shapes only: no such pool on the host
     finally:
         mp.undo()
     caches = [(_sds(chip, (ecfg.num_pages, HKV, ecfg.page_size, D),
@@ -266,14 +268,14 @@ def engine_step(chip):
 def test_from_knobs_on_v5e_is_the_kernel_tier(engine_step):
     _, ecfg, _ = engine_step
     assert ecfg.attention_backend == "kernel"
-    assert ecfg.fused_ingest == "on"
+    assert ecfg.fused_ingest == "off"   # no longer read from the table
     assert ecfg.rungs() == (64, 128, 256, 512, 1024)
 
 
 @pytest.mark.parametrize("which", ["decode", "prefill"])
 def test_engine_step(engine_step, compiled_branch, which):
-    """The whole donated step, as from_knobs configures it (fused ingest
-    included), on the decode rung and on a prefill rung."""
+    """The whole donated step, as from_knobs configures it, on the decode
+    rung and on a prefill rung."""
     eng, ecfg, args = engine_step
     rung = min(ecfg.rungs()) if which == "decode" else 512
     lowered = eng._step.lower(*args(rung))
@@ -288,15 +290,34 @@ def test_engine_step(engine_step, compiled_branch, which):
     # are components of the operations' op_name (jax.named_scope).
     hlo = compiled.as_text()
     for kernel in ("fused_paged_prefill_l0", "fused_paged_prefill_l1",
-                   "fused_paged_prefill_ingest",
                    "paged_decode_attention_split"):
         assert re.search(rf"%{kernel}(\.\d+)? = .*custom-call\(", hlo), kernel
+    assert "fused_paged_prefill_ingest" not in hlo
     for scope in ("embed", "attn.qkv", "attn.kv_write", "attn.core",
                   "attn.out", "mlp", "head", "sample"):
         assert re.search(rf'op_name="jit\(_body\)/(\S+/)?{scope}/', hlo), scope
-    assert ("jit(_body)/attn.kv_write/cond/branch_0_fun/attn.core/"
-            "jit(fused_paged_prefill)/fused_paged_prefill_l0/pallas_call"
-            ) in hlo
+    assert ("jit(_body)/attn.core/jit(fused_paged_prefill)/"
+            "fused_paged_prefill_l0/pallas_call") in hlo
+    # The K/V write is in place (serve/kv_write.py): no branch that returns
+    # the pool, and no operation but the two scatters (K and V, each alone
+    # or as its fusion's root) produces an array of the pool's size, in the
+    # pool's own layout.  A `copy` of that shape is the pool re-laid out
+    # around a write, which costs the pool and not the rows.
+    assert " conditional(" not in hlo
+    pool = (ecfg.num_pages, HKV, ecfg.page_size, D)
+    made = []
+    for dims, layout, op in re.findall(
+            r"^\s*(?:ROOT )?%\S+ = bf16\[([\d,]+)\](\{[\d,]*)\S* ([\w\-]+)\(",
+            hlo, re.M):
+        dims = tuple(int(d) for d in dims.split(","))
+        if (np.prod(dims) == np.prod(pool) and dims[0] % pool[0] == 0
+                and op not in ("parameter", "get-tuple-element", "bitcast")):
+            made.append((dims, layout, op))
+    assert made and all(
+        dims == pool and layout == "{3,2,1,0" and op in ("scatter", "fusion")
+        for dims, layout, op in made), made
+    # ... and the step's temporaries hold no copy of a pool (128 MiB each)
+    assert mem.temp_size_in_bytes < 100 * 2**20
 
 
 # ---------------------------------------------------------------------------

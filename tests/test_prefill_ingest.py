@@ -650,12 +650,9 @@ def test_mixed_step_ingest_token_parity(monkeypatch):
     assert ms._plan.fused_ingest is False
 
 
-def test_engine_ingest_token_parity_and_trace_budget():
-    """Engine kernel tier with prefill.fused_ingest on: tokens bitwise
-    equal to both the composed kernel tier and the reference oracle,
-    the from-scratch prefill step actually takes the ingest branch,
-    and the one-trace-per-rung budget holds (the lax.cond dispatch is
-    value-level, not a trace axis)."""
+def _engine_run(fused, backend, prompts, *, max_new_tokens=4, **statics):
+    """Serve ``prompts`` through a small engine; (tokens by request, the
+    engine, the plan bundles its steps built)."""
     import flashinfer_tpu.serve.engine_kernels as ek
     from flashinfer_tpu.models.llama import LlamaConfig, init_llama_params
     from flashinfer_tpu.serve.engine import (EngineConfig, EngineRequest,
@@ -663,40 +660,93 @@ def test_engine_ingest_token_parity_and_trace_budget():
 
     cfg = LlamaConfig.tiny(num_layers=2, dtype=jnp.float32)
     params = init_llama_params(jax.random.PRNGKey(0), cfg)
+    bundles = []
+    orig = ek.build_engine_work_units
 
-    def run(fused, backend="kernel", spy_hits=None):
-        orig = ek.build_engine_work_units
-        if spy_hits is not None:
-            def spy(*a, **kw):
-                out = orig(*a, **kw)
-                spy_hits.append(out.get("ingest_on", 0))
-                return out
-            ek.build_engine_work_units = spy
-        try:
-            ec = EngineConfig(
-                num_pages=64, page_size=8, max_batch=4,
-                prefill_budget_tokens=32, max_seq_tokens=64,
-                attention_backend=backend, fused_ingest=fused,
-                enable_prefix_cache=False)
-            eng = ServingEngine(cfg, params, ec)
-            # 16 + 16 fill the first step's budget from scratch with
-            # both requests' rows on INGEST_KV_ALIGN: the ingest step
-            for i, n in enumerate([16, 16, 19]):
-                eng.submit(EngineRequest(f"r{i}", list(range(1, n + 1)),
-                                         max_new_tokens=4))
-            out = eng.run()
-        finally:
-            ek.build_engine_work_units = orig
-        return {k: v for k, v in sorted(out.items())}, eng
+    def spy(*a, **kw):
+        bundles.append(orig(*a, **kw))
+        return bundles[-1]
 
-    hits = []
-    on, eng_on = run("on", spy_hits=hits)
-    off, _ = run("off")
-    ref, _ = run("off", backend="reference")
-    assert on == off == ref
-    assert sum(hits) >= 1, "no step took the ingest branch"
-    assert all(n == 1 for n in eng_on._rung_traced.values())
-    assert eng_on.num_traces == len(eng_on._rung_traced) <= 9
+    ek.build_engine_work_units = spy
+    try:
+        eng = ServingEngine(cfg, params, EngineConfig(**dict(
+            dict(num_pages=64, page_size=8, max_batch=4,
+                 prefill_budget_tokens=32, max_seq_tokens=64),
+            attention_backend=backend, fused_ingest=fused,
+            enable_prefix_cache=False, **statics)))
+        for i, n in enumerate(prompts):
+            eng.submit(EngineRequest(f"r{i}", list(range(1, n + 1)),
+                                     max_new_tokens=max_new_tokens))
+        out = eng.run()
+    finally:
+        ek.build_engine_work_units = orig
+    return dict(sorted(out.items())), eng, bundles
+
+
+@pytest.mark.parametrize("fused", ["on", "off"])
+def test_engine_ingest_token_parity_and_trace_budget(fused):
+    """The engine takes ``fused_ingest`` and ignores it (PR 29): under "on"
+    and "off" the kernel tier serves tokens bitwise equal to the reference
+    tier, no plan bundle carries an ``ingest`` plan (16 + 16 is the
+    from-scratch, aligned first step the ingest launch used to take), and a
+    rung traces once."""
+    prompts = [16, 16, 19]
+    got, eng, bundles = _engine_run(fused, "kernel", prompts)
+    ref, _, _ = _engine_run("off", "reference", prompts)
+    assert got == ref
+    assert eng._ingest is False
+    assert bundles and not any(
+        "ingest" in b or "ingest_on" in b for b in bundles)
+    assert all(n == 1 for n in eng._rung_traced.values())
+    assert eng.num_traces == len(eng._rung_traced) <= 9
+
+
+def test_engine_lowered_step_ignores_ingest_knob():
+    """``fused_ingest="on"`` and ``"off"`` engines lower the same program,
+    rung by rung."""
+    import flashinfer_tpu.serve.engine_kernels as ek
+    from flashinfer_tpu.models.llama import LlamaConfig, init_llama_params
+    from flashinfer_tpu.serve.engine import EngineConfig, ServingEngine
+
+    cfg = LlamaConfig.tiny(num_layers=2, dtype=jnp.float32)
+    params = init_llama_params(jax.random.PRNGKey(0), cfg)
+
+    def lowered(fused):
+        ec = EngineConfig(num_pages=64, page_size=8, max_batch=4,
+                          prefill_budget_tokens=32, max_seq_tokens=64,
+                          attention_backend="kernel", fused_ingest=fused)
+        eng = ServingEngine(cfg, params, ec)
+        texts = []
+        for rung in ec.rungs():
+            plans = ek.build_engine_work_units(
+                [ek.SchedSeg(row0=0, n=rung, pages=tuple(range(1, 9)),
+                             split=0, kv_after=rung, decoding=False, slot=0,
+                             group=0)], rung=rung, geom=eng._geom)
+            B, ppr = ec.max_batch, eng._ppr
+            args = (params, *(np.zeros(rung, np.int32),) * 5,
+                    *(np.zeros((B, ppr), np.int32),) * 2,
+                    *(np.zeros(rung, np.int32),) * 2,
+                    *(np.zeros(B, np.int32),) * 2,
+                    ek.plans_to_device(plans), eng.caches)
+            texts.append(eng._step.lower(*args).as_text())
+        return texts
+
+    assert lowered("on") == lowered("off")
+
+
+def test_engine_serves_lone_short_prefill_with_ingest_on():
+    """PERF.md section 7.5 (found in PR 23): a lone fresh prompt shorter than
+    its rung, of a length that is no multiple of 16, at an idle kernel-tier
+    engine built with ``fused_ingest="on"`` tripped the ingest planner's
+    ``ingest_kv_aligned`` assertion.  The planner is gone; the step runs and
+    serves what the reference tier serves."""
+    statics = dict(max_new_tokens=3, max_batch=8, prefill_budget_tokens=56,
+                   max_seq_tokens=128)       # 40 rows ride the rung of 64
+    got, eng, _ = _engine_run("on", "kernel", [40], **statics)
+    ref, _, _ = _engine_run("off", "reference", [40], **statics)
+    assert got == ref and len(got["r0"]) == 3
+    assert 64 in eng._rung_traced
+    assert eng.num_traces == len(eng._rung_traced)
 
 
 def test_engine_config_validates_ingest_knob():
