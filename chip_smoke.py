@@ -482,13 +482,9 @@ def run_four_chips(sz: Sizes, seed: int, on_tpu: bool, cache) -> None:
     if worst > 0.3 * model_b:
         raise AssertionError("a device holds more than its tp share")
 
-    # mode="shard_map": JAX refuses to partition a Mosaic kernel on its
-    # own ("Mosaic kernels cannot be automatically partitioned. Please
-    # wrap the call in a shard_map."), so the GSPMD mode cannot lower the
-    # Pallas step; the explicit-collective mode is the one a chip runs
     def sharded(depth):
         step = ShardedServingStep()
-        step.plan(spec, plan, num_layers=depth, mode="shard_map")
+        step.plan(spec, plan, num_layers=depth)
         return step
 
     def chain(fn, ws, hd, hs, put):

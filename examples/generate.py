@@ -11,6 +11,11 @@ canonical serving lifecycle —
    (plan once per geometry bucket, run per layer per step);
 4. sample with the logits pipeline.
 
+``--engine`` then serves the same prompts through the serving surface,
+``flashinfer_tpu.serve.ServingEngine`` (``submit`` / ``run``), which owns
+all four steps, and checks its first tokens against the hand-built
+prefill's.
+
 Run: ``python examples/generate.py`` (tiny random model).  It runs on the
 devices JAX has — ``JAX_PLATFORMS=cpu`` for the CPU — and prints which.
 """
@@ -61,16 +66,14 @@ def _print_lifecycle_summary(label: str) -> None:
 
 
 def generate(prompt_lens, max_new_tokens=8, seed=0, int8_weights=False,
-             fused_step=False):
+             engine=False):
     """Serving loop; ``int8_weights=True`` runs every projection on the
     int8 MXU path (quantize_llama_weights) — the quantized serving mode.
 
-    ``fused_step=True`` ADDITIONALLY routes the decode loop through the
-    compile-once donated-buffer serving step (flashinfer_tpu.serve):
-    one jitted XLA program per token instead of a Python loop over ops,
-    with a token-for-token parity assert against the per-op loop — the
-    fused step must be a pure dispatch-structure change, never a
-    numerics change."""
+    ``engine=True`` ADDITIONALLY serves the same prompts through
+    ``ServingEngine`` (greedy), whose first token of every request must
+    be the argmax of the logits this function's own prefill computed:
+    two routes through the library, one model."""
     cfg = LlamaConfig.tiny(num_layers=2, dtype=jnp.float32)
     params = init_llama_params(jax.random.PRNGKey(seed), cfg)
     if int8_weights:
@@ -182,53 +185,9 @@ def generate(prompt_lens, max_new_tokens=8, seed=0, int8_weights=False,
     for b, rid in enumerate(rids):
         obs.prefill_chunk(rid, prompt_lens[b])
 
-    # ---- fused decode loop (serve/step.py): plan ONCE outside the
-    # loop — all statics (shapes, page geometry, sampling config,
-    # backend) freeze here, so the loop below is pure replay of one
-    # donated-buffer XLA program (the per-op loop's per-step op
-    # re-dispatch is hoisted into this single plan)
-    fused_out = None
-    if fused_step:
-        from flashinfer_tpu.serve import SamplingConfig, ServingStep
-
-        sstep = ServingStep()
-        sstep.plan(
-            cfg, page_table=page_table, kv_lens=kv_lens,
-            kv_dtype=caches[0][0].dtype,
-            sampling=SamplingConfig(temperature=0.8, top_k=40,
-                                    top_p=0.95),
-            use_pallas=use_pallas,
-        )
-        # the step DONATES page_table/kv_lens: keep host copies so the
-        # per-op parity loop below can rebuild its own starting state
-        pt_host = np.asarray(page_table)
-        lens_host = np.asarray(kv_lens)
-        state = sstep.make_state(caches, page_table, kv_lens, logits,
-                                 jax.random.PRNGKey(seed + 1))
-        # the fused loop IS the serving path, so it owns the real
-        # begin -> prefill -> decode lifecycle lanes (`rids`); the
-        # per-op loop below becomes the parity oracle and gets fresh
-        # decode-only lanes — otherwise its TTFT would absorb this
-        # whole fused replay's wall time
-        fused_out = [[] for _ in range(B)]
-        for _ in range(max_new_tokens):
-            tokens, state = sstep.run(params, state)
-            for b in range(B):
-                fused_out[b].append(int(tokens[b]))
-                obs.decode_step(rids[b])
-        fused_summaries = [obs.request_finish(rid) for rid in rids]
-        assert sstep.num_traces == 1, (
-            f"fused step traced {sstep.num_traces}x across "
-            f"{max_new_tokens} tokens — the compile-once contract broke")
-        # the donated post-prefill state was consumed by the fused
-        # loop; its FINAL caches are a valid restart state for the
-        # parity loop below (slots past each request's kv_len are
-        # masked by the attention, and the loop re-appends every
-        # position it reads), and page_table/kv_lens rebuild from the
-        # host copies
-        caches = list(state[1])
-        page_table = jnp.asarray(pt_host)
-        kv_lens = jnp.asarray(lens_host)
+    if engine:
+        _serve_through_engine(cfg, params, prompts, max_new_tokens,
+                              np.asarray(jnp.argmax(logits, -1)))
 
     # ---- per-op decode loop with sampling pipeline.  The jitted step
     # is hoisted OUT of the loop (one trace, then replay): re-entering
@@ -239,41 +198,53 @@ def generate(prompt_lens, max_new_tokens=8, seed=0, int8_weights=False,
     )
     pipe = LogitsPipe([Temperature(), Softmax(), TopK(), TopP(), Sample()])
     key = jax.random.PRNGKey(seed + 1)
-    if fused_out is not None:
-        # parity-oracle lanes: decode-only, begun NOW (the real
-        # request lifecycle already finished through the fused loop)
-        perop_rids = [f"req{b}.per_op" for b in range(B)]
-        for rid in perop_rids:
-            obs.request_begin(rid)
-    else:
-        perop_rids = rids
     for step in range(max_new_tokens):
         key, sk = jax.random.split(key)
         tokens = pipe(logits, key=sk, temperature=0.8, top_k=40, top_p=0.95)
         for b in range(B):
             out_tokens[b].append(int(tokens[b]))
-            obs.decode_step(perop_rids[b])
+            obs.decode_step(rids[b])
         logits, caches = step_fn(
             params, cfg, tokens, kv_lens, caches, page_table, kv_lens,
         )
         kv_lens = kv_lens + 1
-    summaries = [obs.request_finish(rid) for rid in perop_rids]
-    if fused_out is not None:
-        assert fused_out == out_tokens, (
-            f"fused-step tokens {fused_out} != per-op loop "
-            f"{out_tokens} — the fused step changed numerics")
-        if all(summaries) and all(fused_summaries):
-            # the SPAN LAYER's per-request token counts must agree
-            # between the two dispatch structures too — the lifecycle
-            # metering is part of the parity contract, not a bystander
-            fused_counts = [s["tokens"] for s in fused_summaries]
-            perop_counts = [s["tokens"] for s in summaries]
-            assert fused_counts == perop_counts, (
-                f"span-layer token counts diverge: fused {fused_counts} "
-                f"!= per-op {perop_counts}")
-        print("# fused-step parity: "
-              f"{max_new_tokens} tokens/request identical, 1 trace")
+    for rid in rids:
+        obs.request_finish(rid)
     return out_tokens
+
+
+def _serve_through_engine(cfg, params, prompts, max_new_tokens,
+                          first_tokens):
+    """The prompts through ``ServingEngine``: it allocates the pages,
+    chunks the prefill, batches the decode and samples (greedy here, so
+    the first token of a request is a function of its prompt alone and
+    ``first_tokens``, the argmax of the hand-built prefill, must be
+    what it serves)."""
+    from flashinfer_tpu.serve import (EngineConfig, EngineRequest,
+                                      SamplingConfig, ServingEngine)
+
+    page_size = 8
+    pages = -(-(max(map(len, prompts)) + max_new_tokens) // page_size)
+    eng = ServingEngine(cfg, params, EngineConfig(
+        num_pages=1 + len(prompts) * pages, page_size=page_size,
+        max_batch=len(prompts), prefill_budget_tokens=16,
+        max_seq_tokens=pages * page_size,
+        sampling=SamplingConfig(top_k=1)))
+    for b, prompt in enumerate(prompts):
+        eng.submit(EngineRequest(f"req{b}.engine", prompt,
+                                 max_new_tokens=max_new_tokens))
+    served = eng.run()
+    firsts = [served[f"req{b}.engine"][0] for b in range(len(prompts))]
+    assert firsts == [int(t) for t in first_tokens], (
+        f"engine's first tokens {firsts} != the hand-built prefill's "
+        f"argmax {list(first_tokens)}")
+    assert eng.num_traces == len(eng._rung_traced), (
+        f"{eng.num_traces} traces for {len(eng._rung_traced)} rungs — "
+        "the compile-once contract broke")
+    for b in range(len(prompts)):
+        print(f"engine request {b}: generated {served[f'req{b}.engine']}")
+    print(f"# engine parity: first tokens equal the prefill's argmax, "
+          f"{eng.num_traces} traces for {len(eng._rung_traced)} rungs")
 
 
 def generate_stepwise(model: str, prompt_lens, max_new_tokens=8, seed=0):
@@ -369,7 +340,7 @@ if __name__ == "__main__":
     print(f"# running on {jax.devices()[0].platform} "
           f"({jax.devices()[0].device_kind})")
     int8 = "int8" in sys.argv
-    fused = "--fused-step" in sys.argv
+    engine = "--engine" in sys.argv
     model = next((a for a in sys.argv[1:] if a in ("mixtral", "deepseek")),
                  None)
     if model:
@@ -377,9 +348,9 @@ if __name__ == "__main__":
         label = model
     else:
         outs = generate([5, 9], max_new_tokens=6, int8_weights=int8,
-                        fused_step=fused)
+                        engine=engine)
         label = "llama" + (" int8 weights" if int8 else "") + \
-            (" fused-step" if fused else "")
+            (" engine" if engine else "")
     for b, toks in enumerate(outs):
         print(f"request {b}: generated {toks}")
     _print_lifecycle_summary(label)

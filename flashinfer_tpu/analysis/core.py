@@ -52,7 +52,7 @@ def project_relpath(path: str) -> str:
     p = os.path.normpath(os.path.abspath(path)).replace(os.sep, "/")
     best = -1
     for marker in ("/flashinfer_tpu/", "/tests/", "/scripts/",
-                   "/benchmarks/", "/examples/"):
+                   "/examples/"):
         best = max(best, p.rfind(marker))
     if best >= 0:
         return p[best + 1:]
@@ -317,7 +317,7 @@ def _walk_defs(tree: ast.Module):
 # L012 static_flow) share, so a future compile wrapper registered here
 # is seen by both (registering it in only one pass would silently
 # under-report in the other)
-JIT_LIKE_NAMES = frozenset({"jit", "compile_step_with_plan"})
+JIT_LIKE_NAMES = frozenset({"jit"})
 
 
 def expr_basename(expr: ast.expr) -> str:

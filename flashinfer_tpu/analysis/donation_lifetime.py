@@ -18,13 +18,11 @@ pass proves the three lifetime contracts at lint time:
    donation: the traced constant and the donated operand race, and XLA
    may fold the closure copy into the program (a silent stale read,
    not an error).
-3. **Both-or-neither shardings.**  The ``parallel/plan.py``
-   ``compile_step_with_plan`` contract, statically: a ``jax.jit`` (or
-   ``compile_step_with_plan``) call spelling exactly one of
-   ``in_shardings``/``out_shardings`` lets the compiler re-derive the
-   missing side and partition the program differently than the plan
-   says.  ``compile_step_with_plan`` raises at runtime; this makes it
-   a cannot-land review failure instead.
+3. **Both-or-neither shardings.**  A ``jax.jit`` call spelling
+   exactly one of ``in_shardings``/``out_shardings`` lets the compiler
+   re-derive the missing side and partition the program differently
+   than the plan says (``parallel/plan.py`` passes both): a
+   cannot-land review failure.
 
 Resolution rides the ``core.py`` layer: donation-compiled callables
 resolve through once-assigned locals (``step = jax.jit(...)``),
@@ -264,8 +262,8 @@ class _ClassDonations:
     """``self.<attr> = jax.jit(..., donate_argnums=...)`` assignments
     collected per class: the attribute map a ``self.<attr>(...)`` call
     site resolves against.  Multiple assignments to one attribute union
-    their donations (step.py compiles the same body down either the
-    sharded or plain branch with identical donations)."""
+    their donations (one body compiled down two branches with
+    identical donations)."""
 
     def __init__(self, cls: ast.ClassDef):
         self.attrs: Dict[str, _DonatedCallable] = {}
@@ -664,8 +662,7 @@ def _check_sharding_contract(sf: SourceFile, findings: List[Finding]
             findings.append(Finding(
                 CODE, sf.path, n.lineno, expr_basename(n.func),
                 f"step compiled with {present}= but no {missing}= — "
-                "the both-or-neither contract (parallel/plan.py "
-                "compile_step_with_plan): a half-specified sharding "
+                "the both-or-neither contract: a half-specified sharding "
                 "set lets the compiler re-derive the missing side and "
                 "partition the program differently than the plan says"))
 

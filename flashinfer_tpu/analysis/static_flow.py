@@ -36,9 +36,8 @@ Taint model (function-local, resolution via ``core.py``):
   4. a nested def that is jit-compiled AND branches (``if``/``while``)
      on a tainted closure — the branch keys the trace.
 
-Deliberately NOT tainted: plan()-time parameters of the re-plan-per-
-scheduling-decision steps (``MixedServingStep.plan`` replans by
-design), the rung (the quantized ladder is the sanctioned static), and
+Deliberately NOT tainted: plan()-time parameters of a step that
+replans per scheduling decision, the rung (the quantized ladder is the sanctioned static), and
 anything outside registered source scopes — a taint pass that guesses
 trains people to ignore it.
 """

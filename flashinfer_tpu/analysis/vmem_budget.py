@@ -104,11 +104,6 @@ def waive_knob_launch(knob: str, reason: str) -> None:
 
 # host-side / scheduler-only knobs: no VMEM launch by design
 waive_knob_launch(
-    "serve.mixed_chunk",
-    "host-side chunked-prefill scheduling quantum (tokens per mixed "
-    "step) — no kernel launch of its own; the step's attention rides "
-    "the flash/work-unit launchers whose own knobs carry the proofs")
-waive_knob_launch(
     "parallel.dp",
     "mesh axis size — host-side sharding topology, no VMEM launch; "
     "plan_axes falls back on invalid combinations before a mesh exists")

@@ -38,13 +38,7 @@ class BatchAttention(BatchPrefillWithPagedKVCacheWrapper):
     """Holistic mixed prefill+decode attention (reference
     flashinfer/attention/_core.py:44).  plan() takes the same geometry as
     the reference: per-request qo lens may mix 1 (decode) and many
-    (prefill/append).
-
-    The inherited ``plan_arrays`` export is how the compile-once mixed
-    serving step (``flashinfer_tpu.serve.step.MixedServingStep``)
-    closes this wrapper's frozen holistic plan — token axes, gather
-    rows, attention statics — into its single donated-buffer XLA
-    program (the ``TwoStageHolisticPlan``/persistent-kernel analog)."""
+    (prefill/append)."""
 
     def plan(
         self,

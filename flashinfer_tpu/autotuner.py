@@ -126,12 +126,6 @@ register_knob("moe_gmm.tiles", arity=3,
 register_knob("mla_decode.layout", kind="str",
               choices=("split", "packed"),
               description="MLA decode scratch layout")
-register_knob("serve.mixed_chunk",
-              description="chunked-prefill chunk size (tokens per "
-                          "prefilling request per mixed serving step) "
-                          "— serve.step.mixed_chunk_tokens; larger "
-                          "amortizes the step launch, smaller bounds "
-                          "decode-latency interference")
 # sharded serving mesh axes (parallel/plan.py plan_axes, shape key
 # world_hidden_hq_hkv): dp x tp must equal the world size and tp must
 # tile both head counts — invalid entries fall back to the all-tp

@@ -381,34 +381,6 @@ class BatchDecodeWithPagedKVCacheWrapper:
             statics=self._plan,
         )
 
-    @property
-    def plan_arrays(self) -> dict:
-        """Export the frozen plan (padded arrays + statics) for closure
-        into a compile-once serving step (``flashinfer_tpu.serve.step``).
-
-        The serving step's plan/run split mirrors this wrapper's: the
-        exported ``page_table``/``kv_lens`` seed the step's DONATED
-        state (they evolve across decode steps in place), while the
-        statics (heads/dims/page geometry/scales) freeze into the step
-        closure — the analog of the reference's ``fast_decode_plan``
-        handing its frozen workspace arrays to CUDAGraph capture."""
-        p = self._plan
-        if p is None:
-            raise RuntimeError("plan() must be called before plan_arrays")
-        return dict(
-            page_table=p.page_table,
-            kv_lens=p.kv_lens,
-            batch_size=p.batch_size,
-            num_qo_heads=p.num_qo_heads,
-            num_kv_heads=p.num_kv_heads,
-            head_dim=p.head_dim,
-            page_size=p.page_size,
-            sm_scale=p.sm_scale,
-            logits_soft_cap=p.logits_soft_cap,
-            window_left=p.window_left,
-            kv_layout=self._kv_layout,
-        )
-
     def run(
         self,
         q: jax.Array,  # [batch, num_qo_heads, head_dim]
@@ -529,7 +501,7 @@ class BatchDecodeWithPagedKVCacheWrapper:
             # the per-request cold-start DMA stall with compile-time slot
             # indices (see _decode_kernel_fused_heads); "off" keeps the
             # stall.  Default static BY MEASUREMENT (2026-07-31 A/B,
-            # scripts/exp_decode_prefetch.py: bit-identical outputs and
+            # before PR 1, script since deleted: bit-identical outputs and
             # +1-2.4% everywhere measured, 0.713->0.728 TB/s at the
             # headline shape).  The dynamic SMEM-parity variant measured
             # losing on v5e (0.68 vs 0.75 TB/s) and is env-only.

@@ -131,8 +131,8 @@ def fused_moe(
 # Grouped-GEMM tile-shape selection.  The megablox-form kernel's HBM
 # traffic scales as tiles_n * M * K (lhs re-streaming across the n sweep)
 # + group_visits * K * N (expert-weight streaming), so both shrink with
-# bigger tiles: the banked v5e sweep (scripts/exp_moe_tiles.py,
-# BENCH_BANKED.md 2026-07-31, Mixtral 8x7B) has the stock (128, 128, 512)
+# bigger tiles: the pre-PR-1 v5e sweep (2026-07-31, Mixtral 8x7B;
+# script and record since deleted) has the stock (128, 128, 512)
 # blocks at 20-27 TFLOP/s vs (256, 2048, 1024) at 85 bf16 / 132 int8 —
 # a 3-4x swing on tile shape alone.  The heuristic below picks
 # largest-that-fits tiles; tuning_configs/ ships measured per-shape
@@ -350,8 +350,8 @@ def fused_moe_ep(
       use ``"alltoall"`` when bounded step time matters more than exact
       delivery.
 
-    Mode selection is backed by the banked skew study (BENCH_BANKED.md
-    round 5, `benchmarks/bench_ep_skew.py`): at balanced routing exact
+    Mode selection is backed by a CPU skew study of round counts (round
+    5; its script went with `bench.py`): at balanced routing exact
     delivery is FREE (1 round, same bytes/time as capacity mode), so
     ``alltoall_exact`` is the right default for load-balanced routers;
     at zipf-1.5 skew capacity mode silently zeroes ~31% of routes while

@@ -25,19 +25,19 @@ Commands:
   round-5 VERDICT computed by hand.  ``--json`` for the
   schema-stable machine form; exits non-zero on malformed banked
   blocks (the CI smoke gate).
-- ``trace``: the flight-recorder export (ISSUE 10) — run a small
-  compile-once fused serving loop (``--steps``, default 9) with the
-  spans gate + metrics + op timeline ALL on, a metered request
-  lifecycle per batch lane, and (unless ``--no-perturb``) one
+- ``trace``: the flight-recorder export (ISSUE 10) — run a tiny
+  ``ServingEngine`` loop (two requests of ``--steps`` tokens, default
+  9) with the spans gate + metrics + op timeline ALL on, the request
+  lifecycle metered by the engine, and (unless ``--no-perturb``) one
   deliberately perturbed static at the end, then write the UNIFIED
   chrome trace (lifecycle spans + op events + registry snapshot on one
   clock base) to ``--out``.  ``--selftest`` exits non-zero unless the
   export is schema-valid, the loop held the compile-once retrace
-  budget (<= 1 trace), and the perturbed static was named in the
+  budget (one trace a rung), and the perturbed static was named in the
   retrace-cause table — the CI gate (lint.yml), the perf/3 smoke-gate
   precedent.
 - ``steploop``: the step-loop flight deck (obs.steploop) — run the
-  compile-once fused serving loop with the ``FLASHINFER_TPU_STEPLOOP``
+  same tiny engine loop with the ``FLASHINFER_TPU_STEPLOOP``
   gate on, write the unified trace with the host/device step lanes
   merged in, and print the ledger summary (host_frac, Amdahl ceiling,
   sub-phase decomposition).  ``--selftest`` exits non-zero on a
@@ -105,90 +105,60 @@ def _workload() -> None:
 
 
 def _serving_workload(steps: int, perturb: bool) -> dict:
-    """A tiny compile-once fused serving loop (tiny Llama, CPU-safe)
-    with the request lifecycle metered per batch lane: begin ->
-    prefill chunk -> ``steps`` fused decode steps -> finish.  With
-    ``perturb``, one extra run afterwards moves EXACTLY ONE run-state
-    static (the carried logits dtype) so the retrace-cause attribution
-    has a known answer.  Returns the selftest facts."""
+    """A tiny ``ServingEngine`` run (tiny Llama, CPU-safe): two requests
+    of ``steps`` tokens each, so one mixed step takes both prompts and
+    ``steps - 1`` decode steps follow on the decode rung; the engine
+    meters each request's lifecycle itself.  With ``perturb``, one more
+    request afterwards meets EXACTLY ONE moved static (the dtype of the
+    embedding table) on a rung that has compiled, so the retrace-cause
+    attribution has a known answer.  Returns the selftest facts."""
+    import time
+
     import jax
     import jax.numpy as jnp
 
     from flashinfer_tpu import obs
     from flashinfer_tpu.models import LlamaConfig, init_llama_params
-    from flashinfer_tpu.serve import SamplingConfig, ServingStep
+    from flashinfer_tpu.serve import (EngineConfig, EngineRequest,
+                                      SamplingConfig, ServingEngine)
 
     cfg = LlamaConfig.tiny(num_layers=2, dtype=jnp.float32)
     params = init_llama_params(jax.random.PRNGKey(0), cfg)
-    B, PS, PPR = 2, 8, 4
-    npages = B * PPR
-
-    def mk_caches():
-        return [
-            (jnp.zeros((npages, cfg.num_kv_heads, PS, cfg.head_dim),
-                       cfg.dtype),
-             jnp.zeros((npages, cfg.num_kv_heads, PS, cfg.head_dim),
-                       cfg.dtype))
-            for _ in range(cfg.num_layers)
-        ]
-
-    def mk_pt():
-        return jnp.arange(npages, dtype=jnp.int32).reshape(B, PPR)
-
-    prompt_lens = [3, 5]
-    rids = [f"req{b}" for b in range(B)]
-    for rid in rids:
-        obs.request_begin(rid)
-
-    step = ServingStep()
-    with obs.span("serving.plan", cat="plan"):
-        step.plan(cfg, page_table=mk_pt(),
-                  kv_lens=jnp.asarray(prompt_lens, jnp.int32),
-                  sampling=SamplingConfig(temperature=0.8, top_k=40,
-                                          top_p=0.95), use_pallas=False)
-    # stand-in prefill: seed each lane's handoff logits (the real
-    # prefill flow is examples/generate.py's; the lifecycle shape —
-    # queue window closed by the first chunk — is identical)
-    with obs.span("serving.prefill", cat="prefill"):
-        logits = jax.random.normal(jax.random.PRNGKey(1),
-                                   (B, cfg.vocab_size), jnp.float32)
-        for rid, n in zip(rids, prompt_lens):
-            obs.prefill_chunk(rid, n)
-    state = step.make_state(mk_caches(), mk_pt(),
-                            jnp.asarray(prompt_lens, jnp.int32), logits,
-                            jax.random.PRNGKey(2))
-    import time as _time
-
-    loop_t0 = _time.perf_counter()
-    for _ in range(int(steps)):
-        tokens, state = step.run(params, state)
-        for rid in rids:
-            obs.decode_step(rid)
-    loop_wall_s = _time.perf_counter() - loop_t0
-    summaries = [obs.request_finish(rid) for rid in rids]
-    traces_loop = step.num_traces
+    eng = ServingEngine(cfg, params, EngineConfig(
+        num_pages=32, page_size=8, max_batch=2, prefill_budget_tokens=8,
+        max_seq_tokens=32,
+        sampling=SamplingConfig(temperature=0.8, top_k=40, top_p=0.95)))
+    prompts = ([3, 1, 4], [1, 5, 9, 2, 6])
+    for b, prompt in enumerate(prompts):
+        eng.submit(EngineRequest(f"req{b}", list(prompt),
+                                 max_new_tokens=int(steps)))
+    with obs.span("serving.loop", cat="request"):
+        loop_t0 = time.perf_counter()
+        eng.run()
+        loop_wall_s = time.perf_counter() - loop_t0
+    traces_loop, rungs = eng.num_traces, len(eng._rung_traced)
 
     cause_keys = []
     if perturb:
-        # the deliberate perturbation: ONE static moves (logits dtype
-        # f32 -> bf16); the attribution must name exactly "logits"
-        bad = (jax.random.normal(jax.random.PRNGKey(3),
-                                 (B, cfg.vocab_size), jnp.bfloat16),
-               mk_caches(), mk_pt(),
-               jnp.asarray(prompt_lens, jnp.int32),
-               jax.random.PRNGKey(4))
-        step.run(params, bad)
+        # the deliberate perturbation: ONE static moves (the embedding
+        # table f32 -> bf16) under a rung that has compiled; the
+        # attribution must name exactly that leaf
+        eng.params = dict(eng.params,
+                          embed=eng.params["embed"].astype(jnp.bfloat16))
+        eng.submit(EngineRequest("perturbed", list(prompts[1]),
+                                 max_new_tokens=1))
+        eng.run()
         from flashinfer_tpu.obs import spans as _spans
 
         cause_keys = [r["key"] for r in
                       _spans.top_retrace_causes(obs.snapshot())
-                      if r["wrapper"] == "ServingStep"]
+                      if r["wrapper"] == "ServingEngine"]
     return {
         "num_traces_loop": traces_loop,
+        "rungs": rungs,
         "steps": int(steps),
         "loop_wall_s": loop_wall_s,
         "cause_keys": cause_keys,
-        "requests": [s for s in summaries if s],
     }
 
 
@@ -364,7 +334,7 @@ def cmd_trace(args) -> int:
     elif args.engine:
         # the ENGINE retrace budget: the whole Zipf run must stay on
         # the pre-compiled rung ladder (<= 9 traces, the same budget
-        # the fused-step loop pins), and the prefix cache must be LIVE
+        # the two-request loop pins), and the prefix cache must be LIVE
         # (a zero hit rate under a Zipf prompt mix means the trie or
         # the block-sharing path silently broke)
         if facts["num_traces"] > 9:
@@ -398,16 +368,18 @@ def cmd_trace(args) -> int:
                 f"request(s) (first: {bad[:3]}) — the work-unit "
                 "lowering diverged from the oracle tier")
     else:
-        # the compile-once retrace budget over the fused serving loop
-        # (test_serve_step's 9-step pin, now CI-gated with attribution)
-        if facts["num_traces_loop"] > 1:
+        # the compile-once retrace budget over the serving loop: one
+        # trace a rung, CI-gated with attribution
+        if facts["num_traces_loop"] > facts["rungs"]:
             problems.append(
                 f"retrace budget: {facts['num_traces_loop']} traces "
-                f"across {facts['steps']} fused steps (budget: 1)")
-        if not args.no_perturb and facts["cause_keys"] != ["logits"]:
+                f"across {facts['steps']} steps on {facts['rungs']} "
+                "rung(s) (budget: one a rung)")
+        want = ["params['embed']"]
+        if not args.no_perturb and facts["cause_keys"] != want:
             problems.append(
-                "deliberate logits-dtype perturb attributed to "
-                f"{facts['cause_keys']!r}, expected ['logits']")
+                "deliberate embedding-dtype perturb attributed to "
+                f"{facts['cause_keys']!r}, expected {want!r}")
 
     ls = obs.lifecycle_snapshot()
 
@@ -452,8 +424,8 @@ def cmd_trace(args) -> int:
 
 
 def cmd_steploop(args) -> int:
-    """Step-loop flight deck selftest (ISSUE 17): drive the compile-once
-    fused serving loop with the steploop gate ON, merge the host/device
+    """Step-loop flight deck selftest (ISSUE 17): drive the tiny engine
+    loop with the steploop gate ON, merge the host/device
     step lanes into the unified trace, and gate on the ledger's internal
     consistency — every step must carry a device window (the completion
     probe ran), no gap may be negative (both edges share one
@@ -482,7 +454,7 @@ def cmd_steploop(args) -> int:
     if s["steps"] < int(args.steps):
         problems.append(
             f"ledger recorded {s['steps']} steps across a "
-            f"{args.steps}-step loop — the ServingStep wiring is dead")
+            f"{args.steps}-step loop — the ServingEngine wiring is dead")
     if s["missing_device_lane"]:
         problems.append(
             f"{s['missing_device_lane']} step(s) missing the device "
@@ -899,20 +871,20 @@ def main(argv=None) -> int:
                          "(default: v5e, the banked history's chip)")
     sp.set_defaults(fn=cmd_perf)
     sp = sub.add_parser("trace", help="flight-recorder export: unified "
-                                      "chrome trace of a metered fused "
+                                      "chrome trace of a metered "
                                       "serving loop")
     sp.add_argument("--out", metavar="PATH",
                     default="/tmp/flashinfer_tpu_unified_trace.json",
                     help="unified chrome-trace output path")
     sp.add_argument("--steps", type=int, default=9,
-                    help="fused serving steps (retrace budget: <= 1 "
-                         "trace across all of them)")
+                    help="tokens per request, one engine step each "
+                         "(retrace budget: one trace a rung)")
     sp.add_argument("--no-perturb", action="store_true",
                     help="skip the deliberate one-static perturbation "
                          "(and its attribution assert)")
     sp.add_argument("--engine", action="store_true",
                     help="run the continuous-batching ENGINE workload "
-                         "instead of the fused-step loop: a short "
+                         "instead of the two-request loop: a short "
                          "Zipf-shared-prefix request mix through "
                          "serve/engine.py; --selftest then fails on a "
                          "retrace-budget breach (> 9 traces or any "
@@ -936,14 +908,14 @@ def main(argv=None) -> int:
     sp.set_defaults(fn=cmd_trace)
     sp = sub.add_parser("steploop",
                         help="step-loop flight deck: host/device "
-                             "overlap ledger over the fused serving "
+                             "overlap ledger over a tiny engine "
                              "loop (gate forced ON for the run)")
     sp.add_argument("--out", metavar="PATH",
                     default="/tmp/flashinfer_tpu_steploop_trace.json",
                     help="unified chrome-trace output path (host/"
                          "device step lanes merged in)")
     sp.add_argument("--steps", type=int, default=9,
-                    help="fused serving steps to ledger")
+                    help="engine steps to ledger")
     sp.add_argument("--selftest", action="store_true",
                     help="exit non-zero on a missing device lane, a "
                          "negative gap, unattributed host time, or a "
@@ -952,9 +924,9 @@ def main(argv=None) -> int:
     sp.set_defaults(fn=cmd_steploop)
     sp = sub.add_parser(
         "bringup",
-        help="hardware graduation session: mosaic-risk smoke ladder -> "
-             "banked bench -> emit-config sweeps -> provenance "
-             "graduation, journaled and resumable (ISSUE 20); flags "
+        help="hardware graduation session: mosaic-risk smoke ladder, "
+             "journaled and resumable, and provenance graduation of "
+             "emit-config files (ISSUE 20); flags "
              "are owned by obs.bringup (--selftest, --resume, "
              "--graduate, --list, ...)",
         add_help=False)

@@ -4,7 +4,8 @@ VERDICT weak #3: the banked bench record contains poison rows
 (degraded-window artifacts reading ~19x low) that were only caught by
 manual cross-checking, and the in-phase ``<0.35x best`` re-measure
 guard had never executed.  This module moves the audit to EMIT time:
-every ``bench.py`` row is routed through :class:`RowAuditor`, which
+a row is routed through :class:`RowAuditor` (its one emitter,
+``bench.py``, is deleted: ROADMAP C3 decides this module), which
 compares the row's primary throughput metric against the best known
 measurement of the same configuration — across this run AND the
 ``BENCH_BANKED.md`` history — and stamps::

@@ -19,8 +19,8 @@ failure mode).  A wedge halts the session and writes a quarantine
 entry to ``bringup_quarantine.json``; knob-rung entries carry
 ``op``/``tactic`` so ``tactics_blocklist.blocked`` (hence the
 autotuner resolver and the choosers) skips the wedge-proven tactic,
-and ``bench_phases`` so bench.py's orchestrator skips the phases that
-would re-launch it.
+and ``bench_phases``, which ``obs perf`` joins to the tuning sections
+it reports as quarantined.
 
 **Session journal.**  Append-only JSONL (``bringup_journal.jsonl`` in
 the cache dir) recording every rung/phase/sweep/probe with outcome and
@@ -29,9 +29,9 @@ wall time.  ``--resume`` skips entries whose last outcome is ``pass``
 Journal entries and graduated tuning sections join to BENCH_BANKED.md
 rows by the RowAuditor configuration stamp (``bench_audit.row_stamp``).
 
-**Provenance graduation.**  ``--graduate`` consumes the
-``--emit-config`` outputs of bench_prefill_blocks / bench_decode_splits
-/ bench_sharded_step plus the journal and rewrites tuning_configs
+**Provenance graduation.**  ``--graduate`` consumes ``--emit-config``
+JSON files (no sweep in the tree writes one today) plus the journal
+and rewrites tuning_configs
 sections ``seed -> "provenance": "measured"``, carrying
 ``{journal_id, banked_row}`` references that L006 requires on every
 measured section.  ``obs perf`` reports per-section graduation status
@@ -45,8 +45,8 @@ simulated wedge (a rung subprocess sleeping past its timeout) with
 exact-rung attribution + quarantine + resume, and a graduation rewrite
 on a synthetic emit-config validated by L006.
 
-Module import stays jax-free (the doctor section and bench.py consult
-it on broken trees); drivers import jax lazily inside the rung
+Module import stays jax-free (the doctor section consults it on
+broken trees); drivers import jax lazily inside the rung
 subprocess.
 """
 
@@ -112,9 +112,9 @@ DRIVER_FOR = {
 # launcher-derived fused_prefill one
 KNOB_DRIVER = {"engine.attention_backend": "engine_step"}
 
-# bench.py phases a wedged rung poisons (written into the quarantine
-# entry; bench.py's orchestrator skips them).  Knob rungs by knob name,
-# kernel/planner rungs by kernel function.
+# measurement phases a wedged rung poisons (written into the quarantine
+# entry; `obs perf` reports their tuning sections as quarantined).  Knob
+# rungs by knob name, kernel/planner rungs by kernel function.
 KNOB_BENCH_PHASES = {
     "decode.splits": ["decode_splits"],
     "fused_prefill.blocks": ["prefill"],
@@ -149,17 +149,6 @@ SECTION_BANK_PHASES = {
     "paged_decode": ("decode",),
     "moe": ("moe",),
 }
-
-# hardware-session sweeps after the ladder: (journal id, argv tail).
-# Outputs land in the cache dir and feed --graduate.
-SESSION_SWEEPS = [
-    ("bench_decode_splits", ["benchmarks/bench_decode_splits.py",
-                             "--emit-config"]),
-    ("bench_prefill_blocks", ["benchmarks/bench_prefill_blocks.py",
-                              "--emit-config", "--sweep-ingest"]),
-    ("bench_sharded_step", ["benchmarks/bench_sharded_step.py",
-                            "--emit-config"]),
-]
 
 
 # --------------------------------------------------------------------------
@@ -274,8 +263,7 @@ def quarantine_add(entry: dict, path: Optional[str] = None) -> None:
 
 
 def quarantined_bench_phases() -> List[str]:
-    """Bench phases any quarantine entry names (bench.py's orchestrator
-    drops them from its dispatch list)."""
+    """Measurement phases any quarantine entry names."""
     out: List[str] = []
     for e in tactics_blocklist.bringup_entries():
         for p in e.get("bench_phases") or ():
@@ -1004,18 +992,6 @@ def run_ladder(rungs: List[dict], *, journal: Journal, journal_id: str,
     return summary
 
 
-def record_phases_pending(phases: List[str], probe: Optional[dict] = None,
-                          journal: Optional[Journal] = None) -> None:
-    """bench.py's orchestrator calls this when a post-timeout probe
-    comes back unhealthy: the phases it refuses to dispatch are
-    journaled ``pending`` so ``obs bringup --resume`` re-runs them."""
-    j = journal or Journal()
-    jid = j.last_session_id() or new_journal_id()
-    for name in phases:
-        j.append(journal_id=jid, kind="phase", id=name, outcome="pending",
-                 probe=probe, detail="chip unhealthy after phase timeout")
-
-
 def _is_tpu() -> bool:
     try:
         from flashinfer_tpu.utils import is_tpu
@@ -1322,37 +1298,8 @@ def selftest(chip: str = "v5e", rung_timeout_s: float = 240.0,
 # --------------------------------------------------------------------------
 
 
-def _run_step(name: str, cmd: List[str], *, journal: Journal,
-              journal_id: str, kind: str, timeout_s: float,
-              capture_to: Optional[str] = None) -> bool:
-    """One journaled bench/sweep subprocess of the hardware session."""
-    t0 = time.time()
-    try:
-        p = subprocess.run(cmd, timeout=timeout_s, capture_output=True,
-                           text=True)
-        ok = p.returncode == 0
-        detail = "" if ok else (p.stderr or p.stdout or "")[-500:]
-        if ok and capture_to:
-            # the sweeps print the emit-config JSON last; keep the tail
-            # starting at its first top-level brace
-            out = p.stdout or ""
-            start = out.find("{")
-            if start >= 0:
-                open(capture_to, "w").write(out[start:])
-            else:
-                ok, detail = False, "no emit-config JSON in sweep output"
-    except subprocess.TimeoutExpired:
-        ok, detail = False, f"timed out after {timeout_s:.0f}s"
-    journal.append(journal_id=journal_id, kind=kind, id=name,
-                   outcome="pass" if ok else "fail",
-                   wall_s=round(time.time() - t0, 2), detail=detail)
-    print(f"  {kind} {name}: {'pass' if ok else 'FAIL'}")
-    return ok
-
-
 def run_session(args) -> int:
-    """The graduation session: ladder -> banked bench -> emit-config
-    sweeps -> graduation, all journaled and resumable."""
+    """The smoke ladder as one journaled, resumable session."""
     journal = Journal(args.journal)
     jid = (journal.last_session_id() if args.resume else None) \
         or new_journal_id()
@@ -1367,29 +1314,6 @@ def run_session(args) -> int:
         print("session halted: wedge quarantined — recover the chip and "
               "re-run `obs bringup --resume`")
         return 3
-    repo = os.path.dirname(_pkg_root())
-    done = journal.step_outcomes("phase") if args.resume else {}
-    if done.get("bench") != "pass":
-        _run_step("bench", [sys.executable, os.path.join(repo, "bench.py"),
-                            "--bank"],
-                  journal=journal, journal_id=jid, kind="phase",
-                  timeout_s=7200)
-    emit_paths: List[str] = []
-    sweeps_done = journal.step_outcomes("sweep") if args.resume else {}
-    for name, tail in SESSION_SWEEPS:
-        out_path = os.path.join(os.path.dirname(journal.path),
-                                f"bringup_emit_{name}.json")
-        if sweeps_done.get(name) == "pass" and os.path.exists(out_path):
-            emit_paths.append(out_path)
-            continue
-        cmd = [sys.executable, os.path.join(repo, tail[0])] + tail[1:]
-        if _run_step(name, cmd, journal=journal, journal_id=jid,
-                     kind="sweep", timeout_s=7200, capture_to=out_path):
-            emit_paths.append(out_path)
-    if emit_paths:
-        g = graduate(emit_paths, chip=args.chip, journal=journal,
-                     journal_id=jid, banked_path=args.banked)
-        print(json.dumps(g, indent=1))
     return 0
 
 
@@ -1400,7 +1324,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("--selftest", action="store_true",
                     help="prove the whole contract on CPU (CI gate)")
     ap.add_argument("--resume", action="store_true",
-                    help="skip journal-completed rungs/phases/sweeps")
+                    help="skip journal-completed rungs")
     ap.add_argument("--graduate", action="store_true",
                     help="only run provenance graduation on --emit-config")
     ap.add_argument("--emit-config", action="append", default=[],

@@ -75,11 +75,11 @@ METRICS: Dict[str, Tuple[str, Tuple[str, ...], str]] = {
         "(cost-model-guided, L009-feasibility-pruned; splits=1 means "
         "the unsplit kernel was predicted faster — a hot >1 label "
         "means the short-context split path is live)"),
-    # -- compile-once serving step (serve/step.py) ------------------------
+    # -- compile-once serving step ----------------------------------------
     "serve.step_retraces": (
         "counter", ("wrapper",),
-        "fused serving-step traces beyond the first under a live plan "
-        "(ServingStep / MixedServingStep) — the compile-once contract "
+        "serving-step traces beyond the first of a rung or a live plan "
+        "(ServingEngine / ShardedServingStep) — the compile-once contract "
         "says this stays at ZERO: a non-zero count means the donated "
         "state's pytree structure, a shape, or a dtype moved between "
         "steps and every step is paying a retrace"),
@@ -160,8 +160,7 @@ METRICS: Dict[str, Tuple[str, Tuple[str, ...], str]] = {
     "steploop.steps": (
         "counter", ("surface",),
         "serving-step dispatches recorded by the step-loop ledger, per "
-        "step surface (ServingEngine / ServingStep / MixedServingStep "
-        "/ ShardedServingStep)"),
+        "step surface (ServingEngine / ShardedServingStep)"),
     "steploop.idle_ticks": (
         "counter", ("surface",),
         "idle ticks recorded by the step-loop ledger (empty-schedule "
@@ -266,12 +265,6 @@ METRICS: Dict[str, Tuple[str, Tuple[str, ...], str]] = {
         "the compiled program; wire bytes = 2(p-1)/p x payload, "
         "costmodel.collective).  Joins measured collective traffic "
         "against the roofline's predicted ICI bytes"),
-    # -- serving-loop phase decomposition (bench.py) ----------------------
-    "serving.phase_us": (
-        "histogram", ("phase",),
-        "per-step cost of each serving-loop phase from the bench.py "
-        "micro-loop decomposition (attention / kv_append / moe_or_mlp / "
-        "norm_rope / sampling / lm_head / residual)"),
     # -- bench row quality audit (obs.bench_audit) ------------------------
     "bench.rows": (
         "counter", ("phase", "quality"),
@@ -342,8 +335,6 @@ API_OPS = frozenset({
     "sampling_from_probs", "sampling_from_logits",
     "top_p_sampling_from_probs", "top_k_sampling_from_probs",
     "min_p_sampling_from_probs", "top_k_top_p_sampling_from_probs",
-    # serve/step.py (the compile-once fused serving steps)
-    "serve.step", "serve.mixed_step",
     # serve/engine.py (the continuous-batching engine step)
     "engine.step",
     # serve/kv_tier.py (the tiered-KV movements: host spill/restore +
@@ -360,7 +351,6 @@ API_OPS = frozenset({
 # cover — the span-layer extension of the L005 ships-observed rule: a
 # new serving op cannot silently ship untraceable.
 SERVING_OPS = frozenset({
-    "serve.step", "serve.mixed_step", "parallel.sharded_step",
-    "engine.step",
+    "parallel.sharded_step", "engine.step",
     "engine.kv_spill", "engine.kv_restore", "engine.kv_migrate",
 })

@@ -9,7 +9,7 @@ work (the attended tokens a perfect packing would compute).
 :mod:`~flashinfer_tpu.obs.roofline` joins a :class:`Cost` with a
 measured wall time and a :class:`~flashinfer_tpu.obs.hwspec.ChipSpec`.
 
-Conventions (pinned by ``tests/test_roofline.py`` against brute-force
+Conventions (pinned by ``tests/test_obs_roofline.py`` against brute-force
 tiny-shape counts):
 
 - a multiply-add is 2 FLOPs (matching ``testing.utils.attention_flops``
@@ -1157,13 +1157,9 @@ API_OP_COSTS: Dict[str, str] = {
     "top_k_sampling_from_probs": "sampling",
     "min_p_sampling_from_probs": "sampling",
     "top_k_top_p_sampling_from_probs": "sampling",
-    # the fused serving steps: whole-step cost is the phase-sum model
-    # (serving_step = norm_rope + attention + kv_append + moe_or_mlp +
-    # lm_head + sampling — the fused step EXCLUDES nothing)
-    "serve.step": "serving_step",
-    "serve.mixed_step": "serving_step",
-    # the mesh twin: phase sum + the collective ICI family (tp
-    # allreduces, optional EP all-to-all, sampling gather)
+    # the mesh step: the phase-sum model (norm_rope + attention +
+    # kv_append + moe_or_mlp + lm_head + sampling) + the collective ICI
+    # family (tp allreduces, optional EP all-to-all, sampling gather)
     "parallel.sharded_step": "serving_step_sharded",
     # the continuous-batching engine step: mixed decode + chunked
     # prefill on one flat axis, exact attended-pair accounting and a
@@ -1465,10 +1461,6 @@ waive_chooser("rmsnorm.row_block",
 waive_chooser("fused_add_rmsnorm.row_block",
               "same as rmsnorm.row_block: VMEM-fit resolution of a "
               "bandwidth-bound elementwise kernel")
-waive_chooser("serve.mixed_chunk",
-              "priced per-step by predict_step_seconds against the "
-              "SLO budget inside the engine scheduler (serve/step), "
-              "not by a standalone candidate chooser")
 waive_chooser("parallel.dp",
               "mesh topology knob: validity (dp x tp == world) and "
               "capacity math live in parallel/plan.py; no kernel "

@@ -129,9 +129,8 @@ def stamp_row(row: Dict, cost: Cost, seconds: float,
               fused_ingest: Optional[bool] = None,
               ingest_bytes_avoided: Optional[float] = None) -> Dict:
     """Write the canonical roofline fields onto a bench row in place.
-    Every bench.py routine stamps through here — the uniform schema is
-    what makes ``obs perf`` and the auditor's roofline-fraction rule
-    possible.
+    The uniform schema is what makes ``obs perf`` and the auditor's
+    roofline-fraction rule possible.
 
     ``num_splits``/``merge_bytes`` are the split-KV decode metadata
     (docs/observability.md): ``num_splits`` is part of the row's

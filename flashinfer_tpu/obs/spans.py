@@ -65,8 +65,6 @@ SPAN_CATEGORIES_VALID = frozenset({
 # ``catalog.SERVING_OPS`` missing here (a serving op that opens no
 # span), extending the L005 ships-observed rule to the flight recorder.
 SPAN_CATEGORIES: Dict[str, str] = {
-    "serve.step": "dispatch",
-    "serve.mixed_step": "dispatch",
     "parallel.sharded_step": "dispatch",
     "engine.step": "dispatch",
     # tiered-KV movements (serve/kv_tier.py): host-side page copies

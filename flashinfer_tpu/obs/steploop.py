@@ -3,15 +3,15 @@
 The obs stack meters ops (PR 2/5) and requests (PR 10) but was blind to
 the STEP LOOP itself — the host work between device steps that ROADMAP
 item 4's pipeline refactor exists to hide.  This module records, for
-every serving-step dispatch (``ServingEngine.step`` / ``ServingStep`` /
-``MixedServingStep`` / ``ShardedServingStep``), one bounded ledger entry:
+every serving-step dispatch (``ServingEngine.step`` /
+``ShardedServingStep``), one bounded ledger entry:
 
 - named host sub-phase durations (the engine decomposes into ``admit``
   / ``schedule`` / ``assemble`` (schedule-array assembly) / ``lower``
   (kernel-plan lowering via ``build_engine_work_units``) / ``dispatch``
   (signature + host→device upload + the jitted call), then ``fetch``
   (the wait for the sampled tokens) and ``post`` (trie inserts, token
-  scatter, page release); the fused step wrappers record ``signature``
+  scatter, page release); the sharded step records ``signature``
   + ``dispatch``).  A phase opened with :meth:`StepTicket.enter` /
   :meth:`StepTicket.phase` is ALSO a ``jax.profiler.TraceAnnotation``
   (``engine.<phase>`` inside one ``engine.step``), so a device trace

@@ -5,11 +5,10 @@ TPU-native re-design of the reference's ``flashinfer/parallel_attention/``
 parallel_wrapper.py:10; ring P2P parallel_wrapper.py:216-242) and of the
 decode-context-parallel path (``flashinfer/comm/dcp_alltoall.py``).
 
-``parallel/plan.py`` adds the compile-once SHARDED serving step
-(ROADMAP item 3): :class:`ShardingPlan` (mesh + dp/tp/ep axes ->
-NamedShardings per serving-state leaf), :func:`compile_step_with_plan`
-(explicit shardings + donation), and the sharded fused / per-op step
-builders with a shard_map fallback.
+``parallel/plan.py`` adds the compile-once SHARDED serving step:
+:class:`ShardingPlan` (mesh + dp/tp/ep axes -> NamedShardings per
+serving-state leaf) and the sharded fused step, one ``shard_map`` over
+the mesh.
 """
 
 from flashinfer_tpu.parallel.attention import (  # noqa: F401
@@ -22,12 +21,8 @@ from flashinfer_tpu.parallel.plan import (  # noqa: F401
     ShardedServingStep,
     ShardingPlan,
     build_sharded_fused_step,
-    build_sharded_per_op_step,
-    compile_step_with_plan,
-    llama_step_shardings,
     make_serving_mesh,
     plan_axes,
-    sharded_step_body,
     split_shard_weights_for_spec,
     validate_dp_page_table,
 )

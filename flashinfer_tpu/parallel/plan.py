@@ -1,15 +1,12 @@
-"""Mesh-aware compile-once SHARDED serving step (pjit/shard_map unification).
+"""Mesh-aware compile-once SHARDED serving step.
 
-The multi-chip half of the plan/run story (ROADMAP item 3; reference
-analogue: one captured multi-GPU program instead of a Python loop over
-per-op sharded calls).  ``serve/`` compiled the whole serving step into
-ONE donated XLA program on one chip (PR 7); the per-op parallel layer
-(``parallel/attention.py`` Ulysses/Ring, ``fused_moe_ep``, ``comm/``
-fusions) already speaks mesh axes — what was missing is the Titanax
-``compile_step_with_plan`` pattern (SNIPPETS.md [2]): a :class:`ShardingPlan`
-that derives explicit ``NamedSharding``s for every serving-state leaf,
-and one compile entry that lowers the WHOLE sharded step under the mesh
-with explicit in/out shardings and donated KV buffers.
+The multi-chip half of the plan/run story (reference analogue: one
+captured multi-GPU program instead of a Python loop over per-op sharded
+calls).  The per-op parallel layer (``parallel/attention.py``
+Ulysses/Ring, ``fused_moe_ep``, ``comm/`` fusions) speaks mesh axes; this
+module adds a :class:`ShardingPlan` that derives explicit
+``NamedSharding``s for every serving-state leaf, and one step that runs
+the WHOLE sharded program under the mesh with donated KV buffers.
 
 Components:
 
@@ -19,31 +16,18 @@ Components:
   key), TP-sharded weights/heads (column-shard q/k/v/gate/up/lm_head,
   row-shard o/down, KV-head axis of the paged caches), DP-sharded batch
   state (activations, page tables, kv_lens, the page axis of the caches).
-- :func:`compile_step_with_plan` — explicit in/out shardings + donation
-  in one ``jax.jit``; both-or-neither sharding contract (the Titanax
-  rule), degenerating to a plain donated jit when no shardings are given.
 - :class:`ShardedServingStep` / :func:`build_sharded_fused_step` — the
-  bench 70B-shard int8 pipeline (``serve/shard.py``) at GLOBAL model
-  dims compiled ONCE under the mesh: ``mode="pjit"`` traces the global
-  math and lets GSPMD partition it along the plan's shardings;
-  ``mode="shard_map"`` is the explicit-collective fallback (per-device
-  body, int32-psum TP reductions, pmax-amax activation quantization,
-  logits all-gather epilogue) that is parity-tested against pjit.
-- :func:`build_sharded_per_op_step` — the SAME math as per-layer jitted
-  sharded calls chained by a host loop: the pre-fused dispatch
-  structure, the A/B twin ``bench.py phase_serving_sharded`` measures.
-- :func:`llama_step_shardings` — the sharding table for
-  ``serve/step.py``'s :class:`~flashinfer_tpu.serve.step.ServingStep`
-  state (the Llama pytree), so ``ServingStep.plan(sharding_plan=...)``
-  compiles the model-family mega-step under a mesh too.
+  int8 shard pipeline (``serve/shard.py``) at GLOBAL model dims compiled
+  ONCE as a ``shard_map`` over the mesh: a per-device body with explicit
+  collectives (int32-psum TP reductions, pmax-amax activation
+  quantization, logits all-gather epilogue).  There is one mode: JAX
+  refuses to partition a Mosaic kernel on its own, so a step that holds
+  Pallas ops lowers only inside a ``shard_map``.
 
 Numerics contract (pinned by tests/test_sharded_step.py): the int8
-shard pipeline's TP reductions accumulate in int32 (order-free), so
-fused-sharded, per-op-sharded, shard_map, and the unsharded
-``serve/shard.py`` step sample token-for-token identical sequences.
-The bf16 :class:`ServingStep` under a tp>1 plan reorders f32 partial
-sums across the contraction split (documented tolerance); dp-only
-sharding never moves a contraction and stays tokens-bitwise.
+shard pipeline's TP reductions accumulate in int32 (order-free), so the
+sharded step and the unsharded ``serve/shard.py`` step sample
+token-for-token identical sequences.
 
 DP paged-KV contract: the page axis of every cache shards over dp, so
 all pages of a request must live in its dp block —
@@ -52,15 +36,14 @@ entry (:func:`validate_dp_page_table`; the per-replica block-pool
 layout a dp-sharded serving engine allocates naturally).
 
 Everything here is testable off-hardware on a CPU mesh
-(``XLA_FLAGS=--xla_force_host_platform_device_count=8``); the
-predicted multi-chip performance story lives in the ICI-aware cost
-model (``obs/costmodel.py`` collective family + ``obs perf``'s
-tp1->tp8 scaling curve).
+(``XLA_FLAGS=--xla_force_host_platform_device_count=8``) and ran on four
+v5e chips through ``chip_smoke.py --chips 4``.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import time
 from typing import Dict, List, Optional, Tuple
@@ -122,9 +105,8 @@ class ShardingPlan:
 
     @property
     def mesh_axes(self) -> str:
-        """The row-identity string bench rows carry (``"dp2.tp4"``):
-        mesh SHAPE is configuration, so a tp8 row must never compete
-        with tp1 history in the quality audit (obs.bench_audit)."""
+        """The mesh's shape as a string (``"dp2.tp4"``): configuration
+        a record carries beside its numbers."""
         parts = [f"dp{self.dp_size}", f"tp{self.tp_size}"]
         if self.ep:
             parts.append(f"ep{self.ep_size}")
@@ -223,7 +205,7 @@ def validate_dp_page_table(page_table, num_pages: int,
     must all live in b's dp block of the page pool (each dp replica owns
     a contiguous ``num_pages // dp`` slab).  Raises with the offending
     request; a violated contract would silently read another replica's
-    pages under ``mode="shard_map"``."""
+    pages inside the ``shard_map``."""
     pt = np.asarray(page_table)
     dp = plan.dp_size
     if dp == 1:
@@ -273,97 +255,14 @@ def split_shard_weights_for_spec(layer_ws,
 
 
 # ---------------------------------------------------------------------------
-# compile_step_with_plan: the Titanax entry
-# ---------------------------------------------------------------------------
-
-
-def compile_step_with_plan(fn, plan: Optional[ShardingPlan] = None, *,
-                           in_shardings=None, out_shardings=None,
-                           donate_argnums=(), static_argnums=()):
-    """Compile one serving-step body under explicit shardings + donation.
-
-    The SNIPPETS.md [2] contract: ``in_shardings`` and ``out_shardings``
-    come together or not at all — a half-specified sharding set silently
-    compiles a differently-partitioned program, so it raises instead.
-    With both absent the step compiles as a plain donated ``jax.jit``
-    (the single-device degenerate; ``plan`` may be None there).  The
-    shard_map fallback is not spelled here — it needs a per-device body
-    with explicit collectives, which :func:`build_sharded_fused_step`
-    provides via ``mode="shard_map"``."""
-    if (in_shardings is None) != (out_shardings is None):
-        raise ValueError(
-            "compile_step_with_plan needs BOTH in_shardings and "
-            "out_shardings (or neither, for the single-device jit): a "
-            "half-specified set would let the compiler re-derive the "
-            "missing side and split the program differently than the "
-            "plan says")
-    kw = dict(donate_argnums=donate_argnums, static_argnums=static_argnums)
-    if in_shardings is None:
-        return jax.jit(fn, **kw)
-    return jax.jit(fn, in_shardings=in_shardings,
-                   out_shardings=out_shardings, **kw)
-
-
-# ---------------------------------------------------------------------------
 # The sharded shard-pipeline step bodies
 # ---------------------------------------------------------------------------
 
 
-class _GlobalComm:
-    """Global-math strategy (the pjit path): no explicit collectives —
-    the traced body is the whole-model math and GSPMD partitions it
-    along the plan's shardings."""
-
-    def __init__(self, spec: Int8ShardSpec,
-                 plan: Optional[ShardingPlan] = None):
-        self.spec = spec
-        self.plan = plan
-        self.hq_l, self.hkv_l = spec.hq, spec.hkv
-        self.qdim_l = spec.qdim
-
-    def local_pages(self, pt, kcl):
-        return pt
-
-    def quantize_tp(self, x):
-        from flashinfer_tpu.quantization import quantize_int8
-
-        return quantize_int8(x)
-
-    def mm_row(self, a8, w, name, scale_name, a_scale):
-        from flashinfer_tpu.gemm import mm_int8
-
-        return mm_int8(a8, w[name], a_scale, w[scale_name])
-
-    def gather_logits(self, logits):
-        # replicate BEFORE sampling: this jax's threefry is not
-        # partitionable (jax_threefry_partitionable=False), so random
-        # bits generated over a sharded operand differ from the
-        # unsharded stream — the constraint forces the gather here
-        # (where the shard_map fallback gathers anyway) and keeps every
-        # step shape tokens-identical with the single-chip pipeline
-        if self.plan is not None:
-            return jax.lax.with_sharding_constraint(
-                logits, self.plan.replicated)
-        return logits
-
-    def pin_tokens(self, tok):
-        # fence the sampler from the BACK side too: a sharded consumer
-        # of the tokens would let GSPMD back-propagate its sharding
-        # into the RNG (the serve/step.py threefry note)
-        if self.plan is not None:
-            return jax.lax.with_sharding_constraint(
-                tok, self.plan.replicated)
-        return tok
-
-    def first_token(self, tok):
-        return tok[0]
-
-
 class _ShardMapComm:
-    """Per-device strategy (the shard_map fallback): explicit
-    collectives spelled to land bit-identically with the partitioned
-    global program — TP matmul reductions psum in int32 BEFORE the f32
-    scale multiply (integer addition is order-free), activation
+    """The per-device body's collectives, spelled to land bit-identically
+    with the unsharded program — TP matmul reductions psum in int32
+    BEFORE the f32 scale multiply (integer addition is order-free), activation
     quantization pmaxes the local amax so every shard applies the
     global scale, and the sampling epilogue all-gathers the
     vocab/batch-sharded logits so every device samples the same
@@ -408,11 +307,6 @@ class _ShardMapComm:
                                       tiled=True)
         return full
 
-    def pin_tokens(self, tok):
-        return tok  # per-device body: the gather already replicated
-
-    def first_token(self, tok):
-        return tok[0]
 
 
 def _sharded_layer(x, w: Dict[str, jax.Array], kcl, vcl, pt, lens,
@@ -485,15 +379,12 @@ def _sharded_epilogue(x, head, head_s, skey, spec: Int8ShardSpec, comm):
     logits = comm.gather_logits(logits)
     tok = sampling_from_logits(top_k_mask_logits(logits, spec.top_k),
                                skey)
-    tok = comm.pin_tokens(tok)
-    return tok, jax.random.fold_in(skey, comm.first_token(tok))
+    return tok, jax.random.fold_in(skey, tok[0])
 
 
 def _step_math(x0, layer_ws, caches, head, head_s, pt, lens, skey,
                spec: Int8ShardSpec, comm):
-    """One whole serving step (layers + sampling epilogue) — the body
-    every builder here compiles (fused, per-op chains per-layer slices
-    of it, and the bench's in-jit scan slope floor)."""
+    """One whole serving step (layers + sampling epilogue)."""
     x = x0
     new_caches = []
     for w, (kcl, vcl) in zip(layer_ws, caches):
@@ -504,32 +395,27 @@ def _step_math(x0, layer_ws, caches, head, head_s, pt, lens, skey,
     return tok, new_caches, pt, lens, skey
 
 
-def sharded_step_body(spec: Int8ShardSpec, plan: ShardingPlan):
-    """The UNJITTED global-math step body ``(x0, layer_ws, caches,
-    head, head_s, pt, lens, skey) -> (tok, caches, pt, lens, skey)`` —
-    for custom compositions like bench.py's in-jit ``lax.scan`` slope
-    floor (the zero-host-dispatch steady state both A/B variants
-    chase).  :func:`build_sharded_fused_step` compiles exactly this
-    math."""
-    comm = _GlobalComm(spec, plan)
-
-    def body(x0, layer_ws, caches, head, head_s, pt, lens, skey):
-        return _step_math(x0, layer_ws, caches, head, head_s, pt, lens,
-                          skey, spec, comm)
-
-    return body
-
-
 class _CountingStep:
-    """A compiled step that counts its own traces (the compile-once
-    pin's instrument; mirrors serve/step.py's body-side counter)."""
+    """One step body as a jitted ``shard_map`` over the plan's mesh: it
+    counts its own traces (the compile-once pin's instrument) and keeps
+    its donated state on the plan's layout."""
 
-    def __init__(self, fn, build):
+    def __init__(self, body, plan: ShardingPlan, num_layers: int,
+                 donate: bool):
+        in_sh, out_sh = plan.shard_step_shardings(num_layers)
         self.num_traces = 0
-        self._fn = build(self._tick, fn)
+        self._state_shardings = (in_sh[2], in_sh[5], in_sh[6], in_sh[7])
 
-    def _tick(self):
-        self.num_traces += 1
+        def _counted(*args):
+            self.num_traces += 1  # trace-time only
+            return body(*args)
+
+        mapped = jax.shard_map(
+            _counted, mesh=plan.mesh, in_specs=plan.spec_tree(in_sh),
+            out_specs=plan.spec_tree(out_sh), check_vma=False)
+        self._fn = jax.jit(mapped, in_shardings=in_sh,
+                           out_shardings=out_sh,
+                           donate_argnums=(2, 5, 6, 7) if donate else ())
 
     @property
     def jitted(self):
@@ -537,116 +423,38 @@ class _CountingStep:
         the donation-aliasing pin in tests)."""
         return self._fn
 
-    def __call__(self, *args):
-        return self._fn(*args)
+    def __call__(self, x0, layer_ws, caches, head, head_s, pt, lens, skey):
+        # a state no device of the mesh holds yet and the state a step
+        # returned are two argument types to ``jax.jit`` (the type names
+        # the mesh an array is committed to): without this, call 2 traces
+        # and compiles the whole step again.  For a state that is on the
+        # layout already this hands back the same buffers.
+        caches, pt, lens, skey = jax.device_put(
+            (caches, pt, lens, skey), self._state_shardings)
+        return self._fn(x0, layer_ws, caches, head, head_s, pt, lens, skey)
 
 
 def build_sharded_fused_step(spec: Int8ShardSpec, plan: ShardingPlan, *,
-                             num_layers: Optional[int] = None,
-                             donate: bool = True, mode: str = "pjit"):
+                             num_layers: int, donate: bool = True):
     """The compile-once SHARDED shard step: ONE XLA program per serving
-    step over the whole mesh.
+    step over the whole mesh, a ``shard_map`` of the per-device body with
+    explicit collectives.
 
     ``spec`` carries GLOBAL model dims (the whole 70B, not the per-chip
     shard); the plan's shardings slice it per device.  Signature is
     ``serve/shard.py build_fused_step``'s with split-named layer dicts
     (:func:`split_shard_weights_for_spec`): ``step(x0, layer_ws, caches,
     head, head_s, pt, lens, skey) -> (tok, caches, pt, lens, skey)``;
-    caches / page table / lens / PRNG key are donated.
-
-    ``mode="pjit"`` (default): global math + explicit in/out shardings
-    (GSPMD inserts the collectives).  ``mode="shard_map"``: the
-    explicit-collective per-device fallback, numerics-parity with pjit
-    (tests/test_sharded_step.py).  Returns a :class:`_CountingStep`
-    (callable; ``num_traces`` pins compile-once)."""
+    caches / page table / lens / PRNG key are donated.  Returns a
+    :class:`_CountingStep` (callable; ``num_traces`` pins compile-once)."""
     shard_check(spec, plan)
-    if mode not in ("pjit", "shard_map"):
-        raise ValueError(f"mode must be 'pjit' or 'shard_map', got {mode!r}")
-    donate_argnums = (2, 5, 6, 7) if donate else ()
-
-    def _build(tick, _unused):
-        def _body(x0, layer_ws, caches, head, head_s, pt, lens, skey,
-                  comm):
-            tick()  # trace-time only: the compile-once counter
-            return _step_math(x0, layer_ws, caches, head, head_s, pt,
-                              lens, skey, spec, comm)
-
-        if mode == "pjit":
-            comm = _GlobalComm(spec, plan)
-            if num_layers is None:
-                # shardings need the layer count up front; trace-time
-                # len(layer_ws) would do, but jit in_shardings cannot
-                return jax.jit(
-                    lambda *a: _body(*a, comm),
-                    donate_argnums=donate_argnums)
-            in_sh, out_sh = plan.shard_step_shardings(num_layers)
-            return compile_step_with_plan(
-                lambda *a: _body(*a, comm), plan,
-                in_shardings=in_sh, out_shardings=out_sh,
-                donate_argnums=donate_argnums)
-        # shard_map fallback: explicit per-device body
-        if num_layers is None:
-            raise ValueError("mode='shard_map' needs num_layers= (the "
-                             "in_specs pytree is built up front)")
-        comm = _ShardMapComm(spec, plan)
-        in_sh, out_sh = plan.shard_step_shardings(num_layers)
-
-        mapped = jax.shard_map(
-            lambda *a: _body(*a, comm), mesh=plan.mesh,
-            in_specs=plan.spec_tree(in_sh),
-            out_specs=plan.spec_tree(out_sh), check_vma=False,
-        )
-        return jax.jit(mapped, donate_argnums=donate_argnums)
-
-    return _CountingStep(None, _build)
-
-
-def build_sharded_per_op_step(spec: Int8ShardSpec, plan: ShardingPlan, *,
-                              donate: bool = True):
-    """The SAME sharded math in the pre-fused dispatch structure: one
-    jitted sharded program PER LAYER plus a jitted epilogue, chained by
-    a host loop — ``layers + 1`` dispatches (and their collectives) per
-    step instead of 1.  The A/B twin of
-    :func:`build_sharded_fused_step`; numerics identical."""
-    shard_check(spec, plan)
-    comm = _GlobalComm(spec, plan)
-    layer_sh = plan.shard_layer_shardings()
-    cache = plan.cache_sharding()
-    x_sh = plan.named(plan.dp, None)
-    pt_sh = plan.named(plan.dp, None)
-    lens_sh = plan.named(plan.dp)
-    layer_fn = compile_step_with_plan(
-        lambda x, w, kcl, vcl, pt, lens: _sharded_layer(
-            x, w, kcl, vcl, pt, lens, spec, comm),
-        plan,
-        in_shardings=(x_sh, dict(layer_sh), cache, cache, pt_sh, lens_sh),
-        out_shardings=(x_sh, cache, cache),
-        donate_argnums=(2, 3) if donate else (),
-    )
-    epilogue_fn = compile_step_with_plan(
-        lambda x, head, head_s, skey: _sharded_epilogue(
-            x, head, head_s, skey, spec, comm),
-        plan,
-        in_shardings=(x_sh, plan.named(None, plan.tp),
-                      plan.named(None, plan.tp), plan.replicated),
-        out_shardings=(plan.replicated, plan.replicated),
-    )
-
-    def step(x0, layer_ws, caches, head, head_s, pt, lens, skey):
-        x = x0
-        new_caches = []
-        for w, (kcl, vcl) in zip(layer_ws, caches):
-            x, kcl, vcl = layer_fn(x, w, kcl, vcl, pt, lens)
-            new_caches.append((kcl, vcl))
-        tok, skey = epilogue_fn(x, head, head_s, skey)
-        return tok, new_caches, pt, lens, skey
-
-    return step
+    body = functools.partial(_step_math, spec=spec,
+                             comm=_ShardMapComm(spec, plan))
+    return _CountingStep(body, plan, num_layers, donate)
 
 
 class ShardedServingStep:
-    """plan/run lifecycle over :func:`build_sharded_fused_step` —
-    the mesh twin of ``serve/step.py``'s :class:`ServingStep`.
+    """plan/run lifecycle over :func:`build_sharded_fused_step`.
 
     >>> splan = ShardingPlan(mesh, dp="dp", tp="tp")
     >>> step = ShardedServingStep()
@@ -656,16 +464,14 @@ class ShardedServingStep:
 
     ``num_traces`` pins compile-once; a trace beyond the first under a
     live plan increments the ``serve.step_retraces`` obs counter (the
-    same catalog contract as the single-chip step)."""
+    same catalog contract as the engine's rungs)."""
 
     _STATE_NAMES = ("x0", "layer_ws", "caches", "head", "head_s",
                     "page_table", "kv_lens", "key")
 
     def __init__(self):
         self._plan: Optional[ShardingPlan] = None
-        self._spec: Optional[Int8ShardSpec] = None
         self._step: Optional[_CountingStep] = None
-        self._mode = "pjit"
         self._last_sig = None
 
     @property
@@ -681,20 +487,19 @@ class ShardedServingStep:
         return self._plan.mesh_axes if self._plan else ""
 
     def plan(self, spec: Int8ShardSpec, plan: ShardingPlan, *,
-             num_layers: int, donate: bool = True,
-             mode: str = "pjit") -> None:
+             num_layers: int, donate: bool = True) -> None:
         from flashinfer_tpu import obs
 
         replan = self._step is not None
-        self._spec, self._plan, self._mode = spec, plan, mode
+        self._plan = plan
         self._step = build_sharded_fused_step(
-            spec, plan, num_layers=num_layers, donate=donate, mode=mode)
+            spec, plan, num_layers=num_layers, donate=donate)
         self._last_sig = None
         # the sharded plan's frozen statics for retrace-cause
         # attribution: shard spec + mesh identity + step shape
         obs.record_plan(self, replan=replan, statics=dict(
             spec=spec, mesh_axes=plan.mesh_axes,
-            num_layers=int(num_layers), donate=bool(donate), mode=mode))
+            num_layers=int(num_layers), donate=bool(donate)))
 
     @flashinfer_api(name="parallel.sharded_step")
     def run(self, x0, layer_ws, caches, head, head_s, pt, lens, skey):
@@ -733,54 +538,6 @@ class ShardedServingStep:
             tick.done()
             tick.commit(tokens=int(x0.shape[0]))
         return out
-
-
-# ---------------------------------------------------------------------------
-# ServingStep (llama pytree) sharding table
-# ---------------------------------------------------------------------------
-
-
-def llama_step_shardings(plan: ShardingPlan, cfg, *,
-                         weights_int8: bool = False):
-    """(in_shardings, out_shardings) for ``ServingStep``'s jitted body
-    ``(params, logits, caches, page_table, kv_lens, key)``: the
-    ``models/llama.py`` TP weight table (column-shard q/k/v/gate/up,
-    row-shard o/down, vocab-shard lm_head) as NamedShardings, batch
-    state over dp, caches (pages over dp, KV heads over tp).
-
-    dp-only plans move no contraction axis, so the sharded step stays
-    tokens-BITWISE with the unsharded one; tp>1 splits the o/down/qkv
-    contractions and reorders their f32 partial sums (documented
-    tolerance — tests/test_sharded_step.py)."""
-    from flashinfer_tpu.models.llama import _tp_param_specs
-
-    def ns(p):
-        return NamedSharding(plan.mesh, p)
-
-    layer_specs = _tp_param_specs(cfg, plan.tp, quantized=weights_int8)
-    param_sh = dict(
-        embed=ns(P(None, None)),
-        final_norm=ns(P(None)),
-        lm_head=ns(P(None, plan.tp)),
-        layers=[{k: ns(v) for k, v in layer_specs.items()}
-                for _ in range(cfg.num_layers)],
-    )
-    if weights_int8:
-        param_sh["lm_head_scale"] = ns(P(None, plan.tp))
-    cache = plan.cache_sharding()
-    caches_sh = [(cache, cache) for _ in range(cfg.num_layers)]
-    logits_sh = plan.named(plan.dp, None)
-    pt_sh = plan.named(plan.dp, None)
-    lens_sh = plan.named(plan.dp)
-    in_sh = (param_sh, logits_sh, caches_sh, pt_sh, lens_sh,
-             plan.replicated)
-    # tokens come back REPLICATED: the sampling chain must stay on the
-    # replicated logits (see the threefry note in ServingStep.plan) —
-    # a dp-sharded token output would let GSPMD re-partition the
-    # sampler and fork its random stream per shard
-    out_sh = (plan.replicated, logits_sh, caches_sh, pt_sh, lens_sh,
-              plan.replicated)
-    return in_sh, out_sh
 
 
 # ---------------------------------------------------------------------------
@@ -830,8 +587,8 @@ def make_serving_mesh(world_size: Optional[int] = None, *, hidden: int,
                       num_qo_heads: int, num_kv_heads: int,
                       devices=None) -> ShardingPlan:
     """Build a (dp, tp) serving mesh over the visible devices with
-    knob-selected axis sizes — the one-call entry the bench and
-    examples use."""
+    knob-selected axis sizes (``chip_smoke.py --chips 4`` builds its
+    mesh with it)."""
     devices = list(devices if devices is not None else jax.devices())
     if world_size is None:
         world_size = len(devices)

@@ -95,9 +95,8 @@ def flash_block_key(total_q, total_kv, num_qo_heads, num_kv_heads,
                     head_dim, dtype, causal) -> tuple:
     """The ``flash_attention.blocks`` tactic key for a shape — pow2-
     bucketed token axes keep the key space finite and make shipped-config
-    keys hit across nearby lengths.  THE key builder: ``_tuned_flash``
-    and bench.py's block-metadata lookup both call it, so the bench can
-    never bank metadata under a desynced hand-copied key."""
+    keys hit across nearby lengths.  THE key builder (``_tuned_flash``
+    calls it)."""
     return (
         next_power_of_two(max(int(total_q), 16)),
         next_power_of_two(max(int(total_kv), 128)),
@@ -467,9 +466,7 @@ def resolve_prefill_ingest(
     cost-model chooser (``costmodel.predict_prefill_ingest_win`` — the
     ``choose_decode_splits`` pattern: the fused launch must beat the
     separate-op composition by >2% predicted time or the proven
-    composition stays).  THE single resolution point — the wrapper,
-    MixedServingStep, and the engine all route here so the knob can
-    never mean different things per surface."""
+    composition stays).  THE single resolution point of the knob."""
     from flashinfer_tpu.autotuner import AutoTuner
 
     v = AutoTuner.get().lookup("prefill.fused_ingest", fused_key,
@@ -964,43 +961,12 @@ class BatchPrefillWithPagedKVCacheWrapper:
             return None
         return dict(self._fused_stats)
 
-    @property
-    def plan_arrays(self) -> dict:
-        """Export the frozen gather-path plan arrays + statics for
-        closure into a compile-once mixed serving step
-        (``flashinfer_tpu.serve.step.MixedServingStep``): the flattened
-        token axes (``q_seg``/``q_pos``/``kv_seg``/``kv_pos``), the
-        flat paged-cache gather rows, the padded extents, and the
-        attention statics.  The light fused-path plan defers these
-        arrays; exporting materializes the gather plan once (same
-        contract as a ``return_lse`` fallback run), preserving any live
-        sm_scale / soft-cap rebind."""
-        if self._plan is None:
-            raise RuntimeError("plan() must be called before plan_arrays")
-        plan = self._materialize_gather_plan()
-        return dict(
-            q_seg=plan.q_seg, q_pos=plan.q_pos,
-            kv_seg=plan.kv_seg, kv_pos=plan.kv_pos,
-            kv_gather_rows=plan.kv_gather_rows,
-            total_q=plan.total_q, total_kv=plan.total_kv,
-            tq_pad=plan.tq_pad, tkv_pad=plan.tkv_pad,
-            batch_size=plan.batch_size,
-            num_qo_heads=plan.num_qo_heads,
-            num_kv_heads=plan.num_kv_heads,
-            head_dim=plan.head_dim, page_size=plan.page_size,
-            causal=plan.causal, sm_scale=plan.sm_scale,
-            logits_soft_cap=plan.logits_soft_cap,
-            window_left=plan.window_left,
-            kv_layout=self._kv_layout,
-        )
-
     def _materialize_gather_plan(self) -> "_PrefillPlan":
         """Materialize the deferred gather plan if the light fused-path
         plan is live (the builder recomputes PLANNED values, so any
-        live sm_scale / logits_soft_cap rebind is carried over) — the
-        ONE copy of this logic, shared by run()'s return_lse fallback
-        and the ``plan_arrays`` export.  Returns the (possibly new)
-        live plan."""
+        live sm_scale / logits_soft_cap rebind is carried over), for
+        run()'s return_lse fallback.  Returns the (possibly new) live
+        plan."""
         plan = self._plan
         if plan.kv_gather_rows is None:
             new_plan = self._gather_plan_builder()
@@ -1188,7 +1154,7 @@ class BatchPrefillWithPagedKVCacheWrapper:
         if plan.kv_gather_rows is None:
             # fused plan was active but this call needs the gather path
             # (return_lse): materialize the deferred plan once, rebinds
-            # preserved (shared helper with the plan_arrays export)
+            # preserved
             plan = self._materialize_gather_plan()
         if check_kv_layout(self._kv_layout) == TensorLayout.HND:
             k_cache = jnp.swapaxes(k_cache, 1, 2)

@@ -42,8 +42,7 @@ def annotate(name: str) -> Iterator[None]:
 @contextlib.contextmanager
 def scope(name: str) -> Iterator[None]:
     """Named span usable INSIDE jit-traced code: the emitted XLA ops
-    carry ``name`` so device traces (and the bench.py serving-loop
-    phase decomposition) attribute work to serving phases.
+    carry ``name`` so device traces attribute work to serving phases.
     ``annotate`` is the host-side twin (TraceAnnotation does nothing
     under tracing)."""
     import jax

@@ -1,48 +1,39 @@
-"""flashinfer_tpu.serve — the compile-once serving layer.
+"""flashinfer_tpu.serve — the serving layer: one surface, :class:`ServingEngine`.
 
-The serving engine's fused-step substrate (ROADMAP items 1 and 3): a
-decode (or mixed chunked-prefill + decode) step compiled ONCE into a
-single donated-buffer XLA program, replacing the Python-loop-over-ops
-serving flow whose per-step host dispatch tax PR 2's
-``overhead_decomposition`` measured at 1.13-1.31x e2e-vs-slope.
+A continuous-batching engine whose every step (decode-only or mixed
+chunked prefill + decode) is ONE donated-buffer XLA program per rung of
+a small ladder of token counts, compiled once: ``jax.jit`` with
+``donate_argnums`` on the KV caches, so XLA aliases the serving state in
+place and a step never retraces across tokens.
 
-Reference analogue: ``fast_decode_plan`` + CUDAGraph capture (frozen
-shapes, replayed launches) and the persistent mixed-batch kernel
-(``persistent.cuh:682``) that runs a whole decode step as one launch.
-The TPU form: plan() freezes every static (shapes, page geometry,
-quantization mode, sampling config, backend) host-side, and run() is
-one ``jax.jit`` call with ``donate_argnums`` on the KV caches, page
-tables, and sampling key so XLA aliases the serving state in place and
-the step never retraces across tokens.
-
-- :mod:`~flashinfer_tpu.serve.step` — :class:`ServingStep` (decode
-  mega-step over the Llama model family), :class:`MixedServingStep`
-  (chunked prefill + decode in ONE step over the holistic
-  BatchAttention plan arrays), :class:`SamplingConfig`;
-- :mod:`~flashinfer_tpu.serve.shard` — the int8-weight 70B-shard step
-  pipeline bench.py's ``serving``/``serving_fused`` phases measure;
-- :mod:`~flashinfer_tpu.serve.engine` — the continuous-batching
-  serving ENGINE above the steps: ref-counted paged-KV block pool,
-  prefix-cache reuse via the cascade merge operator, and SLO-aware
-  scheduling on a pre-compiled rung ladder (:class:`ServingEngine`,
-  :class:`EngineConfig`, :class:`EngineRequest`, :class:`BlockPool`,
-  :class:`PrefixCache`);
-- :mod:`~flashinfer_tpu.serve.kv_tier` — the TIERED KV subsystem:
-  :class:`HostKVStore` (host-RAM offload below the block pool —
-  spill/restore with bit-exact resume, so effective KV capacity
-  exceeds the chip's HBM GiB) and :class:`DisaggServing`
-  (prefill-pool → decode-pool disaggregation joined by the
-  ICI-priced ``kv_migrate`` handoff; docs/serving.md §"Tiered KV &
-  disaggregation");
+- :mod:`~flashinfer_tpu.serve.engine` — :class:`ServingEngine`,
+  :class:`EngineConfig`, :class:`EngineRequest`: ref-counted paged-KV
+  block pool (:class:`BlockPool`), prefix-cache reuse via the cascade
+  merge operator (:class:`PrefixCache`), scheduling on the pre-compiled
+  rung ladder; the step of the Llama-style dense layer;
+- :mod:`~flashinfer_tpu.serve.block_step` — the step of a model that
+  brings its own layer (``engine_layer``), :mod:`~flashinfer_tpu.serve.
+  recurrent` its recurrent layers' work units, :mod:`~flashinfer_tpu.
+  serve.kv_write` the in-place K/V row write both step bodies call;
 - :mod:`~flashinfer_tpu.serve.engine_kernels` — the engine's KERNEL
   attention tier (``EngineConfig.attention_backend="kernel"``): the
   host planner that lowers each step's schedule onto the work-unit
   prefill mainloop + split-KV decode plan arrays, and the in-jit
-  cascade-merged composition (docs/performance.md §"Engine kernel
-  graduation").
+  cascade-merged composition;
+- :mod:`~flashinfer_tpu.serve.kv_tier` — the TIERED KV subsystem:
+  :class:`HostKVStore` (host-RAM offload below the block pool —
+  spill/restore with bit-exact resume) and :class:`DisaggServing`
+  (prefill-pool -> decode-pool disaggregation joined by the
+  ``kv_migrate`` handoff; docs/serving.md §"Tiered KV &
+  disaggregation");
+- :mod:`~flashinfer_tpu.serve.step` — :class:`SamplingConfig` and the
+  sampling epilogue :func:`sample_next_tokens`;
+- :mod:`~flashinfer_tpu.serve.shard` — the int8 shard step on one
+  device, the unsharded oracle of ``parallel/plan.py``'s
+  :class:`~flashinfer_tpu.parallel.plan.ShardedServingStep`.
 
-See docs/performance.md ("Compile-once serving step") for the step
-lifecycle and donation contract, and docs/serving.md for the engine.
+See docs/serving.md for the engine.  ``perfbench/`` is the benchmark;
+``PERF.md`` and ``PERF_LEDGER.jsonl`` are where a speed is stated.
 """
 
 from flashinfer_tpu.serve.engine import (
@@ -57,14 +48,7 @@ from flashinfer_tpu.serve.kv_tier import (
     HostKVStore,
     migrate_request,
 )
-from flashinfer_tpu.serve.step import (
-    MixedServingStep,
-    SamplingConfig,
-    ServingStep,
-    ServingStepPlan,
-    mixed_chunk_tokens,
-    sample_next_tokens,
-)
+from flashinfer_tpu.serve.step import SamplingConfig, sample_next_tokens
 
 __all__ = [
     "BlockPool",
@@ -72,13 +56,9 @@ __all__ = [
     "EngineConfig",
     "EngineRequest",
     "HostKVStore",
-    "MixedServingStep",
     "PrefixCache",
     "SamplingConfig",
     "ServingEngine",
-    "ServingStep",
-    "ServingStepPlan",
     "migrate_request",
-    "mixed_chunk_tokens",
     "sample_next_tokens",
 ]

@@ -61,8 +61,8 @@ Attention backends (``EngineConfig.attention_backend``, the
   kernel tier keeps the compile-once ladder; it skips the masked-lane
   HBM/FLOP waste the reference tier pays.  Tokens are pinned equal to
   the reference tier (tests/test_engine_kernels.py), and interpret
-  mode makes the whole path CPU-testable before the first on-chip
-  session (``bench.py --only serving_engine`` A/Bs the two).
+  mode makes the whole path CPU-testable; ``chip_smoke.py`` compares
+  the two on the chip, and every cell of ``perfbench/`` runs this tier.
 
 See docs/serving.md for lifecycle, pool invariants, prefix-cache
 semantics, scheduler knobs, and the retrace-budget contract.
@@ -703,8 +703,8 @@ class ServingEngine:
     def aggregate_cost(self):
         """The whole run's work as one ``costmodel.engine_step`` Cost
         over the accumulated totals (the formula is linear in each
-        term) — what bench.py's ``serving_engine`` phase stamps its
-        rows with, shared-prefix KV dedup included via kv_rows.
+        term), shared-prefix KV dedup included via kv_rows (the
+        predicted side of ``obs perf``'s engine rows).
 
         On the KERNEL backend the launched terms come from the REAL
         work-unit stats (the units each launch runs, whole tiles and
@@ -916,9 +916,9 @@ class ServingEngine:
         backend: the attention term comes from the REAL planner —
         ``build_prefill_work_units`` is run for the skipped span and
         its launched MXU-cell stats price the work the kernel tier
-        would actually have executed (bench.py's
-        ``prefill_flops_avoided`` is therefore unit-stats-derived, not
-        a dense-window estimate)."""
+        would actually have executed (``flops_avoided`` is
+        therefore unit-stats-derived, not a dense-window
+        estimate)."""
         from flashinfer_tpu.obs import costmodel
 
         kv_pairs = tokens * (tokens + 1) // 2

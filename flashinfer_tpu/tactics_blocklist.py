@@ -76,7 +76,7 @@ def bringup_entries() -> List[dict]:
     """Raw quarantine entries (``[]`` when absent/unreadable).  Each is a
     dict with at least ``rung_id``/``reason``; knob rungs also carry
     ``op``/``tactic`` (consulted by :func:`blocked`) and ``bench_phases``
-    (consulted by bench.py's orchestrator)."""
+    (read by ``obs.bringup.quarantined_bench_phases``)."""
     global _bringup_cache
     path = bringup_quarantine_path()
     try:

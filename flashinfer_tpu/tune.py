@@ -96,8 +96,7 @@ def run_tuning_workload(stages: Optional[list] = None,
             log(f"decode tuned bs={bs} ctx={ctx}")
 
     def stage_prefill():
-        # shapes match bench.py's prefill sweep exactly (a tuned tactic
-        # only helps a measured row if the tactic KEY covers that shape)
+        # a tuned tactic only helps a shape its KEY covers
         for bs, qlen, ctx in ((4, 1024, 4096), (8, 512, 4096),
                               (1, 8192, 8192), (2, 2048, 8192),
                               (16, 256, 2048)):

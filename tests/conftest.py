@@ -79,3 +79,42 @@ def mesh8():
     devs = np.array(jax.devices()[:8]).reshape(2, 4)
     with Mesh(devs, ("dp", "tp")) as m:
         yield m
+
+
+@pytest.fixture
+def tiny_sharded_step():
+    """``(step, x0, layer_ws, head, head_s, state)``: a planned
+    ``ShardedServingStep`` of one layer on a 1x1 mesh, with host arrays
+    for its arguments (``state`` = caches, page table, lens, key)."""
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from flashinfer_tpu.parallel.plan import (
+        ShardedServingStep, ShardingPlan, split_shard_weights_for_spec)
+    from flashinfer_tpu.quantization import quantize_int8
+    from flashinfer_tpu.serve.shard import Int8ShardSpec
+
+    spec = Int8ShardSpec(bs=2, hidden=64, hq=2, hkv=1, hd=32, inter=128,
+                         vocab_shard=64, page_size=8, use_pallas=False)
+    ks = jax.random.split(jax.random.PRNGKey(0), 6)
+
+    def qw(k, shape):
+        wq, ws = quantize_int8(jax.random.normal(k, shape) / 8.0, axis=0)
+        return wq, ws.reshape(1, -1)
+
+    layer_ws = split_shard_weights_for_spec([(
+        *qw(ks[0], (64, spec.qdim + 2 * spec.kvdim)),
+        *qw(ks[1], (spec.qdim, 64)), *qw(ks[2], (64, 256)),
+        *qw(ks[3], (128, 64)), jnp.ones((64,)), jnp.ones((64,)))], spec)
+    head, head_s = qw(ks[4], (64, 64))
+    x0 = jax.random.normal(ks[5], (2, 64), jnp.bfloat16)
+    state = [[(jnp.zeros((4, 1, 8, 32), jnp.int8),
+               jnp.zeros((4, 1, 8, 32), jnp.int8))],
+             jnp.arange(4, dtype=jnp.int32).reshape(2, 2),
+             jnp.array([3, 5], jnp.int32), jax.random.PRNGKey(2)]
+    step = ShardedServingStep()
+    step.plan(spec, ShardingPlan(Mesh(
+        np.array(jax.devices()[:1]).reshape(1, 1), ("dp", "tp"))),
+        num_layers=1)
+    return step, x0, layer_ws, head, head_s, state

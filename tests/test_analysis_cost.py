@@ -133,7 +133,7 @@ def test_l017_clean_tree():
     st = chooser_coverage.stats(project)
     assert st["choosers"] == 2, st
     assert st["bindings"] == 5, st
-    assert st["waivers"] >= 19, st
+    assert st["waivers"] >= 18, st  # one per waived knob
 
 
 @pytest.mark.quick

@@ -4,7 +4,7 @@ provenance-label extension.
 
 The acceptance regressions run each pass against the REAL serving
 modules with one surgical skew injected — a post-call donated-buffer
-reuse in serve/step.py must flag exactly L011, a schedule value moved
+reuse in parallel/plan.py must flag exactly L011, a schedule value moved
 into a plan-shape static in serve/engine_kernels.py exactly L012, a
 dropped knob binding exactly L013 — and the unmodified tree must stay
 clean under all three (no baseline absorption).
@@ -49,20 +49,21 @@ def _new_pass_findings(project):
 
 @pytest.mark.quick
 def test_l011_flags_post_call_donated_reuse_in_real_step():
-    """THE acceptance regression: a copy of serve/step.py whose run()
-    reads a donated binding after the step call must flag L011 — and
-    ONLY L011 of the three new passes."""
-    real = _real("serve/step.py")
+    """THE acceptance regression: a copy of parallel/plan.py whose
+    sharded step hands back a donated binding after the jitted call
+    must flag L011 — and ONLY L011 of the three new passes."""
+    real = _real("parallel/plan.py")
+    call = ("self._fn(x0, layer_ws, caches, head, head_s, pt, lens, "
+            "skey)")
     skew = real.replace(
-        "return tokens, (new_logits, new_caches, pt, lens, "
-        "new_key)",
-        "return tokens, (new_logits, new_caches, pt, kv_lens, "
-        "new_key)")
+        f"        return {call}",
+        f"        out = {call}\n"
+        "        return out[:3] + (lens,) + out[4:]")
     assert skew != real
-    by_pass = _new_pass_findings(_project(("serve/step.py", skew)))
+    by_pass = _new_pass_findings(_project(("parallel/plan.py", skew)))
     assert [f.code for f in by_pass["L011"]] == ["L011"], by_pass
     f = by_pass["L011"][0]
-    assert f.func == "run" and "kv_lens" in f.message
+    assert f.func == "__call__" and "lens" in f.message
     assert "DONATED" in f.message
     assert by_pass["L012"] == [] and by_pass["L013"] == []
 
@@ -476,17 +477,15 @@ def test_l011_starred_call_layout_skips():
 
 
 def test_l011_half_specified_shardings_flagged():
-    """The both-or-neither contract, statically — for both the raw
-    jax.jit spelling and compile_step_with_plan."""
+    """The both-or-neither contract, statically, on either side."""
     src = """
         import jax
-        from flashinfer_tpu.parallel.plan import compile_step_with_plan
 
         def a(fn, in_sh):
             return jax.jit(fn, in_shardings=in_sh)
 
         def b(fn, out_sh):
-            return compile_step_with_plan(fn, out_shardings=out_sh)
+            return jax.jit(fn, out_shardings=out_sh)
 
         def c(fn, in_sh, out_sh):
             return jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh)
@@ -837,7 +836,7 @@ def test_l013_waiver_hygiene():
 
     project = Project.from_paths([PKG_ROOT])
     waivers = dict(KNOB_WAIVERS)
-    waivers["serve.mixed_chunk"] = "   "          # reasonless
+    waivers["parallel.dp"] = "   "                # reasonless
     waivers["fused_prefill.blocks"] = "shadowing"  # has a binding
     waivers["gone.knob"] = "stale"                 # unregistered
     findings = registry_coverage.run(project, waivers=waivers)

@@ -274,17 +274,6 @@ def test_l006_requires_references_on_measured_sections(tmp_path):
     assert tuning_schema.run(_staged_project(tmp_path / "s", seed)) == []
 
 
-def test_record_phases_pending_journals_for_resume(tmp_path, monkeypatch):
-    jpath = str(tmp_path / "j.jsonl")
-    monkeypatch.setenv("FLASHINFER_TPU_BRINGUP_JOURNAL", jpath)
-    probe = {"healthy": False, "detail": "dead"}
-    bringup.record_phases_pending(["mla", "scans"], probe)
-    j = bringup.Journal(jpath)
-    assert j.step_outcomes("phase") == {"mla": "pending",
-                                        "scans": "pending"}
-    assert all(e["probe"] == probe for e in j.entries())
-
-
 def test_quarantined_bench_phases_surface(tmp_path, monkeypatch):
     qpath = str(tmp_path / "q.json")
     open(qpath, "w").write(json.dumps([

@@ -8,10 +8,8 @@ Pins the three halves of the attribution engine:
   count on tiny shapes (attention/MLA/gmm FLOPs + read/write bytes,
   quantized-KV byte widths, fused-prefill launched-vs-effective from a
   REAL ``build_prefill_work_units`` plan);
-- ``obs.roofline`` — attribute/stamp math by hand, the bench-row
-  schema contract (every bench.py routine stamps through the shared
-  model), the auditor's roofline-fraction comparison space, and the
-  ``obs perf`` doctor reproducing the round-5 VERDICT headline
+- ``obs.roofline`` — attribute/stamp math by hand, the auditor's
+  roofline-fraction comparison space, and the ``obs perf`` doctor reproducing the round-5 VERDICT headline
   fractions from BENCH_BANKED.md with a schema-stable JSON form.
 
 Plus the zero-overhead pin: plain library use (metrics off, no bench)
@@ -20,7 +18,6 @@ never imports the cost model at all.
 
 import json
 import os
-import re
 import subprocess
 import sys
 
@@ -383,25 +380,40 @@ def test_spec_for_row_chip_then_peak_then_default():
         {}, default=hwspec.spec("v6e")).name == "v6e"
 
 
-def test_bench_rows_all_stamped_by_shared_model():
-    """The schema contract, enforced structurally: every `_emit_row`
-    call in bench.py routes through `_stamp` (the shared cost model),
-    except the device-free CI selftest stub; and no inline peak-spec
-    arithmetic survives anywhere in the file."""
-    src = open(os.path.join(REPO_ROOT, "bench.py")).read()
-    calls = [m for m in re.finditer(r"_emit_row\((?!\*\*_stamp\()", src)
-             if "def _emit_row" not in
-             src[src.rfind("\n", 0, m.start()) + 1: m.end()]]
-    unstamped = [src[m.start(): m.start() + 60].splitlines()[0]
-                 for m in calls]
-    assert all("selftest" in u for u in unstamped), unstamped
-    for forbidden in ("HBM_PEAK_TBPS", "chip_peak_tbps",
-                      "attention_flops", "attention_bytes"):
-        assert forbidden not in src, forbidden
-    # the stamped field set is the documented one
-    assert set(roofline.ROW_FIELDS) >= {
-        "flops", "bytes_read", "bytes_written", "intensity", "bound",
-        "pct_roofline", "effective_pct_roofline"}
+def test_step_mode_stamp_is_identity():
+    """roofline.stamp_row(step_mode=...) writes the serving-loop
+    dispatch-structure identity: rows differing only in step_mode are
+    DIFFERENT configurations to the audit (the num_splits precedent),
+    while dispatch_residual_us is a measurement field."""
+    cost = costmodel.serving_step(
+        4, 128, 2, **costmodel.SERVING_SHAPES["llama70b_tp8shard_int8"])
+    spec = hwspec.CHIP_SPECS["v5e"]
+    rows = []
+    for mode in ("fused", "per_op"):
+        row = roofline.stamp_row(
+            dict(phase="serving_fused", bs=4, ctx=128,
+                 us_step=5000.0, dispatch_residual_us=100.0),
+            cost, 5e-3, spec, step_mode=mode)
+        assert row["step_mode"] == mode
+        rows.append(row)
+    k0, k1 = (bench_audit.row_key(r) for r in rows)
+    assert k0 != k1
+    r2 = dict(rows[0])
+    r2["dispatch_residual_us"] = 999.0
+    assert bench_audit.row_key(r2) == k0
+
+
+def test_serving_ops_catalogued_and_cost_covered():
+    """The serving steps are catalogued (L005) and cost-covered (obs
+    doctor's uncovered list stays empty)."""
+    from flashinfer_tpu.obs.catalog import API_OPS, METRICS, SERVING_OPS
+
+    assert {"engine.step", "parallel.sharded_step"} <= SERVING_OPS \
+        <= API_OPS
+    assert "serve.step_retraces" in METRICS
+    assert costmodel.API_OP_COSTS["parallel.sharded_step"] \
+        == "serving_step_sharded"
+    assert costmodel.uncovered_api_ops() == ()
 
 
 def test_timeline_phase_mfu_joins_profiler_spans():

@@ -3,13 +3,13 @@
 Pins the tentpole contracts:
 
 - **zero-overhead subprocess pin**: with ``FLASHINFER_TPU_SPANS``
-  unset, plain library use (decorated ops, wrapper plan/run, a fused
-  ServingStep loop) never imports the spans machinery at all — the
+  unset, plain library use (decorated ops, wrapper plan/run, a
+  ServingEngine run) never imports the spans machinery at all — the
   costmodel precedent, one notch stronger than branch-counting;
 - **ring-buffer bound**: the recorder keeps exactly ``capacity`` spans
   and counts (never silently loses) the overwritten ones;
 - **retrace-cause diff**: change ONE frozen static -> exactly that key
-  reported, for both the wrapper replan path and the fused-step
+  reported, for both the wrapper replan path and the serving steps'
   run-state path;
 - **TTFT/TPOT histogram math** against hand-computed values (driven
   with explicit clocks, no wall-time flake);
@@ -70,8 +70,8 @@ def test_spans_gate_off_is_noop_and_import_free(monkeypatch):
 
 def test_zero_overhead_subprocess_pin():
     """THE tentpole pin: a subprocess doing plain library work — a
-    decorated op, a decode-wrapper plan, a compile-once ServingStep
-    loop — must never load flashinfer_tpu.obs.spans (same standard as
+    decorated op, a decode-wrapper plan, a ServingEngine run — must
+    never load flashinfer_tpu.obs.spans (same standard as
     the metrics registry / costmodel zero-overhead pins)."""
     code = """
 import sys
@@ -83,25 +83,16 @@ w = fi.BatchDecodeWithPagedKVCacheWrapper(kv_layout="NHD")
 w.plan(np.array([0, 2, 4], np.int32), np.arange(4, dtype=np.int32),
        np.array([4, 4], np.int32), 4, 2, 64, 4)
 from flashinfer_tpu.models import LlamaConfig, init_llama_params
-from flashinfer_tpu.serve import SamplingConfig, ServingStep
+from flashinfer_tpu.serve import EngineConfig, EngineRequest, ServingEngine
 cfg = LlamaConfig.tiny(num_layers=2, dtype=jnp.float32)
 params = init_llama_params(jax.random.PRNGKey(0), cfg)
-B, PS, PPR = 2, 8, 4
-caches = [(jnp.zeros((B*PPR, cfg.num_kv_heads, PS, cfg.head_dim), cfg.dtype),
-           jnp.zeros((B*PPR, cfg.num_kv_heads, PS, cfg.head_dim), cfg.dtype))
-          for _ in range(cfg.num_layers)]
-pt = jnp.arange(B*PPR, dtype=jnp.int32).reshape(B, PPR)
-lens = jnp.array([3, 5], jnp.int32)
-st = ServingStep()
-st.plan(cfg, page_table=pt, kv_lens=lens, sampling=SamplingConfig(),
-        use_pallas=False)
-state = st.make_state(
-    caches, jnp.arange(B*PPR, dtype=jnp.int32).reshape(B, PPR), lens,
-    jax.random.normal(jax.random.PRNGKey(1), (B, cfg.vocab_size),
-                      jnp.float32), jax.random.PRNGKey(2))
-for _ in range(2):
-    _, state = st.run(params, state)
-assert st.num_traces == 1
+eng = ServingEngine(cfg, params, EngineConfig(
+    num_pages=16, page_size=8, max_batch=2, prefill_budget_tokens=8,
+    max_seq_tokens=16))
+eng.submit(EngineRequest("r0", [3, 1, 4], max_new_tokens=3))
+eng.submit(EngineRequest("r1", [1, 5, 9, 2, 6], max_new_tokens=3))
+assert all(len(t) == 3 for t in eng.run().values())
+assert eng.num_traces == 2  # the mixed rung and the decode rung
 assert "flashinfer_tpu.obs.spans" not in sys.modules, \\
     "spans machinery loaded on plain library use"
 print("SPANS_ZERO_OVERHEAD_OK")
@@ -198,139 +189,60 @@ def test_wrapper_replan_diff_names_exact_static(spans_on):
     assert obs.snapshot()["counters"]["plan.retrace_cause"] == cells
 
 
-def test_serving_step_retrace_names_moved_state_leaf(spans_on):
-    """A retrace under a live ServingStep plan (one run-state static
-    moved: the carried logits dtype) attributes to exactly that leaf."""
-    import jax
+def test_sharded_step_retrace_names_moved_state_leaf(
+        spans_on, tiny_sharded_step):
+    """A retrace under a live ShardedServingStep plan (one run-state
+    static moved: the dtype of the activations handed in) attributes
+    to exactly that leaf."""
     import jax.numpy as jnp
 
     from flashinfer_tpu import obs
-    from flashinfer_tpu.models import LlamaConfig, init_llama_params
     from flashinfer_tpu.obs import spans
-    from flashinfer_tpu.serve import SamplingConfig, ServingStep
 
-    cfg = LlamaConfig.tiny(num_layers=2, dtype=jnp.float32)
-    params = init_llama_params(jax.random.PRNGKey(0), cfg)
-    B, PS, PPR = 2, 8, 4
-
-    def mk_caches():
-        return [
-            (jnp.zeros((B * PPR, cfg.num_kv_heads, PS, cfg.head_dim),
-                       cfg.dtype),
-             jnp.zeros((B * PPR, cfg.num_kv_heads, PS, cfg.head_dim),
-                       cfg.dtype))
-            for _ in range(cfg.num_layers)
-        ]
-
-    def mk_pt():
-        return jnp.arange(B * PPR, dtype=jnp.int32).reshape(B, PPR)
-
-    lens = jnp.array([3, 5], jnp.int32)
-    st = ServingStep()
-    st.plan(cfg, page_table=mk_pt(), kv_lens=lens,
-            sampling=SamplingConfig(), use_pallas=False)
-    logits = jax.random.normal(jax.random.PRNGKey(1),
-                               (B, cfg.vocab_size), jnp.float32)
-    state = st.make_state(mk_caches(), mk_pt(), lens, logits,
-                          jax.random.PRNGKey(2))
+    st, x0, layer_ws, head, head_s, state = tiny_sharded_step
     for _ in range(3):
-        _, state = st.run(params, state)
+        _, *state = st.run(x0, layer_ws, state[0], head, head_s,
+                           *state[1:])
     assert st.num_traces == 1
     assert "plan.retrace_cause" not in obs.snapshot()["counters"]
 
-    bad = (jax.random.normal(jax.random.PRNGKey(3),
-                             (B, cfg.vocab_size), jnp.bfloat16),
-           mk_caches(), mk_pt(), jnp.array([3, 5], jnp.int32),
-           jax.random.PRNGKey(4))
-    st.run(params, bad)
+    st.run(x0.astype(jnp.float32), layer_ws, state[0], head, head_s,
+           *state[1:])
     assert st.num_traces == 2
     assert spans.top_retrace_causes(obs.snapshot()) == [
-        {"wrapper": "ServingStep", "key": "logits", "count": 1}]
+        {"wrapper": "ShardedServingStep", "key": "x0", "count": 1}]
 
 
-def test_serving_step_retrace_attributes_params_change(spans_on):
+def test_engine_retrace_attributes_params_change(spans_on):
     """The signature covers EVERY jitted argument — a swapped weight
-    dtype (params, caller-owned, outside the donated state) attributes
-    to the exact params leaf, not '<unattributed>'."""
+    dtype (params, outside the donated state) on a rung that has
+    compiled attributes to the exact params leaf, not
+    '<unattributed>'."""
     import jax
     import jax.numpy as jnp
 
     from flashinfer_tpu import obs
     from flashinfer_tpu.models import LlamaConfig, init_llama_params
-    from flashinfer_tpu.serve import SamplingConfig, ServingStep
+    from flashinfer_tpu.serve import (EngineConfig, EngineRequest,
+                                      ServingEngine)
 
     cfg = LlamaConfig.tiny(num_layers=2, dtype=jnp.float32)
-    params = init_llama_params(jax.random.PRNGKey(0), cfg)
-    B, PS, PPR = 2, 8, 4
-
-    def mk_caches():
-        return [
-            (jnp.zeros((B * PPR, cfg.num_kv_heads, PS, cfg.head_dim),
-                       cfg.dtype),
-             jnp.zeros((B * PPR, cfg.num_kv_heads, PS, cfg.head_dim),
-                       cfg.dtype))
-            for _ in range(cfg.num_layers)
-        ]
-
-    def mk_pt():
-        return jnp.arange(B * PPR, dtype=jnp.int32).reshape(B, PPR)
-
-    def mk_state(st):
-        return st.make_state(
-            mk_caches(), mk_pt(), jnp.array([3, 5], jnp.int32),
-            jax.random.normal(jax.random.PRNGKey(1),
-                              (B, cfg.vocab_size), jnp.float32),
-            jax.random.PRNGKey(2))
-
-    st = ServingStep()
-    st.plan(cfg, page_table=mk_pt(),
-            kv_lens=jnp.array([3, 5], jnp.int32),
-            sampling=SamplingConfig(), use_pallas=False)
-    st.run(params, mk_state(st))
-    params2 = dict(params, embed=params["embed"].astype(jnp.bfloat16))
-    st.run(params2, mk_state(st))
-    assert st.num_traces == 2
+    eng = ServingEngine(
+        cfg, init_llama_params(jax.random.PRNGKey(0), cfg),
+        EngineConfig(num_pages=16, page_size=8, max_batch=2,
+                     prefill_budget_tokens=8, max_seq_tokens=16))
+    eng.submit(EngineRequest("r0", [1, 5, 9, 2, 6], max_new_tokens=2))
+    eng.run()
+    assert eng.num_traces == 2  # the mixed rung and the decode rung
+    assert "plan.retrace_cause" not in obs.snapshot()["counters"]
+    eng.params = dict(eng.params,
+                      embed=eng.params["embed"].astype(jnp.bfloat16))
+    eng.submit(EngineRequest("r1", [1, 5, 9, 2, 6], max_new_tokens=1))
+    eng.run()
+    assert eng.num_traces == 3
     causes = obs.snapshot()["counters"]["plan.retrace_cause"]
     assert list(causes) == [
-        "{key=params['embed'],wrapper=ServingStep}"]
-
-
-def test_raw_plan_page_size_freeze_is_not_a_retrace_cause(spans_on):
-    """page_size=0 is the derived-at-make_state sentinel, not a frozen
-    static: raw-geometry plan -> make_state freeze -> replan at the
-    SAME geometry (raw or explicit) must attribute NOTHING — no
-    phantom page_size cause in the doctor table."""
-    import jax
-    import jax.numpy as jnp
-
-    from flashinfer_tpu import obs
-    from flashinfer_tpu.models import LlamaConfig
-    from flashinfer_tpu.serve import SamplingConfig, ServingStep
-
-    cfg = LlamaConfig.tiny(num_layers=2, dtype=jnp.float32)
-    B, PS, PPR = 2, 8, 4
-
-    def mk_pt():
-        return jnp.arange(B * PPR, dtype=jnp.int32).reshape(B, PPR)
-
-    caches = [
-        (jnp.zeros((B * PPR, cfg.num_kv_heads, PS, cfg.head_dim),
-                   cfg.dtype),
-         jnp.zeros((B * PPR, cfg.num_kv_heads, PS, cfg.head_dim),
-                   cfg.dtype))
-        for _ in range(cfg.num_layers)
-    ]
-    lens = jnp.array([3, 5], jnp.int32)
-    st = ServingStep()
-    kw = dict(page_table=mk_pt(), kv_lens=lens,
-              sampling=SamplingConfig(), use_pallas=False)
-    st.plan(cfg, **kw)  # raw geometry: page_size deferred
-    st.make_state(caches, mk_pt(), lens,
-                  jax.random.normal(jax.random.PRNGKey(1),
-                                    (B, cfg.vocab_size), jnp.float32),
-                  jax.random.PRNGKey(2))  # freezes page_size=PS
-    st.plan(cfg, **kw)  # raw replan, same geometry
-    assert "plan.retrace_cause" not in obs.snapshot()["counters"]
+        "{key=params['embed'],wrapper=ServingEngine}"]
 
 
 def test_plan_signature_fingerprints_small_arrays():
@@ -536,7 +448,7 @@ def test_obs_trace_cli_selftest_acceptance(tmp_path):
     """THE acceptance criterion: `python -m flashinfer_tpu.obs trace
     --selftest` produces a schema-valid unified chrome trace with
     request-lifecycle spans, lifecycle histograms in the embedded
-    snapshot, a held retrace budget over the fused loop, and the
+    snapshot, a held retrace budget over the engine's loop, and the
     deliberately perturbed static named in the retrace-cause table."""
     out = str(tmp_path / "unified.json")
     env = dict(os.environ, JAX_PLATFORMS="cpu")
@@ -550,9 +462,9 @@ def test_obs_trace_cli_selftest_acceptance(tmp_path):
     assert p.returncode == 0, p.stderr[-3000:]
     summary = json.loads(p.stdout)
     assert summary["problems"] == []
-    assert summary["num_traces_loop"] == 1
-    assert {"wrapper": "ServingStep", "key": "logits", "count": 1} \
-        in summary["retrace_causes"]
+    assert summary["num_traces_loop"] == summary["rungs"] == 2
+    assert {"wrapper": "ServingEngine", "key": "params['embed']",
+            "count": 1} in summary["retrace_causes"]
     trace = json.loads(open(out).read())
     cats = {e.get("cat") for e in trace["traceEvents"]}
     assert {"request", "decode", "dispatch", "retrace"} <= cats
@@ -573,8 +485,7 @@ def test_doctor_reports_spans_and_retrace_causes():
     report = json.loads(p.stdout)
     assert report["spans"]["unspanned_serving_ops"] == []
     assert set(report["spans"]["serving_ops"]) == {
-        "serve.step", "serve.mixed_step", "parallel.sharded_step",
-        "engine.step",
+        "parallel.sharded_step", "engine.step",
         # the tiered-KV movements (serve/kv_tier.py, ISSUE 13)
         "engine.kv_spill", "engine.kv_restore", "engine.kv_migrate"}
     assert report["retrace_causes"] == []  # fresh process: nothing hot

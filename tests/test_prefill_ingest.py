@@ -18,9 +18,8 @@ attention kernel.  The bar (ISSUE 14 acceptance):
   units (chunks attention pruned everywhere) still reach the cache,
   and the append-only form serves the ``rope_quantize_fp8_append_
   paged_kv_cache`` reroute with the composed tier as its oracle.
-- The serving adoptions keep their token pins: MixedServingStep
-  fused-vs-composed samples identical tokens; the engine kernel tier
-  dispatches per step by VALUE so the one-trace-per-rung budget holds.
+- The engine kernel tier keeps its token pins and its
+  one-trace-per-rung budget.
 - The analysis registrations (L007 planner pair, L009 knob launch,
   L006 tuning sections) cannot skew from the real modules.
 """
@@ -602,52 +601,6 @@ def test_ingest_cost_family_stats_form():
 # ---------------------------------------------------------------------------
 # serving adoptions
 # ---------------------------------------------------------------------------
-
-
-@pytest.mark.quick
-def test_mixed_step_ingest_token_parity(monkeypatch):
-    """MixedServingStep A/B: the fused-ingest step samples the SAME
-    tokens as the composed step (the engine cross-tier pin's bar), the
-    eager oracle matches bitwise per mode, and continuation steps
-    reject/resolve the knob correctly."""
-    monkeypatch.setenv("FLASHINFER_TPU_BACKEND", "pallas")
-    from flashinfer_tpu.models.llama import LlamaConfig, init_llama_params
-    from flashinfer_tpu.serve.step import MixedServingStep, SamplingConfig
-
-    cfg = LlamaConfig.tiny(num_layers=2, dtype=jnp.float32)
-    params = init_llama_params(jax.random.PRNGKey(0), cfg)
-    qo_lens, kv0 = [11, 5, 19], [0, 0, 0]
-    ppr = 6
-    npages = len(qo_lens) * ppr
-    kvp = np.arange(len(qo_lens) + 1) * ppr
-    kvi = np.arange(npages)
-    flat = jnp.asarray(np.random.default_rng(0).integers(
-        1, cfg.vocab_size, sum(qo_lens)), jnp.int32)
-
-    def mk():
-        z = lambda: jnp.zeros(
-            (npages, cfg.num_kv_heads, PS, cfg.head_dim), cfg.dtype)
-        return [(z(), z()) for _ in range(cfg.num_layers)]
-
-    toks = {}
-    for mode in (True, False):
-        ms = MixedServingStep()
-        ms.plan(cfg, qo_lens, kv0, kvp, kvi, PS,
-                sampling=SamplingConfig(0.8, 7), fused_ingest=mode)
-        assert ms._plan.fused_ingest is mode
-        t, _lg, _cc, _ = ms.run(params, flat, mk(), jax.random.PRNGKey(3))
-        t2, _, _, _ = ms.run_unfused(params, flat, mk(),
-                                     jax.random.PRNGKey(3))
-        np.testing.assert_array_equal(np.asarray(t), np.asarray(t2))
-        toks[mode] = np.asarray(t)
-    np.testing.assert_array_equal(toks[True], toks[False])
-    # chunked continuations: explicit fused raises, auto resolves OFF
-    ms = MixedServingStep()
-    with pytest.raises(ValueError, match="from-scratch"):
-        ms.plan(cfg, [4, 6, 1], [0, 2, 9], kvp, kvi, PS,
-                fused_ingest=True)
-    ms.plan(cfg, [4, 6, 1], [0, 2, 9], kvp, kvi, PS)
-    assert ms._plan.fused_ingest is False
 
 
 def _engine_run(fused, backend, prompts, *, max_new_tokens=4, **statics):

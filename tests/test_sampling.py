@@ -279,3 +279,26 @@ class TestThresholdSelect:
             assert kept[row][top_idx[strict_top]].all()
         # renormalized output still sums to 1
         np.testing.assert_allclose(out.sum(-1), 1.0, rtol=1e-3)
+
+
+def test_sampling_epilogue_matches_pipe():
+    """serve.sample_next_tokens == the LogitsPipe chain it mirrors, over
+    several keys."""
+    from flashinfer_tpu.logits_processor import (
+        LogitsPipe, Sample, Softmax, Temperature, TopK, TopP,
+    )
+    from flashinfer_tpu.serve import SamplingConfig, sample_next_tokens
+
+    pipe = LogitsPipe([Temperature(), Softmax(), TopK(), TopP(), Sample()])
+    logits = jax.random.normal(jax.random.PRNGKey(0), (4, 257),
+                               jnp.float32) * 3.0
+    sampling = SamplingConfig(temperature=0.8, top_k=40, top_p=0.95)
+    for i in range(4):
+        k = jax.random.PRNGKey(i)
+        ref = pipe(logits, key=k, temperature=0.8, top_k=40, top_p=0.95)
+        got = sample_next_tokens(logits, k, sampling)
+        np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
+    # stage-skipping configs legalize too
+    greedy_ish = sample_next_tokens(
+        logits, jax.random.PRNGKey(9), SamplingConfig())
+    assert greedy_ish.shape == (4,)

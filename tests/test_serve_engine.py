@@ -176,11 +176,17 @@ def test_prefix_trie_eviction_lru_and_liveness():
 
 
 @pytest.mark.quick
-def test_engine_matches_stepwise_reference(params):
+@pytest.mark.parametrize("weights", ["f32", "int8"])
+def test_engine_matches_stepwise_reference(params, weights):
     """Anchor against an INDEPENDENT oracle: feed the prompt token by
     token through llama_decode_step (the per-op reference path) and
     greedy-decode; the engine (chunked prefill + two-level cascade
-    windows) must produce the same greedy tokens."""
+    windows) must produce the same greedy tokens, with float32 weights
+    and with every projection on the int8 path."""
+    if weights == "int8":
+        from flashinfer_tpu.models import quantize_llama_weights
+
+        params = quantize_llama_weights(params)
     rng = np.random.default_rng(5)
     prompt = [int(t) for t in rng.integers(1, CFG.vocab_size, 13)]
     max_new = 4

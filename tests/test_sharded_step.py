@@ -1,20 +1,17 @@
-"""Compile-once SHARDED serving step (parallel/plan.py — ISSUE 9).
+"""Compile-once SHARDED serving step (parallel/plan.py).
 
-Pins the mesh tentpole's contracts on the 8-virtual-device CPU mesh:
+Pins the mesh step's contracts on the 8-virtual-device CPU mesh:
 
-- **parity**: the sharded fused step (pjit), the per-op sharded loop,
-  the shard_map fallback, and the UNSHARDED ``serve/shard.py`` step
-  sample token-for-token identical sequences and write bit-identical
-  int8 caches — the int8 pipeline's TP reductions accumulate in int32
-  (order-free), so tp sharding moves no numerics;
-- **compile-once**: >= 8 steps, exactly ONE trace under the mesh;
+- **parity**: the sharded fused step (one ``shard_map`` over the mesh)
+  and the UNSHARDED ``serve/shard.py`` step sample token-for-token
+  identical sequences and write bit-identical int8 caches — the int8
+  pipeline's TP reductions accumulate in int32 (order-free), so tp
+  sharding moves no numerics;
+- **compile-once**: >= 8 steps, exactly ONE trace under the mesh,
+  whether the caller's first state sits on the mesh or not;
 - **donation**: the sharded program carries input->output aliasing for
   the KV caches / page table / lens / key, and a mesh-committed state
   is consumed by the step that takes it;
-- **ServingStep under a plan**: dp-only mesh tokens-BITWISE vs the
-  unsharded step; tp>1 reorders the split f32 contractions — logits
-  agree to reassociation tolerance (documented: bf16/f32 weights,
-  unlike the int8 pipeline's exact int32 psums);
 - **collective cost family**: hand-computed ICI byte pins (ring
   allreduce 2(p-1)/p, EP a2a, sampling gather), the single-chip fixed
   point, the tp8-shard == banked-shape identity, and the ``obs perf``
@@ -36,8 +33,6 @@ from flashinfer_tpu.parallel.plan import (
     ShardedServingStep,
     ShardingPlan,
     build_sharded_fused_step,
-    build_sharded_per_op_step,
-    compile_step_with_plan,
     default_tp,
     plan_axes,
     shard_check,
@@ -139,10 +134,10 @@ def _assert_caches_equal(ca, cb, max_codes=0):
 @pytest.mark.quick
 @pytest.mark.devices_8
 def test_sharded_fused_tokens_bitwise_vs_unsharded():
-    """THE tentpole parity: one GSPMD program over a dp2 x tp4 mesh
-    samples the SAME token sequence as the single-device fused step —
-    sharding is a placement decision, not a numerics change (int32 TP
-    reductions; the docstring contract)."""
+    """THE tentpole parity: one program over a dp2 x tp4 mesh samples
+    the SAME token sequence as the single-device fused step — sharding
+    is a placement decision, not a numerics change (int32 psum before
+    the f32 scale, pmax-amax quantization, logits all-gather)."""
     plan = ShardingPlan(_mesh(2, 4))
     spec, layer_ws, split_ws, mkc, head, head_s, pt0, x0 = _fixture(plan)
     validate_dp_page_table(pt0, NPAGES, plan)
@@ -153,41 +148,6 @@ def test_sharded_fused_tokens_bitwise_vs_unsharded():
     for a, b in zip(t_ref, t_sh):
         np.testing.assert_array_equal(a, b)
     _assert_caches_equal(c_ref, c_sh, max_codes=0)
-
-
-@pytest.mark.devices_8
-def test_sharded_fused_vs_per_op_parity():
-    """The bench A/B substrate on a mesh: identical tokens; caches to
-    <= 1 int8 code (separate XLA programs may fuse the scale multiply
-    differently — the single-chip per-op precedent)."""
-    plan = ShardingPlan(_mesh(2, 4))
-    spec, _, split_ws, mkc, head, head_s, pt0, x0 = _fixture(plan)
-    ta, ca = _chain(build_sharded_fused_step(spec, plan, num_layers=L),
-                    split_ws, mkc, head, head_s, pt0, x0)
-    tb, cb = _chain(build_sharded_per_op_step(spec, plan), split_ws,
-                    mkc, head, head_s, pt0, x0)
-    for a, b in zip(ta, tb):
-        np.testing.assert_array_equal(a, b)
-    _assert_caches_equal(ca, cb, max_codes=1)
-
-
-@pytest.mark.quick
-@pytest.mark.devices_8
-def test_shard_map_fallback_parity_vs_pjit():
-    """The explicit-collective fallback is bit-parity with the GSPMD
-    path: int32 psum before the f32 scale (mirroring the partitioned
-    dot), pmax-amax quantization, logits all-gather."""
-    plan = ShardingPlan(_mesh(2, 4))
-    spec, _, split_ws, mkc, head, head_s, pt0, x0 = _fixture(plan)
-    ta, ca = _chain(build_sharded_fused_step(spec, plan, num_layers=L),
-                    split_ws, mkc, head, head_s, pt0, x0)
-    sm = build_sharded_fused_step(spec, plan, num_layers=L,
-                                  mode="shard_map")
-    tb, cb = _chain(sm, split_ws, mkc, head, head_s, pt0, x0)
-    assert sm.num_traces == 1
-    for a, b in zip(ta, tb):
-        np.testing.assert_array_equal(a, b)
-    _assert_caches_equal(ca, cb, max_codes=0)
 
 
 @pytest.mark.devices_8
@@ -266,10 +226,12 @@ def test_sharded_compile_once_and_donation():
     plan = ShardingPlan(_mesh(2, 4))
     spec, _, split_ws, mkc, head, head_s, pt0, x0 = _fixture(plan)
     fused = build_sharded_fused_step(spec, plan, num_layers=L)
-    caches = mkc()
-    p = jnp.asarray(pt0)
-    lens = jnp.full((BS,), CTX - 1, jnp.int32)
-    sk = jax.random.PRNGKey(3)
+    # lowered as the step calls it, on the plan's layout: an array the
+    # mesh does not hold yet is another argument type to jax.jit
+    x0, split_ws, caches, head, head_s, p, lens, sk = jax.device_put(
+        (x0, split_ws, mkc(), head, head_s, jnp.asarray(pt0),
+         jnp.full((BS,), CTX - 1, jnp.int32), jax.random.PRNGKey(3)),
+        plan.shard_step_shardings(L)[0])
     # structural proof: aliasing annotations in the lowered program
     txt = fused.jitted.lower(x0, split_ws, caches, head, head_s, p,
                              lens, sk).as_text()
@@ -293,9 +255,9 @@ def test_sharded_compile_once_and_donation():
 
 @pytest.mark.devices_8
 def test_sharded_serving_step_lifecycle():
-    """ShardedServingStep plan/run mirrors ServingStep's contract:
-    num_traces pins compile-once, run before plan raises, re-plan
-    counts as replan."""
+    """ShardedServingStep's plan/run contract: run before plan raises,
+    and num_traces pins compile-once from a first state that no device
+    of the mesh holds yet (the step lays it out)."""
     plan = ShardingPlan(_mesh(2, 4))
     spec, _, split_ws, mkc, head, head_s, pt0, x0 = _fixture(plan)
     step = ShardedServingStep()
@@ -313,103 +275,23 @@ def test_sharded_serving_step_lifecycle():
     assert step.num_traces == 1
 
 
-# -------------------------------------------------------------------------
-# ServingStep (llama pytree) under a ShardingPlan
-# -------------------------------------------------------------------------
-
-
-def _llama_setup():
-    from flashinfer_tpu.models import LlamaConfig, init_llama_params
-
-    cfg = LlamaConfig.tiny(num_layers=2, dtype=jnp.float32)
-    params = init_llama_params(jax.random.PRNGKey(0), cfg)
-    B, ps, ppr = 4, 8, 4
-    npages = B * ppr
-    pt0 = np.arange(npages, dtype=np.int32).reshape(B, ppr)
-    lens0 = np.array([3, 5, 2, 7], np.int32)
-    logits0 = jax.random.normal(jax.random.PRNGKey(9),
-                                (B, cfg.vocab_size), jnp.float32)
-
-    def caches():
-        return [(jnp.zeros((npages, cfg.num_kv_heads, ps,
-                            cfg.head_dim), cfg.dtype),
-                 jnp.zeros((npages, cfg.num_kv_heads, ps,
-                            cfg.head_dim), cfg.dtype))
-                for _ in range(cfg.num_layers)]
-
-    return cfg, params, caches, pt0, lens0, logits0
-
-
-def _llama_run(cfg, params, caches, pt0, lens0, logits0, sharding_plan,
-               steps=4):
-    from flashinfer_tpu.serve import SamplingConfig, ServingStep
-
-    step = ServingStep()
-    step.plan(cfg, page_table=jnp.asarray(pt0),
-              kv_lens=jnp.asarray(lens0),
-              sampling=SamplingConfig(0.8, 40, 0.95), use_pallas=False,
-              sharding_plan=sharding_plan)
-    state = step.make_state(caches(), jnp.asarray(pt0),
-                            jnp.asarray(lens0), jnp.array(logits0),
-                            jax.random.PRNGKey(7))
-    toks, logits = [], []
-    for _ in range(steps):
-        t, state = step.run(params, state)
-        toks.append(np.asarray(t))
-        logits.append(np.asarray(state[0]))
-    return toks, logits, step
-
-
-@pytest.mark.quick
-@pytest.mark.devices_8
-def test_serving_step_dp_only_tokens_bitwise():
-    """dp-only sharding moves no contraction axis: the sharded
-    ServingStep is tokens-BITWISE with the unsharded one, still one
-    trace, and the plan statics carry the mesh identity."""
-    setup = _llama_setup()
-    t_ref, _, _ = _llama_run(*setup, None)
-    t_dp, _, step = _llama_run(
-        *setup, ShardingPlan(_mesh(4, 1)))
-    assert step.num_traces == 1
-    assert step.plan_statics.mesh_axes == "dp4.tp1"
-    for a, b in zip(t_ref, t_dp):
-        np.testing.assert_array_equal(a, b)
-
-
-@pytest.mark.devices_8
-def test_serving_step_tp_contraction_tolerance():
-    """tp>1 splits the o/down/qkv f32 contractions: logits agree to
-    reassociation tolerance (NOT bitwise — the documented bf16/f32
-    contrast with the int8 pipeline's exact int32 psums).  The sampled
-    tokens still match here because the fenced sampler sees identical
-    random bits and the logit perturbation (~1e-6) sits far from any
-    sampling threshold at these shapes."""
-    setup = _llama_setup()
-    t_ref, l_ref, _ = _llama_run(*setup, None)
-    t_tp, l_tp, step = _llama_run(
-        *setup, ShardingPlan(_mesh(2, 4)))
-    assert step.num_traces == 1
-    for a, b in zip(t_ref, t_tp):
-        np.testing.assert_array_equal(a, b)
-    for a, b in zip(l_ref, l_tp):
-        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
-        assert not np.array_equal(a, b) or np.max(np.abs(a)) == 0.0
+def test_unsharded_fused_step_donates():
+    """The oracle itself (serve/shard.py build_fused_step) consumes its
+    donated state, and the state it returns replays."""
+    spec, layer_ws, _, mkc, head, head_s, pt0, x0 = _fixture()
+    caches = mkc()
+    kc0 = caches[0][0]
+    step = build_fused_step(spec)
+    tok, caches, p, lens, sk = step(
+        x0, layer_ws, caches, head, head_s, jnp.asarray(pt0),
+        jnp.full((BS,), CTX - 1, jnp.int32), jax.random.PRNGKey(3))
+    assert kc0.is_deleted()
+    step(x0, layer_ws, caches, head, head_s, p, lens, sk)
 
 
 # -------------------------------------------------------------------------
 # plan-table / contract surfaces (no mesh needed)
 # -------------------------------------------------------------------------
-
-
-@pytest.mark.quick
-def test_compile_step_with_plan_half_shardings_raise():
-    plan = ShardingPlan(_mesh(1, 1))
-    with pytest.raises(ValueError, match="BOTH in_shardings"):
-        compile_step_with_plan(lambda x: x, plan,
-                               in_shardings=(plan.replicated,))
-    # neither -> the single-device donated jit degenerate
-    f = compile_step_with_plan(lambda x: x + 1, None)
-    assert int(f(jnp.int32(1))) == 2
 
 
 def test_shard_check_and_page_table_contract():

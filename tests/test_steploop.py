@@ -7,7 +7,7 @@ the SUBPROCESS pin that plain library serving never even imports
 chaining, host_frac / overlap efficiency / Amdahl ceiling, phases with
 children, ``fetch`` and ``post``), the bounded-ring and thread-safety
 contracts, negative-gap and idle-tick semantics, the unified-trace step
-lanes, the engine / ServingStep wiring (sub-phases, device lane, the
+lanes, the engine / ShardedServingStep wiring (sub-phases, device lane, the
 ``engine.*`` spans in a ``jax.profiler`` trace), the
 ``python -m flashinfer_tpu.obs steploop --selftest`` acceptance gate,
 and the perf/6 report's ``host_loop`` section (banked-row Amdahl projection +
@@ -66,28 +66,15 @@ import sys
 import jax
 import jax.numpy as jnp
 from flashinfer_tpu.models import LlamaConfig, init_llama_params
-from flashinfer_tpu.serve import SamplingConfig, ServingStep
+from flashinfer_tpu.serve import EngineConfig, EngineRequest, ServingEngine
 
 cfg = LlamaConfig.tiny(num_layers=1, dtype=jnp.float32)
-params = init_llama_params(jax.random.PRNGKey(0), cfg)
-B, PS, PPR = 1, 8, 2
-npages = B * PPR
-caches = [(jnp.zeros((npages, cfg.num_kv_heads, PS, cfg.head_dim),
-                     cfg.dtype),
-           jnp.zeros((npages, cfg.num_kv_heads, PS, cfg.head_dim),
-                     cfg.dtype))
-          for _ in range(cfg.num_layers)]
-pt = jnp.arange(npages, dtype=jnp.int32).reshape(B, PPR)
-lens = jnp.asarray([3], jnp.int32)
-step = ServingStep()
-step.plan(cfg, page_table=pt, kv_lens=lens,
-          sampling=SamplingConfig(temperature=0.8, top_k=4, top_p=0.95),
-          use_pallas=False)
-logits = jax.random.normal(jax.random.PRNGKey(1), (B, cfg.vocab_size),
-                           jnp.float32)
-state = step.make_state(caches, pt, lens, logits, jax.random.PRNGKey(2))
-for _ in range(2):
-    tokens, state = step.run(params, state)
+eng = ServingEngine(cfg, init_llama_params(jax.random.PRNGKey(0), cfg),
+                    EngineConfig(num_pages=8, page_size=8, max_batch=1,
+                                 prefill_budget_tokens=8,
+                                 max_seq_tokens=16))
+eng.submit(EngineRequest("r0", [3, 1, 4], max_new_tokens=3))
+assert len(eng.run()["r0"]) == 3
 assert "flashinfer_tpu.obs.steploop" not in sys.modules, \
     "gate-off serving imported obs.steploop"
 print("PIN_OK")
@@ -96,7 +83,7 @@ print("PIN_OK")
 
 def test_zero_overhead_subprocess_pin():
     """THE zero-overhead pin: a plain gate-off serving loop (the
-    wired ServingStep surface) must finish without ``obs.steploop``
+    wired ServingEngine surface) must finish without ``obs.steploop``
     ever entering sys.modules — the facade checks the gate BEFORE the
     import, so disabled processes pay nothing, not even module init."""
     env = dict(os.environ, JAX_PLATFORMS="cpu")
@@ -488,36 +475,15 @@ def test_engine_phases_are_profiler_spans(gate_on, tmp_path):
 
 
 @pytest.mark.quick
-def test_serving_step_wiring_device_lane(gate_on):
-    import jax
-    import jax.numpy as jnp
-
-    from flashinfer_tpu.models import LlamaConfig, init_llama_params
-    from flashinfer_tpu.serve import SamplingConfig, ServingStep
-
-    cfg = LlamaConfig.tiny(num_layers=1, dtype=jnp.float32)
-    params = init_llama_params(jax.random.PRNGKey(0), cfg)
-    B, PS, PPR = 2, 8, 2
-    npages = B * PPR
-    caches = [(jnp.zeros((npages, cfg.num_kv_heads, PS, cfg.head_dim),
-                         cfg.dtype),
-               jnp.zeros((npages, cfg.num_kv_heads, PS, cfg.head_dim),
-                         cfg.dtype))
-              for _ in range(cfg.num_layers)]
-    pt = jnp.arange(npages, dtype=jnp.int32).reshape(B, PPR)
-    lens = jnp.asarray([3, 4], jnp.int32)
-    step = ServingStep()
-    step.plan(cfg, page_table=pt, kv_lens=lens,
-              sampling=SamplingConfig(temperature=0.8, top_k=4,
-                                      top_p=0.95), use_pallas=False)
-    logits = jax.random.normal(jax.random.PRNGKey(1),
-                               (B, cfg.vocab_size), jnp.float32)
-    state = step.make_state(caches, pt, lens, logits,
-                            jax.random.PRNGKey(2))
+def test_sharded_step_wiring_device_lane(gate_on, tiny_sharded_step):
+    """The sharded step's ticket: ``signature`` + ``dispatch`` phases, a
+    device lane on every step, steady-state gaps between them."""
+    step, x0, layer_ws, head, head_s, state = tiny_sharded_step
     for _ in range(4):
-        tokens, state = step.run(params, state)
+        _, *state = step.run(x0, layer_ws, state[0], head, head_s,
+                             *state[1:])
     s = steploop.summarize()
-    assert s["surfaces"] == ["ServingStep"]
+    assert s["surfaces"] == ["ShardedServingStep"]
     assert s["steps"] == 4 and s["missing_device_lane"] == 0
     assert {"signature", "dispatch"} <= set(s["phases"])
     assert s["negative_gaps"] == 0
@@ -528,7 +494,7 @@ def test_serving_step_wiring_device_lane(gate_on):
 
 
 def test_steploop_selftest_cli_acceptance(tmp_path):
-    """Acceptance: the 9-step compile-once loop yields a ledger whose
+    """Acceptance: the engine's 9-step loop yields a ledger whose
     decomposition survives every selftest gate (device lane on all
     steps, zero negative gaps, attributed host time, wall-sum within
     5%) and a schema-valid unified trace with the step lanes."""

@@ -115,22 +115,6 @@ def test_api_logging_dump(monkeypatch, tmp_path):
     np.testing.assert_allclose(np.load(dumps[0]), np.arange(4.0))
 
 
-def test_benchmark_harness_quick(tmp_path):
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "fb", "benchmarks/flashinfer_benchmark.py"
-    )
-    fb = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(fb)
-    out = tmp_path / "rows.csv"
-    rc = fb.main(["--routine", "sampling", "--quick", "--csv", str(out)])
-    assert rc == 0
-    lines = out.read_text().strip().splitlines()
-    assert lines[0] == "routine,config,latency_us,tbps,tflops"
-    assert len(lines) == 2 and "sampling_topk_topp" in lines[1]
-
-
 def test_autotuner_cache_and_context(monkeypatch, tmp_path):
     monkeypatch.setenv("FLASHINFER_TPU_CACHE_DIR", str(tmp_path))
     import flashinfer_tpu.autotuner as at
