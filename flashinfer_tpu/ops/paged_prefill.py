@@ -183,7 +183,9 @@ def build_prefill_work_units(
 
     Returns a dict of numpy arrays padded to a power-of-two unit count
     (padding units have ``first=0, wout=0`` and an empty row span so
-    they neither write nor corrupt), plus the static (block_q,
+    they neither write nor corrupt; the real units are written into
+    arrays allocated at that count, so a pad unit costs the host
+    nothing), plus the static (block_q,
     pages_per_chunk) the arrays were built for and a ``stats`` dict
     (unit counts before/after pruning, row/MXU-cell fill — the
     padding-waste numbers the obs histograms report).
@@ -226,7 +228,9 @@ def build_prefill_work_units(
     analogue: packed_custom_mask consumed inside the kernel,
     prefill.cuh:2682).  All-ones windows are demoted to CODE_PARTIAL
     (no expansion) and all-zero windows are pruned, so the expansion
-    dot only runs where the mask actually cuts.  The per-unit re-pack is
+    dot only runs where the mask actually cuts, and only those
+    ``CODE_PARTIAL_MASK`` units get a bitmap: every other row of
+    ``mask_bytes`` is zero and never touched.  The per-unit re-pack is
     the hottest host-plan loop; when the unit enumeration is canonical
     (``pack_tiles=False`` or every qo_len a multiple of ``block_q``) it
     runs in the C++ planner (csrc/planner.cpp prefill_mask_plan) and the
@@ -310,8 +314,8 @@ def build_prefill_work_units(
 
     # ---- classify + prune (canonical index kept for the native-mask
     #      row selection) ---------------------------------------------------
-    # unit: [qstart, rowlo, rowhi, qpos0, kvstart, kvlen, code, pages,
-    #        tile_key, canon_idx, request]
+    # unit: [qstart, rowlo, rowhi, qpos0, kvstart, kvlen, code, kv chunk
+    #        (-1: none), tile_key, canon_idx, request]
     units = []
     canon_idx = 0
     n_pruned = 0
@@ -321,9 +325,6 @@ def build_prefill_work_units(
     for ts, rowlo, rowhi, r in spans:
         qs, qe = int(qo_indptr[r]), int(qo_indptr[r + 1])
         kv_len = int(kv_lens[r])
-        pages = kv_page_indices[
-            int(kv_page_indptr[r]) : int(kv_page_indptr[r + 1])
-        ]
         qpos0 = kv_len - (qe - qs) + (ts - qs)
         n_chunks = max(cdiv(kv_len, chunk_tokens), 1) if kv_len > 0 else 1
         if mask_flat is not None and kv_len > 0:
@@ -368,10 +369,8 @@ def build_prefill_work_units(
                 code = CODE_PARTIAL_MASK
             else:
                 code = CODE_PARTIAL
-            pg = pages[c * pages_per_chunk : (c + 1) * pages_per_chunk]
-            pg = np.pad(pg, (0, pages_per_chunk - len(pg)))
             units.append([ts, rowlo, rowhi, qpos0, kvstart, kv_len, code,
-                          pg, ts if packed else (ts, r), ci, r])
+                          c, ts if packed else (ts, r), ci, r])
             if ingest and (r, c) not in covered:
                 covered.add((r, c))
                 wkv.append(1)
@@ -383,8 +382,7 @@ def build_prefill_work_units(
             # an accumulator reset + write-back so those rows emit zeros
             # (attention over the empty set) instead of uninitialized HBM
             units.append([ts, rowlo, rowlo, qpos0, 0, 0, CODE_PARTIAL,
-                          np.zeros(pages_per_chunk, np.int64),
-                          ts if packed else (ts, r), -1, r])
+                          -1, ts if packed else (ts, r), -1, r])
             wkv.append(0)
 
     # ---- first/wout flags + q slots per tile -----------------------------
@@ -420,14 +418,9 @@ def build_prefill_work_units(
             for c in range(cdiv(kv_len, chunk_tokens)):
                 if (r, c) in covered:
                     continue
-                pages = kv_page_indices[
-                    int(kv_page_indptr[r]) : int(kv_page_indptr[r + 1])
-                ]
-                pg = pages[c * pages_per_chunk : (c + 1) * pages_per_chunk]
-                pg = np.pad(pg, (0, pages_per_chunk - len(pg)))
                 wo_units.append(
                     [units[0][0] if units else 0, 0, 0, 0,
-                     c * chunk_tokens, kv_len, CODE_WRITE_ONLY, pg,
+                     c * chunk_tokens, kv_len, CODE_WRITE_ONLY, c,
                      None, -1, r])
         n_write_only = len(wo_units)
         if wo_units:
@@ -458,6 +451,10 @@ def build_prefill_work_units(
     else:
         U = max(next_power_of_two(max(n_real, 1)), 8)
     n_mxu = n_real - n_write_only  # write-only units run no MXU dot
+    # the real units as columns; everything past them is the pad unit
+    head = np.asarray([u[:8] for u in units], np.int64).reshape(n_real, 8)
+    req = np.asarray([u[10] for u in units], np.int64)
+    rows_valid = head[:, 2] - head[:, 1]
     stats = {
         "units": n_real,
         "units_canonical": canon_idx,
@@ -465,12 +462,10 @@ def build_prefill_work_units(
         "tiles": tile_ord + 1,
         "packed": bool(packed),
         "unit_rows_total": n_mxu * block_q,
-        "unit_rows_valid": int(sum(u[2] - u[1] for u in units)),
+        "unit_rows_valid": int(rows_valid.sum()),
         "mxu_cells_total": n_mxu * block_q * chunk_tokens,
-        "mxu_cells_valid": int(sum(
-            (u[2] - u[1]) * max(min(chunk_tokens, u[5] - u[4]), 0)
-            for u in units
-        )),
+        "mxu_cells_valid": int((rows_valid * np.clip(
+            head[:, 5] - head[:, 4], 0, chunk_tokens)).sum()),
     }
     if ingest:
         stats["ingest_write_only_units"] = n_write_only
@@ -478,26 +473,34 @@ def build_prefill_work_units(
         # the cost model prices): one owner unit per (request, chunk)
         stats["ingest_chunks"] = int(sum(wkv))
         stats["ingest_kv_aligned"] = ingest_rows_aligned(
-            [kv_bases[u[10]] for u in units if u[10] >= 0], chunk_tokens)
-    # pad units: first=0 (no q fetch/wait), wout=0 (never write), empty
-    # row span + kvlen 0 (identity online-softmax steps)
-    pad_unit = [0, 0, 0, 0, 0, 0, CODE_PARTIAL,
-                np.zeros(pages_per_chunk, np.int64), None, -1, -1]
-    while len(units) < U:
-        units.append(pad_unit)
-        first.append(0)
-        wout.append(0)
-        qslot.append(0)
-        wkv.append(0)
+            kv_bases[req], chunk_tokens)
 
-    arr = lambda i, dt: np.asarray([u[i] for u in units], dt)
+    # pad units are what zeros say: first=0 (no q fetch/wait), wout=0
+    # (never write), empty row span + kvlen 0 (identity online-softmax
+    # steps), page 0; only their code is not 0
+    def col(values, fill=0):
+        out = np.full(U, fill, np.int32)
+        out[:n_real] = values
+        return out
+
+    # a unit's pages: chunk ``c`` of its request's page run, zeros past
+    # the run's end (and for a fallback unit, whose chunk is -1)
+    page_indptr = np.asarray(kv_page_indptr, np.int64)
+    p0 = page_indptr[req] + np.maximum(head[:, 7], 0) * pages_per_chunk
+    p1 = np.where(head[:, 7] >= 0, page_indptr[req + 1], p0)
+    idx = p0[:, None] + np.arange(pages_per_chunk)[None, :]
+    held = idx < p1[:, None]
+    pages = np.zeros((U, pages_per_chunk), np.int32)
+    if held.any():
+        pages[:n_real] = np.where(
+            held, np.asarray(kv_page_indices)[np.where(held, idx, 0)], 0)
     plan = dict(
-        qstart=arr(0, np.int32), rowlo=arr(1, np.int32),
-        rowhi=arr(2, np.int32), qpos0=arr(3, np.int32),
-        kvstart=arr(4, np.int32), kvlen=arr(5, np.int32),
-        first=np.asarray(first, np.int32), wout=np.asarray(wout, np.int32),
-        qslot=np.asarray(qslot, np.int32), code=arr(6, np.int32),
-        pages=np.stack([u[7] for u in units]).astype(np.int32).reshape(-1),
+        qstart=col(head[:, 0]), rowlo=col(head[:, 1]),
+        rowhi=col(head[:, 2]), qpos0=col(head[:, 3]),
+        kvstart=col(head[:, 4]), kvlen=col(head[:, 5]),
+        first=col(first), wout=col(wout), qslot=col(qslot),
+        code=col(head[:, 6], CODE_PARTIAL),
+        pages=pages.reshape(-1),
         n_units=np.int32(max(n_real, 1)),
         num_units=U,
         block_q=block_q,
@@ -505,15 +508,11 @@ def build_prefill_work_units(
         stats=stats,
     )
     if ingest:
-        # per-unit raw-row base + global-position offset (pad units and
-        # kv-less fallbacks read harmless row 0 / offset 0)
-        plan["kvbase"] = np.asarray(
-            [int(kv_bases[u[10]]) if u[10] >= 0 else 0 for u in units],
-            np.int32)
-        plan["posoff"] = np.asarray(
-            [int(pos_offsets[u[10]]) if u[10] >= 0 else 0 for u in units],
-            np.int32)
-        plan["wkv"] = np.asarray(wkv, np.int32)
+        # per-unit raw-row base + global-position offset (pad units
+        # read harmless row 0 / offset 0)
+        plan["kvbase"] = col(kv_bases[req])
+        plan["posoff"] = col(pos_offsets[req])
+        plan["wkv"] = col(wkv)
     if mask_flat is not None:
         plan["mask_bytes"] = _build_unit_masks(
             units, U, qo_indptr, kv_lens, mask_bits, mask_native,
@@ -526,10 +525,13 @@ def build_prefill_work_units(
 def _build_unit_masks(units, U, qo_indptr, kv_lens, mask_bits, mask_native,
                       mask_total_bits, mask_offsets, block_q, chunk_tokens,
                       packed, n_canonical):
-    """Per-unit packed bitmaps [U, block_q, mask_lane_bytes].
+    """Per-unit packed bitmaps [U, block_q, mask_lane_bytes]: the window
+    of the mask of each ``CODE_PARTIAL_MASK`` unit, the only units whose
+    bitmap the kernel expands; every other row stays zero (and, the
+    array being ``np.zeros``, is never touched).
 
     Canonical enumeration -> the C++ planner builds bitmaps for ALL
-    canonical units in one pass and the kept units row-select from it
+    canonical units in one pass and the mask units row-select from it
     (pruning removes whole units, never rewrites a bitmap); packed
     enumeration (tile rows offset into the request) -> numpy per-tile
     extraction."""
@@ -537,6 +539,10 @@ def _build_unit_masks(units, U, qo_indptr, kv_lens, mask_bits, mask_native,
 
     mb = mask_lane_bytes(chunk_tokens)
     out = np.zeros((U, block_q, mb), np.uint8)
+    masked = [(i, u) for i, u in enumerate(units)
+              if u[6] == CODE_PARTIAL_MASK]
+    if not masked:
+        return out
     if not packed and native.get_lib() is not None:
         # mask_native is the caller's ORIGINAL packed-bytes form when one
         # was supplied — the C++ planner reads LSB-first bytes directly,
@@ -546,21 +552,12 @@ def _build_unit_masks(units, U, qo_indptr, kv_lens, mask_bits, mask_native,
             qo_indptr, np.asarray(kv_lens, np.int64),
             block_q, chunk_tokens, mb, max(n_canonical, 1),
         )
-        for i, u in enumerate(units):
-            if u[9] >= 0:
-                out[i] = canon[u[9]]
+        for i, u in masked:
+            out[i] = canon[u[9]]
         return out
-    for i, u in enumerate(units):
-        ts, rowlo, rowhi, _qpos0, kvstart, kv_len, _code, _pg, key, ci = \
-            u[:10]
-        if ci < 0 or rowhi <= rowlo or kv_len <= kvstart:
-            continue
-        r = key[1] if isinstance(key, tuple) else None
-        if r is None:
-            # packed tile key carries no request id; recover it from the
-            # row span (rows [ts+rowlo, ts+rowhi) lie inside one request)
-            tok = ts + rowlo
-            r = int(np.searchsorted(qo_indptr, tok, side="right") - 1)
+    for i, u in masked:
+        ts, rowlo, rowhi, _qpos0, kvstart, kv_len = u[:6]
+        r = u[10]
         qs, qe = int(qo_indptr[r]), int(qo_indptr[r + 1])
         req = mask_bits[mask_offsets[r] : mask_offsets[r + 1]].reshape(
             qe - qs, kv_len

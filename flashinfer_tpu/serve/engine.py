@@ -80,6 +80,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from flashinfer_tpu.api_logging import flashinfer_api
+from flashinfer_tpu.serve import handover as _handover
 from flashinfer_tpu.serve.step import SamplingConfig
 
 _NO_SPAN = contextlib.nullcontext()
@@ -1242,8 +1243,12 @@ class ServingEngine:
         requests sampled / finished, the kernel tier's work units and
         cells useful against launched (``_UNIT_FACTS``), the pages its
         K/V rows were written to (``kv_pages_written``, of
-        ``kv_pages_pool`` usable ones) and the queue wait of the requests
-        whose first prompt chunk ran in it."""
+        ``kv_pages_pool`` usable ones), the queue wait of the requests
+        whose first prompt chunk ran in it, and what it handed to the
+        device: ``upload_kib`` of host arrays in ``upload_transfers``
+        buffers, ``plans_lowered`` (1 on the kernel tier), level 0's
+        ``mask_units`` and whether the whole bitmap crossed for them
+        (``mask_full_uploads``)."""
         from flashinfer_tpu import obs
 
         t_step = time.perf_counter()
@@ -1291,7 +1296,8 @@ class ServingEngine:
         samplers: List[EngineRequest] = []
         facts = dict.fromkeys(
             ("prefill_tokens", "decode_tokens", "first_scheduled",
-             "kv_pages_written", *self._UNIT_FACTS), 0)
+             "kv_pages_written", *self._UNIT_FACTS, "plans_lowered",
+             "mask_units", "mask_full_uploads"), 0)
         facts.update(step=self._steps, rung=rung, tokens=total,
                      queue_wait_s=0.0, kv_pages_pool=cfg.num_pages - 1)
 
@@ -1400,7 +1406,11 @@ class ServingEngine:
             us["kv_rows_launched"] += (st["prefill_rows_launched"]
                                        + st["decode_rows_launched"])
             with _span(tick, "lower.upload"):
-                kplans = _ek.plans_to_device(plans)
+                kplans = _ek.plans_to_host(
+                    plans, self._geom.mask_unit_cap(rung))
+            facts.update(
+                plans_lowered=1, mask_units=st["mask_units"],
+                mask_full_uploads=int("mask_bytes" in kplans["prefill0"]))
         if self._block is not None:
             from flashinfer_tpu.serve import recurrent as _rec
 
@@ -1415,16 +1425,24 @@ class ServingEngine:
         if tick is not None:
             tick.enter("dispatch")
 
+        # everything the step reads of this schedule crosses together
+        # (serve/handover.py): the schedule arrays, the plans, a model's
+        # own plan; a whole bitmap crosses as it did, a buffer a leaf
+        host = dict(sched=(flat, pos, tok_req, token_page, token_slot,
+                           page_table, grp_pages, tok_grp, split, last_rows,
+                           sample_seeds), kplans=kplans,
+                    mplan=mplan if self._block is not None else {})
         with _span(tick, "dispatch.upload"):
-            full_args = (self.params, jnp.asarray(flat), jnp.asarray(pos),
-                         jnp.asarray(tok_req), jnp.asarray(token_page),
-                         jnp.asarray(token_slot), jnp.asarray(page_table),
-                         jnp.asarray(grp_pages), jnp.asarray(tok_grp),
-                         jnp.asarray(split), jnp.asarray(last_rows),
-                         jnp.asarray(sample_seeds), kplans, self.caches)
+            dev, facts["upload_transfers"], facts["upload_kib"] = \
+                _handover.to_device(
+                    host, packed=not facts["mask_full_uploads"],
+                    finish=(_ek.step_inputs_on_device
+                            if self._kernel_backend else None))
+            full_args = (self.params, *dev["sched"], dev["kplans"],
+                         self.caches)
         names = self._STATE_NAMES
         if self._block is not None:
-            full_args += ({k: jnp.asarray(v) for k, v in mplan.items()},)
+            full_args += (dev["mplan"],)
             names += ("mplan",)
         sig = obs.state_signature(full_args, names=names)
         seen = self._rung_traced.get(rung, 0)

@@ -48,13 +48,21 @@ Every flat row — scheduled or rung padding — is covered by a plan
 segment (padding rides trailing ``kv_len = 0`` segments), so both
 levels emit defined ``(0, -inf)`` empty states for unused rows instead
 of uninitialized HBM.
+
+Those shapes are what the step's program takes, not what crosses to the
+device: :func:`plans_to_host` sends of the level-0 bitmap the rows of
+the units that read one, and :func:`plans_on_device` spreads them into
+the rung's ``[U, block_q, mb]`` there (serve/handover.py has the rest of
+the crossing).
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from flashinfer_tpu.utils import cdiv, next_power_of_two, round_up
@@ -143,6 +151,13 @@ class EngineKernelGeom:
         spans = cdiv(rung, self.block_q) + 2 * (self.max_batch + 1)
         return max(next_power_of_two(spans * self.max_prefill_chunks), 8)
 
+    def mask_unit_cap(self, rung: int) -> int:
+        """Level-0 units whose bitmap rows a step sends by themselves
+        (:func:`plans_to_host`): the units on the diagonal of a chunk
+        prefilled inside its own span, two or so a q tile.  A step with
+        more sends the whole bitmap."""
+        return min(32, self.prefill_unit_cap(rung))
+
 
 # plan-dict keys that ride into the jitted engine body (arrays only —
 # statics and stats stay host-side so the traced pytree is rung-stable)
@@ -185,7 +200,8 @@ def build_engine_work_units(
     engine's compile-once contract.
     """
     from flashinfer_tpu.ops.paged_decode import build_decode_split_units
-    from flashinfer_tpu.ops.paged_prefill import build_prefill_work_units
+    from flashinfer_tpu.ops.paged_prefill import (CODE_PARTIAL_MASK,
+                                                  build_prefill_work_units)
 
     ps = geom.page_size
     total = segs[-1].row0 + segs[-1].n if segs else 0
@@ -313,26 +329,74 @@ def build_engine_work_units(
         "decode_cells_launched": float(
             dplan["stats"]["pages_launched"] * ps),
         "decode_cells_valid": float(dec_lens.sum()),
+        # level-0 units whose bitmap the launch expands
+        "mask_units": int(np.count_nonzero(
+            plan0["code"] == CODE_PARTIAL_MASK)),
     }
     return dict(prefill0=plan0, prefill1=plan1, decode=dplan,
                 dec_rows=dec_rows, stats=stats)
 
 
-def plans_to_device(plans: Dict) -> Dict:
-    """The rung-stable traced pytree of a plan bundle: array leaves
-    only (statics/stats stripped), in a FIXED key layout so the jitted
-    body never sees a structure change."""
-    import jax.numpy as jnp
+def plans_to_host(plans: Dict, mask_cap: Optional[int] = None) -> Dict:
+    """What a plan bundle's launches read, as host arrays: array leaves
+    only (statics/stats stripped), in a FIXED key layout.
 
+    Level 0's bitmap is ``[U, block_q, mb]`` (33.5 MB at the cells'
+    statics) and the launch expands it for ``CODE_PARTIAL_MASK`` units
+    alone, a handful a step.  Where a step has at most ``mask_cap`` of
+    them the bundle carries their rows (``mask_rows``, ``[mask_cap,
+    block_q, mb]``) and unit indices (``mask_idx``, unused entries out
+    of range) in ``mask_bytes``' place, for :func:`plans_on_device` to
+    spread; a step with more, or ``mask_cap=None``, carries the whole
+    array."""
+    from flashinfer_tpu.ops.paged_prefill import CODE_PARTIAL_MASK
+
+    p0 = plans["prefill0"]
+    lvl0 = {k: p0[k] for k in PREFILL_ARRAY_KEYS if k != "mask_bytes"}
+    units = np.flatnonzero(p0["code"] == CODE_PARTIAL_MASK)
+    if mask_cap is None or len(units) > mask_cap:
+        lvl0["mask_bytes"] = p0["mask_bytes"]
+    else:
+        idx = np.full(mask_cap, len(p0["code"]), np.int32)
+        idx[:len(units)] = units
+        rows = np.zeros((mask_cap,) + p0["mask_bytes"].shape[1:], np.uint8)
+        rows[:len(units)] = p0["mask_bytes"][units]
+        lvl0["mask_idx"], lvl0["mask_rows"] = idx, rows
     return dict(
-        prefill0={k: jnp.asarray(plans["prefill0"][k])
-                  for k in PREFILL_ARRAY_KEYS},
-        prefill1={k: jnp.asarray(plans["prefill1"][k])
+        prefill0=lvl0,
+        prefill1={k: plans["prefill1"][k]
                   for k in PREFILL_ARRAY_KEYS if k != "mask_bytes"},
-        decode={k: jnp.asarray(plans["decode"][k])
-                for k in DECODE_ARRAY_KEYS},
-        dec_rows=jnp.asarray(plans["dec_rows"]),
+        decode={k: plans["decode"][k] for k in DECODE_ARRAY_KEYS},
+        dec_rows=plans["dec_rows"],
     )
+
+
+def plans_on_device(kplans: Dict) -> Dict:
+    """The rung-stable traced pytree of a plan bundle, from
+    :func:`plans_to_host`'s tree once it is on the device: the step's
+    leaves, dtypes and shapes whichever way the bitmap crossed.  Bitmap
+    rows are spread into zeros ``[U, block_q, mb]``, ``mask_rows[i]`` at
+    unit ``mask_idx[i]`` (an index out of range writes nothing): outside
+    the step's program, in the one that cuts its inputs."""
+    p0 = dict(kplans["prefill0"])
+    if "mask_rows" in p0:
+        idx, rows = p0.pop("mask_idx"), p0.pop("mask_rows")
+        p0["mask_bytes"] = jnp.zeros(
+            p0["code"].shape + rows.shape[1:], rows.dtype
+        ).at[idx].set(rows, mode="drop")
+    return dict(kplans, prefill0=p0)
+
+
+def step_inputs_on_device(host: Dict) -> Dict:
+    """:func:`plans_on_device` over the ``kplans`` of a step's inputs
+    (what ``serve/handover.py`` runs inside its cutting program)."""
+    return dict(host, kplans=plans_on_device(host["kplans"]))
+
+
+def plans_to_device(plans: Dict) -> Dict:
+    """A plan bundle's traced pytree the plain way: every leaf, the whole
+    bitmap, a buffer each."""
+    return jax.device_put(plans_to_host(plans))
 
 
 def engine_kernel_attention(q, k_cache, v_cache, kplans, *,

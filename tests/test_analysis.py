@@ -951,8 +951,8 @@ def test_l007_flags_plan_key_the_planner_never_emits():
     (planner -> launch) half of the contract."""
     real = open(OPS_PREFILL).read()
     dropkey = real.replace(
-        "qslot=np.asarray(qslot, np.int32), code=arr(6, np.int32),",
-        "code=arr(6, np.int32),")
+        "first=col(first), wout=col(wout), qslot=col(qslot),",
+        "first=col(first), wout=col(wout),")
     assert dropkey != real
     from flashinfer_tpu.analysis import pallas_contract
 

@@ -297,6 +297,65 @@ _UNIT_SCHEDULES = {
                  decoding=True, slot=0, group=0)],
 }
 
+# three lanes past their prompts, two of them on one prefix: level 0 is a
+# one-row unit a group row (each lane attending its prompt's full pages),
+# no mask cuts, level 1 is fallback units only.  Kept out of
+# _UNIT_SCHEDULES: its unit count equals "mixed"'s.
+_DECODE_ONLY = [
+    SchedSeg(row0=0, n=1, pages=(1, 2, 3), split=16, kv_after=21,
+             decoding=True, slot=0, group=0),
+    SchedSeg(row0=1, n=1, pages=(4, 5), split=8, kv_after=12,
+             decoding=True, slot=2, group=1),
+    SchedSeg(row0=2, n=1, pages=(1, 2, 6), split=16, kv_after=18,
+             decoding=True, slot=3, group=0)]
+_LOWERED_SCHEDULES = dict(_UNIT_SCHEDULES, decode_only=_DECODE_ONLY)
+
+# What the planner of PR 30 gives for these schedules at rung 16 (read off
+# that tree): each level's REAL units in order, one row a unit:
+# (qstart, rowlo, rowhi, qpos0, kvstart, kvlen, first, wout, qslot, code,
+# the unit's pages before the zeros).
+_UNIT_KEYS = ("qstart", "rowlo", "rowhi", "qpos0", "kvstart", "kvlen",
+              "first", "wout", "qslot", "code")
+_PARENT_UNITS = {
+    ("mixed", "prefill0"): [
+        (0, 0, 0, -1, 0, 0, 1, 0, 0, 1, ()),
+        (0, 1, 1, -2, 0, 0, 0, 0, 0, 1, ()),
+        (0, 2, 2, -8, 0, 0, 0, 0, 0, 1, ()),
+        (0, 8, 8, -13, 0, 0, 0, 0, 0, 1, ()),
+        (0, 13, 13, -16, 0, 0, 0, 1, 0, 1, ())],
+    ("mixed", "prefill1"): [
+        (0, 0, 0, -1, 0, 0, 1, 0, 0, 1, ()),
+        (0, 1, 1, -2, 0, 0, 0, 0, 0, 1, ()),
+        (0, 2, 8, 11, 0, 19, 0, 0, 0, 1, (6, 7, 8)),
+        (0, 8, 13, -8, 0, 5, 0, 0, 0, 1, (9,)),
+        (0, 13, 13, -16, 0, 0, 0, 1, 0, 1, ())],
+    ("shared_prefix", "prefill0"): [
+        (0, 0, 8, 8, 0, 16, 1, 0, 0, 2, (1, 2)),
+        (0, 8, 8, -11, 0, 0, 0, 0, 0, 1, ()),
+        (0, 11, 11, -16, 0, 0, 0, 1, 0, 1, ())],
+    ("shared_prefix", "prefill1"): [
+        (0, 0, 0, -1, 0, 0, 1, 0, 0, 1, ()),
+        (0, 1, 8, -5, 0, 3, 0, 0, 0, 1, (4,)),
+        (0, 8, 11, -8, 0, 3, 0, 0, 0, 1, (5,)),
+        (0, 11, 11, -16, 0, 0, 0, 1, 0, 1, ())],
+    ("fallback_only", "prefill0"): [
+        (0, 0, 0, -1, 0, 0, 1, 0, 0, 1, ()),
+        (0, 1, 1, -16, 0, 0, 0, 1, 0, 1, ())],
+    ("fallback_only", "prefill1"): [
+        (0, 0, 0, -1, 0, 0, 1, 0, 0, 1, ()),
+        (0, 1, 1, -16, 0, 0, 0, 1, 0, 1, ())],
+    ("decode_only", "prefill0"): [
+        (0, 0, 1, 15, 0, 16, 1, 0, 0, 1, (1, 2)),
+        (0, 1, 2, 6, 0, 8, 0, 0, 0, 1, (4,)),
+        (0, 2, 3, 13, 0, 16, 0, 0, 0, 1, (1, 2)),
+        (0, 3, 3, -16, 0, 0, 0, 1, 0, 1, ())],
+    ("decode_only", "prefill1"): [
+        (0, 0, 0, -1, 0, 0, 1, 0, 0, 1, ()),
+        (0, 1, 1, -2, 0, 0, 0, 0, 0, 1, ()),
+        (0, 2, 2, -3, 0, 0, 0, 0, 0, 1, ()),
+        (0, 3, 3, -16, 0, 0, 0, 1, 0, 1, ())],
+}
+
 
 def test_planner_counts_the_units_it_launches():
     """``n_units`` is the plan's count of real units, at least one per
@@ -379,6 +438,179 @@ def test_dynamic_unit_bound_is_bitwise_the_static_grid(schedule):
         launch(build_engine_work_units(_UNIT_SCHEDULES[other], rung=16,
                                        geom=g), "prefill0", True)
     assert fused_paged_prefill._cache_size() == traced
+
+
+def _assert_plans_are_the_parents(name, plans, g, rung=16):
+    """Every plan array against the parent's rule: the real units first,
+    then the pad unit (zeros, code CODE_PARTIAL) up to the rung's cap; a
+    bitmap for the units whose mask cuts and zeros for every other."""
+    from flashinfer_tpu.ops.paged_prefill import (CODE_PARTIAL,
+                                                  CODE_PARTIAL_MASK)
+
+    segs, U, ps = _LOWERED_SCHEDULES[name], g.prefill_unit_cap(rung), \
+        g.page_size
+    for lvl in ("prefill0", "prefill1"):
+        p, units = plans[lvl], _PARENT_UNITS[name, lvl]
+        assert p["n_units"] == len(units) and p["n_units"].dtype == np.int32
+        for i, k in enumerate(_UNIT_KEYS):
+            want = np.full(U, CODE_PARTIAL if k == "code" else 0, np.int32)
+            want[:len(units)] = [u[i] for u in units]
+            assert p[k].dtype == np.int32, (lvl, k)
+            np.testing.assert_array_equal(p[k], want, err_msg=f"{lvl} {k}")
+        pages = np.zeros((U, g.prefill_ppc), np.int32)
+        for i, u in enumerate(units):
+            pages[i, :len(u[-1])] = u[-1]
+        assert p["pages"].dtype == np.int32
+        np.testing.assert_array_equal(p["pages"], pages.reshape(-1))
+    assert "mask_bytes" not in plans["prefill1"]
+    # level 0's bitmap: causal by GLOBAL position over the group's run,
+    # ``kv_row <= pos``, for the rows of a unit whose mask cuts
+    pos = np.zeros(rung, np.int64)
+    for e in segs:
+        pos[e.row0:e.row0 + e.n] = np.arange(e.kv_after - e.n, e.kv_after)
+    p0 = plans["prefill0"]
+    chunk = g.prefill_ppc * ps
+    want = np.zeros((U, g.block_q, chunk // 8), np.uint8)
+    for u in np.flatnonzero(p0["code"] == CODE_PARTIAL_MASK):
+        bits = np.zeros((g.block_q, chunk), bool)
+        lo, hi = p0["rowlo"][u], p0["rowhi"][u]
+        cols = p0["kvstart"][u] + np.arange(
+            min(chunk, p0["kvlen"][u] - p0["kvstart"][u]))
+        bits[lo:hi, :len(cols)] = cols[None, :] <= pos[
+            p0["qstart"][u] + lo:p0["qstart"][u] + hi, None]
+        want[u] = np.packbits(bits, axis=-1, bitorder="little")
+    mb = p0["mask_bytes"]
+    assert mb.dtype == np.uint8 and mb.shape[:2] == want.shape[:2]
+    np.testing.assert_array_equal(mb[..., :chunk // 8], want)
+    assert not mb[..., chunk // 8:].any()
+    assert plans["stats"]["mask_units"] == np.count_nonzero(
+        p0["code"] == CODE_PARTIAL_MASK) == (name == "shared_prefix")
+    # the decode lanes: each slot's suffix pages and length, one unit a
+    # slot (num_splits 1), the lane's flat row (the rung where idle)
+    d = plans["decode"]
+    table = np.zeros((g.max_batch, g.dec_width), np.int32)
+    lens = np.zeros(g.max_batch, np.int32)
+    rows = np.full(g.max_batch, rung, np.int32)
+    for e in segs:
+        if e.decoding:
+            sfx = e.pages[e.split // ps:-(-e.kv_after // ps)]
+            table[e.slot, :len(sfx)] = sfx
+            lens[e.slot], rows[e.slot] = e.kv_after - e.split, e.row0
+    for k, want in (("pages", table), ("kvlen", lens), ("wu_kvlen", lens),
+                    ("wu_req", np.arange(g.max_batch, dtype=np.int32)),
+                    ("wu_page0", np.zeros(g.max_batch, np.int32))):
+        assert d[k].dtype == np.int32, k
+        np.testing.assert_array_equal(d[k], want, err_msg=f"decode {k}")
+    assert plans["dec_rows"].dtype == np.int32
+    np.testing.assert_array_equal(plans["dec_rows"], rows)
+
+
+def _leaves_equal(a, b):
+    la, ta = jax.tree_util.tree_flatten(a)
+    lb, tb = jax.tree_util.tree_flatten(b)
+    assert ta == tb
+    for x, y in zip(la, lb):
+        assert x.dtype == y.dtype and x.shape == y.shape
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+@pytest.mark.parametrize("after_larger", [False, True],
+                         ids=["fresh", "after_larger_step"])
+@pytest.mark.parametrize("schedule", sorted(_LOWERED_SCHEDULES))
+def test_lowering_builds_and_sends_what_the_launches_read(schedule,
+                                                          after_larger):
+    """ISSUE 31: the plans a step is lowered to are the parent's, element
+    for element, but for bitmap rows no launch reads; the bitmap spread on
+    the device from the rows that crossed is the host's whole array; a
+    step with more mask units than the cap sends the whole array and the
+    step's leaves are the same - and none of it depends on what the rung
+    lowered before (a larger step first: no stale tail)."""
+    from flashinfer_tpu.serve import handover
+    from flashinfer_tpu.serve.engine_kernels import (
+        plans_on_device, plans_to_device, plans_to_host,
+        step_inputs_on_device)
+
+    g = _geom()
+
+    def lower(segs, cap):
+        plans = build_engine_work_units(segs, rung=16, geom=g)
+        host = plans_to_host(plans, cap)
+        return plans, host, plans_on_device(jax.device_put(host))
+
+    if after_larger:
+        lower(_UNIT_SCHEDULES["shared_prefix"], g.mask_unit_cap(16))
+        lower(_UNIT_SCHEDULES["mixed"], g.mask_unit_cap(16))
+    plans, host, dev = lower(_LOWERED_SCHEDULES[schedule],
+                             g.mask_unit_cap(16))
+    _assert_plans_are_the_parents(schedule, plans, g)
+    # the rows crossed by themselves ...
+    h0 = host["prefill0"]
+    assert "mask_bytes" not in h0
+    assert h0["mask_rows"].shape == (g.mask_unit_cap(16),) \
+        + plans["prefill0"]["mask_bytes"].shape[1:]
+    n = plans["stats"]["mask_units"]
+    assert (h0["mask_idx"][n:] == g.prefill_unit_cap(16)).all()
+    assert not h0["mask_rows"][n:].any()
+    # ... and the step's leaves are what the whole array would have given
+    whole = plans_to_device(plans)          # the parent's way: no cap
+    assert "mask_bytes" in plans_to_host(plans)["prefill0"]
+    _leaves_equal(dev, whole)
+    np.testing.assert_array_equal(np.asarray(dev["prefill0"]["mask_bytes"]),
+                                  plans["prefill0"]["mask_bytes"])
+    # over the cap: the whole array, the same leaves
+    _, host0, dev0 = lower(_LOWERED_SCHEDULES[schedule], 0)
+    assert ("mask_bytes" in host0["prefill0"]) == (n > 0)
+    _leaves_equal(dev0, whole)
+    # the way step() sends them: one int32 buffer and the bitmap rows,
+    # cut into the step's leaves by one program; the whole bitmap a
+    # buffer a leaf and no program
+    inputs = dict(sched=(np.arange(16, dtype=np.int32),
+                         np.arange(32, dtype=np.int32).reshape(4, 8)),
+                  kplans=host, mplan={"n_tokens": np.int32(11)})
+    got, sent, kib = handover.to_device(inputs,
+                                        finish=step_inputs_on_device)
+    cuts = handover.cut._cache_size()
+    leaves = jax.tree_util.tree_leaves(inputs)
+    assert sent == 2 and kib == sum(x.nbytes for x in leaves) / 1024
+    _leaves_equal(got, dict(jax.device_put(inputs), kplans=whole))
+    inputs0 = dict(inputs, kplans=plans_to_host(plans))
+    got0, sent0, _ = handover.to_device(
+        inputs0, finish=step_inputs_on_device, packed=False)
+    assert sent0 == len(jax.tree_util.tree_leaves(inputs0)) == len(leaves) - 1
+    _leaves_equal(got0, got)
+    handover.to_device(inputs, finish=step_inputs_on_device)
+    assert handover.cut._cache_size() == cuts   # one program a shape
+
+
+def test_step_over_the_mask_cap_serves_the_same_tokens(params, monkeypatch):
+    """A step with more mask units than ``mask_unit_cap`` ships the whole
+    bitmap (``mask_full_uploads``) and serves the tokens the capped path
+    serves; with the rung's real cap no step of this run overflows."""
+    rng = np.random.default_rng(31)
+    prompts = [[int(t) for t in rng.integers(1, CFG.vocab_size, n)]
+               for n in (27, 21, 9, 30)]     # chunks inside their own span
+
+    def run():
+        eng = _mk_engine(params, "kernel")
+        for i, p in enumerate(prompts):
+            eng.submit(EngineRequest(f"r{i}", list(p), max_new_tokens=3))
+        facts = []
+        while eng.has_work():
+            facts.append(eng.step())
+        return {r: q.out_tokens for r, q in eng._finished.items()}, facts
+
+    capped, facts = run()
+    assert sum(f["mask_units"] for f in facts) > 0
+    assert not any(f["mask_full_uploads"] for f in facts)
+    monkeypatch.setattr(EngineKernelGeom, "mask_unit_cap",
+                        lambda self, rung: 0)
+    whole, facts0 = run()
+    assert whole == capped
+    assert [f["mask_full_uploads"] for f in facts0] == [
+        int(f["mask_units"] > 0) for f in facts]
+    for f, f0 in zip(facts, facts0):   # two buffers, or a buffer a leaf
+        assert f["upload_transfers"] == 2
+        assert (f0["upload_transfers"] > 2) == bool(f0["mask_full_uploads"])
 
 
 def test_schedule_gap_raises():

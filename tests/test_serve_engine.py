@@ -498,3 +498,183 @@ def test_engine_lifecycle_spans(params, monkeypatch):
     assert ls["lifecycle.tokens_per_s"]["count"] == 3
     obs.reset()
     spans.reset()
+
+
+# ---------------------------------------------------------------------------
+# Handing a step to the device (ISSUE 31): the kernel tier's lowering
+# builds and sends what the step's launches read, in one transfer
+# ---------------------------------------------------------------------------
+
+_HANDOVER_FACTS = ("upload_kib", "upload_transfers", "mask_units",
+                   "mask_full_uploads", "plans_lowered")
+
+
+def _drain(eng):
+    facts = []
+    while eng.has_work():
+        facts.append(eng.step())
+    return ({rid: r.out_tokens for rid, r in eng._finished.items()},
+            [f for f in facts if f["tokens"]])
+
+
+def _own_span_chunks(params, backend):
+    """Prompts of several pages prefilled 16 tokens a step: every chunk
+    after a prompt's first lies inside the request's own level-0 span."""
+    rng = np.random.default_rng(41)
+    eng = _mk_engine(params, share=False, attention_backend=backend,
+                     sampling=SamplingConfig(temperature=0.8, top_k=20))
+    for i, n in enumerate((29, 43, 18, 35)):
+        eng.submit(EngineRequest(
+            f"r{i}", [int(t) for t in rng.integers(1, CFG.vocab_size, n)],
+            max_new_tokens=4))
+    return _drain(eng)
+
+
+def _shared_prefix(params, backend):
+    rng = np.random.default_rng(43)
+    eng = _mk_engine(params, attention_backend=backend,
+                     sampling=SamplingConfig(temperature=0.8, top_k=20))
+    prompts = _prompts(rng, 6)
+    for i, p in enumerate(prompts[:2]):      # the prefixes enter the trie
+        eng.submit(EngineRequest(f"a{i}", list(p), max_new_tokens=3))
+    first, facts = _drain(eng)
+    for i, p in enumerate(prompts[2:]):      # four riders on two runs
+        eng.submit(EngineRequest(f"b{i}", list(p), max_new_tokens=3))
+    second, more = _drain(eng)
+    assert sum(r.hit_tokens for r in eng._finished.values()) > 0
+    return {**first, **second}, facts + more
+
+
+def _preempt_resume(params, backend):
+    rng = np.random.default_rng(47)
+    pA = [int(t) for t in rng.integers(1, CFG.vocab_size, 20)]
+    pB = [int(t) for t in rng.integers(1, CFG.vocab_size, 20)]
+    eng = _mk_engine(params, attention_backend=backend, num_pages=7,
+                     max_batch=2, max_seq_tokens=48)
+    eng.submit(EngineRequest("A", list(pA), max_new_tokens=8, priority=5))
+    head = [eng.step() for _ in range(6)]    # A is mid-decode ...
+    eng.submit(EngineRequest("B", list(pB), max_new_tokens=4, priority=0))
+    tokens, facts = _drain(eng)              # ... when B takes its pages
+    assert eng._finished["A"].preemptions == 1
+    return tokens, head + facts
+
+
+@pytest.mark.parametrize("run", [_own_span_chunks, _shared_prefix,
+                                 _preempt_resume],
+                         ids=lambda f: f.__name__.lstrip("_"))
+def test_kernel_tier_handover_serves_the_reference_tokens(params, run):
+    """Tokens bitwise equal to the reference tier's with the bitmap sent
+    as the rows that are read, and the facts of the handover in every
+    step: two buffers, the whole bitmap never."""
+    ref, ref_facts = run(params, "reference")
+    got, facts = run(params, "kernel")
+    assert got == ref
+    assert sum(f["mask_units"] for f in facts) > 0   # rows did cross
+    for f in facts:        # one int32 buffer, and the bitmap rows beside it
+        assert f["plans_lowered"] == 1 and f["upload_transfers"] == 2
+        assert f["mask_full_uploads"] == 0 and f["upload_kib"] > 0
+    for f in ref_facts:                       # no plan on the reference tier
+        assert f["plans_lowered"] == f["mask_units"] == 0
+        assert f["mask_full_uploads"] == 0 and f["upload_transfers"] == 1
+        assert 0 < f["upload_kib"] < min(g["upload_kib"] for g in facts)
+
+
+def test_back_to_back_schedules_on_one_rung_agree_with_fresh_engines(params):
+    """A long chunked prompt, then two short prompts, through ONE engine
+    on the same rungs: the second wave's tokens and mask units are what a
+    fresh engine serves for it (nothing of the larger step's plans, bitmap
+    rows or schedule arrays is left in the smaller step's)."""
+    rng = np.random.default_rng(53)
+    waves = [[[int(t) for t in rng.integers(1, CFG.vocab_size, n)]
+              for n in wave] for wave in ((45,), (9, 14))]
+
+    def serve(eng, w):
+        for i, p in enumerate(waves[w]):
+            eng.submit(EngineRequest(f"w{w}r{i}", list(p), max_new_tokens=3))
+        tokens, facts = _drain(eng)
+        return {r: t for r, t in tokens.items() if r[1] == str(w)}, facts
+
+    one = _mk_engine(params, share=False, attention_backend="kernel")
+    _, larger = serve(one, 0)
+    got, facts = serve(one, 1)
+    want, fresh = serve(_mk_engine(params, share=False,
+                                   attention_backend="kernel"), 1)
+    assert got == want and len(got) == 2
+    assert [f["mask_units"] for f in facts] == \
+        [f["mask_units"] for f in fresh]
+    # the second wave met a rung the first had used for a larger step
+
+    def cells(fs):
+        return max(f["prefill_cells_valid"] for f in fs if f["rung"] == 16)
+
+    assert cells(facts) < cells(larger)
+    assert one.num_traces == len(one._rung_traced)
+
+
+def test_lowered_step_is_the_parents_at_every_rung(params):
+    """The step's program does not know how its leaves crossed: lowered
+    from the parent's arguments (a ``jnp.asarray`` a leaf, the whole
+    bitmap) and from this lowering's (one ``device_put``, the bitmap
+    spread on the device), the text is the same, rung by rung."""
+    import flashinfer_tpu.serve.engine_kernels as ek
+
+    eng = _mk_engine(params, attention_backend="kernel", max_seq_tokens=64)
+    ec = eng.config
+    for rung in ec.rungs():
+        plans = ek.build_engine_work_units(
+            [ek.SchedSeg(row0=0, n=rung, pages=tuple(range(1, 9)), split=48,
+                         kv_after=8 + rung, decoding=False, slot=0,
+                         group=0)], rung=rung, geom=eng._geom)
+        assert plans["stats"]["mask_units"] > 0
+        B, ppr = ec.max_batch, eng._ppr
+        sched = (*(np.zeros(rung, np.int32),) * 5,
+                 *(np.zeros((B, ppr), np.int32),) * 2,
+                 *(np.zeros(rung, np.int32),) * 2,
+                 *(np.zeros(B, np.int32),) * 2)
+        parents = dict(
+            prefill0={k: jnp.asarray(plans["prefill0"][k])
+                      for k in ek.PREFILL_ARRAY_KEYS},
+            prefill1={k: jnp.asarray(plans["prefill1"][k])
+                      for k in ek.PREFILL_ARRAY_KEYS if k != "mask_bytes"},
+            decode={k: jnp.asarray(plans["decode"][k])
+                    for k in ek.DECODE_ARRAY_KEYS},
+            dec_rows=jnp.asarray(plans["dec_rows"]))
+        old = eng._step.lower(params, *map(jnp.asarray, sched), parents,
+                              eng.caches).as_text()
+        sched_dev, kplans = jax.device_put(
+            (sched, ek.plans_to_host(plans, eng._geom.mask_unit_cap(rung))))
+        new = eng._step.lower(params, *sched_dev,
+                              ek.plans_on_device(kplans),
+                              eng.caches).as_text()
+        assert new == old, rung
+
+
+def test_handover_at_the_cells_statics(params):
+    """At the benchmark cells' engine statics (page 16, 2,048 tokens a
+    request, 64 slots, a prefill budget of 512: 2,048 units a plan, a
+    33.5 MB bitmap) a step hands over about a megabyte in two buffers,
+    never the whole bitmap; a rung traces its step once and the program
+    that cuts its inputs once."""
+    from flashinfer_tpu.serve import handover
+
+    eng = _mk_engine(params, attention_backend="kernel", num_pages=160,
+                     page_size=16, max_batch=64, prefill_budget_tokens=512,
+                     max_seq_tokens=2048, share=False)
+    assert {eng._geom.prefill_unit_cap(r) for r in eng.config.rungs()} \
+        == {2048}
+    cuts = handover.cut._cache_size()
+    rng = np.random.default_rng(59)
+    for i, n in enumerate((40, 23)):
+        eng.submit(EngineRequest(
+            f"r{i}", [int(t) for t in rng.integers(1, CFG.vocab_size, n)],
+            max_new_tokens=2))
+    tokens, facts = _drain(eng)
+    assert all(len(t) == 2 for t in tokens.values())
+    assert facts[0]["mask_units"] > 0 and len(facts) > 1
+    for f in facts:
+        assert set(_HANDOVER_FACTS) <= set(f)
+        assert f["plans_lowered"] == 1 and f["upload_transfers"] == 2
+        assert f["mask_full_uploads"] == 0
+        assert 512 < f["upload_kib"] < 1536
+    assert eng.num_traces == len(eng._rung_traced) == 1
+    assert handover.cut._cache_size() == cuts + 1
