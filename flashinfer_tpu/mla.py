@@ -250,9 +250,16 @@ class BatchMLAPagedAttentionWrapper:
         ``flashinfer/mla/_sparse_mla_sm120.py`` + sparse_mla bindings):
         attention restricted to the top-k selected KV tokens per request.
         Selection comes from ``flashinfer_tpu.topk.top_k_page_table_transform``
-        over per-token proxy scores; rows < 0 are masked padding."""
+        over per-token proxy scores; rows < 0 are masked padding.
+        ``sm_scale`` is the model's softmax scale; the default is a
+        placeholder (see below) and matches no published model."""
         d_ckv = ckv_cache.shape[-1]
         if sm_scale is None:
+            # A PLACEHOLDER, no published model's scale: the absorbed query
+            # is ``d_ckv + d_kpe`` wide, but a model scales by its own
+            # per-head query/key width (GLM-5: 1/sqrt(192 + 64); DeepSeek-V3:
+            # 1/sqrt(128 + 64)).  A caller that serves a model passes its
+            # ``attention_scale`` (``models/glm_dsa.py`` does).
             sm_scale = 1.0 / float(d_ckv + q_pe.shape[-1]) ** 0.5
         return _sparse_mla_decode(
             q_nope, q_pe, ckv_cache, kpe_cache, sparse_rows,
@@ -274,26 +281,46 @@ def _sparse_mla_decode(
     q_nope, q_pe, ckv_cache, kpe_cache, sparse_rows,
     *, sm_scale: float, return_lse: bool,
 ):
-    """Gather the selected latent rows and run dense MQA attention over the
-    k tokens — with k in the hundreds this is one small MXU matmul per
-    request, the shape the sparse path exists to produce."""
-    batch, H, d_ckv = q_nope.shape
+    """Gather the selected latent rows and attend over them
+    (``ops/sparse_mla.py sparse_mla_attend``, the function the serving
+    engine's latent layer calls too), here in float32 over the wrapper's
+    separate ckv / kpe caches."""
+    from flashinfer_tpu.ops.sparse_mla import sparse_mla_attend
+
+    d_ckv = q_nope.shape[-1]
     rows = jnp.maximum(sparse_rows, 0)
-    valid = sparse_rows >= 0  # [batch, k]
     ckv = ckv_cache.reshape(-1, d_ckv)[rows].astype(jnp.float32)  # [B,k,d]
     kpe = kpe_cache.reshape(-1, kpe_cache.shape[-1])[rows].astype(jnp.float32)
     kpe = kpe[..., : q_pe.shape[-1]]  # drop TPU lane padding if present
-    s = (
-        jnp.einsum("bhd,bkd->bhk", q_nope.astype(jnp.float32), ckv)
-        + jnp.einsum("bhd,bkd->bhk", q_pe.astype(jnp.float32), kpe)
-    ) * sm_scale
-    s = jnp.where(valid[:, None], s, -1e30)
-    m = jnp.max(s, -1, keepdims=True)
-    p = jnp.where(valid[:, None], jnp.exp(s - m), 0.0)
-    l = jnp.sum(p, -1, keepdims=True)
-    out = jnp.einsum("bhk,bkd->bhd", p / jnp.where(l > 0, l, 1.0), ckv)
-    out = out.astype(q_nope.dtype)
+    q = jnp.concatenate([q_nope, q_pe], -1).astype(jnp.float32)
+    out = sparse_mla_attend(
+        q, jnp.concatenate([ckv, kpe], -1), sparse_rows >= 0, d_v=d_ckv,
+        sm_scale=sm_scale, return_lse=return_lse)
     if return_lse:
-        lse = jnp.where(l[..., 0] > 0, m[..., 0] + jnp.log(l[..., 0]), -1e30)
-        return out, lse
-    return out
+        return out[0].astype(q_nope.dtype), out[1]
+    return out.astype(q_nope.dtype)
+
+
+def project_latents(x, layer, positions, *, num_heads: int, nope: int,
+                    rope: int, ckv: int, eps: float, rope_theta: float,
+                    interleave: bool = False):
+    """The MLA projections of normed rows ``x`` ``[N, hidden]`` on a flat
+    token axis, the one definition every MLA model here uses
+    (``models/deepseek.py``, ``models/glm_dsa.py``): ``c_q = RMSNorm(x
+    W_qa)``; ``q = c_q W_qb`` -> per head ``nope`` + ``rope`` dims, the rope
+    part rotated at ``positions``; ``[c_kv | k_pe] = x W_kva``, ``c_kv``
+    normed, ``k_pe`` rotated and shared by all heads.  Returns ``(c_q [N,
+    q_lora], q_nope [N, H, nope], q_pe [N, H, rope], c_kv [N, ckv], k_pe [N,
+    rope])``; the cache row is ``[c_kv | k_pe]``."""
+    from flashinfer_tpu.norm import rmsnorm
+    from flashinfer_tpu.rope import apply_rope_pos_ids
+
+    n = x.shape[0]
+    c_q = rmsnorm(x @ layer["q_a"], layer["q_a_norm"], eps)
+    q = (c_q @ layer["q_b"]).reshape(n, num_heads, nope + rope)
+    kv = x @ layer["kv_a"]  # [N, ckv + rope]
+    c_kv = rmsnorm(kv[:, :ckv], layer["kv_a_norm"], eps)
+    q_pe, k_pe = apply_rope_pos_ids(
+        q[..., nope:], kv[:, None, ckv:], positions, rope_theta=rope_theta,
+        interleave=interleave)
+    return c_q, q[..., :nope], q_pe, c_kv, k_pe[:, 0]

@@ -45,7 +45,6 @@ from flashinfer_tpu.ops.mla_decode import (
     mla_paged_decode_attention,
     xla_mla_paged_decode,
 )
-from flashinfer_tpu.rope import apply_rope_pos_ids
 from flashinfer_tpu.utils import is_tpu
 
 
@@ -147,19 +146,12 @@ def _project_latents(x, layer, cfg: DeepseekConfig, positions):
     roped kpe [N, kpe]) — one definition for the decode step and the
     prefill path (their cache-sharing contract depends on identical
     latent math)."""
-    H, nope, kpe = cfg.num_heads, cfg.head_dim_nope, cfg.head_dim_kpe
-    ckv_dim = cfg.kv_lora_rank
-    N = x.shape[0]
-    q_lat = rmsnorm(x @ layer["q_a"], layer["q_a_norm"], cfg.rms_eps)
-    q = (q_lat @ layer["q_b"]).reshape(N, H, nope + kpe)
-    q_nope, q_pe = q[..., :nope], q[..., nope:]
-    kv = x @ layer["kv_a"]  # [N, ckv + kpe]
-    ckv = rmsnorm(kv[:, :ckv_dim], layer["kv_a_norm"], cfg.rms_eps)
-    kpe_k = kv[:, None, ckv_dim:]  # [N, 1, kpe] — shared across heads
-    q_pe, kpe_k = apply_rope_pos_ids(
-        q_pe, kpe_k, positions, rope_theta=cfg.rope_theta
-    )
-    return q_nope, q_pe, ckv, kpe_k[:, 0]
+    from flashinfer_tpu.mla import project_latents
+
+    return project_latents(
+        x, layer, positions, num_heads=cfg.num_heads, nope=cfg.head_dim_nope,
+        rope=cfg.head_dim_kpe, ckv=cfg.kv_lora_rank, eps=cfg.rms_eps,
+        rope_theta=cfg.rope_theta)[1:]
 
 
 def _append_latents(cache, rows, ckv, kpe_vec, kpe_dim: int):

@@ -147,12 +147,9 @@ class GraniteHybridConfig:
     def engine_facts(self, aux, tokens: int) -> dict:
         """The expert layers' counters of one step, from the rows each held
         expert got in each layer (``aux["moe_rows"]`` ``[layers, held]``)."""
-        rows = aux["moe_rows"]
-        return {"moe_routes": tokens * self.experts_per_tok
-                * self.num_layers,
-                "moe_routes_local": int(rows.sum()),
-                "moe_expert_rows_max": int(rows.max()),
-                "moe_experts_hit": int((rows > 0).sum())}
+        from flashinfer_tpu.models.expert_share import route_facts
+
+        return route_facts(aux["moe_rows"], tokens, self.experts_per_tok)
 
 
 # ---------------------------------------------------------------------------
@@ -395,60 +392,22 @@ def _mamba_mixer(cfg, x, layer, cache, ctx):
     return x, (conv_state, ssm_state)
 
 
-def _grouped(lhs, row_ids, w, group_sizes, gather: bool):
-    """Rows of ``lhs`` (gathered by ``row_ids`` when ``gather``), sorted by
-    expert, times each expert's weight: the Pallas grouped matmul on the
-    chip (its grid covers the groups' rows only, so rows past them are
-    never computed), ``ragged_dot`` where kernels are interpreted or a
-    width is not 128-aligned."""
-    from flashinfer_tpu.utils import use_interpret
-
-    k, n = w.shape[1], w.shape[2]
-    if use_interpret() or k % 128 or n % 128:
-        return jax.lax.ragged_dot(lhs[row_ids] if gather else lhs, w,
-                                  group_sizes)
-    from flashinfer_tpu.fused_moe.core import _heuristic_gmm_tiles
-    from flashinfer_tpu.ops.moe_gmm import gather_gmm, gmm
-
-    tm, tn, tk = _heuristic_gmm_tiles(row_ids.shape[0], k, n,
-                                      w.dtype.itemsize, lhs.dtype.itemsize)
-    if gather:
-        return gather_gmm(lhs, row_ids, w, group_sizes, tm=tm, tn=tn, tk=tk)
-    return gmm(lhs, w, group_sizes, tm=tm, tn=tn, tk=tk)
-
-
 def _expert_layer(cfg, x, layer, ctx):
     """``x + residual_multiplier * (what the held experts add + the shared
     expert)``, and the rows each held expert got."""
     from flashinfer_tpu.activation import silu_and_mul
     from flashinfer_tpu.fused_moe.routing import route_renormalize
+    from flashinfer_tpu.models.expert_share import held_experts
     from flashinfer_tpu.norm import rmsnorm
 
-    t, top_k = x.shape[0], cfg.experts_per_tok
-    first, held = cfg.local_experts
     with jax.named_scope("moe.route"):
         u = rmsnorm(x, layer["post_norm"], cfg.rms_eps)
         logits = jnp.dot(u, layer["router"],
                          preferred_element_type=jnp.float32)
-        weights, ids = route_renormalize(logits, top_k)
-        # a route stays when its expert is held here and its row is a
-        # token; the others sort behind every group and are never computed
-        local = (ids >= first) & (ids < first + held) \
-            & (jnp.arange(t) < ctx.plan["n_tokens"])[:, None]
-        key = jnp.where(local, ids - first, held).reshape(-1)
-        order = jnp.argsort(key, stable=True)
-        token_of = order // top_k
-        group_sizes = jnp.bincount(key, length=held).astype(jnp.int32)
-        # where route (t, k) lies among the sorted rows
-        place = jnp.zeros_like(order).at[order].set(
-            jnp.arange(order.shape[0], dtype=order.dtype))
-    with jax.named_scope("moe.experts"):
-        h1 = _grouped(u, token_of, layer["w_gate_up"], group_sizes, True)
-        h2 = _grouped(silu_and_mul(h1), token_of, layer["w_down"],
-                      group_sizes, False)
-        per_route = h2[place].reshape(t, top_k, -1).astype(jnp.float32)
-        routed = jnp.sum(jnp.where(local[..., None],
-                                   weights[..., None] * per_route, 0.0), 1)
+        weights, ids = route_renormalize(logits, cfg.experts_per_tok)
+    routed, group_sizes = held_experts(
+        u, weights, ids, layer["w_gate_up"], layer["w_down"],
+        first=cfg.local_experts[0], n_tokens=ctx.plan["n_tokens"])
     with jax.named_scope("moe.shared"):
         shared = jnp.dot(silu_and_mul(u @ layer["shared_gate_up"]),
                          layer["shared_down"],

@@ -13,8 +13,9 @@ place and a step never retraces across tokens.
   rung ladder; the step of the Llama-style dense layer;
 - :mod:`~flashinfer_tpu.serve.block_step` — the step of a model that
   brings its own layer (``engine_layer``), :mod:`~flashinfer_tpu.serve.
-  recurrent` its recurrent layers' work units, :mod:`~flashinfer_tpu.
-  serve.kv_write` the in-place K/V row write both step bodies call;
+  recurrent` its recurrent and latent layers' work units,
+  :mod:`~flashinfer_tpu.serve.kv_write` the in-place row write both step
+  bodies (and a latent layer's ``append``) call;
 - :mod:`~flashinfer_tpu.serve.engine_kernels` — the engine's KERNEL
   attention tier (``EngineConfig.attention_backend="kernel"``): the
   host planner that lowers each step's schedule onto the work-unit

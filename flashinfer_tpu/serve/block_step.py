@@ -20,11 +20,17 @@ without int8 pages, which no such model runs.
 
 Such a model object supplies, beside the sizes every model has:
 
-- ``layer_kinds()``: ``"attention"`` or ``"recurrent"`` per layer; an
-  attention layer gets K/V pages, a recurrent one the arrays of
+- ``layer_kinds()``: ``"attention"``, ``"recurrent"`` or ``"latent"`` per
+  layer; an attention layer gets K/V pages, a recurrent one the arrays of
   ``new_recurrent_state(slots)`` (``[slots, ...]``, one slot a batch slot),
-  both as ``cache`` below and donated through the step; with recurrent
-  layers also ``scan_chunk``, the rows of one work unit;
+  a latent one the arrays of ``new_latent_pages(num_pages, page_size)``
+  (``[pages, page_size, W]``, no head axis: one row a token for all heads,
+  as many arrays as the layer caches things a token, all on the request's
+  one page list), each as ``cache`` below and donated through the step;
+  with recurrent or latent layers also ``scan_chunk``, the rows of one work
+  unit of ``serve/recurrent.py``'s plan.  A latent layer attends by itself
+  (``ctx.page_table``, ``ctx.append``): the engine plans and launches no
+  GQA work unit for a model without an attention layer;
 - ``attention_rope`` (False: no rotation) and ``attention_scale``;
 - ``engine_embed(params, tokens)``, ``engine_layer(li, x, layer, cache,
   ctx) -> (x, cache)``, ``engine_head(params, x_last) -> float32 logits``;
@@ -39,8 +45,8 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from flashinfer_tpu.serve.kv_write import (append_rows, page_cap,
-                                           touched_pages)
+from flashinfer_tpu.serve.kv_write import (append_pages, append_rows,
+                                           page_cap, touched_pages)
 
 _NEG_INF = -1e30
 
@@ -52,14 +58,21 @@ class StepContext:
     ``attend(q, k, v, cache) -> (out, cache)`` writes the K/V rows into the
     pages and attends over them (the engine's tier, the model's rotation
     and scale); ``plan`` is ``serve/recurrent.py``'s plan of the step:
-    ``n_tokens`` and, with recurrent layers, each slot's segment and the
-    scan's work units; ``aux`` takes what the layers want fetched."""
+    ``n_tokens`` and, with recurrent or latent layers, each slot's segment
+    and the work units of the longer segments; ``aux`` takes what the layers
+    want fetched.  For a latent layer: ``page_table`` ``[max_batch, pages a
+    request]`` (row = batch slot), ``page_size``, and ``append(pool, rows)
+    -> pool``, which writes the step's rows ``[T, W]`` into a paged array
+    ``[pages, page_size, W]`` through ``serve/kv_write.py``."""
 
     positions: jax.Array
     tok_req: jax.Array
     attend: object
     plan: dict
     aux: dict
+    page_table: object = None
+    page_size: int = 0
+    append: object = None
 
 
 def _sample(logits, sampling, base_key, sample_seeds):
@@ -176,9 +189,15 @@ def build(eng):
                     o, _ = compose_cascade_levels([(o0, lse0), (o1, lse1)])
             return o.astype(mcfg.dtype), (kc, vc)
 
+        def _append(pool, rows):
+            return append_pages(pool, rows.astype(pool.dtype), touched,
+                                token_slot)
+
         aux = {}
         ctx = StepContext(positions=positions, tok_req=tok_req,
-                          attend=_attend_pages, plan=mplan, aux=aux)
+                          attend=_attend_pages, plan=mplan, aux=aux,
+                          page_table=page_table, page_size=cfg.page_size,
+                          append=_append)
         with jax.named_scope("embed"):
             x = mcfg.engine_embed(params, flat_tokens)
         new_caches = []

@@ -532,10 +532,15 @@ class ServingEngine:
                        if self._block is not None
                        else ("attention",) * model_cfg.num_layers)
         self._recurrent = "recurrent" in self._kinds
-        if self._recurrent and config.kv_offload != "off":
-            self._refuse_recurrent("kv_offload='host' (host spill)")
-        if self._recurrent and config.role != "unified":
-            self._refuse_recurrent(f"role={config.role!r} (migration)")
+        self._latent = "latent" in self._kinds
+        # state the K/V paths cannot move or share: a recurrent layer's
+        # slots are not in the pages, a latent layer's pages are not K/V
+        # pairs a head (PrefixCache and kv_tier assume those)
+        self._own_state = self._recurrent or self._latent
+        if self._own_state and config.kv_offload != "off":
+            self._refuse_own_state("kv_offload='host' (host spill)")
+        if self._own_state and config.role != "unified":
+            self._refuse_own_state(f"role={config.role!r} (migration)")
         self.prefix_lookups_refused = 0
         self.last_logits: Dict[str, np.ndarray] = {}
         self.pool = BlockPool(config.num_pages, config.page_size)
@@ -580,24 +585,35 @@ class ServingEngine:
                     else jnp.dtype(model_cfg.dtype))
         self.kv_dtype = kv_dtype
         self._int8_kv = kv_dtype == jnp.int8
+        if self._latent and self._int8_kv:
+            self._refuse_own_state("kv_dtype=int8 (int8 pages)")
         ps, ppr = config.page_size, config.pages_per_req()
         # one entry a layer, donated through the step together: K/V pages
         # for an attention layer; for a recurrent layer the model's state
         # arrays ``[max_batch, ...]``, one SLOT a batch slot
         # (``EngineRequest.slot``, allocated at admission and freed at
         # release with it), so a model without recurrent layers allocates
-        # nothing
-        self.caches = [
-            (jnp.zeros((config.num_pages, model_cfg.num_kv_heads, ps,
-                        model_cfg.head_dim), kv_dtype),
-             jnp.zeros((config.num_pages, model_cfg.num_kv_heads, ps,
-                        model_cfg.head_dim), kv_dtype))
-            if kind == "attention"
-            else model_cfg.new_recurrent_state(config.max_batch)
-            for kind in self._kinds
-        ]
+        # nothing; for a latent layer the model's paged arrays ``[num_pages,
+        # page_size, W]`` (no head axis), addressed by the same page tables
+        def _layer_state(kind):
+            if kind == "attention":
+                shape = (config.num_pages, model_cfg.num_kv_heads, ps,
+                         model_cfg.head_dim)
+                return (jnp.zeros(shape, kv_dtype),
+                        jnp.zeros(shape, kv_dtype))
+            if kind == "recurrent":
+                return model_cfg.new_recurrent_state(config.max_batch)
+            if kind == "latent":
+                return model_cfg.new_latent_pages(config.num_pages, ps)
+            raise ValueError(f"layer kind {kind!r} is none of attention, "
+                             "recurrent, latent")
+
+        self.caches = [_layer_state(kind) for kind in self._kinds]
         self._ppr = ppr
-        self._kernel_backend = config.attention_backend == "kernel"
+        # the GQA kernels' geometry and work units are planned for attention
+        # layers only: a model without one attends by itself
+        self._kernel_backend = (config.attention_backend == "kernel"
+                                and "attention" in self._kinds)
         self._geom = None
         # launched-vs-effective unit accounting (kernel tier): what the
         # padded work-unit grids actually execute vs the attended pairs
@@ -634,14 +650,22 @@ class ServingEngine:
 
             self._step = block_step.build(self)
 
-    def _refuse_recurrent(self, what: str) -> None:
+    def _refuse_own_state(self, what: str) -> None:
         """A recurrent layer's state is not in the K/V pages: a path that
         moves or reuses pages alone would resume from state nobody kept.
-        State snapshots at page boundaries are not there yet."""
+        State snapshots at page boundaries are not there yet.  A latent
+        layer's pages hold one row a token and no K/V pair a head, which is
+        what the prefix cache's, the host tier's and the int8 pages' code
+        assumes."""
+        if self._recurrent:
+            raise NotImplementedError(
+                f"{what} is not supported for a model with recurrent "
+                "layers: the recurrent state slots are neither snapshotted "
+                "nor moved with the K/V pages")
         raise NotImplementedError(
-            f"{what} is not supported for a model with recurrent layers: "
-            "the recurrent state slots are neither snapshotted nor moved "
-            "with the K/V pages")
+            f"{what} is not supported for a model with latent layers: its "
+            "latent and index pages are not K/V pairs a head, and nothing "
+            "spills, migrates or quantizes them yet")
 
     # -- public surface ---------------------------------------------------
 
@@ -826,9 +850,10 @@ class ServingEngine:
         hit_pages: List[int] = []
         hit_tokens = 0
         if staged is None and cfg.enable_prefix_cache:
-            if self._recurrent:
+            if self._own_state:
                 # a hit would skip tokens whose recurrent state nobody
-                # kept: refused, and counted
+                # kept, or share latent pages the cache cannot address:
+                # refused, and counted
                 self.prefix_lookups_refused += 1
                 obs.counter_inc("engine.prefix_lookups_refused")
             else:
@@ -1327,12 +1352,13 @@ class ServingEngine:
         # module-doc contract), so the reference backend is unmoved
         sched.sort(key=lambda e: groups[_grp_key(e[0])])
         segs = []
-        rsegs = []  # recurrent layers: (row0, n, slot, starts from zero)
+        # recurrent and latent layers: (row0, n, slot, starts from zero)
+        rsegs = []
         row = 0
         for r, n in sched:
             g = groups[_grp_key(r)]
             decoding = r.kv_len >= len(r.prompt)
-            if self._recurrent:
+            if self._own_state:
                 # a request (re)admitted since its last step has no
                 # tokens materialized: its slot's state is zero, whatever
                 # the slot's last holder left there
@@ -1414,12 +1440,20 @@ class ServingEngine:
         if self._block is not None:
             from flashinfer_tpu.serve import recurrent as _rec
 
-            with _span(tick, "lower.recurrent"):
+            # for a model that launches no GQA kernel this IS the step's
+            # plan: it goes under the planner's span names, so what reads
+            # the host's planning and handing over reads it (nothing is
+            # converted for the device: an empty span)
+            own = not self._kernel_backend
+            with _span(tick, "lower.build" if own else "lower.recurrent"):
                 mplan, rfacts = _rec.build_plan(
                     rsegs, tokens=total, rung=rung, max_batch=Bpad,
-                    chunk=mcfg.scan_chunk if self._recurrent else 0)
-            facts.update(rfacts)
+                    chunk=mcfg.scan_chunk if self._own_state else 0)
+            if own:
+                with _span(tick, "lower.upload"):
+                    pass
             if self._recurrent:
+                facts.update(rfacts)
                 facts["state_slots_used"] = sum(
                     s is not None for s in self._slots)
         if tick is not None:
@@ -1495,7 +1529,7 @@ class ServingEngine:
         # FIRST (post-run: the page KV is materialized now, and a
         # request finishing this very step must still donate its span
         # to the cache before its own references are released)
-        if cfg.enable_prefix_cache and not self._recurrent:
+        if cfg.enable_prefix_cache and not self._own_state:
             for r, _ in sched:
                 upto = min(r.kv_len, r.split) // ps
                 if upto > r.inserted_pages:
@@ -1562,8 +1596,8 @@ class ServingEngine:
         sampling-seed stream) and frozen cascade ``split``."""
         from flashinfer_tpu import obs
 
-        if self._recurrent:
-            self._refuse_recurrent("adopt_migrated (migration)")
+        if self._own_state:
+            self._refuse_own_state("adopt_migrated (migration)")
         if self.config.role == "prefill":
             raise ValueError("a prefill-role pool cannot adopt "
                              "migrated requests")
@@ -1601,8 +1635,8 @@ class ServingEngine:
         device pages now and restores bit-exactly when it next
         schedules).  The request re-queues as waiting; admission
         pressure decides when it returns."""
-        if self._recurrent:
-            self._refuse_recurrent("offload_idle (host spill)")
+        if self._own_state:
+            self._refuse_own_state("offload_idle (host spill)")
         if self.host_store is None:
             raise ValueError("offload_idle needs kv_offload='host'")
         r = next((x for x in self._running if x.rid == rid), None)

@@ -259,8 +259,8 @@ def spill_request(engine: "ServingEngine", req: "EngineRequest") -> bool:
     then falls back to recompute-on-resume."""
     from flashinfer_tpu import obs
 
-    if engine._recurrent:
-        engine._refuse_recurrent("spill_request (host spill)")
+    if engine._own_state:
+        engine._refuse_own_state("spill_request (host spill)")
     if engine.host_store is None:
         raise ValueError("spill_request on an engine without a host "
                          "tier (EngineConfig.kv_offload is 'off')")
@@ -398,8 +398,8 @@ def migrate_request(src: "ServingEngine", dst: "ServingEngine",
     from flashinfer_tpu.serve.engine import EngineRequest
 
     for eng in (src, dst):
-        if eng._recurrent:
-            eng._refuse_recurrent("migrate_request (migration)")
+        if eng._own_state:
+            eng._refuse_own_state("migrate_request (migration)")
     if src.config.role != "prefill":
         raise ValueError("migrate_request source must be a "
                          "prefill-role engine")

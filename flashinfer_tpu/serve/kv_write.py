@@ -15,6 +15,11 @@ The donated pool is updated in place and the cost follows the rows written.
 It moves bytes: the rows arrive in the pool's dtype (int8 pages are quantized
 by the caller), and the pool afterwards holds, bit for bit, what the plain
 scatter leaves.
+
+A pool without a head axis (``[pages, page_size, W]``: a latent row, an
+indexer's key, one row a token for all heads) goes the same way through
+:func:`append_pages`: the same touched pages, whole ``[page_size, W]`` pages
+put back.
 """
 
 from __future__ import annotations
@@ -61,15 +66,23 @@ def touched_pages(token_page, *, cap: int) -> TouchedPages:
     return TouchedPages(page_ids, rank)
 
 
+def append_pages(pool, rows, touched: TouchedPages, token_slot):
+    """One pool's rows through the touched pages (module doc): ``pool``
+    ``[pages, Hkv, page_size, D]`` with ``rows`` ``[T, Hkv, D]``, or
+    ``[pages, page_size, W]`` with ``rows`` ``[T, W]``."""
+    page_ids, rank = touched
+    staged = jnp.take(pool, page_ids, axis=0, mode="clip")
+    if pool.ndim == 4:
+        staged = staged.at[rank, :, token_slot, :].set(rows, mode="drop")
+    else:
+        staged = staged.at[rank, token_slot, :].set(rows, mode="drop")
+    return pool.at[page_ids].set(staged, mode="drop")
+
+
 def append_rows(kc, vc, k_w, v_w, touched: TouchedPages, token_slot):
     """``kc.at[token_page, :, token_slot, :].set(k_w)`` and the same for V,
     through the touched pages (module doc).  ``k_w`` / ``v_w`` are
-    ``[T, Hkv, D]`` in the pools' dtypes."""
-    page_ids, rank = touched
-
-    def _one(pool, rows):
-        staged = jnp.take(pool, page_ids, axis=0, mode="clip")
-        staged = staged.at[rank, :, token_slot, :].set(rows, mode="drop")
-        return pool.at[page_ids].set(staged, mode="drop")
-
-    return _one(kc, k_w), _one(vc, v_w)
+    ``[T, Hkv, D]`` in the pools' dtypes (or ``[T, W]`` for pools without a
+    head axis)."""
+    return (append_pages(kc, k_w, touched, token_slot),
+            append_pages(vc, v_w, touched, token_slot))

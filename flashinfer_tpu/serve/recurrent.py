@@ -1,7 +1,9 @@
 """The step's plan for a model that brings its own block
 (``serve/engine.py``, "The model's seam"): how many of the rung's rows are
-tokens and, for a model with recurrent layers, where each batch slot's
-segment lies in the flat batch and the work units of the scan.
+tokens and, for a model with recurrent or latent layers, where each batch
+slot's segment lies in the flat batch and the work units of the longer
+segments (a recurrent layer's scan, a latent layer's sparse attention: both
+run one unit's rows of one request at a time).
 
 A recurrent layer keeps a fixed-size state per running request in the
 request's SLOT (its batch slot): the engine's state arrays are

@@ -408,3 +408,44 @@ def test_granite_hybrid_engine_step(chip):
     # a Mamba layer and an attention layer with 36 experts each, half the
     # embedding, 64 slots and 512 pages: 2.1 GB; well under the chip's 16
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 6e9
+
+
+
+@pytest.mark.parametrize("k, n", [(6144, 4096), (2048, 6144)])
+def test_moe_gmm_at_glm_widths(chip, compiled_branch, k, n):
+    """16 held experts of 256, 8 routes of 1,024 tokens (8,192 sorted rows
+    of which one in sixteen is local), the tiles the heuristic picks:
+    gate|up ``6144 -> 2 x 2048`` and down ``2048 -> 6144``."""
+    from flashinfer_tpu.fused_moe.core import _heuristic_gmm_tiles
+    from flashinfer_tpu.ops.moe_gmm import gmm
+
+    m = 8192
+    tm, tn, tk = _heuristic_gmm_tiles(m, k, n, 2, 2)
+    compiled = _compile(
+        lambda lhs, rhs, sizes: gmm(lhs, rhs, sizes, tm=tm, tn=tn, tk=tk),
+        _sds(chip, (m, k), "bfloat16"), _sds(chip, (16, k, n), "bfloat16"),
+        _sds(chip, (16,), "int32"))
+    assert re.search(r"%moe_gmm(\.\d+)? = .*custom-call\(",
+                     compiled.as_text())
+
+
+def test_glm_sparse_unit(chip):
+    """One unit of GLM-5's sparse attention at the cell's shapes (128 query
+    rows against a 16,640-position window: index scores, the exact
+    top-2,048, the gather of each row's list, absorbed MLA over it) fits the
+    chip beside the cell's 10.9 GB of weights and pages, under its scope
+    names (plain XLA: there is no kernel to find)."""
+    from flashinfer_tpu.models.glm_dsa import GlmDsaConfig, _attend_block
+
+    mcfg = GlmDsaConfig()
+    q, k = 128, 16640
+    compiled = jax.jit(
+        lambda *a: _attend_block(mcfg, *a)).lower(
+        _sds(chip, (q, 64, 640), "bfloat16"),
+        _sds(chip, (q, 32, 128), "bfloat16"), _sds(chip, (q, 32), "float32"),
+        _sds(chip, (q,), "int32"), _sds(chip, (k, 128), "bfloat16"),
+        _sds(chip, (k, 640), "bfloat16")).compile()
+    hlo = compiled.as_text()
+    for scope in ("attn.index", "attn.select"):
+        assert f"/{scope}/" in hlo, scope
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
