@@ -45,8 +45,13 @@ segments are cut into units of at most ``scan_chunk`` rows of one request,
 which one ``fori_loop`` with a dynamic bound runs, each unit gathering its
 slot's window of index keys and latent rows once, as wide as the unit's last
 position needs (a quarter, a half or all of a request's pages).  Both go
-through the same function (:func:`_attend_block`): score, select, gather,
-attend.  Shapes depend on the rung alone: one trace per rung.
+through the same function (:func:`_attend_block`): score, select, attend;
+one selection rule for both.  A lane gathers its list's rows (64 MXU rows
+against 2,048 gathered ones); a unit reads its window once under the list
+as a mask (``ops/sparse_mla.py sparse_mla_stream``), because a gather of
+``rows x index_topk`` latent rows, no two lists alike, is bound by rows per
+nanosecond and not by bytes.  Shapes depend on the rung alone: one trace per
+rung.
 """
 
 from __future__ import annotations
@@ -243,16 +248,20 @@ def _residual(cfg, x, delta):
 
 
 _COUNTS = ("attn_pairs_causal", "attn_pairs_selected", "index_rows_scored",
-           "latent_rows_read", "latent_pages_written")
+           "latent_rows_read", "latent_pages_written", "attn_units",
+           "attn_units_streamed")
 
 
 def _step_counts(cfg, ctx):
-    """int32 ``[5]``, :data:`_COUNTS` of one layer of this step: the sums
+    """int32 ``[7]``, :data:`_COUNTS` of one layer of this step: the sums
     over the step's query tokens of the context ``ctx_t`` and of
     ``min(ctx_t, index_topk)``; the cached rows the step's requests hold
     (every index key is scored at least once; a latent row is read at most
     when some query selects it: ``min(held, queries x index_topk)`` a
-    request); the pages its rows are written to."""
+    request); the pages its rows are written to; the multi-row units it runs
+    and those of them that stream their window (all: at each of the three
+    widths a unit chooses from the streamed form is the faster, PERF.md
+    section 6, PR 36; only the lanes' one-row pass gathers)."""
     pos, req = ctx.positions, ctx.tok_req
     real = jnp.arange(pos.shape[0]) < ctx.plan["n_tokens"]
     ctx_t = jnp.where(real, pos + 1, 0)
@@ -262,10 +271,11 @@ def _step_counts(cfg, ctx):
         real.astype(jnp.int32))
     first = jnp.concatenate([jnp.ones((1,), bool), req[1:] != req[:-1]])
     starts_page = first | (pos % ctx.page_size == 0)
+    units = ctx.plan["n_work"]
     return jnp.stack([
         jnp.sum(ctx_t), jnp.sum(jnp.minimum(ctx_t, cfg.index_topk)),
         jnp.sum(held), jnp.sum(jnp.minimum(held, queries * cfg.index_topk)),
-        jnp.sum(real & starts_page)]).astype(jnp.int32)
+        jnp.sum(real & starts_page), units, units]).astype(jnp.int32)
 
 
 def _index_projections(cfg, h, c_q, layer, positions):
@@ -285,20 +295,29 @@ def _index_projections(cfg, h, c_q, layer, positions):
     return q_i, k[:, 0], w
 
 
-def _attend_block(cfg, q, q_i, w_i, pos, win_i, win_l):
-    """Score, select, gather, attend for ``Q`` query rows that share one
-    request's window: ``q`` ``[Q, H, W]`` (absorbed, padded to the window's
-    width), ``q_i`` / ``w_i`` the index queries and head weights, ``pos``
-    ``[Q]``, ``win_i`` ``[K, Di]`` and ``win_l`` ``[K, W]`` the request's
-    cached index keys and latent rows by position.  Float32 ``[Q, H,
-    ckv]``."""
-    from flashinfer_tpu.ops.sparse_mla import (index_scores, select_topk,
-                                               sparse_mla_attend)
+def _attend_block(cfg, q, q_i, w_i, pos, win_i, win_l, last=None):
+    """Score, select, attend for ``Q`` query rows that share one request's
+    window: ``q`` ``[Q, H, W]`` (absorbed, padded to the window's width),
+    ``q_i`` / ``w_i`` the index queries and head weights, ``pos`` ``[Q]``,
+    ``win_i`` ``[K, Di]`` and ``win_l`` ``[K, W]`` the request's cached index
+    keys and latent rows by position.  Float32 ``[Q, H, ckv]``.
+
+    One selection, two forms of the same softmax over it
+    (``ops/sparse_mla.py``): a unit of many rows, which gives ``last`` (the
+    position of its last live row), streams the window once under the
+    selection as a mask; one row (a decode lane) gathers its list's rows."""
+    from flashinfer_tpu.ops.sparse_mla import (
+        index_scores, select_topk, selection_mask, sparse_mla_attend,
+        sparse_mla_stream)
 
     with jax.named_scope("attn.index"):
         scores = index_scores(q_i, w_i, win_i, pos)
     with jax.named_scope("attn.select"):
         idx, valid = select_topk(scores, cfg.index_topk)
+    if last is not None:
+        return sparse_mla_stream(
+            q, win_l, selection_mask(scores, idx, valid), last,
+            d_v=cfg.kv_lora_rank, sm_scale=cfg.attention_scale)
     return sparse_mla_attend(q, win_l[idx], valid, d_v=cfg.kv_lora_rank,
                              sm_scale=cfg.attention_scale)
 
@@ -346,19 +365,20 @@ def _sparse_core(cfg, q, q_i, w_i, lat, kidx, ctx):
     widths = sorted({-(-most // d) for d in (4, 2, 1)})
 
     def at_width(pages):
-        def run(qs, qis, wis, ps_, slot_pages):
+        def run(qs, qis, wis, ps_, slot_pages, last):
             return _attend_block(cfg, qs, qis, wis, ps_,
-                                 *window(slot_pages[:pages]))
+                                 *window(slot_pages[:pages]), last)
         return run
 
     def unit(w, y):
         r0, n, slot = work[w, 0], work[w, 1], work[w, 2]
         cut = lambda v: jax.lax.dynamic_slice_in_dim(v, r0, unit_rows)
-        need = pp[r0 + n - 1] // ps + 1
+        last = pp[r0 + n - 1]
+        need = last // ps + 1
         out = jax.lax.switch(
             sum((need > w_).astype(jnp.int32) for w_ in widths[:-1]),
             [at_width(w_) for w_ in widths],
-            cut(qp), cut(qip), cut(wip), cut(pp), table[slot])
+            cut(qp), cut(qip), cut(wip), cut(pp), table[slot], last)
         live = (jnp.arange(unit_rows) < n)[:, None, None]
         return jax.lax.dynamic_update_slice_in_dim(
             y, jnp.where(live, out.astype(cfg.dtype), cut(y)), r0, 0)

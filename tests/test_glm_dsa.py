@@ -287,6 +287,85 @@ def test_selection_is_the_references_mask():
         assert got == want and len(got) == min(k, row + 1)
 
 
+def _unit_scores(pos, width, ties, seed):
+    """Masked index scores ``[Q, width]`` of query rows at ``pos``; with
+    ``ties`` on a grid of halves, so many are exactly equal in float32."""
+    sc = jax.random.normal(jax.random.PRNGKey(seed), (len(pos), width))
+    if ties:
+        sc = jnp.round(2 * sc) / 2
+    return jnp.where(jnp.arange(width) <= jnp.asarray(pos)[:, None], sc,
+                     -jnp.inf)
+
+
+@pytest.mark.parametrize("case, pos, live, ties", [
+    ("all_below_topk", range(0, 20), 20, False),
+    ("context_is_topk", range(4, 24), 20, False),
+    ("past_topk_two_blocks", range(600, 620), 20, False),
+    ("past_topk_all_blocks", range(1280, 1300), 20, False),
+    ("padded_unit", list(range(700, 705)) + list(range(15)), 5, False),
+    ("ties_at_the_kth", range(900, 920), 20, True),
+])
+def test_a_unit_streams_what_it_would_gather(case, pos, live, ties):
+    """The streamed form (``selection_mask`` + the ``sparse_mla_stream``
+    kernel, interpret mode) equals ``sparse_mla_attend`` over ``win_l[idx]``
+    for a unit of 20 rows (two query tiles, the second padded) against a
+    window of 1,300 positions (three blocks, those past the unit's last
+    position skipped): the same softmax over the same rows, a re-ordered
+    float32 sum apart.  The mask holds exactly the list's positions: all
+    causal ones while there are no more than ``k``, else exactly ``k``, and
+    among scores exactly equal to the ``k``-th the lower positions."""
+    from flashinfer_tpu.ops.sparse_mla import (
+        select_topk, selection_mask, sparse_mla_attend, sparse_mla_stream)
+
+    k, heads, width, d, d_v = 24, 4, 1300, 128, 32
+    pos = list(pos)
+    kq, kwin = jax.random.split(jax.random.PRNGKey(len(case)))
+    q = jax.random.normal(kq, (len(pos), heads, d))
+    win = jax.random.normal(kwin, (width, d))
+    scores = _unit_scores(pos, width, ties, seed=live)
+    idx, valid = select_topk(scores, k)
+    mask = np.asarray(selection_mask(scores, idx, valid))
+    sc = np.asarray(scores)
+    tied = 0
+    for row, p in enumerate(pos):
+        want = np.lexsort((np.arange(p + 1), -sc[row, :p + 1]))[:k]
+        assert set(np.nonzero(mask[row])[0]) == set(want), (case, row)
+        assert mask[row].sum() == min(k, p + 1)
+        kth = sc[row, want[-1]]
+        tied += int((sc[row, :p + 1] == kth).sum() > (sc[row, want] == kth).sum())
+    assert (tied > 0) == ties     # some tie at the k-th score was cut
+    kw = dict(d_v=d_v, sm_scale=0.2)
+    want = sparse_mla_attend(q, win[idx], valid, **kw)
+    got = sparse_mla_stream(q, win, jnp.asarray(mask), pos[live - 1], **kw)
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    np.testing.assert_allclose(got[:live], want[:live], atol=5e-6)
+
+
+def test_units_stream_through_the_engine(params, monkeypatch):
+    """``test_prefill_then_decode_matches_the_reference`` again with windows
+    of several blocks (a block cut to 16 positions here, so a 64-position
+    window is four and a unit skips those past its last row): prompts
+    chunked into units that stream, packed with decode lanes that gather,
+    serve the reference's logits; the two facts count the units."""
+    from flashinfer_tpu.ops import sparse_mla
+
+    monkeypatch.setattr(sparse_mla, "STREAM_BLOCK", 16)
+    eng = _engine(params)
+    reqs = [EngineRequest(f"r{i}", p, max_new_tokens=4)
+            for i, p in enumerate(_prompts((45, 9, 27), seed=3))]
+    seen = []
+    got = _serve(eng, reqs, seen.append)
+    for r in reqs:
+        assert len(r.out_tokens) == 4
+        _close(got[r.rid], _reference_logits(r))
+    units = sum(f["attn_units"] for f in seen)
+    # 45 + 9 + 27 prompt tokens in units of at most 4 rows: at least 21
+    assert units >= 21
+    assert sum(f["attn_units_streamed"] for f in seen) == units
+    lanes_only = [f for f in seen if f["prefill_tokens"] == 0]
+    assert lanes_only and all(f["attn_units"] == 0 for f in lanes_only)
+
+
 def test_run_sparse_takes_the_models_scale():
     """``BatchMLAPagedAttentionWrapper.run_sparse``: its default
     ``sm_scale`` is a placeholder, ``1 / sqrt(d_ckv + d_kpe)``, which is no

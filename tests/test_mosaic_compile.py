@@ -429,23 +429,37 @@ def test_moe_gmm_at_glm_widths(chip, compiled_branch, k, n):
                      compiled.as_text())
 
 
-def test_glm_sparse_unit(chip):
+@pytest.mark.parametrize("k", [4160, 8320, 16640])
+def test_glm_sparse_unit(chip, compiled_branch, k):
     """One unit of GLM-5's sparse attention at the cell's shapes (128 query
-    rows against a 16,640-position window: index scores, the exact
-    top-2,048, the gather of each row's list, absorbed MLA over it) fits the
-    chip beside the cell's 10.9 GB of weights and pages, under its scope
-    names (plain XLA: there is no kernel to find)."""
+    rows against a window of a quarter, a half or all of a request's 16,640
+    positions: index scores, the exact top-2,048, the list as a mask, the
+    window streamed once under it by the ``sparse_mla_stream`` kernel) fits
+    the chip beside the cell's 10.9 GB of weights and pages.  The kernel is
+    a custom call of ``attn.core`` itself, outside the two scopes nested in
+    it, so ``step.index_ms_per_step`` and ``step.select_ms_per_step`` keep
+    reading what they read."""
     from flashinfer_tpu.models.glm_dsa import GlmDsaConfig, _attend_block
 
     mcfg = GlmDsaConfig()
-    q, k = 128, 16640
-    compiled = jax.jit(
-        lambda *a: _attend_block(mcfg, *a)).lower(
-        _sds(chip, (q, 64, 640), "bfloat16"),
+    q = 128
+
+    def unit(*a):
+        with jax.named_scope("attn.core"):
+            return _attend_block(mcfg, *a)
+
+    compiled = _compile(
+        unit, _sds(chip, (q, 64, 640), "bfloat16"),
         _sds(chip, (q, 32, 128), "bfloat16"), _sds(chip, (q, 32), "float32"),
         _sds(chip, (q,), "int32"), _sds(chip, (k, 128), "bfloat16"),
-        _sds(chip, (k, 640), "bfloat16")).compile()
+        _sds(chip, (k, 640), "bfloat16"), _sds(chip, (), "int32"))
     hlo = compiled.as_text()
     for scope in ("attn.index", "attn.select"):
         assert f"/{scope}/" in hlo, scope
-    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+    kernel = re.findall(r"%sparse_mla_stream(?:\.\d+)? = .*custom-call\(.*", hlo)
+    assert len(kernel) == 1
+    assert re.search(r'op_name="jit\(unit\)/(\S+/)?attn\.core/', kernel[0])
+    assert "attn.index" not in kernel[0] and "attn.select" not in kernel[0]
+    # the scores, the sort's operands and the mask, each [128, k] wide: no
+    # [128, 2048, 640] block of gathered rows (335 MB and its two readers)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e8
