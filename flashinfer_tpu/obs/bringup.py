@@ -89,6 +89,7 @@ DRIVER_FOR = {
     "_kda_chunk_kernel": "kda",
     "_ssd_chunk_kernel": "mamba",
     "_mla_decode_kernel": "mla_decode",
+    "_stream_kernel": "sparse_mla_stream",
     "_gather_gmm_rowcache_kernel": "gather_gmm_rowcache",
     "_decode_split_kernel_fused_heads": "decode_split",
     "_fp4_decode_kernel": "fp4_decode",
@@ -603,6 +604,25 @@ def _drv_mla_decode(tactic=None):
         qn, qp, ckv, kpe, pt, lens, sm_scale=sm, layout="packed"))
 
 
+def _drv_sparse_mla_stream(tactic=None):
+    import jax
+    import jax.numpy as jnp
+
+    from flashinfer_tpu.ops.sparse_mla import sparse_mla_stream
+
+    # one 16-token tile of 8 heads against two blocks of a 640-wide window
+    # (512 value columns: the 0:512 lane slice, the bf16 cast of the
+    # probabilities), every second position selected
+    Q, H, D, S, d_v = 16, 8, 640, 1024, 512
+    ka, kb = _keys(2)
+    q = jax.random.normal(ka, (Q, H, D), jnp.bfloat16)
+    window = jax.random.normal(kb, (S, D), jnp.bfloat16)
+    mask = (jnp.arange(S)[None, :] % 2 == 0) & (
+        jnp.arange(S)[None, :] <= 600 + jnp.arange(Q)[:, None])
+    jax.block_until_ready(sparse_mla_stream(
+        q, window, mask, jnp.int32(615), d_v=d_v, sm_scale=D ** -0.5))
+
+
 def _drv_gather_gmm_rowcache(tactic=None):
     import jax
     import jax.numpy as jnp
@@ -831,6 +851,7 @@ DRIVERS: Dict[str, Callable] = {
     "kda": _drv_kda,
     "mamba": _drv_mamba,
     "mla_decode": _drv_mla_decode,
+    "sparse_mla_stream": _drv_sparse_mla_stream,
     "gather_gmm_rowcache": _drv_gather_gmm_rowcache,
     "gmm": _drv_gmm,
     "paged_decode": _drv_paged_decode,

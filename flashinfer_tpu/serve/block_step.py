@@ -20,8 +20,14 @@ without int8 pages, which no such model runs.
 
 Such a model object supplies, beside the sizes every model has:
 
-- ``layer_kinds()``: ``"attention"``, ``"recurrent"`` or ``"latent"`` per
-  layer; an attention layer gets K/V pages, a recurrent one the arrays of
+- ``layer_kinds()``: ``"attention"``, ``"window"``, ``"recurrent"`` or
+  ``"latent"`` per layer; an attention layer gets K/V pages; a window layer
+  (its queries see the last ``attention_window`` positions, the query's own
+  among them) gets K/V pages in a SECOND pool sized by the window
+  (``serve/window_pool.py``): a request holds there only the pages from
+  position ``kv_len - attention_window`` on, the pages behind are released
+  after each step and their rows are gone by design; a recurrent one the
+  arrays of
   ``new_recurrent_state(slots)`` (``[slots, ...]``, one slot a batch slot),
   a latent one the arrays of ``new_latent_pages(num_pages, page_size)``
   (``[pages, page_size, W]``, no head axis: one row a token for all heads,
@@ -31,7 +37,9 @@ Such a model object supplies, beside the sizes every model has:
   unit of ``serve/recurrent.py``'s plan.  A latent layer attends by itself
   (``ctx.page_table``, ``ctx.append``): the engine plans and launches no
   GQA work unit for a model without an attention layer;
-- ``attention_rope`` (False: no rotation) and ``attention_scale``;
+- ``attention_rope`` (False: no rotation; or ``{kind: bool}``, rotation
+  being a property of the layer kind where window layers rotate and full
+  layers do not) and ``attention_scale``;
 - ``engine_embed(params, tokens)``, ``engine_layer(li, x, layer, cache,
   ctx) -> (x, cache)``, ``engine_head(params, x_last) -> float32 logits``;
 - ``engine_facts(aux, tokens)``: the step's facts from what its layers put
@@ -40,6 +48,7 @@ Such a model object supplies, beside the sizes every model has:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 
 import jax
@@ -55,9 +64,11 @@ _NEG_INF = -1e30
 class StepContext:
     """What the step hands the model's layer, once per trace.
 
-    ``attend(q, k, v, cache) -> (out, cache)`` writes the K/V rows into the
-    pages and attends over them (the engine's tier, the model's rotation
-    and scale); ``plan`` is ``serve/recurrent.py``'s plan of the step:
+    ``attend(q, k, v, cache, kind="attention") -> (out, cache)`` writes the
+    K/V rows into the pages and attends over them (the engine's tier, the
+    model's rotation and scale); ``kind="window"`` picks the window layers'
+    pool, page table, plan and rotation, and the window rule; ``plan`` is
+    ``serve/recurrent.py``'s plan of the step:
     ``n_tokens`` and, with recurrent or latent layers, each slot's segment
     and the work units of the longer segments; ``aux`` takes what the layers
     want fetched.  For a latent layer: ``page_table`` ``[max_batch, pages a
@@ -110,18 +121,30 @@ def build(eng):
     K = eng._ppr * cfg.page_size   # per-request KV window rows
     sm_scale = float(getattr(mcfg, "attention_scale", None)
                      or 1.0 / float(mcfg.head_dim) ** 0.5)
-    use_rope = bool(getattr(mcfg, "attention_rope", True))
+    rope = getattr(mcfg, "attention_rope", True)
+    use_rope = rope if isinstance(rope, dict) else \
+        dict.fromkeys(("attention", "window"), bool(rope))
+    # a model with window layers: their scopes are told apart
+    # (``attn.core`` > ``attn.window`` / ``attn.full``), and their side of
+    # the schedule rides the model's plan (``mplan["window"]``)
+    has_window = eng._window
+    window_left = mcfg.attention_window - 1 if has_window else -1
+    Kw = eng._wpages.cap * cfg.page_size if has_window else 0
+    wgeom = eng._wgeom
+    full_scope = (lambda: jax.named_scope("attn.full")) if has_window \
+        else contextlib.nullcontext
     sampling = cfg.sampling
     base_key = jax.random.PRNGKey(cfg.seed)
     kernel_backend, geom = eng._kernel_backend, eng._geom
     return_logits = cfg.return_logits
     Hq, Hkv, D = mcfg.num_qo_heads, mcfg.num_kv_heads, mcfg.head_dim
 
-    def _window(c, table):
+    def _window(c, table, rows=K):
         # [pages, Hkv, PS, D] -> [rows, K, Hkv, D]: the row offset of KV
-        # position j is always j
+        # position j is always j (for a window layer: j less the position of
+        # the first row its request still holds)
         w = c[table]
-        return jnp.swapaxes(w, 2, 3).reshape(w.shape[0], K, Hkv, D)
+        return jnp.swapaxes(w, 2, 3).reshape(w.shape[0], rows, Hkv, D)
 
     def _attend(q, kw, vw, lo, hi):
         # the reference tier's per-token windowed attention: valid rows j in
@@ -152,18 +175,58 @@ def build(eng):
                 token_page, cap=page_cap(token_page.shape[0], cfg.page_size,
                                          cfg.max_batch))
 
-        def _attend_pages(q, k, v, cache):
+        if has_window:
+            win = mplan["window"]
+            with jax.named_scope("attn.kv_write"):
+                wtouched = touched_pages(
+                    win["token_page"],
+                    cap=page_cap(token_page.shape[0], cfg.page_size,
+                                 cfg.max_batch))
+
+        def _rotated(q, k, kind):
+            if not use_rope[kind]:
+                return q, k
+            from flashinfer_tpu.rope import apply_rope_pos_ids
+
+            return apply_rope_pos_ids(q, k, positions,
+                                      rope_theta=mcfg.rope_theta)
+
+        def _attend_window(q, k, v, cache):
+            """A window layer: its own pool, page table and plan; positions
+            counted from the first row the request still holds."""
             kc, vc = cache
             with jax.named_scope("attn.kv_write"):
-                if use_rope:
-                    from flashinfer_tpu.rope import apply_rope_pos_ids
+                q, k = _rotated(q, k, "window")
+                kc, vc = append_rows(kc, vc, k.astype(kc.dtype),
+                                     v.astype(vc.dtype), wtouched, token_slot)
+            with jax.named_scope("attn.core"), jax.named_scope("attn.window"):
+                if kernel_backend:
+                    from flashinfer_tpu.serve.engine_kernels import (
+                        engine_window_attention)
 
-                    q, k = apply_rope_pos_ids(
-                        q, k, positions, rope_theta=mcfg.rope_theta)
+                    # graft-lint: ok env read once a trace, pinned per rung
+                    o = engine_window_attention(
+                        q, kc, vc, win["plans"], geom=wgeom,
+                        sm_scale=sm_scale, window_left=window_left)
+                else:
+                    hi = positions - win["first_tok"][tok_req]
+                    o, _ = _attend(
+                        q, _window(kc, win["page_table"], Kw)[tok_req],
+                        _window(vc, win["page_table"], Kw)[tok_req],
+                        jnp.maximum(hi - window_left, 0), hi)
+            return o.astype(mcfg.dtype), (kc, vc)
+
+        def _attend_pages(q, k, v, cache, kind="attention"):
+            if kind == "window":
+                # graft-lint: ok env read once a trace, pinned per rung
+                return _attend_window(q, k, v, cache)
+            kc, vc = cache
+            with jax.named_scope("attn.kv_write"):
+                q, k = _rotated(q, k, "attention")
                 # pad lanes go to the scratch page (pool page 0)
                 kc, vc = append_rows(kc, vc, k.astype(kc.dtype),
                                      v.astype(vc.dtype), touched, token_slot)
-            with jax.named_scope("attn.core"):
+            with jax.named_scope("attn.core"), full_scope():
                 if kernel_backend:
                     from flashinfer_tpu.serve.engine_kernels import (
                         engine_kernel_attention)

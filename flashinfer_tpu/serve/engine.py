@@ -327,6 +327,12 @@ class EngineRequest:
     out_tokens: List[int] = dataclasses.field(default_factory=list)
     slot: int = -1
     pages: List[int] = dataclasses.field(default_factory=list)
+    # a model with window layers (serve/window_pool.py): the request's pages
+    # in the window layers' pool, from page index ``wfirst`` on (the pages
+    # before it are released), and how many of them admission promised it
+    wpages: List[int] = dataclasses.field(default_factory=list)
+    wfirst: int = 0
+    wreserved: int = 0
     kv_len: int = 0          # tokens whose KV is materialized
     split: int = -1          # cascade level boundary (page-aligned);
     #                          frozen at FIRST admission and preserved
@@ -533,10 +539,12 @@ class ServingEngine:
                        else ("attention",) * model_cfg.num_layers)
         self._recurrent = "recurrent" in self._kinds
         self._latent = "latent" in self._kinds
+        self._window = "window" in self._kinds
         # state the K/V paths cannot move or share: a recurrent layer's
         # slots are not in the pages, a latent layer's pages are not K/V
-        # pairs a head (PrefixCache and kv_tier assume those)
-        self._own_state = self._recurrent or self._latent
+        # pairs a head (PrefixCache and kv_tier assume those), a window
+        # layer's pages behind the window are released
+        self._own_state = self._recurrent or self._latent or self._window
         if self._own_state and config.kv_offload != "off":
             self._refuse_own_state("kv_offload='host' (host spill)")
         if self._own_state and config.role != "unified":
@@ -585,9 +593,20 @@ class ServingEngine:
                     else jnp.dtype(model_cfg.dtype))
         self.kv_dtype = kv_dtype
         self._int8_kv = kv_dtype == jnp.int8
-        if self._latent and self._int8_kv:
+        if (self._latent or self._window) and self._int8_kv:
             self._refuse_own_state("kv_dtype=int8 (int8 pages)")
         ps, ppr = config.page_size, config.pages_per_req()
+        # window layers' pages live in a pool of their own, sized by the
+        # window, the largest chunk of a step and max_batch
+        self._wpages = None
+        if self._window:
+            from flashinfer_tpu.serve.window_pool import WindowPages
+
+            self._wpages = WindowPages(
+                window=model_cfg.attention_window,
+                chunk=min(config.prefill_budget_tokens,
+                          max(config.rungs())),
+                page_size=ps, max_batch=config.max_batch, pages_per_req=ppr)
         # one entry a layer, donated through the step together: K/V pages
         # for an attention layer; for a recurrent layer the model's state
         # arrays ``[max_batch, ...]``, one SLOT a batch slot
@@ -596,8 +615,10 @@ class ServingEngine:
         # nothing; for a latent layer the model's paged arrays ``[num_pages,
         # page_size, W]`` (no head axis), addressed by the same page tables
         def _layer_state(kind):
-            if kind == "attention":
-                shape = (config.num_pages, model_cfg.num_kv_heads, ps,
+            if kind in ("attention", "window"):
+                pages = (config.num_pages if kind == "attention"
+                         else self._wpages.pool.num_pages)
+                shape = (pages, model_cfg.num_kv_heads, ps,
                          model_cfg.head_dim)
                 return (jnp.zeros(shape, kv_dtype),
                         jnp.zeros(shape, kv_dtype))
@@ -606,15 +627,16 @@ class ServingEngine:
             if kind == "latent":
                 return model_cfg.new_latent_pages(config.num_pages, ps)
             raise ValueError(f"layer kind {kind!r} is none of attention, "
-                             "recurrent, latent")
+                             "window, recurrent, latent")
 
         self.caches = [_layer_state(kind) for kind in self._kinds]
         self._ppr = ppr
         # the GQA kernels' geometry and work units are planned for attention
-        # layers only: a model without one attends by itself
+        # and window layers only: a model without one attends by itself
         self._kernel_backend = (config.attention_backend == "kernel"
-                                and "attention" in self._kinds)
-        self._geom = None
+                                and ("attention" in self._kinds
+                                     or self._window))
+        self._geom = self._wgeom = None
         # launched-vs-effective unit accounting (kernel tier): what the
         # padded work-unit grids actually execute vs the attended pairs
         # — costmodel.engine_step prices the bench A/B from these
@@ -630,15 +652,22 @@ class ServingEngine:
         if self._kernel_backend:
             from flashinfer_tpu.serve.engine_kernels import EngineKernelGeom
 
-            self._geom = EngineKernelGeom.build(
-                page_size=ps, pages_per_req=ppr,
-                max_batch=config.max_batch,
-                max_rung=max(config.rungs()),
-                num_kv_heads=model_cfg.num_kv_heads,
-                head_dim=model_cfg.head_dim,
-                kv_itemsize=kv_dtype.itemsize,
-                num_splits=config.decode_num_splits,
-            )
+            def _geom_of(pages_per_req):
+                return EngineKernelGeom.build(
+                    page_size=ps, pages_per_req=pages_per_req,
+                    max_batch=config.max_batch,
+                    max_rung=max(config.rungs()),
+                    num_kv_heads=model_cfg.num_kv_heads,
+                    head_dim=model_cfg.head_dim,
+                    kv_itemsize=kv_dtype.itemsize,
+                    num_splits=config.decode_num_splits,
+                )
+
+            self._geom = _geom_of(ppr)
+            if self._window:
+                # the window layers' plan: the same launches over a
+                # request's narrower window page list
+                self._wgeom = _geom_of(self._wpages.cap)
         # each rung is one whole-model compile: keep it across processes
         from flashinfer_tpu import env
 
@@ -662,6 +691,11 @@ class ServingEngine:
                 f"{what} is not supported for a model with recurrent "
                 "layers: the recurrent state slots are neither snapshotted "
                 "nor moved with the K/V pages")
+        if self._window:
+            raise NotImplementedError(
+                f"{what} is not supported for a model with window layers: "
+                "their pages behind the window are released, and nothing "
+                "shares, spills, migrates or quantizes the second pool yet")
         raise NotImplementedError(
             f"{what} is not supported for a model with latent layers: its "
             "latent and index pages are not K/V pairs a head, and nothing "
@@ -772,6 +806,8 @@ class ServingEngine:
         if r.pages:
             self.pool.decref(r.pages)
             r.pages = []
+        if self._window:
+            self._wpages.release(r)
         if r.slot >= 0:
             self._slots[r.slot] = None
             r.slot = -1
@@ -868,7 +904,9 @@ class ServingEngine:
         need = self._pages_needed(r) - len(hit_pages)
         if need > self.pool.free_pages:
             self.prefix_cache.evict(need - self.pool.free_pages)
-        if need > self.pool.free_pages:
+        if need > self.pool.free_pages or (
+                self._window and not self._wpages.reserve(
+                    r, r.total_len() + self._remaining_new(r))):
             self.pool.decref(hit_pages)  # admission failed: un-adopt
             return False
         fresh = self.pool.alloc(need)
@@ -1249,6 +1287,19 @@ class ServingEngine:
 
     # -- step construction + execution ------------------------------------
 
+    def _count_units(self, facts: dict, st: dict) -> None:
+        """Add one plan's useful-against-launched counts to the step's
+        facts and the run's totals (a model with window layers lowers two
+        plans a step)."""
+        us = self.unit_stats
+        for k in self._UNIT_FACTS:
+            facts[k] += st[k]
+            us[k] += st[k]
+        us["kv_pairs_launched"] += (st["prefill_cells_launched"]
+                                    + st["decode_cells_launched"])
+        us["kv_rows_launched"] += (st["prefill_rows_launched"]
+                                   + st["decode_rows_launched"])
+
     def _sample_seed(self, r: EngineRequest, token_index: int) -> int:
         return (r.arrival * 131071 + token_index) & 0x7FFFFFFF
 
@@ -1273,7 +1324,9 @@ class ServingEngine:
         device: ``upload_kib`` of host arrays in ``upload_transfers``
         buffers, ``plans_lowered`` (1 on the kernel tier), level 0's
         ``mask_units`` and whether the whole bitmap crossed for them
-        (``mask_full_uploads``)."""
+        (``mask_full_uploads``); for a model with window layers the unit
+        counts are of both plans, and ``window_pages_held`` (while the
+        step runs) / ``_pool`` / ``_share`` / ``_released`` (after it)."""
         from flashinfer_tpu import obs
 
         t_step = time.perf_counter()
@@ -1423,14 +1476,7 @@ class ServingEngine:
                 plans = _ek.build_engine_work_units(segs, rung=rung,
                                                     geom=self._geom)
             st = plans["stats"]
-            us = self.unit_stats
-            for k in self._UNIT_FACTS:
-                facts[k] = st[k]
-                us[k] += st[k]
-            us["kv_pairs_launched"] += (st["prefill_cells_launched"]
-                                        + st["decode_cells_launched"])
-            us["kv_rows_launched"] += (st["prefill_rows_launched"]
-                                       + st["decode_rows_launched"])
+            self._count_units(facts, st)
             with _span(tick, "lower.upload"):
                 kplans = _ek.plans_to_host(
                     plans, self._geom.mask_unit_cap(rung))
@@ -1448,7 +1494,8 @@ class ServingEngine:
             with _span(tick, "lower.build" if own else "lower.recurrent"):
                 mplan, rfacts = _rec.build_plan(
                     rsegs, tokens=total, rung=rung, max_batch=Bpad,
-                    chunk=mcfg.scan_chunk if self._own_state else 0)
+                    chunk=mcfg.scan_chunk if self._recurrent or self._latent
+                    else 0)
             if own:
                 with _span(tick, "lower.upload"):
                     pass
@@ -1456,6 +1503,15 @@ class ServingEngine:
                 facts.update(rfacts)
                 facts["state_slots_used"] = sum(
                     s is not None for s in self._slots)
+            if self._window:
+                # the window layers' side of the schedule and their plan
+                # ride the model's plan: one buffer for everything
+                mplan["window"], wst, wfacts = self._wpages.lower(
+                    sched, self._running, rung=rung, max_batch=Bpad,
+                    geom=self._wgeom, span=lambda n: _span(tick, n))
+                facts.update(wfacts)
+                if wst is not None:
+                    self._count_units(facts, wst)
         if tick is not None:
             tick.enter("dispatch")
 
@@ -1535,6 +1591,10 @@ class ServingEngine:
                 if upto > r.inserted_pages:
                     self.prefix_cache.insert(r.prompt, r.pages, upto)
                     r.inserted_pages = upto
+        if self._window:
+            # pages wholly behind the window go back to the window pool
+            facts["window_pages_released"] = sum(
+                self._wpages.trim(r) for r, _ in sched)
         finished = 0
         for r in samplers:
             tok = int(tokens[r.slot])

@@ -172,6 +172,7 @@ def build_engine_work_units(
     *,
     rung: int,
     geom: EngineKernelGeom,
+    window_left: int = -1,
 ):
     """Lower one engine step's schedule into the three plan-array forms.
 
@@ -198,6 +199,15 @@ def build_engine_work_units(
 
     Every array shape depends only on ``rung`` and ``geom`` — the
     engine's compile-once contract.
+
+    ``window_left >= 0`` is the plan of a model's WINDOW layers
+    (serve/window_pool.py): ``segs`` then carry each request's window
+    page list and positions counted from that list's first page (the
+    causal and the window rule read differences of positions only), with
+    ``split == 0``: level 0 is empty and left out (no ``prefill0`` in
+    the result), a prompt chunk rides level 1 under the planner's own window
+    rule, which prunes the chunks behind the window, and a decode lane
+    the split-KV units over the pages it still holds.
     """
     from flashinfer_tpu.ops.paged_decode import build_decode_split_units
     from flashinfer_tpu.ops.paged_prefill import (CODE_PARTIAL_MASK,
@@ -215,54 +225,60 @@ def build_engine_work_units(
                              f"{s.row0})")
         row += s.n
     U = geom.prefill_unit_cap(rung)
+    windowed = window_left >= 0
+    if windowed and any(s.split for s in segs):
+        raise ValueError("a window plan has no shared level: split 0")
 
     # ---- level 0: shared-prefix groups, causal-by-global-position ----
-    qo0 = [0]
-    kv0_lens: List[int] = []
-    pi0 = [0]
-    pages0: List[int] = []
-    mask_parts: List[np.ndarray] = []
+    # (a window plan has none: every segment's would be empty)
+    plan0 = None
+    if not windowed:
+        qo0 = [0]
+        kv0_lens: List[int] = []
+        pi0 = [0]
+        pages0: List[int] = []
+        mask_parts: List[np.ndarray] = []
 
-    def _close_seg0(n_rows, kv_len, pages, pos):
-        qo0.append(qo0[-1] + n_rows)
-        kv0_lens.append(kv_len)
-        pages0.extend(pages)
-        pi0.append(len(pages0))
-        if kv_len > 0:
-            cols = np.arange(kv_len)
-            mask_parts.append(
-                (cols[None, :] <= pos[:, None]).reshape(-1))
+        def _close_seg0(n_rows, kv_len, pages, pos):
+            qo0.append(qo0[-1] + n_rows)
+            kv0_lens.append(kv_len)
+            pages0.extend(pages)
+            pi0.append(len(pages0))
+            if kv_len > 0:
+                cols = np.arange(kv_len)
+                mask_parts.append(
+                    (cols[None, :] <= pos[:, None]).reshape(-1))
 
-    i = 0
-    while i < len(segs):
-        s = segs[i]
-        run = s.pages[:s.split // ps]
-        j = i
-        rows = 0
-        pos: List[int] = []
-        while j < len(segs) and segs[j].group == s.group:
-            e = segs[j]
-            pos.extend(range(e.kv_after - e.n, e.kv_after))
-            rows += e.n
-            j += 1
-        if run:
-            _close_seg0(rows, s.split, run, np.asarray(pos, np.int64))
-        else:
-            # no shared span (split == 0): level 0 is empty for these
-            # rows — a kv_len=0 segment emits the (0, -inf) pass-through
-            _close_seg0(rows, 0, (), np.zeros(0, np.int64))
-        i = j
-    if total < rung:  # rung padding rows: defined zeros, empty state
-        _close_seg0(rung - total, 0, (), np.zeros(0, np.int64))
-    mask0 = (np.concatenate(mask_parts) if mask_parts
-             else np.zeros(0, bool))
-    plan0 = build_prefill_work_units(
-        np.asarray(qo0, np.int64), np.asarray(pi0, np.int64),
-        np.asarray(pages0, np.int64), np.asarray(kv0_lens, np.int64),
-        geom.block_q, geom.prefill_ppc, ps,
-        mask_flat=mask0, causal=False, window_left=-1,
-        pack_tiles=True, prune=True, num_units_pad=U,
-    )
+        i = 0
+        while i < len(segs):
+            s = segs[i]
+            run = s.pages[:s.split // ps]
+            j = i
+            rows = 0
+            pos: List[int] = []
+            while j < len(segs) and segs[j].group == s.group:
+                e = segs[j]
+                pos.extend(range(e.kv_after - e.n, e.kv_after))
+                rows += e.n
+                j += 1
+            if run:
+                _close_seg0(rows, s.split, run, np.asarray(pos, np.int64))
+            else:
+                # no shared span (split == 0): level 0 is empty for these
+                # rows — a kv_len=0 segment emits the (0, -inf) pass-through
+                _close_seg0(rows, 0, (), np.zeros(0, np.int64))
+            i = j
+        if total < rung:  # rung padding rows: defined zeros, empty state
+            _close_seg0(rung - total, 0, (), np.zeros(0, np.int64))
+        mask0 = (np.concatenate(mask_parts) if mask_parts
+                 else np.zeros(0, bool))
+        plan0 = build_prefill_work_units(
+            np.asarray(qo0, np.int64), np.asarray(pi0, np.int64),
+            np.asarray(pages0, np.int64), np.asarray(kv0_lens, np.int64),
+            geom.block_q, geom.prefill_ppc, ps,
+            mask_flat=mask0, causal=False, window_left=-1,
+            pack_tiles=True, prune=True, num_units_pad=U,
+        )
 
     # ---- level 1: per-segment suffix windows, native causal rule ----
     qo1 = [0]
@@ -287,7 +303,7 @@ def build_engine_work_units(
         np.asarray(qo1, np.int64), np.asarray(pi1, np.int64),
         np.asarray(pages1, np.int64), np.asarray(kv1_lens, np.int64),
         geom.block_q, geom.prefill_ppc, ps,
-        causal=True, window_left=-1,
+        causal=True, window_left=window_left,
         pack_tiles=True, prune=True, num_units_pad=U,
     )
 
@@ -313,14 +329,16 @@ def build_engine_work_units(
     chunk_tokens = geom.prefill_ppc * ps
     # what the two prefill launches run: each level's ``n_units``, the
     # trip count of its unit axis (the pad units up to U are not run)
-    launched = int(plan0["n_units"]) + int(plan1["n_units"])
+    # a window plan launches no level 0 (every segment's is empty)
+    levels = [plan1] if windowed else [plan0, plan1]
+    launched = sum(int(p["n_units"]) for p in levels)
     stats = {
-        "prefill_units": plan0["stats"]["units"] + plan1["stats"]["units"],
+        "prefill_units": sum(p["stats"]["units"] for p in levels),
         "prefill_units_launched": launched,
         "prefill_cells_launched": float(
             launched * geom.block_q * chunk_tokens),
-        "prefill_cells_valid": float(plan0["stats"]["mxu_cells_valid"]
-                                     + plan1["stats"]["mxu_cells_valid"]),
+        "prefill_cells_valid": float(sum(
+            p["stats"]["mxu_cells_valid"] for p in levels)),
         "prefill_rows_launched": float(launched * chunk_tokens),
         "decode_pages_real": dplan["stats"]["pages_real"],
         "decode_pages_launched": dplan["stats"]["pages_launched"],
@@ -330,11 +348,11 @@ def build_engine_work_units(
             dplan["stats"]["pages_launched"] * ps),
         "decode_cells_valid": float(dec_lens.sum()),
         # level-0 units whose bitmap the launch expands
-        "mask_units": int(np.count_nonzero(
+        "mask_units": 0 if windowed else int(np.count_nonzero(
             plan0["code"] == CODE_PARTIAL_MASK)),
     }
-    return dict(prefill0=plan0, prefill1=plan1, decode=dplan,
-                dec_rows=dec_rows, stats=stats)
+    out = dict(prefill1=plan1, decode=dplan, dec_rows=dec_rows, stats=stats)
+    return out if windowed else dict(out, prefill0=plan0)
 
 
 def plans_to_host(plans: Dict, mask_cap: Optional[int] = None) -> Dict:
@@ -371,6 +389,17 @@ def plans_to_host(plans: Dict, mask_cap: Optional[int] = None) -> Dict:
     )
 
 
+def window_plans_to_host(plans: Dict) -> Dict:
+    """:func:`plans_to_host` for a window plan (``build_engine_work_units``
+    with ``window_left``): level 1 and the decode units, no bitmap."""
+    return dict(
+        prefill1={k: plans["prefill1"][k]
+                  for k in PREFILL_ARRAY_KEYS if k != "mask_bytes"},
+        decode={k: plans["decode"][k] for k in DECODE_ARRAY_KEYS},
+        dec_rows=plans["dec_rows"],
+    )
+
+
 def plans_on_device(kplans: Dict) -> Dict:
     """The rung-stable traced pytree of a plan bundle, from
     :func:`plans_to_host`'s tree once it is on the device: the step's
@@ -399,30 +428,19 @@ def plans_to_device(plans: Dict) -> Dict:
     return jax.device_put(plans_to_host(plans))
 
 
-def engine_kernel_attention(q, k_cache, v_cache, kplans, *,
-                            geom: EngineKernelGeom, sm_scale: float):
-    """One layer's engine attention on the Pallas work units (traced
-    inside the engine's jitted body): level-0 + level-1 prefill
-    launches, the split-KV decode launch scattered into level 1, and
-    the cascade merge fold.  Returns f32 ``[T, H, D]`` (the same
-    contract as the reference backend's compose output; int8-KV
-    v_scale is applied by the caller after the merge, which is exact
-    because merging is linear in V)."""
-    from flashinfer_tpu.cascade import compose_cascade_levels
+def _level1_and_decode(q, k_cache, v_cache, kplans, geom, sm_scale,
+                       window_left):
+    """Level 1's ``(out, lse)`` with the decode lanes' rows scattered in."""
     from flashinfer_tpu.ops.paged_decode import paged_decode_attention_split
     from flashinfer_tpu.ops.paged_prefill import fused_paged_prefill
 
-    p0, p1, pd = kplans["prefill0"], kplans["prefill1"], kplans["decode"]
-    o0, lse0 = fused_paged_prefill(
-        q, k_cache, v_cache, p0,
-        num_units=p0["qstart"].shape[0], block_q=geom.block_q,
-        pages_per_chunk=geom.prefill_ppc, sm_scale=sm_scale,
-        causal=False, return_lse=True, name="fused_paged_prefill_l0")
+    p1, pd = kplans["prefill1"], kplans["decode"]
     o1, lse1 = fused_paged_prefill(
         q, k_cache, v_cache, p1,
         num_units=p1["qstart"].shape[0], block_q=geom.block_q,
         pages_per_chunk=geom.prefill_ppc, sm_scale=sm_scale,
-        causal=True, return_lse=True, name="fused_paged_prefill_l1")
+        causal=True, window_left=window_left, return_lse=True,
+        name="fused_paged_prefill_l1")
     dec_rows = kplans["dec_rows"]
     # idle lanes carry row == rung: the gather clips to a real row whose
     # q is then attended against a kv_len=0 table (empty state), and
@@ -433,8 +451,43 @@ def engine_kernel_attention(q, k_cache, v_cache, kplans, *,
         num_units=pd["wu_req"].shape[0], num_splits=geom.num_splits,
         single_chunk=geom.single_chunk,
         pages_per_chunk=geom.decode_ppc, sm_scale=sm_scale,
-        return_lse=True)
+        window_left=window_left, return_lse=True)
     o1 = o1.at[dec_rows].set(od.astype(o1.dtype), mode="drop")
     lse1 = lse1.at[dec_rows].set(lsed.astype(lse1.dtype), mode="drop")
+    return o1, lse1
+
+
+def engine_window_attention(q, k_cache, v_cache, wplans, *,
+                            geom: EngineKernelGeom, sm_scale: float,
+                            window_left: int):
+    """One WINDOW layer's attention on the work units of its own plan
+    (``build_engine_work_units`` with ``window_left``): level 1 under the
+    window rule and the decode lanes' split-KV units over the pages the
+    window pool still holds; no level 0, so nothing to merge.  f32
+    ``[T, H, D]``."""
+    return _level1_and_decode(q, k_cache, v_cache, wplans, geom, sm_scale,
+                              window_left)[0]
+
+
+def engine_kernel_attention(q, k_cache, v_cache, kplans, *,
+                            geom: EngineKernelGeom, sm_scale: float):
+    """One layer's engine attention on the Pallas work units (traced
+    inside the engine's jitted body): level-0 + level-1 prefill
+    launches, the split-KV decode launch scattered into level 1, and
+    the cascade merge fold.  Returns f32 ``[T, H, D]`` (the same
+    contract as the reference backend's compose output; int8-KV
+    v_scale is applied by the caller after the merge, which is exact
+    because merging is linear in V)."""
+    from flashinfer_tpu.cascade import compose_cascade_levels
+    from flashinfer_tpu.ops.paged_prefill import fused_paged_prefill
+
+    p0 = kplans["prefill0"]
+    o0, lse0 = fused_paged_prefill(
+        q, k_cache, v_cache, p0,
+        num_units=p0["qstart"].shape[0], block_q=geom.block_q,
+        pages_per_chunk=geom.prefill_ppc, sm_scale=sm_scale,
+        causal=False, return_lse=True, name="fused_paged_prefill_l0")
+    o1, lse1 = _level1_and_decode(q, k_cache, v_cache, kplans, geom,
+                                  sm_scale, -1)
     out, _ = compose_cascade_levels([(o0, lse0), (o1, lse1)])
     return out

@@ -522,9 +522,9 @@ def test_l012_flags_schedule_value_in_plan_shape_static_real_engine():
     engine_kernels.py must flag L012 — and ONLY L012."""
     real = _real("serve/engine_kernels.py")
     skew = real.replace(
-        "pack_tiles=True, prune=True, num_units_pad=U,\n    )\n\n"
+        "pack_tiles=True, prune=True, num_units_pad=U,\n        )\n\n"
         "    # ---- level 1",
-        "pack_tiles=True, prune=True, num_units_pad=total,\n    )\n\n"
+        "pack_tiles=True, prune=True, num_units_pad=total,\n        )\n\n"
         "    # ---- level 1")
     assert skew != real
     by_pass = _new_pass_findings(

@@ -773,3 +773,109 @@ def test_step_facts_add_up_and_queue_wait_counts_once(params, backend):
         (a.first_sched_t - a.enqueue_t) + (b.first_sched_t - b.enqueue_t))
     assert all(f["queue_wait_s"] == 0.0 for f in busy
                if not f["first_scheduled"])
+
+
+# ---------------------------------------------------------------------------
+# The window layers' plan (ISSUE 37): the same planner once more, under the
+# window rule, over a request's window page list
+# ---------------------------------------------------------------------------
+
+
+def _window_segs():
+    """A 12-row chunk whose request holds 5 pages from its window's first
+    one (positions counted from there: 28 before the chunk, 40 after), and
+    a decode lane holding 3 pages (21 positions)."""
+    return [SchedSeg(row0=0, n=12, pages=(3, 4, 5, 6, 7), split=0,
+                     kv_after=40, decoding=False, slot=0, group=0),
+            SchedSeg(row0=12, n=1, pages=(9, 10, 11), split=0, kv_after=21,
+                     decoding=True, slot=2, group=1)]
+
+
+def test_window_plan_is_level_1_under_the_window_rule_and_no_level_0():
+    from flashinfer_tpu.ops.paged_prefill import build_prefill_work_units
+
+    g = _geom(ppr=5)
+    segs = _window_segs()
+    plans = build_engine_work_units(segs, rung=16, geom=g, window_left=11)
+    assert set(plans) == {"prefill1", "decode", "dec_rows", "stats"}
+    assert plans["stats"]["mask_units"] == 0
+    # level 1 is the planner's own plan of the chunk under causal + window
+    U = g.prefill_unit_cap(16)
+    want = build_prefill_work_units(
+        np.asarray([0, 12, 13, 16]), np.asarray([0, 5, 5, 5]),
+        np.asarray([3, 4, 5, 6, 7]), np.asarray([40, 0, 0]), g.block_q,
+        g.prefill_ppc, 8, causal=True, window_left=11, pack_tiles=True,
+        prune=True, num_units_pad=U)
+    for k in ("qstart", "rowlo", "rowhi", "qpos0", "kvstart", "kvlen",
+              "code", "pages", "n_units"):
+        np.testing.assert_array_equal(plans["prefill1"][k], want[k])
+    # chunks wholly behind the first query's window are pruned: a request
+    # that still held 300 positions launches one unit under the window rule
+    # and one a chunk without it
+    wide = _geom(ppr=40)
+    long = [SchedSeg(row0=0, n=12, pages=tuple(range(1, 39)), split=0,
+                     kv_after=300, decoding=False, slot=0, group=0)]
+    assert wide.prefill_ppc * 8 == 128     # 300 positions: three chunks
+    # (+ 1: the rung's padding rows keep their zero-write unit)
+    assert build_engine_work_units(long, rung=16, geom=wide)[
+        "prefill1"]["n_units"] == 3 + 1
+    assert build_engine_work_units(long, rung=16, geom=wide, window_left=11)[
+        "prefill1"]["n_units"] == 1 + 1
+    # the decode lane: its held pages, its length counted from the first
+    d = plans["decode"]
+    np.testing.assert_array_equal(d["pages"][2, :3], [9, 10, 11])
+    assert d["kvlen"][2] == 21 and plans["dec_rows"][2] == 12
+    # a window plan has no shared level
+    shared = [SchedSeg(row0=0, n=4, pages=(1, 2), split=8, kv_after=12,
+                       decoding=False, slot=0, group=0)]
+    with pytest.raises(ValueError, match="no shared level"):
+        build_engine_work_units(shared, rung=16, geom=g, window_left=11)
+
+
+@pytest.mark.parametrize("schedule", sorted(_LOWERED_SCHEDULES))
+def test_a_plan_without_a_window_is_the_plan_it_was(schedule):
+    """``window_left`` left out or -1: the three plans, leaf for leaf (what
+    ``_assert_plans_are_the_parents`` holds to the parent's rule)."""
+    from flashinfer_tpu.serve.engine_kernels import plans_to_host
+
+    g = _geom()
+    segs = _LOWERED_SCHEDULES[schedule]
+    a = build_engine_work_units(segs, rung=16, geom=g)
+    b = build_engine_work_units(segs, rung=16, geom=g, window_left=-1)
+    assert list(a) == ["prefill1", "decode", "dec_rows", "stats",
+                       "prefill0"] and a["stats"] == b["stats"]
+    _leaves_equal(plans_to_host(a, None), plans_to_host(b, None))
+    _assert_plans_are_the_parents(schedule, a, g)
+
+
+def test_window_attention_is_dense_attention_under_the_window():
+    """``engine_window_attention`` over the plan above against a dense
+    softmax over each row's last 12 positions."""
+    from flashinfer_tpu.serve.engine_kernels import (engine_window_attention,
+                                                     window_plans_to_host)
+
+    g = _geom(ppr=5)
+    segs = _window_segs()
+    plans = window_plans_to_host(
+        build_engine_work_units(segs, rung=16, geom=g, window_left=11))
+    hq, hkv, d, ps = 4, 2, 32, 8
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = jax.random.normal(kq, (16, hq, d), jnp.float32)
+    kc = jax.random.normal(kk, (12, hkv, ps, d), jnp.float32)
+    vc = jax.random.normal(kv, (12, hkv, ps, d), jnp.float32)
+    out = np.asarray(engine_window_attention(
+        q, kc, vc, jax.tree.map(jnp.asarray, plans), geom=g,
+        sm_scale=d ** -0.5, window_left=11))
+    for e in segs:
+        rows = jnp.swapaxes(kc[jnp.asarray(e.pages)], 1, 2).reshape(-1, hkv, d)
+        vals = jnp.swapaxes(vc[jnp.asarray(e.pages)], 1, 2).reshape(-1, hkv, d)
+        for i in range(e.n):
+            pos = e.kv_after - e.n + i
+            lo = max(pos - 11, 0)
+            kw, vw = rows[lo:pos + 1], vals[lo:pos + 1]
+            qg = q[e.row0 + i].reshape(hkv, hq // hkv, d)
+            p = jax.nn.softmax(
+                jnp.einsum("ngd,snd->ngs", qg, kw) * d ** -0.5, -1)
+            want = jnp.einsum("ngs,snd->ngd", p, vw).reshape(hq, d)
+            np.testing.assert_allclose(out[e.row0 + i], want, atol=2e-5)
+    assert not out[13:].any()      # rung padding: attention over nothing

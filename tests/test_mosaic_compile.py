@@ -463,3 +463,85 @@ def test_glm_sparse_unit(chip, compiled_branch, k):
     # the scores, the sort's operands and the mask, each [128, k] wide: no
     # [128, 2048, 640] block of gathered rows (335 MB and its two readers)
     assert compiled.memory_analysis().temp_size_in_bytes < 1e8
+
+
+def test_afmoe_engine_step(chip):
+    """The donated step of one period of Trinity-Mini's layers (three window
+    layers and a full one, a dense MLP and three expert layers, 16 of 128
+    experts held, an eighth of the vocabulary) at the cell's engine sizes on
+    the largest rung: both plans' launches, the window layers' over their own
+    pool and narrower page lists under the window rule, rotation on the
+    window layers only, and every part under its name."""
+    from flashinfer_tpu.models.afmoe import AfmoeConfig, init_afmoe_params
+    from flashinfer_tpu.serve import (EngineConfig, SamplingConfig,
+                                      ServingEngine, engine_kernels as ek,
+                                      recurrent)
+
+    mp = pytest.MonkeyPatch()
+    _as_on_a_v5e(mp)
+    try:
+        mcfg = AfmoeConfig(vocab_size=25024, num_dense_layers=1,
+                           layer_types=AfmoeConfig().layer_types[:4],
+                           local_experts=(0, 16))
+        ecfg = EngineConfig.from_knobs(
+            mcfg, num_pages=8321, max_seq_tokens=16640, max_batch=8,
+            prefill_budget_tokens=1016, page_size=16, ladder=(8, 512, 1024),
+            attention_backend="kernel", kv_offload="off",
+            spill_policy="recompute",
+            sampling=SamplingConfig(temperature=0.8, top_k=40, top_p=0.95))
+        params = jax.eval_shape(
+            lambda: init_afmoe_params(jax.random.PRNGKey(0), mcfg))
+        eng = ServingEngine(mcfg, params, dataclasses.replace(
+            ecfg, num_pages=2))   # shapes only: no 8,321-page pool here
+        wp = eng._wpages
+        assert wp.cap == 193 and wp.pool.num_pages == 8 * 193 + 1
+        assert eng._wgeom.pages_per_req == 193 \
+            and eng._geom.pages_per_req == 1040
+        rung, chunk = 1024, 1016
+        seg = ek.SchedSeg(row0=0, n=chunk, pages=tuple(range(1, 65)),
+                          split=0, kv_after=chunk, decoding=False, slot=0,
+                          group=0)
+        plans = ek.build_engine_work_units([seg], rung=rung, geom=eng._geom)
+        wplans = ek.build_engine_work_units(
+            [seg], rung=rung, geom=eng._wgeom, window_left=2047)
+        B, ppr = ecfg.max_batch, eng._ppr
+        mplan, _ = recurrent.build_plan([(0, chunk, 0, True)], tokens=chunk,
+                                        rung=rung, max_batch=B, chunk=0)
+        mplan["window"] = dict(
+            token_page=np.zeros(rung, np.int32),
+            page_table=np.zeros((B, wp.cap), np.int32),
+            first_tok=np.zeros(B, np.int32),
+            plans=ek.window_plans_to_host(wplans))
+        caches = [
+            (jax.ShapeDtypeStruct(
+                (ecfg.num_pages if kind == "attention"
+                 else wp.pool.num_pages, 4, 16, 128), jnp.bfloat16),) * 2
+            for kind in mcfg.layer_kinds()]
+        host = (params, *(np.zeros(rung, np.int32),) * 5,
+                *(np.zeros((B, ppr), np.int32),) * 2,
+                *(np.zeros(rung, np.int32),) * 2,
+                *(np.zeros(B, np.int32),) * 2, ek.plans_to_device(plans),
+                caches, mplan)
+        compiled = eng._step.lower(*_like(chip, host)).compile()
+    finally:
+        mp.undo()
+    hlo = compiled.as_text()
+    launches = {k: len(re.findall(rf"%{k}(\.\d+)? = .*custom-call\(", hlo))
+                for k in ("fused_paged_prefill_l0", "fused_paged_prefill_l1",
+                          "paged_decode_attention_split", "moe_gmm")}
+    # level 0 in the full layer alone; level 1 and decode in all four
+    assert launches == {"fused_paged_prefill_l0": 1,
+                        "fused_paged_prefill_l1": 4,
+                        "paged_decode_attention_split": 4, "moe_gmm": 6}
+    for scope in ("embed", "attn.qkv", "attn.kv_write", "attn.core",
+                  "attn.window", "attn.full", "attn.out", "mlp", "moe.route",
+                  "moe.experts", "moe.shared", "head", "sample"):
+        assert re.search(rf'op_name="jit\(_body\)/(\S+/)?{scope}/', hlo), scope
+    # the kernels of a kind lie under that kind's scope
+    assert re.search(r'attn\.core/attn\.window/\S*fused_paged_prefill_l1',
+                     hlo)
+    assert re.search(r'attn\.core/attn\.full/\S*fused_paged_prefill_l0', hlo)
+    assert not re.search(r'attn\.window/\S*fused_paged_prefill_l0', hlo)
+    mem = compiled.memory_analysis()
+    # four layers' weights, an eighth of the ends, both pools of four layers
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 4e9

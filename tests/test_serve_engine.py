@@ -678,3 +678,74 @@ def test_handover_at_the_cells_statics(params):
         assert 512 < f["upload_kib"] < 1536
     assert eng.num_traces == len(eng._rung_traced) == 1
     assert handover.cut._cache_size() == cuts + 1
+
+
+# ---------------------------------------------------------------------------
+# The window layers' pool (ISSUE 37, serve/window_pool.py)
+# ---------------------------------------------------------------------------
+
+
+def test_window_pages_hold_the_window_and_never_pass_the_cap():
+    """Requests grow by chunks and by single tokens under a random schedule:
+    a request holds the pages from ``page_floor(kv_len - window)`` to its
+    last position and never more than the cap, the pool's invariants hold,
+    and everything is back when the requests end."""
+    from flashinfer_tpu.serve.window_pool import WindowPages, pages_cap
+
+    window, chunk, ps, batch = 40, 24, 8, 3
+    wp = WindowPages(window=window, chunk=chunk, page_size=ps,
+                     max_batch=batch, pages_per_req=64)
+    assert wp.cap == pages_cap(window, chunk, ps) == 9
+    assert wp.usable == batch * 9 and wp.pool.used_pages == 0
+    rng = np.random.default_rng(0)
+    reqs = []
+    for slot, total in enumerate((300, 37, 150)):
+        r = EngineRequest(f"r{slot}", [1] * total, max_new_tokens=1)
+        r.slot = slot
+        assert wp.reserve(r, total)
+        reqs.append(r)
+    assert wp.reserved == 9 + 5 + 9
+    late = EngineRequest("late", [1] * 300, max_new_tokens=1)
+    assert not wp.reserve(late, 300) and late.wreserved == 0
+    live = list(reqs)
+    while live:
+        sched = []
+        for r in live:
+            n = min(int(rng.integers(1, chunk + 1)), len(r.prompt) - r.kv_len)
+            r.kv_len += n
+            sched.append((r, n))
+        arrays, local, held = wp.assemble(sched, live, 64, batch)
+        assert held == wp.pool.used_pages <= wp.reserved
+        row = 0
+        for (r, n), (_, row0, n2, kv_after) in zip(sched, local):
+            assert (row0, n2) == (row, n) and len(r.wpages) <= wp.cap
+            assert kv_after == r.kv_len - r.wfirst * ps
+            # each row lands on the page of its position
+            for i in range(n):
+                p = r.kv_len - n + i
+                assert arrays["token_page"][row + i] \
+                    == r.wpages[p // ps - r.wfirst]
+            np.testing.assert_array_equal(
+                arrays["page_table"][r.slot, :len(r.wpages)], r.wpages)
+            assert arrays["first_tok"][r.slot] == r.wfirst * ps
+            row += n
+        assert len({p for r in live for p in r.wpages}) == held   # no alias
+        for r, _ in sched:
+            wp.trim(r)
+            assert r.wfirst == max(r.kv_len - window, 0) // ps
+            assert r.wfirst + len(r.wpages) == (r.kv_len - 1) // ps + 1
+        for r in [r for r in live if r.kv_len == len(r.prompt)]:
+            wp.release(r)
+            live.remove(r)
+    assert wp.pool.used_pages == 0 and wp.reserved == 0
+    assert wp.released_total > 0 and wp.reserve(late, 300)
+
+
+def test_a_model_without_a_window_layer_has_no_second_pool(params):
+    eng = _mk_engine(params)
+    assert eng._wpages is None and eng._wgeom is None and not eng._window
+    r = EngineRequest("r", [3, 4, 5, 6, 7], max_new_tokens=2)
+    eng.submit(r)
+    facts = eng.step()
+    assert not r.wpages and r.wfirst == 0 and r.wreserved == 0
+    assert not any(k.startswith("window_") for k in facts)
