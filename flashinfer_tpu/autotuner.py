@@ -144,8 +144,9 @@ register_knob("parallel.ep",
 # continuous-batching engine scheduler statics (serve/engine.py,
 # EngineConfig.from_knobs; shape key = (hidden, hq, hkv, hd) of the
 # served model) — the shape ladder the engine compiles is derived from
-# these, so each chip generation can trade batch width against the
-# chunked-prefill budget
+# these (powers of two from max_batch; the top rung is max_batch +
+# prefill_budget_tokens rounded up to the prefill q tile), so each chip
+# generation can trade batch width against the chunked-prefill budget
 register_knob("engine.block_size",
               description="serving-engine KV block (page) size in "
                           "tokens — the block-pool / prefix-cache "
@@ -160,8 +161,9 @@ register_knob("engine.prefill_budget_tokens",
                           "EngineConfig.slo_step_seconds")
 register_knob("engine.max_batch",
               description="serving-engine batch slots (concurrent "
-                          "running requests); also the decode floor "
-                          "of the compile-once rung ladder")
+                          "running requests); the decode floor of "
+                          "the compile-once rung ladder, and with the "
+                          "prefill budget its top rung")
 # tiered-KV statics (serve/kv_tier.py; same (hidden, hq, hkv, hd)
 # shape key as the other engine.* knobs): whether the host-RAM tier
 # below the block pool is attached, how preemption resumes, and how

@@ -60,7 +60,7 @@ def _tuned_row_block(n: int, hidden: int, dtype, op: str, runner) -> int:
         default=_ROW_BLOCK,
         module=_norm_module,
     )
-    rb = min(int(rb), n)
+    rb = _whole_blocks(min(int(rb), n), n)
     _row_block_memo[memo_key] = rb
     return rb
 
@@ -367,3 +367,17 @@ def select_knobs(*_, **__):
     TPU row-block choice lives in the autotuner (rmsnorm.row_block
     tactics), so there is nothing to select here."""
     return {}
+
+
+def _whole_blocks(rb: int, n: int) -> int:
+    """``rb``, or where it leaves ``n`` rows a ragged last block, the
+    largest smaller candidate that divides them (none: ``rb``).  A ragged
+    block takes kernel stack beside the blocks' own buffers: ``[640, 8192]``
+    bf16 at 256 rows passes the compiler's VMEM limit inside a step where
+    ``[1024, 8192]`` just fits, and a rung is a multiple of 128.  (Last in
+    the file so that the kernels above keep the lines their compiled
+    programs are cached under.)"""
+    if n % rb == 0:
+        return rb
+    return next((c for c in reversed(_ROW_BLOCK_CANDIDATES)
+                 if c < rb and n % c == 0), rb)

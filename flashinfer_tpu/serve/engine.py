@@ -454,21 +454,21 @@ class EngineConfig:
         return -(-self.max_seq_tokens // self.page_size)
 
     def rungs(self) -> Tuple[int, ...]:
-        """The shape ladder: power-of-two token-axis sizes from the
-        decode floor (max_batch) up to the full mixed budget.  Each
-        rung is one trace — the ladder is deliberately small (<= 8
-        rungs fits the 9-trace budget with room for a warmup)."""
+        """The shape ladder, one trace a rung (at most 8): sizes doubling
+        from the decode floor (max_batch) to a TOP rung that follows the
+        step: max_batch + prefill_budget_tokens rounded up to the prefill
+        launches' q tile (``ingest_block_q``: a power of two under 128
+        rows, 128 above).  An explicit ``ladder`` is taken as written."""
         if self.ladder:
             return tuple(sorted(set(int(r) for r in self.ladder)))
-        lo = 1
-        while lo < self.max_batch:
-            lo *= 2
-        hi = lo
+        from flashinfer_tpu.ops.paged_prefill import ingest_block_q
+        from flashinfer_tpu.utils import next_power_of_two, round_up
+
         top = self.max_batch + self.prefill_budget_tokens
-        rungs = [lo]
-        while hi < top:
-            hi *= 2
-            rungs.append(hi)
+        top = round_up(top, ingest_block_q(top))
+        rungs = [next_power_of_two(self.max_batch)]
+        while rungs[-1] < top:
+            rungs.append(min(rungs[-1] * 2, top))
         return tuple(rungs[:8])
 
 

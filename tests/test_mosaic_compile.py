@@ -269,15 +269,18 @@ def test_from_knobs_on_v5e_is_the_kernel_tier(engine_step):
     _, ecfg, _ = engine_step
     assert ecfg.attention_backend == "kernel"
     assert ecfg.fused_ingest == "off"   # no longer read from the table
-    assert ecfg.rungs() == (64, 128, 256, 512, 1024)
+    # the top rung is 64 + 512 rounded up to the q tile, not to 1,024
+    assert ecfg.rungs() == (64, 128, 256, 512, 640)
 
 
-@pytest.mark.parametrize("which", ["decode", "prefill"])
+@pytest.mark.parametrize("which", ["decode", "prefill", "top"])
 def test_engine_step(engine_step, compiled_branch, which):
     """The whole donated step, as from_knobs configures it, on the decode
-    rung and on a prefill rung."""
+    rung, on a prefill rung and on the top rung (640 rows: five q tiles,
+    no power of two), each with the same launches and parts."""
     eng, ecfg, args = engine_step
-    rung = min(ecfg.rungs()) if which == "decode" else 512
+    rung = {"decode": min(ecfg.rungs()), "prefill": 512,
+            "top": max(ecfg.rungs())}[which]
     lowered = eng._step.lower(*args(rung))
     assert lowered.as_text().count("tpu_custom_call") >= 5
     compiled = lowered.compile()
@@ -367,12 +370,12 @@ def test_granite_hybrid_engine_step(chip):
             mcfg, num_pages=512, max_seq_tokens=2048, fused_ingest="off",
             kv_offload="off", spill_policy="recompute",
             sampling=SamplingConfig(temperature=0.8, top_k=40, top_p=0.95))
-        assert ecfg.rungs() == (64, 128, 256, 512, 1024)
+        assert ecfg.rungs() == (64, 128, 256, 512, 640)
         params = jax.eval_shape(
             lambda: init_granite_hybrid_params(jax.random.PRNGKey(0), mcfg))
         eng = ServingEngine(mcfg, params, dataclasses.replace(
             ecfg, num_pages=2))   # shapes only: no 512-page pool on the host
-        rung, chunk = 1024, 512
+        rung, chunk = max(ecfg.rungs()), 512
         n = -(-rung // ecfg.page_size)
         plans = ek.build_engine_work_units(
             [ek.SchedSeg(row0=0, n=chunk, pages=tuple(range(1, n + 1)),

@@ -66,3 +66,24 @@ def test_layernorm():
         xn.var(-1, keepdims=True) + 1e-5
     ) * np.asarray(g) + np.asarray(b)
     np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.quick
+@pytest.mark.parametrize("rows, want", [
+    (1024, 256), (512, 256), (64, 64),   # the block divides: as before
+    (640, 128), (384, 128),              # ragged at 256: the candidate below
+    (300, 256), (19, 19),                # no candidate divides: as before
+])
+def test_row_block_divides_the_rows_where_a_candidate_can(rows, want):
+    """A ragged last block costs the kernel VMEM stack beside its blocks
+    (``[640, 8192]`` at 256 rows is refused inside a step), so rows that a
+    smaller candidate divides take that one; the result is the same."""
+    from flashinfer_tpu import norm
+
+    assert norm._tuned_row_block(rows, 8192, jnp.bfloat16, "rmsnorm",
+                                 None) == want
+    x = jax.random.normal(jax.random.PRNGKey(rows), (rows, 256), jnp.float32)
+    w = jax.random.normal(jax.random.PRNGKey(1), (256,), jnp.float32)
+    np.testing.assert_allclose(
+        np.asarray(fi.rmsnorm(x, w, eps=1e-6, backend="pallas")),
+        ref_rmsnorm(x, w, 1e-6), rtol=1e-5, atol=1e-5)

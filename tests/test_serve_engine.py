@@ -403,6 +403,73 @@ def test_engine_knobs_registered_and_resolved():
 
 
 @pytest.mark.quick
+@pytest.mark.parametrize("max_batch, budget, ladder, want", [
+    (64, 512, (), (64, 128, 256, 512, 640)),     # the older cells' statics
+    (8, 1016, (), (8, 16, 32, 64, 128, 256, 512, 1024)),  # a full pow2
+    (8, 32, (), (8, 16, 32, 64)),                # perfbench/tiny.json
+    (4, 16, (), (4, 8, 16, 32)),                 # this file's engines
+    (8, 100, (), (8, 16, 32, 64, 128)),          # 108: under one q tile
+    (8, 376, (), (8, 16, 32, 64, 128, 256, 384)),
+    (64, 448, (), (64, 128, 256, 512)),          # fills a power of two
+    (8, 1016, (8, 512, 1024), (8, 512, 1024)),   # written out: as written
+    (64, 512, (1024, 64, 64), (64, 1024)),
+])
+def test_top_rung_follows_the_step(max_batch, budget, ladder, want):
+    """Rungs double from the decode floor; the top one is ``max_batch +
+    prefill_budget_tokens`` rounded up to the prefill launches' q tile, so
+    a step never pads a whole tile and never more than the power of two
+    the ladder used to end on."""
+    from flashinfer_tpu.ops.paged_prefill import ingest_block_q
+    from flashinfer_tpu.utils import next_power_of_two
+
+    rungs = EngineConfig(num_pages=9, max_batch=max_batch, ladder=ladder,
+                         prefill_budget_tokens=budget).rungs()
+    assert rungs == want
+    assert all(a < b for a, b in zip(rungs, rungs[1:]))
+    if not ladder:
+        top, need = rungs[-1], max_batch + budget
+        assert need <= top <= next_power_of_two(need)
+        assert top % ingest_block_q(top) == 0
+        assert top - need < ingest_block_q(top)
+        assert rungs[0] == next_power_of_two(max_batch)
+        assert all(b == 2 * a for a, b in zip(rungs, rungs[1:-1]))
+
+
+@pytest.mark.parametrize("backend", ["reference", "kernel"])
+def test_a_top_rung_that_is_no_power_of_two_serves_the_same_tokens(
+        params, backend):
+    """8 slots and a budget of 376: the ladder ends on 384 where it ended
+    on 512.  A 376-token chunk beside two decode lanes rides that rung, and
+    every served token is the one the same engine serves on the old
+    ladder, given through ``ladder=``."""
+    rng = np.random.default_rng(61)
+    prompts = [[int(t) for t in rng.integers(1, CFG.vocab_size, n)]
+               for n in (11, 19, 402)]   # 376 + 26: the tail rides rung 32
+
+    def serve(**over):
+        eng = _mk_engine(
+            params, share=False, attention_backend=backend, num_pages=80,
+            max_batch=8, prefill_budget_tokens=376, max_seq_tokens=416,
+            sampling=SamplingConfig(temperature=0.8, top_k=20), **over)
+        for i, p in enumerate(prompts[:2]):
+            eng.submit(EngineRequest(f"r{i}", list(p), max_new_tokens=6))
+        head = [eng.step() for _ in range(2)]      # two lanes decoding ...
+        eng.submit(EngineRequest("r2", list(prompts[2]), max_new_tokens=3))
+        tokens, facts = _drain(eng)                # ... beside the chunks
+        return eng, tokens, head + facts
+
+    eng, got, facts = serve()
+    assert eng.config.rungs() == (8, 16, 32, 64, 128, 256, 384)
+    _, want, old_facts = serve(ladder=(8, 16, 32, 64, 128, 256, 512))
+    assert got == want and [len(t) for t in got.values()] == [6, 6, 3]
+    assert [f["tokens"] for f in facts] == [f["tokens"] for f in old_facts]
+    assert max(f["tokens"] for f in facts) == 378
+    assert {f["rung"] for f in facts if f["tokens"] > 256} == {384}
+    assert {f["rung"] for f in old_facts if f["tokens"] > 256} == {512}
+    assert eng.num_traces == len(eng._rung_traced)
+
+
+@pytest.mark.quick
 def test_engine_obs_coverage_closed():
     """engine.step ships observed: catalog + span category + cost
     family all present, so L005/doctor coverage stays empty-pinned."""
