@@ -52,7 +52,7 @@ __all__ = [
     "diff_state_sigs", "record_span",
     "request_begin", "prefill_chunk", "decode_step", "request_finish",
     "lifecycle_snapshot",
-    "steploop_begin", "steploop_summary",
+    "steploop_begin", "steploop_summary", "child_span",
 ]
 
 _declared = False
@@ -298,3 +298,13 @@ def steploop_summary():
     from flashinfer_tpu.obs import steploop as _steploop
 
     return _steploop.summarize()
+
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def child_span(span, name: str):
+    """``span(name)``, or a context manager that does nothing where
+    ``span`` is None: how a callee of a step surface opens a child of the
+    caller's phase (``StepTicket.under``) at no cost with the gate off."""
+    return _NO_SPAN if span is None else span(name)

@@ -772,7 +772,8 @@ def cmd_doctor(args) -> int:
                 phases_us=s["phases"],
                 unattributed_frac=s["unattributed_frac"],
                 negative_gaps=s["negative_gaps"],
-                missing_device_lane=s["missing_device_lane"])
+                missing_device_lane=s["missing_device_lane"],
+                gc=s["gc"], compile_log_lines=s["compile_log_lines"])
     except Exception as e:  # doctor must never crash on a broken tree
         report["host_loop"] = f"<unavailable: {type(e).__name__}>"
 

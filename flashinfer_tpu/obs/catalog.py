@@ -173,8 +173,12 @@ METRICS: Dict[str, Tuple[str, Tuple[str, ...], str]] = {
         "histogram", ("surface", "phase"),
         "named sub-phase durations per step (engine host window: admit "
         "/ schedule / assemble / lower / dispatch, their children "
-        "lower.build / lower.upload / dispatch.upload / dispatch.call, "
-        "then fetch / post; fused step wrappers: signature / dispatch) "
+        "lower.build / lower.upload / dispatch.upload / dispatch.call "
+        "and theirs: lower.build.l0 / .l1 / .decode, "
+        "lower.build.window.l1 / .decode, dispatch.upload.pack / .put / "
+        ".cut; then fetch / post; gc and compile.trace / .lower / "
+        ".backend / .cache_load inside the step; fused step wrappers: "
+        "signature / dispatch) "
         "— the host-gap decomposition ROADMAP item 4's pipeline "
         "refactor is judged against"),
     "steploop.device_us": (

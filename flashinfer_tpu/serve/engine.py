@@ -1473,8 +1473,9 @@ class ServingEngine:
             from flashinfer_tpu.serve import engine_kernels as _ek
 
             with _span(tick, "lower.build"):
-                plans = _ek.build_engine_work_units(segs, rung=rung,
-                                                    geom=self._geom)
+                plans = _ek.build_engine_work_units(
+                    segs, rung=rung, geom=self._geom,
+                    span=tick and tick.under("lower.build"))
             st = plans["stats"]
             self._count_units(facts, st)
             with _span(tick, "lower.upload"):
@@ -1516,8 +1517,7 @@ class ServingEngine:
             tick.enter("dispatch")
 
         # everything the step reads of this schedule crosses together
-        # (serve/handover.py): the schedule arrays, the plans, a model's
-        # own plan; a whole bitmap crosses as it did, a buffer a leaf
+        # (serve/handover.py); a whole bitmap crosses a buffer a leaf
         host = dict(sched=(flat, pos, tok_req, token_page, token_slot,
                            page_table, grp_pages, tok_grp, split, last_rows,
                            sample_seeds), kplans=kplans,
@@ -1527,7 +1527,8 @@ class ServingEngine:
                 _handover.to_device(
                     host, packed=not facts["mask_full_uploads"],
                     finish=(_ek.step_inputs_on_device
-                            if self._kernel_backend else None))
+                            if self._kernel_backend else None),
+                    span=tick and tick.under("dispatch.upload"))
             full_args = (self.params, *dev["sched"], dev["kplans"],
                          self.caches)
         names = self._STATE_NAMES
@@ -1537,7 +1538,6 @@ class ServingEngine:
         sig = obs.state_signature(full_args, names=names)
         seen = self._rung_traced.get(rung, 0)
         before = self._traces
-        t0 = time.perf_counter() if sig is not None else 0.0
         with _span(tick, "dispatch.call"):
             out = self._step(*full_args)
         tokens_dev, self.caches = out[:2]
@@ -1546,11 +1546,6 @@ class ServingEngine:
             tick.enter("fetch")
         if self._traces > before:
             self._rung_traced[rung] = seen + 1
-            if sig is not None:
-                obs.record_span("ServingEngine.trace_and_compile",
-                                "compile", t0, time.perf_counter(),
-                                wrapper="ServingEngine", rung=rung,
-                                trace_index=self._traces)
             if seen:
                 # a rung that already compiled traced AGAIN: the
                 # compile-once contract broke — count + attribute
@@ -1611,7 +1606,7 @@ class ServingEngine:
         facts["finished"] = finished
         if tick is not None:
             # a step whose call traced (and compiled) says so in the
-            # ledger, with no need of the SPANS gate
+            # ledger, and leaves a line in its compile log
             tick.commit(tokens=total, rung=rung,
                         traced=self._traces > before)
         return facts

@@ -38,22 +38,19 @@ a pure function of the RUNG, never the schedule values — prefill plans
 pad to :meth:`EngineKernelGeom.prefill_unit_cap` via the planner's
 ``num_units_pad`` (the two launches run only the plan's real units: its
 ``n_units`` leaf is the trip count of their unit axis, a value and not a
-shape), the decode plan is ``max_batch x num_splits`` units
-over a fixed-width page table, and the level-0 custom-mask operand is
-always present (all-ones windows demote to the maskless PARTIAL code
-in-plan, so steady-state decode pays no expansion).  One rung == one
-trace, the same <= 9-trace budget the reference backend pins.
+shape), the decode plan is ``max_batch x num_splits`` units over a
+fixed-width page table, and the level-0 custom-mask operand is always
+present (all-ones windows demote to the maskless PARTIAL code in-plan).
+One rung == one trace, the <= 9-trace budget the reference backend pins.
 
 Every flat row — scheduled or rung padding — is covered by a plan
 segment (padding rides trailing ``kv_len = 0`` segments), so both
-levels emit defined ``(0, -inf)`` empty states for unused rows instead
-of uninitialized HBM.
+levels emit defined ``(0, -inf)`` empty states for unused rows.
 
 Those shapes are what the step's program takes, not what crosses to the
 device: :func:`plans_to_host` sends of the level-0 bitmap the rows of
 the units that read one, and :func:`plans_on_device` spreads them into
-the rung's ``[U, block_q, mb]`` there (serve/handover.py has the rest of
-the crossing).
+the rung's ``[U, block_q, mb]`` there (serve/handover.py has the rest).
 """
 
 from __future__ import annotations
@@ -173,6 +170,7 @@ def build_engine_work_units(
     rung: int,
     geom: EngineKernelGeom,
     window_left: int = -1,
+    span=None,
 ):
     """Lower one engine step's schedule into the three plan-array forms.
 
@@ -193,22 +191,22 @@ def build_engine_work_units(
                     row, scatters drop)
         stats     — launched-vs-effective unit accounting for the
                     ``engine_step`` cost family (a prefill launch runs
-                    its plan's real units, so launched counts those:
-                    what is launched and not effective is the idle
-                    rows and columns inside a real unit's tile)
+                    its plan's real units: what is launched and not
+                    effective is the idle rows and columns of a tile)
 
-    Every array shape depends only on ``rung`` and ``geom`` — the
-    engine's compile-once contract.
+    Every array shape depends only on ``rung`` and ``geom``.
 
     ``window_left >= 0`` is the plan of a model's WINDOW layers
     (serve/window_pool.py): ``segs`` then carry each request's window
-    page list and positions counted from that list's first page (the
-    causal and the window rule read differences of positions only), with
-    ``split == 0``: level 0 is empty and left out (no ``prefill0`` in
-    the result), a prompt chunk rides level 1 under the planner's own window
-    rule, which prunes the chunks behind the window, and a decode lane
-    the split-KV units over the pages it still holds.
+    page list and positions counted from that list's first page, with
+    ``split == 0``: no level 0 (no ``prefill0`` in the result), a prompt
+    chunk rides level 1 under the planner's own window rule, a decode
+    lane the split-KV units over the pages it still holds.
+
+    ``span(name)`` opens a child span of the caller's (``l0``: group walk,
+    mask parts, level 0's plan; ``l1``; ``decode``); None: the gate is off.
     """
+    from flashinfer_tpu.obs import child_span
     from flashinfer_tpu.ops.paged_decode import build_decode_split_units
     from flashinfer_tpu.ops.paged_prefill import (CODE_PARTIAL_MASK,
                                                   build_prefill_work_units)
@@ -233,103 +231,105 @@ def build_engine_work_units(
     # (a window plan has none: every segment's would be empty)
     plan0 = None
     if not windowed:
-        qo0 = [0]
-        kv0_lens: List[int] = []
-        pi0 = [0]
-        pages0: List[int] = []
-        mask_parts: List[np.ndarray] = []
+        with child_span(span, "l0"):
+            qo0 = [0]
+            kv0_lens: List[int] = []
+            pi0 = [0]
+            pages0: List[int] = []
+            mask_parts: List[np.ndarray] = []
 
-        def _close_seg0(n_rows, kv_len, pages, pos):
-            qo0.append(qo0[-1] + n_rows)
-            kv0_lens.append(kv_len)
-            pages0.extend(pages)
-            pi0.append(len(pages0))
-            if kv_len > 0:
-                cols = np.arange(kv_len)
-                mask_parts.append(
-                    (cols[None, :] <= pos[:, None]).reshape(-1))
+            def _close_seg0(n_rows, kv_len, pages, pos):
+                qo0.append(qo0[-1] + n_rows)
+                kv0_lens.append(kv_len)
+                pages0.extend(pages)
+                pi0.append(len(pages0))
+                if kv_len > 0:
+                    mask_parts.append((np.arange(kv_len)[None, :]
+                                       <= pos[:, None]).reshape(-1))
 
-        i = 0
-        while i < len(segs):
-            s = segs[i]
-            run = s.pages[:s.split // ps]
-            j = i
-            rows = 0
-            pos: List[int] = []
-            while j < len(segs) and segs[j].group == s.group:
-                e = segs[j]
-                pos.extend(range(e.kv_after - e.n, e.kv_after))
-                rows += e.n
-                j += 1
-            if run:
-                _close_seg0(rows, s.split, run, np.asarray(pos, np.int64))
-            else:
-                # no shared span (split == 0): level 0 is empty for these
-                # rows — a kv_len=0 segment emits the (0, -inf) pass-through
-                _close_seg0(rows, 0, (), np.zeros(0, np.int64))
-            i = j
-        if total < rung:  # rung padding rows: defined zeros, empty state
-            _close_seg0(rung - total, 0, (), np.zeros(0, np.int64))
-        mask0 = (np.concatenate(mask_parts) if mask_parts
-                 else np.zeros(0, bool))
-        plan0 = build_prefill_work_units(
-            np.asarray(qo0, np.int64), np.asarray(pi0, np.int64),
-            np.asarray(pages0, np.int64), np.asarray(kv0_lens, np.int64),
+            i = 0
+            while i < len(segs):
+                s = segs[i]
+                run = s.pages[:s.split // ps]
+                j = i
+                rows = 0
+                pos: List[int] = []
+                while j < len(segs) and segs[j].group == s.group:
+                    e = segs[j]
+                    pos.extend(range(e.kv_after - e.n, e.kv_after))
+                    rows += e.n
+                    j += 1
+                if run:
+                    _close_seg0(rows, s.split, run,
+                                np.asarray(pos, np.int64))
+                else:
+                    # no shared span (split == 0): level 0 is empty for
+                    # these rows: a kv_len=0 segment emits (0, -inf)
+                    _close_seg0(rows, 0, (), np.zeros(0, np.int64))
+                i = j
+            if total < rung:  # rung padding rows: defined zeros, empty state
+                _close_seg0(rung - total, 0, (), np.zeros(0, np.int64))
+            mask0 = (np.concatenate(mask_parts) if mask_parts
+                     else np.zeros(0, bool))
+            plan0 = build_prefill_work_units(
+                np.asarray(qo0, np.int64), np.asarray(pi0, np.int64),
+                np.asarray(pages0, np.int64), np.asarray(kv0_lens, np.int64),
+                geom.block_q, geom.prefill_ppc, ps,
+                mask_flat=mask0, causal=False, window_left=-1,
+                pack_tiles=True, prune=True, num_units_pad=U,
+            )
+
+    # ---- level 1: per-segment suffix windows, native causal rule ----
+    with child_span(span, "l1"):
+        qo1 = [0]
+        kv1_lens: List[int] = []
+        pi1 = [0]
+        pages1: List[int] = []
+        for s in segs:
+            qo1.append(qo1[-1] + s.n)
+            suffix = s.kv_after - s.split
+            if s.decoding or suffix <= 0:
+                kv1_lens.append(0)
+                pi1.append(len(pages1))
+                continue
+            kv1_lens.append(suffix)
+            pages1.extend(s.pages[s.split // ps: cdiv(s.kv_after, ps)])
+            pi1.append(len(pages1))
+        if total < rung:
+            qo1.append(rung)
+            kv1_lens.append(0)
+            pi1.append(len(pages1))
+        plan1 = build_prefill_work_units(
+            np.asarray(qo1, np.int64), np.asarray(pi1, np.int64),
+            np.asarray(pages1, np.int64), np.asarray(kv1_lens, np.int64),
             geom.block_q, geom.prefill_ppc, ps,
-            mask_flat=mask0, causal=False, window_left=-1,
+            causal=True, window_left=window_left,
             pack_tiles=True, prune=True, num_units_pad=U,
         )
 
-    # ---- level 1: per-segment suffix windows, native causal rule ----
-    qo1 = [0]
-    kv1_lens: List[int] = []
-    pi1 = [0]
-    pages1: List[int] = []
-    for s in segs:
-        qo1.append(qo1[-1] + s.n)
-        suffix = s.kv_after - s.split
-        if s.decoding or suffix <= 0:
-            kv1_lens.append(0)
-            pi1.append(len(pages1))
-            continue
-        kv1_lens.append(suffix)
-        pages1.extend(s.pages[s.split // ps: cdiv(s.kv_after, ps)])
-        pi1.append(len(pages1))
-    if total < rung:
-        qo1.append(rung)
-        kv1_lens.append(0)
-        pi1.append(len(pages1))
-    plan1 = build_prefill_work_units(
-        np.asarray(qo1, np.int64), np.asarray(pi1, np.int64),
-        np.asarray(pages1, np.int64), np.asarray(kv1_lens, np.int64),
-        geom.block_q, geom.prefill_ppc, ps,
-        causal=True, window_left=window_left,
-        pack_tiles=True, prune=True, num_units_pad=U,
-    )
-
     # ---- decode lanes: split-KV units over per-slot suffix tables ----
-    dec_table = np.zeros((geom.max_batch, geom.dec_width), np.int32)
-    dec_lens = np.zeros(geom.max_batch, np.int64)
-    dec_rows = np.full(geom.max_batch, rung, np.int32)  # rung == idle
-    for s in segs:
-        if not s.decoding:
-            continue
-        suffix_pages = s.pages[s.split // ps: cdiv(s.kv_after, ps)]
-        dec_table[s.slot, :len(suffix_pages)] = suffix_pages
-        dec_lens[s.slot] = s.kv_after - s.split
-        dec_rows[s.slot] = s.row0
-    dplan = build_decode_split_units(
-        dec_table, dec_lens, num_splits=geom.num_splits,
-        page_size=ps, pages_per_chunk=geom.decode_ppc,
-    )
+    with child_span(span, "decode"):
+        dec_table = np.zeros((geom.max_batch, geom.dec_width), np.int32)
+        dec_lens = np.zeros(geom.max_batch, np.int64)
+        dec_rows = np.full(geom.max_batch, rung, np.int32)  # rung == idle
+        for s in segs:
+            if not s.decoding:
+                continue
+            suffix_pages = s.pages[s.split // ps: cdiv(s.kv_after, ps)]
+            dec_table[s.slot, :len(suffix_pages)] = suffix_pages
+            dec_lens[s.slot] = s.kv_after - s.split
+            dec_rows[s.slot] = s.row0
+        dplan = build_decode_split_units(
+            dec_table, dec_lens, num_splits=geom.num_splits,
+            page_size=ps, pages_per_chunk=geom.decode_ppc,
+        )
     assert dplan["pages"].shape == (geom.max_batch, geom.dec_width), \
         (dplan["pages"].shape, geom.dec_width)
     assert not geom.single_chunk or dplan["single_chunk"]
 
     chunk_tokens = geom.prefill_ppc * ps
-    # what the two prefill launches run: each level's ``n_units``, the
-    # trip count of its unit axis (the pad units up to U are not run)
-    # a window plan launches no level 0 (every segment's is empty)
+    # what the prefill launches run: each level's ``n_units`` (the pad
+    # units up to U are not run); a window plan launches no level 0
     levels = [plan1] if windowed else [plan0, plan1]
     launched = sum(int(p["n_units"]) for p in levels)
     stats = {

@@ -2,12 +2,10 @@
 
 A transfer costs the host a fraction of a millisecond whatever its size
 (43 leaves of a step, a megabyte together, took 8.5 ms on a v5e host), so
-a step's inputs cross as ONE int32 buffer, with any leaf of another dtype
+a step's inputs cross as ONE int32 buffer, any leaf of another dtype
 beside it, and one small program, outside the step's, cuts the buffer into
-the leaves the step takes: the same leaves, dtypes and shapes as a
-transfer a leaf, so the same step program.  The handover ends when that
-program's outputs are ready, so the step is never launched on inputs
-that are still being produced."""
+the leaves the step takes: the same leaves, dtypes and shapes as a transfer
+a leaf, so the same step program.  It ends when that program's outputs are."""
 
 from __future__ import annotations
 
@@ -18,33 +16,35 @@ import jax
 import numpy as np
 
 
-def to_device(host, finish=None, packed=True):
-    """``host``: a pytree of numpy arrays and scalars.  Returns (the tree
-    on the device, buffers sent, KiB sent).  ``finish``, a module-level
-    function of the tree, is applied inside the cutting program.
-    ``packed=False`` sends a buffer a leaf and runs no program (a step
-    whose inputs have a shape its rung has not compiled a cut for)."""
-    leaves, treedef = jax.tree_util.tree_flatten(host)
+def to_device(host, finish=None, packed=True, span=None):
+    """``host``: a pytree of numpy arrays.  Returns (the tree on the
+    device, buffers sent, KiB sent).  ``finish``, a module-level function
+    of the tree, is applied inside the cutting program.  ``packed=False``
+    sends a buffer a leaf and runs no program (a step whose inputs have a
+    shape its rung has not compiled a cut for).  ``span(name)`` opens a
+    child span of the caller's (``pack``, ``put``, ``cut``), None nothing."""
+    from flashinfer_tpu.obs import child_span
+
+    with child_span(span, "pack"):
+        leaves, treedef = jax.tree_util.tree_flatten(host)
+        inbuf = tuple(packed and x.dtype == np.int32 for x in leaves)
+        sent = [x for x, p in zip(leaves, inbuf) if not p]
+        if packed:
+            sent.insert(0, np.concatenate(
+                [x.reshape(-1) for x, p in zip(leaves, inbuf) if p]))
+    with child_span(span, "put"):
+        on_device = jax.device_put(sent)
     if packed:
-        inbuf = tuple(x.dtype == np.int32 for x in leaves)
-        sent = (np.concatenate([x.reshape(-1)
-                                for x, p in zip(leaves, inbuf) if p]),
-                *(x for x, p in zip(leaves, inbuf) if not p))
-        buf, *rest = jax.device_put(sent)
-        tree = cut(buf, tuple(rest), finish=finish, layout=(
-            treedef, tuple((x.shape, p) for x, p in zip(leaves, inbuf))))
-        # ready before the step is dispatched: a launch whose inputs the
-        # cutting program was still producing took the v5e's runtime 12 ms
-        # a step longer (granite cell, PERF.md section 6, PR 31); the
-        # program takes 0.2 ms and the step could not start before it
-        # anyway (the wait still costs the chat cell ~2%).  One leaf is
-        # waited for: a program's outputs are ready together
-        jax.block_until_ready(jax.tree_util.tree_leaves(tree)[0])
+        with child_span(span, "cut"):
+            tree = cut(on_device[0], tuple(on_device[1:]), finish=finish,
+                       layout=(treedef, tuple((x.shape, p) for x, p
+                                              in zip(leaves, inbuf))))
+            # a launch whose inputs were still being produced took 12 ms
+            # longer (PERF.md section 6, PR 31); outputs are ready together
+            jax.block_until_ready(jax.tree_util.tree_leaves(tree)[0])
     else:
-        sent = leaves
-        tree = treedef.unflatten(jax.device_put(leaves))
-        if finish is not None:
-            tree = finish(tree)
+        tree = treedef.unflatten(on_device)
+        tree = tree if finish is None else finish(tree)
     return tree, len(sent), sum(x.nbytes for x in sent) / 1024
 
 

@@ -151,7 +151,8 @@ class WindowPages:
         crosses in the handover's one buffer), the planner's stats (None on
         the reference tier) and the step's facts.  ``geom``: the window
         layers' launch geometry on the kernel tier, else None;
-        ``span(name)`` opens a child span of the step's ``lower`` phase."""
+        ``span(name)`` opens a child span of the step's ``lower`` phase
+        (the planner's levels go under ``lower.build.window``)."""
         from flashinfer_tpu.serve import engine_kernels as _ek
 
         arrays, local, held = self.assemble(sched, running, rung, max_batch)
@@ -165,7 +166,8 @@ class WindowPages:
             with span("lower.build"):
                 plans = _ek.build_engine_work_units(
                     segs, rung=rung, geom=geom,
-                    window_left=self.window - 1)
+                    window_left=self.window - 1,
+                    span=lambda n: span(f"lower.build.window.{n}"))
             stats = plans["stats"]
             with span("lower.upload"):
                 arrays["plans"] = _ek.window_plans_to_host(plans)

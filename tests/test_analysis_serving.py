@@ -522,10 +522,10 @@ def test_l012_flags_schedule_value_in_plan_shape_static_real_engine():
     engine_kernels.py must flag L012 — and ONLY L012."""
     real = _real("serve/engine_kernels.py")
     skew = real.replace(
-        "pack_tiles=True, prune=True, num_units_pad=U,\n        )\n\n"
+        "pack_tiles=True, prune=True, num_units_pad=U,\n            )\n\n"
         "    # ---- level 1",
-        "pack_tiles=True, prune=True, num_units_pad=total,\n        )\n\n"
-        "    # ---- level 1")
+        "pack_tiles=True, prune=True, num_units_pad=total,\n"
+        "            )\n\n    # ---- level 1")
     assert skew != real
     by_pass = _new_pass_findings(
         _project(("serve/engine_kernels.py", skew)))
@@ -542,9 +542,9 @@ def test_l012_positional_planner_static_resolved_cross_module():
     real = _real("serve/engine_kernels.py")
     skew = real.replace(
         "np.asarray(pages1, np.int64), np.asarray(kv1_lens, "
-        "np.int64),\n        geom.block_q, geom.prefill_ppc, ps,",
+        "np.int64),\n            geom.block_q, geom.prefill_ppc, ps,",
         "np.asarray(pages1, np.int64), np.asarray(kv1_lens, "
-        "np.int64),\n        segs[0].n, geom.prefill_ppc, ps,")
+        "np.int64),\n            segs[0].n, geom.prefill_ppc, ps,")
     assert skew != real
     findings = static_flow.run(_project(
         ("serve/engine_kernels.py", skew),
