@@ -879,7 +879,13 @@ def engine_step(num_tokens: int, batch: int, layers: int, *, hidden: int,
     ``kv_rows_launched`` carry the REAL unit stats — the work units
     each launch runs (a prefill-level launch runs its plan's real units,
     never the pad units of the rung's cap), each a whole tile against a
-    whole chunk-aligned page walk (``ServingEngine.unit_stats``).  The
+    whole chunk-aligned page walk (``ServingEngine.unit_stats``).  A
+    plan holds a unit only where its launch has work: a span with
+    nothing to attend adds none to a tile that holds another (a tile of
+    such spans keeps ONE zero-write unit, priced like any other), and a
+    decode lane alone in its group is priced as DECODE pages over its
+    whole context (``decode_lanes_whole``), no longer as one-row prefill
+    units over its prompt plus decode pages over the suffix.  The
     attention term then prices ``flops`` from launched pairs and
     ``bytes`` from launched rows, with ``flops_effective`` holding the
     exact attended-pair work, so ``obs perf`` exposes the tier's true

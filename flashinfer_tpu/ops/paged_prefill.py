@@ -182,13 +182,12 @@ def build_prefill_work_units(
     """Host-side plan: flatten (qo-tile, request, kv-chunk) work units.
 
     Returns a dict of numpy arrays padded to a power-of-two unit count
-    (padding units have ``first=0, wout=0`` and an empty row span so
-    they neither write nor corrupt; the real units are written into
-    arrays allocated at that count, so a pad unit costs the host
-    nothing), plus the static (block_q,
-    pages_per_chunk) the arrays were built for and a ``stats`` dict
-    (unit counts before/after pruning, row/MXU-cell fill — the
-    padding-waste numbers the obs histograms report).
+    (pad units: ``first=0, wout=0``, an empty row span: they write and
+    corrupt nothing and cost the host nothing), plus the statics the
+    arrays were built for and ``stats`` (units before/after pruning,
+    ``units_fallback``, row/MXU-cell fill).  A span with nothing left to
+    attend (all chunks pruned, ``kv_len == 0``) gets a zero-write
+    FALLBACK unit only where its packed tile would else hold no unit.
 
     ``num_units_pad`` overrides the power-of-two padding with an exact
     unit count (>= the real units, else ValueError): callers that
@@ -378,12 +377,12 @@ def build_prefill_work_units(
                 wkv.append(0)
             kept_any = True
         if not kept_any:
-            # every chunk pruned (e.g. kv_len == 0): the tile still needs
-            # an accumulator reset + write-back so those rows emit zeros
-            # (attention over the empty set) instead of uninitialized HBM
+            # every chunk pruned (e.g. kv_len == 0): a zero-write fallback unit
             units.append([ts, rowlo, rowlo, qpos0, 0, 0, CODE_PARTIAL,
                           -1, ts if packed else (ts, r), -1, r])
             wkv.append(0)
+    if packed:  # a tile's first unit resets it, its last writes it: keep one
+        units, wkv = _one_fallback_a_tile(units, wkv)
 
     # ---- first/wout flags + q slots per tile -----------------------------
     first = [0] * len(units)
@@ -446,7 +445,7 @@ def build_prefill_work_units(
         assert n_real >= 1, (
             "a plan padded to a fixed unit count needs a real unit: the "
             "caller covers every q row with a segment, so even a step "
-            "with no KV keeps its zero-write fallback units")
+            "with no KV keeps a zero-write fallback unit a tile")
         U = int(num_units_pad)
     else:
         U = max(next_power_of_two(max(n_real, 1)), 8)
@@ -459,6 +458,7 @@ def build_prefill_work_units(
         "units": n_real,
         "units_canonical": canon_idx,
         "units_pruned": n_pruned,
+        "units_fallback": int((head[:, 7] < 0).sum()),
         "tiles": tile_ord + 1,
         "packed": bool(packed),
         "unit_rows_total": n_mxu * block_q,
@@ -1600,3 +1600,22 @@ def fused_paged_prefill_ingest(
             total_q, H)
         return result, lse, (kc2, vc2)
     return result, (kc2, vc2)
+
+
+def _one_fallback_a_tile(units, wkv):
+    """A packed plan's fallback units (kv chunk -1: a span with nothing
+    to attend), cut to those a launch needs: a tile's FIRST unit resets
+    its whole ``[block_q x group]`` accumulator and its LAST writes the
+    whole tile back, so a tile that holds any other unit needs none (the
+    rows no unit covers leave ``o = 0``, ``lse = -inf``, what the
+    fallback unit left), and a tile that holds nothing else needs one.
+    Unit order is kept; ``wkv`` rides along (a fallback owns no chunk)."""
+    busy = {u[8] for u in units if u[7] >= 0}
+    keep = []
+    for i, u in enumerate(units):
+        if u[7] < 0:
+            if u[8] in busy:
+                continue
+            busy.add(u[8])
+        keep.append(i)
+    return [units[i] for i in keep], [wkv[i] for i in keep]

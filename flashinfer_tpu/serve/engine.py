@@ -496,12 +496,12 @@ class ServingEngine:
                     "token_page", "token_slot", "page_table", "grp_pages",
                     "tok_grp", "split", "last_rows", "sample_seeds",
                     "kplans", "caches")
-    # what the kernel tier's planner counts per step (useful against
-    # launched): summed into ``unit_stats``, and each step's own values
-    # go into the facts ``step()`` returns (0 on the reference tier)
+    # what the kernel tier's planner counts a step: summed into
+    # ``unit_stats``; a step's own ride ``step()``'s facts (reference: 0)
     _UNIT_FACTS = ("prefill_units", "prefill_units_launched",
                    "prefill_cells_valid", "prefill_cells_launched",
-                   "decode_pages_real", "decode_pages_launched")
+                   "decode_pages_real", "decode_pages_launched",
+                   "decode_lanes_whole", "prefill_fallback_units")
 
     def __init__(self, model_cfg, params, config: EngineConfig):
         if config.attention_backend not in ("reference", "kernel"):
@@ -640,10 +640,10 @@ class ServingEngine:
         # launched-vs-effective unit accounting (kernel tier): what the
         # padded work-unit grids actually execute vs the attended pairs
         # — costmodel.engine_step prices the bench A/B from these
+        # (beside the useful-against-launched pairs: the lanes whose whole
+        # context rode decode units and the prefill plans' fallback units)
         self.unit_stats = {
-            "prefill_units": 0, "prefill_units_launched": 0,
-            "prefill_cells_launched": 0.0, "prefill_cells_valid": 0.0,
-            "decode_pages_real": 0, "decode_pages_launched": 0,
+            **dict.fromkeys(self._UNIT_FACTS, 0),
             "kv_pairs_launched": 0.0, "kv_rows_launched": 0.0,
         }
         # the engine adopts no fused ingest, whatever ``config.fused_ingest``
@@ -1317,11 +1317,11 @@ class ServingEngine:
         scatter results.  Returns this step's facts: its index, rung,
         tokens scheduled (and how many were prompt / decode tokens),
         requests sampled / finished, the kernel tier's work units and
-        cells useful against launched (``_UNIT_FACTS``), the pages its
-        K/V rows were written to (``kv_pages_written``, of
-        ``kv_pages_pool`` usable ones), the queue wait of the requests
-        whose first prompt chunk ran in it, and what it handed to the
-        device: ``upload_kib`` of host arrays in ``upload_transfers``
+        cells useful against launched, its lanes on decode units alone
+        and its prefill fallback units (``_UNIT_FACTS``), the pages its
+        K/V rows landed on (``kv_pages_written`` of ``kv_pages_pool``),
+        the queue wait of the requests first scheduled in it, and what it
+        handed to the device: ``upload_kib`` in ``upload_transfers``
         buffers, ``plans_lowered`` (1 on the kernel tier), level 0's
         ``mask_units`` and whether the whole bitmap crossed for them
         (``mask_full_uploads``); for a model with window layers the unit
@@ -1443,9 +1443,9 @@ class ServingEngine:
                 split[row] = r.split
                 tok_grp[row] = g
                 row += 1
-                # work accounting: every token attends [0, p] (pairs);
-                # its level-1 rows [split, p] stream per request, its
-                # level-0 rows are charged once per GROUP below
+                # work accounting: every token attends [0, p] (pairs); its
+                # rows [split, p] stream per request, its run's rows once
+                # per GROUP below (a lone lane's decode units stream both)
                 self.kv_pairs_total += p + 1
                 self.kv_rows_total += max(p + 1 - r.split, 0)
             r.kv_len += n

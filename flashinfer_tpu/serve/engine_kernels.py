@@ -1,11 +1,10 @@
 """Pallas work-unit lowering of the serving engine's attention step.
 
-The graduation ROADMAP item 1 names: the continuous-batching engine
-(serve/engine.py) schedules mixed decode + chunked-prefill tokens on one
-flat axis with a two-level cascade decomposition, and PR 11 deliberately
-ran that attention in the dense XLA reference form so the bitwise
-no-sharing contract could be proved on CPU.  This module lowers the
-SAME per-step schedule onto the proven work-unit kernels instead:
+The continuous-batching engine (serve/engine.py) schedules mixed decode
++ chunked-prefill tokens on one flat axis with a two-level cascade
+decomposition; its reference backend runs that attention in dense XLA
+form.  This module lowers the SAME per-step schedule onto the work-unit
+kernels, a unit only where a launch has work:
 
 - :func:`build_engine_work_units` — the host-side planner.  It takes
   the engine's flat-row schedule segments (tokens/positions, group page
@@ -23,8 +22,13 @@ SAME per-step schedule onto the proven work-unit kernels instead:
     nothing and emit the empty-state sentinel);
   * **decode tokens** (``q_len == 1``) become
     :func:`~flashinfer_tpu.ops.paged_decode.build_decode_split_units`
-    units over their suffix page runs (PR 6's split-KV partials +
-    ``merge_states`` reduction).
+    units (split-KV partials + ``merge_states``): over its suffix page
+    run for a lane whose group holds another row this step, over its
+    WHOLE page run, with no level-0 unit, for a lane alone in its group
+    (a decode lane costs decode units only).  The planner makes that
+    choice per group from the schedule it is given: riders of one cached
+    run, and a chunk beside its sharers, keep level 0 (the cascade's
+    dedup), and a lane turns lone or shared from one step to the next.
 
 - :func:`engine_kernel_attention` — the in-jit composition: the three
   kernel launches produce per-level ``(out, lse)`` states and fold
@@ -44,8 +48,10 @@ present (all-ones windows demote to the maskless PARTIAL code in-plan).
 One rung == one trace, the <= 9-trace budget the reference backend pins.
 
 Every flat row — scheduled or rung padding — is covered by a plan
-segment (padding rides trailing ``kv_len = 0`` segments), so both
-levels emit defined ``(0, -inf)`` empty states for unused rows.
+segment (padding, decode rows at level 1 and lone lanes at level 0 ride
+``kv_len = 0`` segments), so both levels emit defined ``(0, -inf)``
+empty states for rows that attend nothing there: the reset state of a
+tile, which needs a unit of its own only where it holds no other.
 
 Those shapes are what the step's program takes, not what crosses to the
 device: :func:`plans_to_host` sends of the level-0 bitmap the rows of
@@ -141,8 +147,8 @@ class EngineKernelGeom:
         one rung: each of the <= max_batch scheduled segments (plus the
         trailing padding segment) overlaps at most ``cdiv(n, block_q) +
         1`` qo tiles, each (tile, segment) span walks at most
-        ``max_prefill_chunks`` KV chunks, and pruned spans keep one
-        zero-write fallback unit.  The cap is a RUNG static, so a rung's
+        ``max_prefill_chunks`` KV chunks, and a tile of pruned spans keeps
+        one zero-write fallback unit.  The cap is a RUNG static, so a rung's
         plan shape can never vary step to step.  It sizes the plan
         arrays only: a launch runs the units a step's plan has."""
         spans = cdiv(rung, self.block_q) + 2 * (self.max_batch + 1)
@@ -181,18 +187,23 @@ def build_engine_work_units(
         prefill0  — level-0 shared-prefix plan (custom mask, causal by
                     global position; one qo segment per contiguous
                     group span, so the run's pages stream once per
-                    (tile, chunk) for ALL riders — the cascade dedup)
+                    (tile, chunk) for ALL riders — the cascade dedup;
+                    a group that is ONE decoding lane this step rides
+                    a kv_len=0 segment: no unit, no mask part)
         prefill1  — level-1 suffix plan (causal; decode rows and
-                    fully-in-span chunks ride kv_len=0 segments that
-                    emit the empty state)
-        decode    — split-KV decode plan over per-slot suffix tables
+                    fully-in-span chunks ride kv_len=0 segments)
+        decode    — split-KV decode plan over per-slot tables: a lone
+                    lane's whole context from page 0, a sharer's suffix
         dec_rows  — [max_batch] flat row of each decode lane's token
                     (== rung for idle slots: gathers clip to a harmless
                     row, scatters drop)
         stats     — launched-vs-effective unit accounting for the
                     ``engine_step`` cost family (a prefill launch runs
                     its plan's real units: what is launched and not
-                    effective is the idle rows and columns of a tile)
+                    effective is the idle rows and columns of a tile),
+                    the prefill plans' ``prefill_fallback_units`` and
+                    ``decode_lanes_whole`` (0 in a window plan, which
+                    never had a level 0 to leave)
 
     Every array shape depends only on ``rung`` and ``geom``.
 
@@ -208,8 +219,7 @@ def build_engine_work_units(
     """
     from flashinfer_tpu.obs import child_span
     from flashinfer_tpu.ops.paged_decode import build_decode_split_units
-    from flashinfer_tpu.ops.paged_prefill import (CODE_PARTIAL_MASK,
-                                                  build_prefill_work_units)
+    from flashinfer_tpu.ops.paged_prefill import build_prefill_work_units
 
     ps = geom.page_size
     total = segs[-1].row0 + segs[-1].n if segs else 0
@@ -230,6 +240,8 @@ def build_engine_work_units(
     # ---- level 0: shared-prefix groups, causal-by-global-position ----
     # (a window plan has none: every segment's would be empty)
     plan0 = None
+    # slots of the lone decoding lanes: their decode units start at page 0
+    whole = set()
     if not windowed:
         with child_span(span, "l0"):
             qo0 = [0]
@@ -259,12 +271,20 @@ def build_engine_work_units(
                     pos.extend(range(e.kv_after - e.n, e.kv_after))
                     rows += e.n
                     j += 1
-                if run:
+                # a group that is ONE decoding lane this step shares its
+                # run with no other row: nothing to dedup, and a one-row
+                # unit a chunk through a block_q tile is the dearest way
+                # to read a context.  Its decode units take all of it.
+                lone = j - i == 1 and s.decoding
+                if lone:
+                    whole.add(s.slot)
+                if run and not lone:
                     _close_seg0(rows, s.split, run,
                                 np.asarray(pos, np.int64))
                 else:
-                    # no shared span (split == 0): level 0 is empty for
-                    # these rows: a kv_len=0 segment emits (0, -inf)
+                    # no shared span (split == 0) or a lone lane: level 0
+                    # is empty for these rows: a kv_len=0 segment emits
+                    # (0, -inf)
                     _close_seg0(rows, 0, (), np.zeros(0, np.int64))
                 i = j
             if total < rung:  # rung padding rows: defined zeros, empty state
@@ -307,7 +327,7 @@ def build_engine_work_units(
             pack_tiles=True, prune=True, num_units_pad=U,
         )
 
-    # ---- decode lanes: split-KV units over per-slot suffix tables ----
+    # ---- decode lanes: split-KV units over per-slot page tables ----
     with child_span(span, "decode"):
         dec_table = np.zeros((geom.max_batch, geom.dec_width), np.int32)
         dec_lens = np.zeros(geom.max_batch, np.int64)
@@ -315,9 +335,11 @@ def build_engine_work_units(
         for s in segs:
             if not s.decoding:
                 continue
-            suffix_pages = s.pages[s.split // ps: cdiv(s.kv_after, ps)]
-            dec_table[s.slot, :len(suffix_pages)] = suffix_pages
-            dec_lens[s.slot] = s.kv_after - s.split
+            # a sharer's units cover its suffix (level 0 has the run)
+            lo = 0 if s.slot in whole else s.split
+            lane_pages = s.pages[lo // ps: cdiv(s.kv_after, ps)]
+            dec_table[s.slot, :len(lane_pages)] = lane_pages
+            dec_lens[s.slot] = s.kv_after - lo
             dec_rows[s.slot] = s.row0
         dplan = build_decode_split_units(
             dec_table, dec_lens, num_splits=geom.num_splits,
@@ -327,30 +349,8 @@ def build_engine_work_units(
         (dplan["pages"].shape, geom.dec_width)
     assert not geom.single_chunk or dplan["single_chunk"]
 
-    chunk_tokens = geom.prefill_ppc * ps
-    # what the prefill launches run: each level's ``n_units`` (the pad
-    # units up to U are not run); a window plan launches no level 0
-    levels = [plan1] if windowed else [plan0, plan1]
-    launched = sum(int(p["n_units"]) for p in levels)
-    stats = {
-        "prefill_units": sum(p["stats"]["units"] for p in levels),
-        "prefill_units_launched": launched,
-        "prefill_cells_launched": float(
-            launched * geom.block_q * chunk_tokens),
-        "prefill_cells_valid": float(sum(
-            p["stats"]["mxu_cells_valid"] for p in levels)),
-        "prefill_rows_launched": float(launched * chunk_tokens),
-        "decode_pages_real": dplan["stats"]["pages_real"],
-        "decode_pages_launched": dplan["stats"]["pages_launched"],
-        "decode_rows_launched": float(
-            dplan["stats"]["pages_launched"] * ps),
-        "decode_cells_launched": float(
-            dplan["stats"]["pages_launched"] * ps),
-        "decode_cells_valid": float(dec_lens.sum()),
-        # level-0 units whose bitmap the launch expands
-        "mask_units": 0 if windowed else int(np.count_nonzero(
-            plan0["code"] == CODE_PARTIAL_MASK)),
-    }
+    # launched against useful, fallback units, lanes on decode units alone
+    stats = _plan_stats(plan0, plan1, dplan, dec_lens, geom, len(whole))
     out = dict(prefill1=plan1, decode=dplan, dec_rows=dec_rows, stats=stats)
     return out if windowed else dict(out, prefill0=plan0)
 
@@ -491,3 +491,41 @@ def engine_kernel_attention(q, k_cache, v_cache, kplans, *,
                                   sm_scale, -1)
     out, _ = compose_cascade_levels([(o0, lse0), (o1, lse1)])
     return out
+
+
+def _plan_stats(plan0, plan1, dplan, dec_lens, geom, lanes_whole) -> Dict:
+    """``build_engine_work_units``' ``stats``: what a step's launches run
+    against what of it is useful, and the two counts of how the planner
+    placed the work (``plan0`` None: a window plan, no level 0)."""
+    from flashinfer_tpu.ops.paged_prefill import CODE_PARTIAL_MASK
+
+    ps = geom.page_size
+    chunk_tokens = geom.prefill_ppc * ps
+    # what the prefill launches run: each level's ``n_units`` (the pad
+    # units up to the rung's cap are not run)
+    levels = [plan1] if plan0 is None else [plan0, plan1]
+    launched = sum(int(p["n_units"]) for p in levels)
+    return {
+        "prefill_units": sum(p["stats"]["units"] for p in levels),
+        "prefill_units_launched": launched,
+        # of those, units that attend nothing: a tile's lone write-back
+        "prefill_fallback_units": sum(
+            p["stats"]["units_fallback"] for p in levels),
+        "prefill_cells_launched": float(
+            launched * geom.block_q * chunk_tokens),
+        "prefill_cells_valid": float(sum(
+            p["stats"]["mxu_cells_valid"] for p in levels)),
+        "prefill_rows_launched": float(launched * chunk_tokens),
+        "decode_pages_real": dplan["stats"]["pages_real"],
+        "decode_pages_launched": dplan["stats"]["pages_launched"],
+        "decode_rows_launched": float(
+            dplan["stats"]["pages_launched"] * ps),
+        "decode_cells_launched": float(
+            dplan["stats"]["pages_launched"] * ps),
+        "decode_cells_valid": float(dec_lens.sum()),
+        # lanes whose whole context rode decode units (no level-0 unit)
+        "decode_lanes_whole": lanes_whole,
+        # level-0 units whose bitmap the launch expands
+        "mask_units": 0 if plan0 is None else int(np.count_nonzero(
+            plan0["code"] == CODE_PARTIAL_MASK)),
+    }

@@ -291,70 +291,62 @@ _UNIT_SCHEDULES = {
         SchedSeg(row0=8, n=3, pages=(5,), split=0, kv_after=3,
                  decoding=False, slot=2, group=1)],
     # one decode lane with no prefix: neither level has KV to attend,
-    # both plans are zero-write fallback units only
+    # both plans are their tile's one zero-write fallback unit
     "fallback_only": [
         SchedSeg(row0=0, n=1, pages=(1, 2), split=0, kv_after=9,
                  decoding=True, slot=0, group=0)],
 }
 
-# three lanes past their prompts, two of them on one prefix: level 0 is a
-# one-row unit a group row (each lane attending its prompt's full pages),
-# no mask cuts, level 1 is fallback units only.  Kept out of
-# _UNIT_SCHEDULES: its unit count equals "mixed"'s.
+# three lanes past their prompts, two of them riding one prefix (adjacent,
+# as the engine's group sort leaves them): level 0 is ONE two-row unit over
+# the shared run, no mask cuts; the third lane is alone in its group and has
+# no level-0 unit; level 1 is its tile's fallback unit.  Kept out of
+# _UNIT_SCHEDULES: its unit count equals "fallback_only"'s.
 _DECODE_ONLY = [
     SchedSeg(row0=0, n=1, pages=(1, 2, 3), split=16, kv_after=21,
              decoding=True, slot=0, group=0),
-    SchedSeg(row0=1, n=1, pages=(4, 5), split=8, kv_after=12,
-             decoding=True, slot=2, group=1),
-    SchedSeg(row0=2, n=1, pages=(1, 2, 6), split=16, kv_after=18,
-             decoding=True, slot=3, group=0)]
+    SchedSeg(row0=1, n=1, pages=(1, 2, 6), split=16, kv_after=18,
+             decoding=True, slot=3, group=0),
+    SchedSeg(row0=2, n=1, pages=(4, 5), split=8, kv_after=12,
+             decoding=True, slot=2, group=1)]
 _LOWERED_SCHEDULES = dict(_UNIT_SCHEDULES, decode_only=_DECODE_ONLY)
 
-# What the planner of PR 30 gives for these schedules at rung 16 (read off
-# that tree): each level's REAL units in order, one row a unit:
+# What the planner gives for these schedules at rung 16 since ISSUE 40 (the
+# units of PR 30's planner less the fallback units of a tile that holds
+# another unit, and less a lone lane's level-0 units): each level's REAL
+# units in order, one row a unit:
 # (qstart, rowlo, rowhi, qpos0, kvstart, kvlen, first, wout, qslot, code,
 # the unit's pages before the zeros).
 _UNIT_KEYS = ("qstart", "rowlo", "rowhi", "qpos0", "kvstart", "kvlen",
               "first", "wout", "qslot", "code")
-_PARENT_UNITS = {
+_PLAN_UNITS = {
+    # both lanes are alone in their groups and no chunk has a prefix:
+    # level 0 attends nothing, its one tile keeps one write-back unit
     ("mixed", "prefill0"): [
-        (0, 0, 0, -1, 0, 0, 1, 0, 0, 1, ()),
-        (0, 1, 1, -2, 0, 0, 0, 0, 0, 1, ()),
-        (0, 2, 2, -8, 0, 0, 0, 0, 0, 1, ()),
-        (0, 8, 8, -13, 0, 0, 0, 0, 0, 1, ()),
-        (0, 13, 13, -16, 0, 0, 0, 1, 0, 1, ())],
+        (0, 0, 0, -1, 0, 0, 1, 1, 0, 1, ())],
     ("mixed", "prefill1"): [
-        (0, 0, 0, -1, 0, 0, 1, 0, 0, 1, ()),
-        (0, 1, 1, -2, 0, 0, 0, 0, 0, 1, ()),
-        (0, 2, 8, 11, 0, 19, 0, 0, 0, 1, (6, 7, 8)),
-        (0, 8, 13, -8, 0, 5, 0, 0, 0, 1, (9,)),
-        (0, 13, 13, -16, 0, 0, 0, 1, 0, 1, ())],
+        (0, 2, 8, 11, 0, 19, 1, 0, 0, 1, (6, 7, 8)),
+        (0, 8, 13, -8, 0, 5, 0, 1, 0, 1, (9,))],
+    # the lane rides the run WITH the chunk: level 0 keeps both rows
     ("shared_prefix", "prefill0"): [
-        (0, 0, 8, 8, 0, 16, 1, 0, 0, 2, (1, 2)),
-        (0, 8, 8, -11, 0, 0, 0, 0, 0, 1, ()),
-        (0, 11, 11, -16, 0, 0, 0, 1, 0, 1, ())],
+        (0, 0, 8, 8, 0, 16, 1, 1, 0, 2, (1, 2))],
     ("shared_prefix", "prefill1"): [
-        (0, 0, 0, -1, 0, 0, 1, 0, 0, 1, ()),
-        (0, 1, 8, -5, 0, 3, 0, 0, 0, 1, (4,)),
-        (0, 8, 11, -8, 0, 3, 0, 0, 0, 1, (5,)),
-        (0, 11, 11, -16, 0, 0, 0, 1, 0, 1, ())],
+        (0, 1, 8, -5, 0, 3, 1, 0, 0, 1, (4,)),
+        (0, 8, 11, -8, 0, 3, 0, 1, 0, 1, (5,))],
     ("fallback_only", "prefill0"): [
-        (0, 0, 0, -1, 0, 0, 1, 0, 0, 1, ()),
-        (0, 1, 1, -16, 0, 0, 0, 1, 0, 1, ())],
+        (0, 0, 0, -1, 0, 0, 1, 1, 0, 1, ())],
     ("fallback_only", "prefill1"): [
-        (0, 0, 0, -1, 0, 0, 1, 0, 0, 1, ()),
-        (0, 1, 1, -16, 0, 0, 0, 1, 0, 1, ())],
+        (0, 0, 0, -1, 0, 0, 1, 1, 0, 1, ())],
     ("decode_only", "prefill0"): [
-        (0, 0, 1, 15, 0, 16, 1, 0, 0, 1, (1, 2)),
-        (0, 1, 2, 6, 0, 8, 0, 0, 0, 1, (4,)),
-        (0, 2, 3, 13, 0, 16, 0, 0, 0, 1, (1, 2)),
-        (0, 3, 3, -16, 0, 0, 0, 1, 0, 1, ())],
+        (0, 0, 2, 14, 0, 16, 1, 1, 0, 1, (1, 2))],
     ("decode_only", "prefill1"): [
-        (0, 0, 0, -1, 0, 0, 1, 0, 0, 1, ()),
-        (0, 1, 1, -2, 0, 0, 0, 0, 0, 1, ()),
-        (0, 2, 2, -3, 0, 0, 0, 0, 0, 1, ()),
-        (0, 3, 3, -16, 0, 0, 0, 1, 0, 1, ())],
+        (0, 0, 0, -1, 0, 0, 1, 1, 0, 1, ())],
 }
+
+
+def _lone(segs, e):
+    """A decoding lane whose group holds no other segment this step."""
+    return e.decoding and sum(o.group == e.group for o in segs) == 1
 
 
 def test_planner_counts_the_units_it_launches():
@@ -364,8 +356,8 @@ def test_planner_counts_the_units_it_launches():
 
     g = _geom()
     chunk = g.prefill_ppc * g.page_size
-    seen = set()
-    for segs in _UNIT_SCHEDULES.values():
+    seen = {}
+    for name, segs in _UNIT_SCHEDULES.items():
         plans = build_engine_work_units(segs, rung=16, geom=g)
         n = 0
         for lvl in ("prefill0", "prefill1"):
@@ -379,8 +371,15 @@ def test_planner_counts_the_units_it_launches():
         assert st["prefill_cells_launched"] == n * g.block_q * chunk
         assert st["prefill_rows_launched"] == n * chunk
         assert st["prefill_cells_valid"] <= st["prefill_cells_launched"]
-        seen.add(n)
-    assert len(seen) == len(_UNIT_SCHEDULES)  # three counts, one shape
+        assert st["prefill_fallback_units"] == sum(
+            int((plans[lvl]["kvlen"][:int(plans[lvl]["n_units"])] == 0).sum())
+            for lvl in ("prefill0", "prefill1"))
+        assert st["decode_lanes_whole"] == sum(_lone(segs, e) for e in segs)
+        seen[name] = (n, st["prefill_fallback_units"],
+                      st["decode_lanes_whole"])
+    # other counts at one shape; a fallback unit only in a tile of no other
+    assert seen == {"mixed": (3, 1, 2), "shared_prefix": (3, 0, 0),
+                    "fallback_only": (2, 2, 1)}
     # a plan padded to a cap with no unit at all would launch an empty grid
     with pytest.raises(AssertionError, match="real unit"):
         build_prefill_work_units(
@@ -423,8 +422,8 @@ def test_dynamic_unit_bound_is_bitwise_the_static_grid(schedule):
     assert (schedule == "shared_prefix") == bool(
         (real0 == CODE_PARTIAL_MASK).any())
     if schedule == "fallback_only":
-        for p in (p0, p1):  # the lane's row, and the rung's padding rows
-            assert not p["kvlen"].any() and p["n_units"] == 2
+        for p in (p0, p1):  # the lane's row and the rung's padding: one tile
+            assert not p["kvlen"].any() and p["n_units"] == 1
     for lvl in ("prefill0", "prefill1"):
         assert plans[lvl]["n_units"] < plans[lvl]["qstart"].shape[0]
         o_dyn, lse_dyn = launch(plans, lvl, True)
@@ -440,17 +439,139 @@ def test_dynamic_unit_bound_is_bitwise_the_static_grid(schedule):
     assert fused_paged_prefill._cache_size() == traced
 
 
+def _real(plan):
+    """A plan's real units as ``{key: leading n_units entries}``."""
+    n = int(plan["n_units"])
+    return {k: plan[k][:n] for k in _UNIT_KEYS}
+
+
+def test_a_lone_lane_rides_decode_units_over_its_whole_context():
+    """ISSUE 40: a decoding lane alone in its group this step has no
+    level-0 unit and no mask part; its decode row starts at page 0 with
+    ``kvlen = kv_after``.  Lanes that ride one cached run together, and a
+    chunk prefilled inside its own span, keep level 0 and its masks; the
+    same lane turns lone when its sharer is not in the step."""
+    from flashinfer_tpu.ops.paged_prefill import CODE_PARTIAL_MASK
+
+    g = _geom()
+    ps = g.page_size
+    lane = SchedSeg(row0=0, n=1, pages=(1, 2, 3), split=16, kv_after=21,
+                    decoding=True, slot=1, group=0)
+    lone = build_engine_work_units([lane], rung=16, geom=g)
+    for lvl in ("prefill0", "prefill1"):   # one write-back unit a tile
+        assert lone[lvl]["n_units"] == 1
+        assert not _real(lone[lvl])["kvlen"].any()
+    assert not lone["prefill0"]["mask_bytes"].any()
+    d = lone["decode"]
+    np.testing.assert_array_equal(d["pages"][1, :3], [1, 2, 3])
+    assert d["kvlen"][1] == d["wu_kvlen"][1] == 21 and d["wu_page0"][1] == 0
+    assert lone["dec_rows"][1] == 0
+    st = lone["stats"]
+    assert (st["decode_lanes_whole"], st["prefill_fallback_units"],
+            st["mask_units"]) == (1, 2, 0)
+    assert st["decode_pages_real"] == -(-21 // ps)
+    assert st["decode_cells_valid"] == 21.0 and st["prefill_cells_valid"] == 0
+
+    # the same lane beside a rider of its run: level 0 as it was (one
+    # two-row unit over the run), both decode rows over their suffixes
+    rider = SchedSeg(row0=1, n=1, pages=(1, 2, 4), split=16, kv_after=18,
+                     decoding=True, slot=2, group=0)
+    both = build_engine_work_units([lane, rider], rung=16, geom=g)
+    u0 = _real(both["prefill0"])
+    assert both["prefill0"]["n_units"] == 1
+    assert (u0["rowlo"][0], u0["rowhi"][0], u0["kvlen"][0]) == (0, 2, 16)
+    np.testing.assert_array_equal(both["prefill0"]["pages"][:2], [1, 2])
+    d = both["decode"]
+    assert list(d["kvlen"][[1, 2]]) == [5, 2]
+    assert d["pages"][1, 0] == 3 and d["pages"][2, 0] == 4
+    assert both["stats"]["decode_lanes_whole"] == 0
+    assert both["stats"]["prefill_fallback_units"] == 1   # level 1's tile
+
+    # a chunk inside its own span (a resumed request re-reading its cached
+    # run): level 0 with a mask that cuts, and no lane to move
+    chunk = SchedSeg(row0=0, n=6, pages=(1, 2, 3), split=16, kv_after=14,
+                     decoding=False, slot=0, group=0)
+    own = build_engine_work_units([chunk], rung=16, geom=g)
+    u0 = _real(own["prefill0"])
+    assert (u0["rowlo"][0], u0["rowhi"][0], u0["kvlen"][0]) == (0, 6, 16)
+    assert u0["code"][0] == CODE_PARTIAL_MASK
+    assert own["prefill0"]["mask_bytes"][0].any()
+    assert own["stats"]["mask_units"] == 1
+    assert own["stats"]["decode_lanes_whole"] == 0
+    assert not own["decode"]["kvlen"].any()
+
+    # a lone lane beside that chunk: the chunk's units stay, the lane adds
+    # none at either level, and the counts are the plans'
+    far = SchedSeg(row0=6, n=1, pages=(5, 6, 7, 8), split=24, kv_after=30,
+                   decoding=True, slot=3, group=1)
+    mixed = build_engine_work_units([chunk, far], rung=16, geom=g)
+    for lvl in ("prefill0", "prefill1"):
+        for k in _UNIT_KEYS:
+            np.testing.assert_array_equal(mixed[lvl][k], own[lvl][k])
+        np.testing.assert_array_equal(mixed[lvl]["pages"], own[lvl]["pages"])
+    np.testing.assert_array_equal(mixed["prefill0"]["mask_bytes"],
+                                  own["prefill0"]["mask_bytes"])
+    d = mixed["decode"]
+    np.testing.assert_array_equal(d["pages"][3, :4], [5, 6, 7, 8])
+    assert d["kvlen"][3] == 30 and mixed["dec_rows"][3] == 6
+    st = mixed["stats"]
+    assert st["decode_lanes_whole"] == 1
+    # (level 1: the chunk lies inside its span, so its tile attends nothing)
+    assert st["prefill_fallback_units"] == 1 == \
+        own["stats"]["prefill_fallback_units"]
+    assert st["prefill_units"] == st["prefill_units_launched"] == sum(
+        int(mixed[lvl]["n_units"]) for lvl in ("prefill0", "prefill1"))
+
+
+def test_lone_and_shared_lanes_attend_the_same_context():
+    """``engine_kernel_attention`` over a lone lane's plan (decode units
+    over the whole context) and over the plan of the same lane beside a
+    rider (level 0 over the run merged with decode units over the suffix):
+    the lane's row agrees to rounding, and both are the dense softmax."""
+    from flashinfer_tpu.serve.engine_kernels import (engine_kernel_attention,
+                                                     plans_to_device)
+
+    g = _geom()
+    hq, hkv, d, ps = 4, 2, 32, g.page_size
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(7), 3)
+    q = jax.random.normal(kq, (16, hq, d), jnp.float32)
+    kc = jax.random.normal(kk, (12, hkv, ps, d), jnp.float32)
+    vc = jax.random.normal(kv, (12, hkv, ps, d), jnp.float32)
+    lane = SchedSeg(row0=0, n=1, pages=(1, 2, 3), split=16, kv_after=21,
+                    decoding=True, slot=1, group=0)
+    rider = SchedSeg(row0=1, n=1, pages=(1, 2, 4), split=16, kv_after=18,
+                     decoding=True, slot=2, group=0)
+
+    def attend(segs):
+        plans = build_engine_work_units(segs, rung=16, geom=g)
+        return np.asarray(engine_kernel_attention(
+            q, kc, vc, plans_to_device(plans), geom=g, sm_scale=d ** -0.5))
+
+    alone, shared = attend([lane]), attend([lane, rider])
+    rows = jnp.swapaxes(kc[jnp.asarray(lane.pages)], 1, 2).reshape(-1, hkv, d)
+    vals = jnp.swapaxes(vc[jnp.asarray(lane.pages)], 1, 2).reshape(-1, hkv, d)
+    qg = q[0].reshape(hkv, hq // hkv, d)
+    p = jax.nn.softmax(
+        jnp.einsum("ngd,snd->ngs", qg, rows[:21]) * d ** -0.5, -1)
+    want = jnp.einsum("ngs,snd->ngd", p, vals[:21]).reshape(hq, d)
+    np.testing.assert_allclose(alone[0], want, atol=2e-5)
+    np.testing.assert_allclose(shared[0], want, atol=2e-5)
+    assert not alone[1:].any()        # every other row attends nothing
+
+
 def _assert_plans_are_the_parents(name, plans, g, rung=16):
-    """Every plan array against the parent's rule: the real units first,
-    then the pad unit (zeros, code CODE_PARTIAL) up to the rung's cap; a
-    bitmap for the units whose mask cuts and zeros for every other."""
+    """Every plan array against the planner's rule (PR 31's, with ISSUE
+    40's units): the real units first, then the pad unit (zeros, code
+    CODE_PARTIAL) up to the rung's cap; a bitmap for the units whose mask
+    cuts and zeros for every other; a decode row over the lane's suffix,
+    or over its whole context where the lane is alone in its group."""
     from flashinfer_tpu.ops.paged_prefill import (CODE_PARTIAL,
                                                   CODE_PARTIAL_MASK)
 
     segs, U, ps = _LOWERED_SCHEDULES[name], g.prefill_unit_cap(rung), \
         g.page_size
     for lvl in ("prefill0", "prefill1"):
-        p, units = plans[lvl], _PARENT_UNITS[name, lvl]
+        p, units = plans[lvl], _PLAN_UNITS[name, lvl]
         assert p["n_units"] == len(units) and p["n_units"].dtype == np.int32
         for i, k in enumerate(_UNIT_KEYS):
             want = np.full(U, CODE_PARTIAL if k == "code" else 0, np.int32)
@@ -485,17 +606,20 @@ def _assert_plans_are_the_parents(name, plans, g, rung=16):
     assert not mb[..., chunk // 8:].any()
     assert plans["stats"]["mask_units"] == np.count_nonzero(
         p0["code"] == CODE_PARTIAL_MASK) == (name == "shared_prefix")
-    # the decode lanes: each slot's suffix pages and length, one unit a
-    # slot (num_splits 1), the lane's flat row (the rung where idle)
+    # the decode lanes: each slot's pages and length (from its split on
+    # for a lane that shares its run this step, from page 0 for a lone
+    # one), one unit a slot (num_splits 1), the lane's flat row (the rung
+    # where idle)
     d = plans["decode"]
     table = np.zeros((g.max_batch, g.dec_width), np.int32)
     lens = np.zeros(g.max_batch, np.int32)
     rows = np.full(g.max_batch, rung, np.int32)
     for e in segs:
         if e.decoding:
-            sfx = e.pages[e.split // ps:-(-e.kv_after // ps)]
+            lo = 0 if _lone(segs, e) else e.split
+            sfx = e.pages[lo // ps:-(-e.kv_after // ps)]
             table[e.slot, :len(sfx)] = sfx
-            lens[e.slot], rows[e.slot] = e.kv_after - e.split, e.row0
+            lens[e.slot], rows[e.slot] = e.kv_after - lo, e.row0
     for k, want in (("pages", table), ("kvlen", lens), ("wu_kvlen", lens),
                     ("wu_req", np.arange(g.max_batch, dtype=np.int32)),
                     ("wu_page0", np.zeros(g.max_batch, np.int32))):
@@ -816,11 +940,11 @@ def test_window_plan_is_level_1_under_the_window_rule_and_no_level_0():
     long = [SchedSeg(row0=0, n=12, pages=tuple(range(1, 39)), split=0,
                      kv_after=300, decoding=False, slot=0, group=0)]
     assert wide.prefill_ppc * 8 == 128     # 300 positions: three chunks
-    # (+ 1: the rung's padding rows keep their zero-write unit)
+    # (the rung's padding rows share the chunk's tile: no unit of their own)
     assert build_engine_work_units(long, rung=16, geom=wide)[
-        "prefill1"]["n_units"] == 3 + 1
+        "prefill1"]["n_units"] == 3
     assert build_engine_work_units(long, rung=16, geom=wide, window_left=11)[
-        "prefill1"]["n_units"] == 1 + 1
+        "prefill1"]["n_units"] == 1
     # the decode lane: its held pages, its length counted from the first
     d = plans["decode"]
     np.testing.assert_array_equal(d["pages"][2, :3], [9, 10, 11])

@@ -70,7 +70,7 @@ def _setup(qo_lens, kv_lens, seed=0):
 def _run(qo_indptr, kv_page_indptr, kv_page_indices, kv_lens, q, kc, vc,
          bq, ppc, *, causal=True, window_left=-1, mask_flat=None,
          mask_total_bits=None, pack_tiles=True, prune=True,
-         force_partial=False):
+         force_partial=False, return_lse=False):
     plan_np = build_prefill_work_units(
         qo_indptr, kv_page_indptr, kv_page_indices,
         np.asarray(kv_lens, np.int64), block_q=bq, pages_per_chunk=ppc,
@@ -89,8 +89,10 @@ def _run(qo_indptr, kv_page_indptr, kv_page_indices, kv_lens, q, kc, vc,
     plan = {k: jnp.asarray(v) for k, v in plan_np.items()}
     out = fused_paged_prefill(
         q, kc, vc, plan, sm_scale=D ** -0.5, causal=causal,
-        window_left=window_left, **statics,
+        window_left=window_left, return_lse=return_lse, **statics,
     )
+    if return_lse:      # (o, lse)
+        return tuple(np.asarray(x) for x in out), stats, plan_np
     return np.asarray(out, np.float32), stats, plan_np
 
 
@@ -356,3 +358,110 @@ def test_wrapper_live_retune_refreshes_plan_and_stats(monkeypatch):
     np.testing.assert_allclose(out, np.asarray(ref.run(q, (kc, vc)),
                                                np.float32),
                                rtol=3e-2, atol=3e-2)
+
+
+# ---------------------------------------------------------------------------
+# One write-back unit a packed tile (ISSUE 40)
+# ---------------------------------------------------------------------------
+
+# (qo_lens, kv_lens, causal, window_left) at block_q 64, 2 pages a chunk:
+EMPTY_SPAN_PLANS = {
+    # 48 decode rows that attend nothing at this level, on one tile
+    "one_row_lanes": ([1] * 48, [0] * 48, True, -1),
+    # real units with empty spans before, between and after them, over two
+    # tiles (the 40-row request crosses the boundary)
+    "real_between_empty": ([1, 5, 1, 30, 2, 40, 1, 9],
+                           [0, 24, 0, 10, 0, 40, 0, 0], True, -1),
+    # a rung with no KV at all: two tiles, five spans
+    "all_empty_rung": ([40, 30, 50, 8], [0, 0, 0, 0], True, -1),
+    # a windowed plan: the 70-row request's rows in tile 0 lie before its
+    # first position (all pruned there), its rows in tile 1 attend
+    "windowed": ([3, 70, 5, 2], [10, 8, 0, 90], True, 7),
+    # the same without the causal rule (level 0's form, less the mask)
+    "non_causal": ([3, 1, 1, 70, 5], [10, 0, 0, 33, 0], False, -1),
+}
+
+
+def _plan_and_run(args, kv_lens, causal, window_left, pack_tiles=True):
+    """(plan arrays with their ``stats``, o, lse) at block_q 64, 2 pages a
+    chunk."""
+    (o, lse), stats, plan = _run(
+        *args[:3], kv_lens, *args[3:], 64, 2, causal=causal,
+        window_left=window_left, pack_tiles=pack_tiles, return_lse=True)
+    return dict(plan, stats=stats), o, lse
+
+
+@pytest.mark.parametrize("name", sorted(EMPTY_SPAN_PLANS))
+def test_a_packed_tile_keeps_one_write_back_unit(name, monkeypatch):
+    """A span with nothing to attend gets a fallback unit only where its
+    packed tile would hold no unit.  Against the parent's plan (every such
+    span its own fallback unit: the planner with the cut taken out): the
+    kept units are the parent's in the parent's order, at most one fallback
+    unit a tile and none in a tile with a real unit, and the launch's ``o``
+    and ``lse`` are the parent's bit for bit (rows no unit covers leave the
+    tile's reset state).  An unpacked plan is the parent's array for
+    array."""
+    import flashinfer_tpu.ops.paged_prefill as pp
+
+    qo_lens, kv_lens, causal, wl = EMPTY_SPAN_PLANS[name]
+    args = _setup(qo_lens, kv_lens, seed=4)
+    plan, o, lse = _plan_and_run(args, kv_lens, causal, wl)
+    unpacked, o_u, lse_u = _plan_and_run(args, kv_lens, causal, wl,
+                                         pack_tiles=False)
+    monkeypatch.setattr(pp, "_one_fallback_a_tile", lambda u, w: (u, w))
+    parent, o_p, lse_p = _plan_and_run(args, kv_lens, causal, wl)
+    parent_u, _, _ = _plan_and_run(args, kv_lens, causal, wl,
+                                   pack_tiles=False)
+
+    np.testing.assert_array_equal(o, o_p)
+    np.testing.assert_array_equal(lse, lse_p)
+    np.testing.assert_array_equal(o, o_u)     # packing stays bit-exact
+    np.testing.assert_array_equal(lse, lse_u)
+    ref = _oracle(args[0], args[1], args[2], kv_lens, *args[3:],
+                  causal=causal, window_left=wl)
+    np.testing.assert_allclose(o, ref, rtol=2e-5, atol=2e-5)
+
+    assert plan["stats"]["packed"] and not unpacked["stats"]["packed"]
+    keys = ("qstart", "rowlo", "rowhi", "qpos0", "kvstart", "kvlen", "code")
+    n, n_p = plan["stats"]["units"], parent["stats"]["units"]
+    assert n < n_p and plan["n_units"] == n
+    units = [tuple(int(plan[k][i]) for k in keys) for i in range(n)]
+    units_p = [tuple(int(parent[k][i]) for k in keys) for i in range(n_p)]
+    fallback = [u for u in units if u[5] == 0]
+    tiles_real = {u[0] for u in units if u[5] > 0}
+    assert [u for u in units_p if u[5] > 0] == [u for u in units if u[5] > 0]
+    assert len({u[0] for u in fallback}) == len(fallback)
+    assert not {u[0] for u in fallback} & tiles_real
+    assert {u[0] for u in units} == {u[0] for u in units_p}   # every tile
+    # ... in the parent's order, first and last of a tile flagged
+    it = iter(units_p)
+    assert all(u in it for u in units)
+    for t in {u[0] for u in units}:
+        idx = [i for i, u in enumerate(units) if u[0] == t]
+        assert idx == list(range(idx[0], idx[-1] + 1))
+        assert [int(plan["first"][i]) for i in idx] == \
+            [1] + [0] * (len(idx) - 1)
+        assert [int(plan["wout"][i]) for i in idx] == \
+            [0] * (len(idx) - 1) + [1]
+    assert plan["stats"]["units_fallback"] == len(fallback)
+    assert parent["stats"]["units_fallback"] == n_p - n + len(fallback)
+    if name in ("one_row_lanes", "all_empty_rung"):
+        assert n == len(fallback) == plan["stats"]["tiles"]
+        assert not o.any() and (lse < -1e29).all()
+
+    for k, v in unpacked.items():
+        if isinstance(v, np.ndarray):
+            np.testing.assert_array_equal(v, parent_u[k], err_msg=k)
+    assert unpacked["stats"] == parent_u["stats"]
+
+
+def test_an_aligned_plan_keeps_a_fallback_unit_a_request():
+    """Requests that each start on a tile boundary are unpacked whatever
+    ``pack_tiles`` says: every span is its own tile, and an empty one keeps
+    its fallback unit."""
+    args = _setup([64, 64, 64], [24, 0, 0], seed=2)
+    plan, o, lse = _plan_and_run(args, [24, 0, 0], True, -1)
+    assert not plan["stats"]["packed"]
+    assert plan["stats"]["units"] == 2 + 2
+    assert plan["stats"]["units_fallback"] == 2
+    assert not o[64:].any() and (lse[64:] < -1e29).all()

@@ -646,6 +646,77 @@ def test_kernel_tier_handover_serves_the_reference_tokens(params, run):
         assert 0 < f["upload_kib"] < min(g["upload_kib"] for g in facts)
 
 
+def _lone_lanes(params, backend):
+    """No sharing: every decode lane is alone in its group in every step."""
+    rng = np.random.default_rng(53)
+    eng = _mk_engine(params, share=False, attention_backend=backend,
+                     sampling=SamplingConfig(temperature=0.8, top_k=20))
+    for i, n in enumerate((29, 9, 18, 35, 3)):
+        eng.submit(EngineRequest(
+            f"r{i}", [int(t) for t in rng.integers(1, CFG.vocab_size, n)],
+            max_new_tokens=5))
+    return _drain(eng)
+
+
+def _riders(params, backend, new=(6, 6, 6, 6)):
+    """Two cached runs, then riders on them with ``new`` tokens to serve
+    (the first and third ride one run, the second and fourth the other)."""
+    rng = np.random.default_rng(59)
+    eng = _mk_engine(params, attention_backend=backend,
+                     sampling=SamplingConfig(temperature=0.8, top_k=20))
+    prompts = _prompts(rng, 2 + len(new))
+    for i, p in enumerate(prompts[:2]):      # the prefixes enter the trie
+        eng.submit(EngineRequest(f"a{i}", list(p), max_new_tokens=2))
+    first, facts = _drain(eng)
+    for i, (p, n) in enumerate(zip(prompts[2:], new)):
+        eng.submit(EngineRequest(f"b{i}", list(p), max_new_tokens=n))
+    second, more = _drain(eng)
+    assert all(eng._finished[f"b{i}"].hit_tokens == 16
+               for i in range(len(new)))
+    return {**first, **second}, facts + more
+
+
+def _sharer_finishes_first(params, backend):
+    """Two riders of one run; the first is done after 2 tokens, so the
+    second turns from a shared lane into a lone one between two steps."""
+    return _riders(params, backend, new=(2, 1, 9))
+
+
+@pytest.mark.parametrize("run", [_lone_lanes, _riders,
+                                 _sharer_finishes_first, _preempt_resume],
+                         ids=lambda f: f.__name__.lstrip("_"))
+def test_a_decode_lane_costs_decode_units_only(params, run):
+    """ISSUE 40: a lane alone in its group rides decode units over its
+    whole context, a lane that shares its run this step keeps level 0, and
+    a lane may change from one to the other between steps: the kernel tier
+    serves the reference tier's tokens in each case, and the step's facts
+    say which lanes went which way."""
+    ref, ref_facts = run(params, "reference")
+    got, facts = run(params, "kernel")
+    assert got == ref
+    assert all(f["decode_lanes_whole"] == f["prefill_fallback_units"] == 0
+               for f in ref_facts)
+    lanes = [(f["decode_tokens"], f["decode_lanes_whole"]) for f in facts]
+    assert all(0 <= whole <= dec for dec, whole in lanes)
+    assert sum(dec for dec, _ in lanes) > 0
+    # at most a fallback unit a q tile of each level (one tile a level at
+    # these rungs), and never a unit that is not launched
+    for f in facts:
+        assert f["prefill_fallback_units"] <= 2
+        assert f["prefill_units"] == f["prefill_units_launched"] >= 2
+    shared = [(dec, whole) for dec, whole in lanes if whole < dec]
+    if run in (_lone_lanes, _preempt_resume):
+        assert not shared                 # nobody to share with
+    else:
+        # sharers come in twos at least: a lane whose sharer is in the
+        # step stays on level 0 with it
+        assert shared and all(dec - whole >= 2 for dec, whole in shared)
+    if run is _sharer_finishes_first:
+        # b0 and b2 decode together, then b2 goes on alone
+        assert (2, 0) in lanes and lanes[-1] == (1, 1)
+        assert lanes.index((2, 0)) < len(lanes) - 1
+
+
 def test_back_to_back_schedules_on_one_rung_agree_with_fresh_engines(params):
     """A long chunked prompt, then two short prompts, through ONE engine
     on the same rungs: the second wave's tokens and mask units are what a
