@@ -885,7 +885,11 @@ def engine_step(num_tokens: int, batch: int, layers: int, *, hidden: int,
     such spans keeps ONE zero-write unit, priced like any other), and a
     decode lane alone in its group is priced as DECODE pages over its
     whole context (``decode_lanes_whole``), no longer as one-row prefill
-    units over its prompt plus decode pages over the suffix.  The
+    units over its prompt plus decode pages over the suffix.  A prompt
+    chunk alone in its group is priced as the same units it always was,
+    run by level 1's launch over its whole context
+    (``prefill_tokens_whole``: its rows inside the span) in place of
+    level 0's.  The
     attention term then prices ``flops`` from launched pairs and
     ``bytes`` from launched rows, with ``flops_effective`` holding the
     exact attended-pair work, so ``obs perf`` exposes the tier's true

@@ -528,7 +528,11 @@ def _build_unit_masks(units, U, qo_indptr, kv_lens, mask_bits, mask_native,
     """Per-unit packed bitmaps [U, block_q, mask_lane_bytes]: the window
     of the mask of each ``CODE_PARTIAL_MASK`` unit, the only units whose
     bitmap the kernel expands; every other row stays zero (and, the
-    array being ``np.zeros``, is never touched).
+    array being ``np.zeros``, is never touched).  A plan with no such
+    unit gets a read-only view of ONE zero byte in that shape: nobody
+    reads it, and at the serving engine's caps the allocation alone
+    (8 MB a step, cleared by the allocator once it reuses the block) was
+    most of a step's level-0 planning.
 
     Canonical enumeration -> the C++ planner builds bitmaps for ALL
     canonical units in one pass and the mask units row-select from it
@@ -538,11 +542,11 @@ def _build_unit_masks(units, U, qo_indptr, kv_lens, mask_bits, mask_native,
     from flashinfer_tpu import native
 
     mb = mask_lane_bytes(chunk_tokens)
-    out = np.zeros((U, block_q, mb), np.uint8)
     masked = [(i, u) for i, u in enumerate(units)
               if u[6] == CODE_PARTIAL_MASK]
     if not masked:
-        return out
+        return np.broadcast_to(np.uint8(0), (U, block_q, mb))
+    out = np.zeros((U, block_q, mb), np.uint8)
     if not packed and native.get_lib() is not None:
         # mask_native is the caller's ORIGINAL packed-bytes form when one
         # was supplied — the C++ planner reads LSB-first bytes directly,

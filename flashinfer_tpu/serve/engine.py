@@ -501,7 +501,8 @@ class ServingEngine:
     _UNIT_FACTS = ("prefill_units", "prefill_units_launched",
                    "prefill_cells_valid", "prefill_cells_launched",
                    "decode_pages_real", "decode_pages_launched",
-                   "decode_lanes_whole", "prefill_fallback_units")
+                   "decode_lanes_whole", "prefill_tokens_whole",
+                   "prefill_fallback_units")
 
     def __init__(self, model_cfg, params, config: EngineConfig):
         if config.attention_backend not in ("reference", "kernel"):
@@ -641,7 +642,8 @@ class ServingEngine:
         # padded work-unit grids actually execute vs the attended pairs
         # — costmodel.engine_step prices the bench A/B from these
         # (beside the useful-against-launched pairs: the lanes whose whole
-        # context rode decode units and the prefill plans' fallback units)
+        # context rode decode units, the prompt tokens whose whole context
+        # rode level 1, and the prefill plans' fallback units)
         self.unit_stats = {
             **dict.fromkeys(self._UNIT_FACTS, 0),
             "kv_pairs_launched": 0.0, "kv_rows_launched": 0.0,
@@ -1317,9 +1319,10 @@ class ServingEngine:
         scatter results.  Returns this step's facts: its index, rung,
         tokens scheduled (and how many were prompt / decode tokens),
         requests sampled / finished, the kernel tier's work units and
-        cells useful against launched, its lanes on decode units alone
-        and its prefill fallback units (``_UNIT_FACTS``), the pages its
-        K/V rows landed on (``kv_pages_written`` of ``kv_pages_pool``),
+        cells useful against launched, its lanes on decode units alone,
+        its prompt tokens on level 1 alone and its prefill fallback units
+        (``_UNIT_FACTS``), the pages its K/V rows landed on
+        (``kv_pages_written`` of ``kv_pages_pool``),
         the queue wait of the requests first scheduled in it, and what it
         handed to the device: ``upload_kib`` in ``upload_transfers``
         buffers, ``plans_lowered`` (1 on the kernel tier), level 0's

@@ -12,23 +12,39 @@ kernels, a unit only where a launch has work:
   into the existing plan-array forms:
 
   * **level 0** (the shared-prefix span, gathered once per prefix
-    GROUP) and **level 1 chunked prefill** (each request's suffix
-    window, ``q_len >= 1``) both become
+    GROUP of two or more segments) and **level 1 chunked prefill**
+    (each request's window, ``q_len >= 1``) both become
     :func:`~flashinfer_tpu.ops.paged_prefill.build_prefill_work_units`
     plans — level 0 with a per-token custom mask encoding the
     causal-by-global-position rule ``kv_row <= pos`` over the group
-    run, level 1 with the planner's native causal rule (suffix-local
-    positions, negative ``qpos0`` rows inside the shared span attend
-    nothing and emit the empty-state sentinel);
+    run, level 1 with the planner's native causal rule: over the
+    suffix of a chunk that shares its run (suffix-local positions,
+    negative ``qpos0`` rows inside the shared span attend nothing and
+    emit the empty-state sentinel), over the WHOLE context from page 0
+    of a chunk alone in its group (global positions: the same rule as
+    level 0's mask, as integer bounds a unit and no bitmap; the rows of
+    such a chunk PAST its split, the prompt's last page of rows at most,
+    stay the cascade's: the run at level 0, the suffix here);
   * **decode tokens** (``q_len == 1``) become
     :func:`~flashinfer_tpu.ops.paged_decode.build_decode_split_units`
     units (split-KV partials + ``merge_states``): over its suffix page
     run for a lane whose group holds another row this step, over its
     WHOLE page run, with no level-0 unit, for a lane alone in its group
-    (a decode lane costs decode units only).  The planner makes that
-    choice per group from the schedule it is given: riders of one cached
-    run, and a chunk beside its sharers, keep level 0 (the cascade's
-    dedup), and a lane turns lone or shared from one step to the next.
+    (a decode lane costs decode units only).
+
+  A group that is ONE segment this step has nothing for level 0 to
+  de-duplicate, so it leaves level 0 with nothing: no unit, no ``pos``
+  list, no ``[rows x split]`` mask part.  The planner makes that choice
+  per group from the schedule it is given: riders of one cached run, and
+  a chunk beside its sharers, keep level 0 (the cascade's dedup), and a
+  segment turns lone or shared from one step to the next.  One part of a
+  lone chunk does not move: the rows past its split (the chunk that ends
+  a prompt has 1 to ``page_size`` of them, the prompt's last row among
+  them) attended the run at level 0 and the suffix at level 1 and were
+  merged, and they still are, under a mask part of ones that the planner
+  demotes to maskless units.  One pass over run and suffix is the same
+  softmax to rounding, not to the bit, and the prompt's last row samples
+  the request's first token.
 
 - :func:`engine_kernel_attention` — the in-jit composition: the three
   kernel launches produce per-level ``(out, lse)`` states and fold
@@ -44,12 +60,14 @@ pad to :meth:`EngineKernelGeom.prefill_unit_cap` via the planner's
 ``n_units`` leaf is the trip count of their unit axis, a value and not a
 shape), the decode plan is ``max_batch x num_splits`` units over a
 fixed-width page table, and the level-0 custom-mask operand is always
-present (all-ones windows demote to the maskless PARTIAL code in-plan).
+present (all-ones windows demote to the maskless PARTIAL code in-plan;
+a plan with no mask unit carries the planner's read-only view of one zero
+byte in the rung's ``[U, block_q, mb]``, not that much allocated).
 One rung == one trace, the <= 9-trace budget the reference backend pins.
 
 Every flat row — scheduled or rung padding — is covered by a plan
-segment (padding, decode rows at level 1 and lone lanes at level 0 ride
-``kv_len = 0`` segments), so both levels emit defined ``(0, -inf)``
+segment (padding, decode rows at level 1 and lone segments at level 0
+ride ``kv_len = 0`` segments), so both levels emit defined ``(0, -inf)``
 empty states for rows that attend nothing there: the reset state of a
 tile, which needs a unit of its own only where it holds no other.
 
@@ -148,9 +166,13 @@ class EngineKernelGeom:
         trailing padding segment) overlaps at most ``cdiv(n, block_q) +
         1`` qo tiles, each (tile, segment) span walks at most
         ``max_prefill_chunks`` KV chunks, and a tile of pruned spans keeps
-        one zero-write fallback unit.  The cap is a RUNG static, so a rung's
-        plan shape can never vary step to step.  It sizes the plan
-        arrays only: a launch runs the units a step's plan has."""
+        one zero-write fallback unit.  (A lone chunk that ends its prompt
+        is TWO plan segments, its rows on either side of the split: a
+        boundary inside a tile adds one (tile, segment) span, and ``2 x
+        max_batch`` boundaries are counted here.)  The cap is a RUNG
+        static, so a rung's plan shape can never vary step to step.  It
+        sizes the plan arrays only: a launch runs the units a step's plan
+        has."""
         spans = cdiv(rung, self.block_q) + 2 * (self.max_batch + 1)
         return max(next_power_of_two(spans * self.max_prefill_chunks), 8)
 
@@ -188,10 +210,19 @@ def build_engine_work_units(
                     global position; one qo segment per contiguous
                     group span, so the run's pages stream once per
                     (tile, chunk) for ALL riders — the cascade dedup;
-                    a group that is ONE decoding lane this step rides
-                    a kv_len=0 segment: no unit, no mask part)
-        prefill1  — level-1 suffix plan (causal; decode rows and
-                    fully-in-span chunks ride kv_len=0 segments)
+                    a group that is ONE segment this step, a decoding
+                    lane or a prompt chunk, rides a kv_len=0 segment:
+                    no unit, no mask part; but a lone chunk's rows
+                    PAST its split are a segment of their own over the
+                    run, every column, under a part of ones)
+        prefill1  — level-1 plan (causal): a lone chunk's WHOLE context
+                    from page 0 (``kv_len = kv_after``, so ``qpos0`` is
+                    the chunk's first GLOBAL position; ``kv_len =
+                    split`` for the rows inside the span of the chunk
+                    that ends its prompt, whose rows past the split are
+                    a second segment over the suffix), a sharer's
+                    suffix; decode rows and a sharer's fully-in-span
+                    chunk ride kv_len=0 segments
         decode    — split-KV decode plan over per-slot tables: a lone
                     lane's whole context from page 0, a sharer's suffix
         dec_rows  — [max_batch] flat row of each decode lane's token
@@ -201,9 +232,12 @@ def build_engine_work_units(
                     ``engine_step`` cost family (a prefill launch runs
                     its plan's real units: what is launched and not
                     effective is the idle rows and columns of a tile),
-                    the prefill plans' ``prefill_fallback_units`` and
-                    ``decode_lanes_whole`` (0 in a window plan, which
-                    never had a level 0 to leave)
+                    the prefill plans' ``prefill_fallback_units``,
+                    ``decode_lanes_whole`` and ``prefill_tokens_whole``
+                    (the lanes, and the prompt tokens inside the spans
+                    of the chunks, that were alone in their groups; 0
+                    in a window plan, which never had a level 0 to
+                    leave)
 
     Every array shape depends only on ``rung`` and ``geom``.
 
@@ -240,8 +274,12 @@ def build_engine_work_units(
     # ---- level 0: shared-prefix groups, causal-by-global-position ----
     # (a window plan has none: every segment's would be empty)
     plan0 = None
-    # slots of the lone decoding lanes: their decode units start at page 0
+    # slots of the segments alone in their groups: their level-1 or decode
+    # units start at page 0
     whole = set()
+    # of a lone chunk, the rows PAST its split, by slot: they keep level 0
+    # over the run and the suffix at level 1 (see below)
+    past: Dict[int, int] = {}
     if not windowed:
         with child_span(span, "l0"):
             qo0 = [0]
@@ -250,45 +288,60 @@ def build_engine_work_units(
             pages0: List[int] = []
             mask_parts: List[np.ndarray] = []
 
-            def _close_seg0(n_rows, kv_len, pages, pos):
+            def _close_seg0(n_rows, kv_len=0, pages=()):
                 qo0.append(qo0[-1] + n_rows)
                 kv0_lens.append(kv_len)
                 pages0.extend(pages)
                 pi0.append(len(pages0))
-                if kv_len > 0:
-                    mask_parts.append((np.arange(kv_len)[None, :]
-                                       <= pos[:, None]).reshape(-1))
 
             i = 0
             while i < len(segs):
                 s = segs[i]
-                run = s.pages[:s.split // ps]
-                j = i
-                rows = 0
-                pos: List[int] = []
+                j = i + 1
                 while j < len(segs) and segs[j].group == s.group:
-                    e = segs[j]
-                    pos.extend(range(e.kv_after - e.n, e.kv_after))
-                    rows += e.n
                     j += 1
-                # a group that is ONE decoding lane this step shares its
-                # run with no other row: nothing to dedup, and a one-row
-                # unit a chunk through a block_q tile is the dearest way
-                # to read a context.  Its decode units take all of it.
-                lone = j - i == 1 and s.decoding
+                group = segs[i:j]
+                rows = sum(e.n for e in group)
+                # a group that is ONE segment this step shares its run
+                # with no other row: nothing to dedup.  A lane's decode
+                # units, a chunk's level-1 units take its whole context
+                # (a one-row unit a chunk through a block_q tile is the
+                # dearest way to read one; a chunk's causal rule is level
+                # 1's own arithmetic, no [rows x split] mask to build)
+                lone = j - i == 1
                 if lone:
                     whole.add(s.slot)
+                run = s.pages[:s.split // ps]
                 if run and not lone:
-                    _close_seg0(rows, s.split, run,
-                                np.asarray(pos, np.int64))
+                    pos = np.concatenate([
+                        np.arange(e.kv_after - e.n, e.kv_after)
+                        for e in group])
+                    mask_parts.append((np.arange(s.split)[None, :]
+                                       <= pos[:, None]).reshape(-1))
+                    _close_seg0(rows, s.split, run)
+                elif run and not s.decoding and s.kv_after > s.split:
+                    # the lone chunk that ends its prompt: its last rows
+                    # (a page of them at most) lie PAST the split and attend
+                    # the run AND the suffix.  They stay two states merged,
+                    # the run here (every column: a part of ones, which
+                    # the planner demotes to maskless units) and the
+                    # suffix at level 1, so the token sampled from the
+                    # prompt's last row is the cascade's bit for bit.  The
+                    # rows inside the span leave level 0 like any other
+                    n_past = min(s.kv_after - s.split, s.n)
+                    past[s.slot] = n_past
+                    if s.n > n_past:
+                        _close_seg0(s.n - n_past)
+                    mask_parts.append(np.ones(n_past * s.split, bool))
+                    _close_seg0(n_past, s.split, run)
                 else:
-                    # no shared span (split == 0) or a lone lane: level 0
-                    # is empty for these rows: a kv_len=0 segment emits
+                    # no shared span (split == 0) or a lone segment: level
+                    # 0 is empty for these rows: a kv_len=0 segment emits
                     # (0, -inf)
-                    _close_seg0(rows, 0, (), np.zeros(0, np.int64))
+                    _close_seg0(rows)
                 i = j
             if total < rung:  # rung padding rows: defined zeros, empty state
-                _close_seg0(rung - total, 0, (), np.zeros(0, np.int64))
+                _close_seg0(rung - total)
             mask0 = (np.concatenate(mask_parts) if mask_parts
                      else np.zeros(0, bool))
             plan0 = build_prefill_work_units(
@@ -299,26 +352,35 @@ def build_engine_work_units(
                 pack_tiles=True, prune=True, num_units_pad=U,
             )
 
-    # ---- level 1: per-segment suffix windows, native causal rule ----
+    # ---- level 1: per-segment windows, native causal rule: a sharer's
+    # suffix, a lone chunk's whole context (global positions) ----
     with child_span(span, "l1"):
         qo1 = [0]
         kv1_lens: List[int] = []
         pi1 = [0]
         pages1: List[int] = []
+
+        def _close_seg1(n_rows, lo=0, hi=0, pages=()):
+            qo1.append(qo1[-1] + n_rows)
+            kv1_lens.append(max(hi - lo, 0))
+            if hi > lo:
+                pages1.extend(pages[lo // ps: cdiv(hi, ps)])
+            pi1.append(len(pages1))
+
         for s in segs:
-            qo1.append(qo1[-1] + s.n)
-            suffix = s.kv_after - s.split
-            if s.decoding or suffix <= 0:
-                kv1_lens.append(0)
-                pi1.append(len(pages1))
-                continue
-            kv1_lens.append(suffix)
-            pages1.extend(s.pages[s.split // ps: cdiv(s.kv_after, ps)])
-            pi1.append(len(pages1))
+            if s.decoding:
+                _close_seg1(s.n)
+            elif s.slot in past:
+                # rows inside the span: the run from page 0, causally;
+                # rows past the split: the suffix, level 0 has the run
+                if s.n > past[s.slot]:
+                    _close_seg1(s.n - past[s.slot], 0, s.split, s.pages)
+                _close_seg1(past[s.slot], s.split, s.kv_after, s.pages)
+            else:
+                _close_seg1(s.n, 0 if s.slot in whole else s.split,
+                            s.kv_after, s.pages)
         if total < rung:
-            qo1.append(rung)
-            kv1_lens.append(0)
-            pi1.append(len(pages1))
+            _close_seg1(rung - total)
         plan1 = build_prefill_work_units(
             np.asarray(qo1, np.int64), np.asarray(pi1, np.int64),
             np.asarray(pages1, np.int64), np.asarray(kv1_lens, np.int64),
@@ -349,8 +411,14 @@ def build_engine_work_units(
         (dplan["pages"].shape, geom.dec_width)
     assert not geom.single_chunk or dplan["single_chunk"]
 
-    # launched against useful, fallback units, lanes on decode units alone
-    stats = _plan_stats(plan0, plan1, dplan, dec_lens, geom, len(whole))
+    # launched against useful, fallback units, and what rode whole: lanes on
+    # decode units alone, chunks' tokens on level 1 alone
+    alone = [s for s in segs if s.slot in whole]
+    stats = _plan_stats(
+        plan0, plan1, dplan, dec_lens, geom,
+        lanes_whole=sum(s.decoding for s in alone),
+        tokens_whole=sum(s.n - past.get(s.slot, 0)
+                         for s in alone if not s.decoding))
     out = dict(prefill1=plan1, decode=dplan, dec_rows=dec_rows, stats=stats)
     return out if windowed else dict(out, prefill0=plan0)
 
@@ -493,9 +561,10 @@ def engine_kernel_attention(q, k_cache, v_cache, kplans, *,
     return out
 
 
-def _plan_stats(plan0, plan1, dplan, dec_lens, geom, lanes_whole) -> Dict:
+def _plan_stats(plan0, plan1, dplan, dec_lens, geom, lanes_whole,
+                tokens_whole) -> Dict:
     """``build_engine_work_units``' ``stats``: what a step's launches run
-    against what of it is useful, and the two counts of how the planner
+    against what of it is useful, and the three counts of how the planner
     placed the work (``plan0`` None: a window plan, no level 0)."""
     from flashinfer_tpu.ops.paged_prefill import CODE_PARTIAL_MASK
 
@@ -525,6 +594,9 @@ def _plan_stats(plan0, plan1, dplan, dec_lens, geom, lanes_whole) -> Dict:
         "decode_cells_valid": float(dec_lens.sum()),
         # lanes whose whole context rode decode units (no level-0 unit)
         "decode_lanes_whole": lanes_whole,
+        # prompt tokens that rode level 1 whole (no level-0 unit): a lone
+        # chunk's rows inside its span
+        "prefill_tokens_whole": tokens_whole,
         # level-0 units whose bitmap the launch expands
         "mask_units": 0 if plan0 is None else int(np.count_nonzero(
             plan0["code"] == CODE_PARTIAL_MASK)),

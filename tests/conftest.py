@@ -71,6 +71,56 @@ def pytest_collection_modifyitems(config, items):
             item.add_marker(pytest.mark.skip(reason="needs real TPU"))
 
 
+def _sharers_of_no_rows(segs):
+    """An engine schedule (``SchedSeg``s) with a sharer of NO rows put behind
+    every prompt chunk that is alone in its group.  The planner then meets a
+    group of two and keeps the chunk at level 0 under its host-built mask:
+    the plans of the planner as it was before a lone chunk rode level 1
+    whole (ISSUE 44), array for array.  The engine's own schedules cannot
+    say this: a chunk that shares a cached run starts at its end, where the
+    mask cuts nothing, so this is what holds level 0's masked units."""
+    import dataclasses
+
+    out = []
+    for s in segs:
+        out.append(s)
+        if not s.decoding and sum(o.group == s.group for o in segs) == 1:
+            out.append(dataclasses.replace(
+                s, row0=s.row0 + s.n, n=0, kv_after=0))
+    return out
+
+
+@pytest.fixture
+def sharers_of_no_rows():
+    return _sharers_of_no_rows
+
+
+@pytest.fixture
+def chunks_at_level0(monkeypatch):
+    """The engine's planner with every lone chunk kept at level 0
+    (:func:`_sharers_of_no_rows` in front of it; a window plan has no
+    level 0 and passes through)."""
+    from flashinfer_tpu.serve import engine_kernels as ek
+
+    plan = ek.build_engine_work_units
+
+    def kept(segs, **kw):
+        if kw.get("window_left", -1) < 0:
+            segs = _sharers_of_no_rows(segs)
+        return plan(segs, **kw)
+
+    monkeypatch.setattr(ek, "build_engine_work_units", kept)
+
+
+@pytest.fixture(params=[False, True], ids=["level1_whole", "level0_kept"])
+def level0_kept(request):
+    """Runs a test twice: with the planner as it is, and under
+    :func:`chunks_at_level0`.  True in the second run."""
+    if request.param:
+        request.getfixturevalue("chunks_at_level0")
+    return request.param
+
+
 @pytest.fixture
 def mesh8():
     import numpy as np

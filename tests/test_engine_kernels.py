@@ -21,6 +21,8 @@ The graduation contracts under pin:
   prefill output against the dense oracle.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -449,8 +451,9 @@ def test_a_lone_lane_rides_decode_units_over_its_whole_context():
     """ISSUE 40: a decoding lane alone in its group this step has no
     level-0 unit and no mask part; its decode row starts at page 0 with
     ``kvlen = kv_after``.  Lanes that ride one cached run together, and a
-    chunk prefilled inside its own span, keep level 0 and its masks; the
-    same lane turns lone when its sharer is not in the step."""
+    chunk prefilled inside the span beside a rider of it, keep level 0 and
+    its masks; the same lane turns lone when its sharer is not in the
+    step."""
     from flashinfer_tpu.ops.paged_prefill import CODE_PARTIAL_MASK
 
     g = _geom()
@@ -487,24 +490,27 @@ def test_a_lone_lane_rides_decode_units_over_its_whole_context():
     assert both["stats"]["decode_lanes_whole"] == 0
     assert both["stats"]["prefill_fallback_units"] == 1   # level 1's tile
 
-    # a chunk inside its own span (a resumed request re-reading its cached
-    # run): level 0 with a mask that cuts, and no lane to move
+    # a chunk inside its span BESIDE a rider of the run (a group of two):
+    # level 0 over both rows' spans with a mask that cuts, and no lane moves
     chunk = SchedSeg(row0=0, n=6, pages=(1, 2, 3), split=16, kv_after=14,
                      decoding=False, slot=0, group=0)
-    own = build_engine_work_units([chunk], rung=16, geom=g)
+    rider = SchedSeg(row0=6, n=1, pages=(1, 2, 4), split=16, kv_after=18,
+                     decoding=True, slot=2, group=0)
+    own = build_engine_work_units([chunk, rider], rung=16, geom=g)
     u0 = _real(own["prefill0"])
-    assert (u0["rowlo"][0], u0["rowhi"][0], u0["kvlen"][0]) == (0, 6, 16)
+    assert (u0["rowlo"][0], u0["rowhi"][0], u0["kvlen"][0]) == (0, 7, 16)
     assert u0["code"][0] == CODE_PARTIAL_MASK
     assert own["prefill0"]["mask_bytes"][0].any()
     assert own["stats"]["mask_units"] == 1
     assert own["stats"]["decode_lanes_whole"] == 0
-    assert not own["decode"]["kvlen"].any()
+    assert own["stats"]["prefill_tokens_whole"] == 0
+    assert list(own["decode"]["kvlen"]) == [0, 0, 2, 0]
 
-    # a lone lane beside that chunk: the chunk's units stay, the lane adds
-    # none at either level, and the counts are the plans'
-    far = SchedSeg(row0=6, n=1, pages=(5, 6, 7, 8), split=24, kv_after=30,
+    # a lone lane beside those two: their units stay, the lane adds none at
+    # either level, and the counts are the plans'
+    far = SchedSeg(row0=7, n=1, pages=(5, 6, 7, 8), split=24, kv_after=30,
                    decoding=True, slot=3, group=1)
-    mixed = build_engine_work_units([chunk, far], rung=16, geom=g)
+    mixed = build_engine_work_units([chunk, rider, far], rung=16, geom=g)
     for lvl in ("prefill0", "prefill1"):
         for k in _UNIT_KEYS:
             np.testing.assert_array_equal(mixed[lvl][k], own[lvl][k])
@@ -513,10 +519,11 @@ def test_a_lone_lane_rides_decode_units_over_its_whole_context():
                                   own["prefill0"]["mask_bytes"])
     d = mixed["decode"]
     np.testing.assert_array_equal(d["pages"][3, :4], [5, 6, 7, 8])
-    assert d["kvlen"][3] == 30 and mixed["dec_rows"][3] == 6
+    assert d["kvlen"][3] == 30 and mixed["dec_rows"][3] == 7
     st = mixed["stats"]
     assert st["decode_lanes_whole"] == 1
-    # (level 1: the chunk lies inside its span, so its tile attends nothing)
+    # (level 1: the chunk lies inside its span and the lanes ride decode
+    # units, so its tile attends nothing)
     assert st["prefill_fallback_units"] == 1 == \
         own["stats"]["prefill_fallback_units"]
     assert st["prefill_units"] == st["prefill_units_launched"] == sum(
@@ -557,6 +564,227 @@ def test_lone_and_shared_lanes_attend_the_same_context():
     np.testing.assert_allclose(alone[0], want, atol=2e-5)
     np.testing.assert_allclose(shared[0], want, atol=2e-5)
     assert not alone[1:].any()        # every other row attends nothing
+
+
+def test_a_lone_chunk_rides_level_1_over_its_whole_context(
+        sharers_of_no_rows):
+    """ISSUE 44: a prompt chunk alone in its group has no level-0 unit, no
+    ``pos`` list and no mask part for its rows inside the span; level 1
+    attends their whole context from page 0 under the planner's causal rule
+    on global positions.  The rows past the split of the chunk that ends
+    its prompt keep level 0 (every column of the run, no mask unit) and the
+    suffix at level 1.  The same chunk with a sharer in its group (here one
+    of no rows: the planner as it was) keeps level 0 and its mask."""
+    from flashinfer_tpu.ops.paged_prefill import (CODE_PARTIAL,
+                                                  CODE_PARTIAL_MASK,
+                                                  mask_lane_bytes)
+
+    g = _geom()
+    U, chunk_tokens = g.prefill_unit_cap(16), g.prefill_ppc * g.page_size
+    chunk = SchedSeg(row0=0, n=6, pages=(1, 2, 3), split=16, kv_after=14,
+                     decoding=False, slot=0, group=0)
+    lone = build_engine_work_units([chunk], rung=16, geom=g)
+    p0 = lone["prefill0"]
+    assert p0["n_units"] == 1 and not _real(p0)["kvlen"].any()
+    assert p0["mask_bytes"].dtype == np.uint8 and p0["mask_bytes"].shape == (
+        U, g.block_q, mask_lane_bytes(chunk_tokens))
+    assert not p0["mask_bytes"].any()
+    # (no unit expands a bitmap: one zero byte in that shape, none allocated)
+    assert p0["mask_bytes"].strides == (0, 0, 0)
+    u1 = _real(lone["prefill1"])
+    assert [int(u1[k][0]) for k in _UNIT_KEYS] == [
+        0, 0, 6, 8, 0, 14, 1, 1, 0, CODE_PARTIAL]
+    np.testing.assert_array_equal(lone["prefill1"]["pages"][:3], [1, 2, 0])
+    st = lone["stats"]
+    assert (st["prefill_tokens_whole"], st["mask_units"],
+            st["decode_lanes_whole"], st["prefill_fallback_units"]) \
+        == (6, 0, 0, 1)
+    assert st["prefill_cells_valid"] == 6 * 14
+
+    # the planner as it was: the chunk's units at level 0, its mask cutting
+    kept = build_engine_work_units(sharers_of_no_rows([chunk]), rung=16,
+                                   geom=g)
+    u0 = _real(kept["prefill0"])
+    assert (u0["rowlo"][0], u0["rowhi"][0], u0["kvlen"][0], u0["code"][0]) \
+        == (0, 6, 16, CODE_PARTIAL_MASK)
+    assert not _real(kept["prefill1"])["kvlen"].any()
+    assert kept["stats"]["mask_units"] == 1
+    assert kept["prefill0"]["mask_bytes"].flags.c_contiguous
+    assert kept["prefill0"]["mask_bytes"][0].any()
+    assert kept["stats"]["prefill_tokens_whole"] == 0
+    assert kept["stats"]["prefill_cells_valid"] == 6 * 16   # masked or not
+
+    # the chunk that ends its prompt (positions 12..20: four rows inside
+    # the span, five past the split), a lone lane and a short prompt of no
+    # full page in one step.  Rows inside the span: nothing at level 0, the
+    # run from page 0 at level 1.  Rows past the split: the run at level 0
+    # with no mask to expand, the suffix at level 1, as the cascade has them
+    last = SchedSeg(row0=0, n=9, pages=(1, 2, 3), split=16, kv_after=21,
+                    decoding=False, slot=0, group=0)
+    lane = SchedSeg(row0=9, n=1, pages=(4, 5), split=8, kv_after=12,
+                    decoding=True, slot=1, group=1)
+    short = SchedSeg(row0=10, n=3, pages=(6,), split=0, kv_after=3,
+                     decoding=False, slot=2, group=2)
+    step = build_engine_work_units([last, lane, short], rung=16, geom=g)
+    u0 = _real(step["prefill0"])
+    assert list(zip(u0["rowlo"], u0["rowhi"], u0["kvlen"], u0["code"])) \
+        == [(4, 9, 16, CODE_PARTIAL)]
+    np.testing.assert_array_equal(step["prefill0"]["pages"][:3], [1, 2, 0])
+    assert not step["prefill0"]["mask_bytes"].any()
+    u1 = _real(step["prefill1"])
+    assert list(zip(u1["rowlo"], u1["rowhi"], u1["qpos0"], u1["kvlen"])) \
+        == [(0, 4, 12, 16), (4, 9, -4, 5), (10, 13, -10, 3)]
+    np.testing.assert_array_equal(
+        step["prefill1"]["pages"].reshape(-1, g.prefill_ppc)[:3, :3],
+        [[1, 2, 0], [3, 0, 0], [6, 0, 0]])
+    assert step["stats"]["prefill_tokens_whole"] == 4 + 3
+    assert step["stats"]["decode_lanes_whole"] == 1
+    assert step["stats"]["mask_units"] == 0
+    # a chunk that lies past its split altogether (a resumed request
+    # re-reading what it generated) is the cascade's plan, rows and all
+    tail = SchedSeg(row0=0, n=3, pages=(1, 2, 3), split=16, kv_after=21,
+                    decoding=False, slot=0, group=0)
+    both = [build_engine_work_units(s, rung=16, geom=g)
+            for s in ([tail], sharers_of_no_rows([tail]))]
+    for lvl in ("prefill0", "prefill1"):
+        for k in _UNIT_KEYS:
+            np.testing.assert_array_equal(both[0][lvl][k], both[1][lvl][k])
+    assert both[0]["stats"]["prefill_tokens_whole"] == 0
+
+    # a longer context: level 1's units walk [0, kv_after) a chunk at a time
+    wide = _geom(ppr=40)
+    far = SchedSeg(row0=0, n=12, pages=tuple(range(1, 39)), split=296,
+                   kv_after=280, decoding=False, slot=0, group=0)
+    p1 = build_engine_work_units([far], rung=16, geom=wide)["prefill1"]
+    u1 = _real(p1)
+    assert list(u1["kvstart"]) == [0, 128, 256] and set(u1["kvlen"]) == {280}
+    assert set(u1["qpos0"]) == {268}
+    np.testing.assert_array_equal(
+        p1["pages"][:3 * wide.prefill_ppc].reshape(3, -1)[:, 0], [1, 17, 33])
+    # a window plan never had a level 0 to leave
+    wplan = build_engine_work_units(_window_segs(), rung=16, geom=_geom(ppr=5),
+                                    window_left=11)
+    assert wplan["stats"]["prefill_tokens_whole"] == 0
+
+
+@pytest.mark.parametrize("max_batch, rung, ppr, ps", [
+    (4, 16, 64, 2), (8, 64, 512, 4), (64, 128, 256, 1)])
+def test_unit_cap_holds_when_every_slot_ends_its_prompt(max_batch, rung, ppr,
+                                                        ps):
+    """The most plan segments a step can have: every slot a lone chunk at
+    the request's longest context whose rows lie on both sides of its
+    split, so each is two segments at both levels.  Both plans fit the
+    rung's unit cap (the planner raises when one does not)."""
+    g = EngineKernelGeom.build(
+        page_size=ps, pages_per_req=ppr, max_batch=max_batch, max_rung=rung,
+        num_kv_heads=2, head_dim=32, kv_itemsize=4)
+    n, ctx = rung // max_batch, ppr * ps
+    segs = [SchedSeg(row0=i * n, n=n,
+                     pages=tuple(range(1 + i * ppr, 1 + (i + 1) * ppr)),
+                     split=ctx - ps, kv_after=ctx, decoding=False, slot=i,
+                     group=i) for i in range(max_batch)]
+    plans = build_engine_work_units(segs, rung=rung, geom=g)
+    chunks, cap = g.max_prefill_chunks, g.prefill_unit_cap(rung)
+    # level 0: the rows past the split over the run; level 1: the rows
+    # inside the span over the run, and one suffix unit a chunk
+    assert plans["prefill0"]["n_units"] == max_batch * chunks <= cap
+    assert plans["prefill1"]["n_units"] == max_batch * (chunks + 1) <= cap
+    assert plans["stats"]["prefill_tokens_whole"] == rung - max_batch * ps
+    assert plans["stats"]["mask_units"] == 0
+
+
+def _attending(stats):
+    return stats["prefill_units"] - stats["prefill_fallback_units"]
+
+
+def _lone_chunk_steps():
+    """Steps whose prompt chunk is alone in its group, as ``(geom, heads
+    (hq, hkv, d), segs, cache pages)``: page 8 and 128-token KV chunks but
+    for the last, page 16 and 256."""
+    wide = _geom(ppr=40)
+    pages = tuple(range(1, 39))
+
+    def seg(row0, n, kv_after, **kw):
+        return SchedSeg(row0=row0, n=n, pages=pages, split=296,
+                        kv_after=kv_after, decoding=False, slot=0, group=0,
+                        **kw)
+
+    long_geom = EngineKernelGeom.build(
+        page_size=16, pages_per_req=1040, max_batch=2, max_rung=16,
+        num_kv_heads=1, head_dim=32, kv_itemsize=4)
+    return {
+        # every row inside the span, the diagonal in the second KV chunk
+        "inside_span": (wide, (4, 2, 32), [seg(0, 12, 200)], 48),
+        # the prompt's last chunk: 8 rows inside the span, 4 past the split
+        "ends_prompt": (wide, (4, 2, 32), [seg(0, 12, 300)], 48),
+        # in one q tile with two lone lanes and a prompt of no full page
+        "packed_with_lanes": (wide, (4, 2, 32), [
+            SchedSeg(row0=0, n=1, pages=(40, 41, 42), split=16, kv_after=21,
+                     decoding=True, slot=1, group=1),
+            dataclasses.replace(seg(1, 9, 150), group=2),
+            SchedSeg(row0=10, n=1, pages=(43, 44), split=8, kv_after=12,
+                     decoding=True, slot=2, group=3),
+            SchedSeg(row0=11, n=4, pages=(45,), split=0, kv_after=4,
+                     decoding=False, slot=3, group=4)], 48),
+        # trinity-mini.longctx's depth at one K/V head: 64 KV chunks
+        "ctx_16k": (long_geom, (2, 1, 32), [
+            SchedSeg(row0=0, n=16, pages=tuple(range(1, 1026)), split=16384,
+                     kv_after=16380, decoding=False, slot=0, group=0)], 1026),
+    }
+
+
+@pytest.mark.parametrize("step", ["inside_span", "ends_prompt",
+                                  "packed_with_lanes", "ctx_16k"])
+def test_lone_chunk_attends_what_the_parents_plan_attends(
+        step, sharers_of_no_rows):
+    """``engine_kernel_attention`` over a lone chunk's plan (rows inside
+    the span at level 1 from page 0, level 0 empty for them) against the
+    plan of the planner as it was (level 0 under the host-built mask, the
+    suffix at level 1): the same KV chunks in the same order for every row,
+    and the rows past the split still two states merged, so the two agree
+    bit for bit.  Both are the dense causal softmax."""
+    from flashinfer_tpu.serve.engine_kernels import (engine_kernel_attention,
+                                                     plans_to_device)
+
+    g, (hq, hkv, d), segs, n_pages = _lone_chunk_steps()[step]
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(11), 3)
+    q = jax.random.normal(kq, (16, hq, d), jnp.float32)
+    kc = jax.random.normal(kk, (n_pages, hkv, g.page_size, d), jnp.float32)
+    vc = jax.random.normal(kv, (n_pages, hkv, g.page_size, d), jnp.float32)
+
+    def attend(segs):
+        plans = build_engine_work_units(segs, rung=16, geom=g)
+        return plans["stats"], np.asarray(engine_kernel_attention(
+            q, kc, vc, plans_to_device(plans), geom=g, sm_scale=d ** -0.5))
+
+    st, alone = attend(segs)
+    st0, kept = attend(sharers_of_no_rows(segs))
+    chunks = [e for e in segs if not e.decoding]
+    # rows past the split of a run, which stay at level 0
+    past = sum(min(e.kv_after - e.split, e.n) for e in chunks
+               if 0 < e.split < e.kv_after)
+    assert past == (4 if step == "ends_prompt" else 0)
+    assert st["mask_units"] == 0 < st0["mask_units"]
+    assert st["prefill_tokens_whole"] == sum(e.n for e in chunks) - past
+    assert st0["prefill_tokens_whole"] == 0
+    # the same units that attend, in another launch; where rows past the
+    # split share a q tile with rows inside the span, the tile's walk over
+    # the run is launched at both levels (3 KV chunks here)
+    assert _attending(st) == _attending(st0) + (3 if past else 0)
+    np.testing.assert_array_equal(alone, kept)
+    for e in segs:
+        ids = jnp.asarray(e.pages[:-(-e.kv_after // g.page_size)])
+        rows = jnp.swapaxes(kc[ids], 1, 2).reshape(-1, hkv, d)
+        vals = jnp.swapaxes(vc[ids], 1, 2).reshape(-1, hkv, d)
+        for i in range(e.n):
+            pos = e.kv_after - e.n + i
+            qg = q[e.row0 + i].reshape(hkv, hq // hkv, d)
+            p = jax.nn.softmax(jnp.einsum(
+                "ngd,snd->ngs", qg, rows[:pos + 1]) * d ** -0.5, -1)
+            want = jnp.einsum("ngs,snd->ngd", p, vals[:pos + 1]).reshape(hq, d)
+            np.testing.assert_allclose(alone[e.row0 + i], want, atol=2e-5)
+    total = segs[-1].row0 + segs[-1].n
+    assert not alone[total:].any()        # rung padding attends nothing
 
 
 def _assert_plans_are_the_parents(name, plans, g, rung=16):
@@ -706,10 +934,14 @@ def test_lowering_builds_and_sends_what_the_launches_read(schedule,
     assert handover.cut._cache_size() == cuts   # one program a shape
 
 
-def test_step_over_the_mask_cap_serves_the_same_tokens(params, monkeypatch):
+def test_step_over_the_mask_cap_serves_the_same_tokens(params, monkeypatch,
+                                                       level0_kept):
     """A step with more mask units than ``mask_unit_cap`` ships the whole
     bitmap (``mask_full_uploads``) and serves the tokens the capped path
-    serves; with the rung's real cap no step of this run overflows."""
+    serves; with the rung's real cap no step of this run overflows.  The
+    engine's own schedules make no mask unit since a lone chunk rides level
+    1 (no whole bitmap then, whatever the cap); with its chunks kept at
+    level 0 they make them as before."""
     rng = np.random.default_rng(31)
     prompts = [[int(t) for t in rng.integers(1, CFG.vocab_size, n)]
                for n in (27, 21, 9, 30)]     # chunks inside their own span
@@ -724,7 +956,11 @@ def test_step_over_the_mask_cap_serves_the_same_tokens(params, monkeypatch):
         return {r: q.out_tokens for r, q in eng._finished.items()}, facts
 
     capped, facts = run()
-    assert sum(f["mask_units"] for f in facts) > 0
+    assert (sum(f["mask_units"] for f in facts) > 0) == level0_kept
+    # (of each prompt, the rows past its split stay at level 0: page 8)
+    assert sum(f["prefill_tokens"] - f["prefill_tokens_whole"]
+               for f in facts) == (
+        87 if level0_kept else sum(n % 8 for n in (27, 21, 9, 30)))
     assert not any(f["mask_full_uploads"] for f in facts)
     monkeypatch.setattr(EngineKernelGeom, "mask_unit_cap",
                         lambda self, rung: 0)

@@ -629,14 +629,17 @@ def _preempt_resume(params, backend):
 @pytest.mark.parametrize("run", [_own_span_chunks, _shared_prefix,
                                  _preempt_resume],
                          ids=lambda f: f.__name__.lstrip("_"))
-def test_kernel_tier_handover_serves_the_reference_tokens(params, run):
+def test_kernel_tier_handover_serves_the_reference_tokens(params, run,
+                                                          level0_kept):
     """Tokens bitwise equal to the reference tier's with the bitmap sent
     as the rows that are read, and the facts of the handover in every
-    step: two buffers, the whole bitmap never."""
+    step: two buffers, the whole bitmap never.  Bitmap rows cross where a
+    chunk is kept at level 0; the engine's own schedules, whose chunks
+    ride level 1 whole (ISSUE 44), send rows of zeros in their place."""
     ref, ref_facts = run(params, "reference")
     got, facts = run(params, "kernel")
     assert got == ref
-    assert sum(f["mask_units"] for f in facts) > 0   # rows did cross
+    assert (sum(f["mask_units"] for f in facts) > 0) == level0_kept
     for f in facts:        # one int32 buffer, and the bitmap rows beside it
         assert f["plans_lowered"] == 1 and f["upload_transfers"] == 2
         assert f["mask_full_uploads"] == 0 and f["upload_kib"] > 0
@@ -717,11 +720,125 @@ def test_a_decode_lane_costs_decode_units_only(params, run):
         assert lanes.index((2, 0)) < len(lanes) - 1
 
 
-def test_back_to_back_schedules_on_one_rung_agree_with_fresh_engines(params):
+def _chunk_then_decode(params, backend):
+    """One long prompt by itself: every chunk alone in its group, then the
+    lane alone in it."""
+    rng = np.random.default_rng(61)
+    eng = _mk_engine(params, share=False, attention_backend=backend,
+                     sampling=SamplingConfig(temperature=0.8, top_k=20))
+    eng.submit(EngineRequest(
+        "r0", [int(t) for t in rng.integers(1, CFG.vocab_size, 43)],
+        max_new_tokens=5))
+    return _drain(eng)
+
+
+def _chunk_beside_a_rider(params, backend, rider_new=8, budget=16):
+    """A cached run, a rider that decodes on it, and a second rider whose
+    prompt arrives while the first runs: its chunk shares the run with the
+    first's lane (``budget`` tokens of it a step)."""
+    rng = np.random.default_rng(67)
+    eng = _mk_engine(params, attention_backend=backend,
+                     prefill_budget_tokens=budget,
+                     sampling=SamplingConfig(temperature=0.8, top_k=20))
+    shared = [int(t) for t in rng.integers(1, CFG.vocab_size, 17)]
+
+    def prompt(n):
+        return shared + [int(t) for t in rng.integers(1, CFG.vocab_size, n)]
+
+    eng.submit(EngineRequest("a0", prompt(3), max_new_tokens=2))
+    first, facts = _drain(eng)               # the prefix enters the trie
+    eng.submit(EngineRequest("b0", prompt(2), max_new_tokens=rider_new))
+    facts.append(eng.step())                 # b0's suffix, alone
+    facts.append(eng.step())                 # b0 decodes ...
+    eng.submit(EngineRequest("b1", prompt(6), max_new_tokens=4))
+    second, more = _drain(eng)               # ... when b1's chunk joins it
+    assert all(eng._finished[r].hit_tokens == 16 for r in ("b0", "b1"))
+    return {**first, **second}, [f for f in facts if f["tokens"]] + more
+
+
+def _chunk_outlives_its_sharer(params, backend):
+    """The same with 4 prompt tokens a step and a rider that is done a step
+    after the chunk joins it: the prompt's first chunk shares the run with
+    the rider's lane, its second is alone in its group."""
+    return _chunk_beside_a_rider(params, backend, rider_new=3, budget=4)
+
+
+def _preempt_mid_prompt(params, backend):
+    """A's 40-token prompt is two chunks in when B, more urgent, takes its
+    pages; A starts over when B is done."""
+    rng = np.random.default_rng(71)
+    pA = [int(t) for t in rng.integers(1, CFG.vocab_size, 40)]
+    pB = [int(t) for t in rng.integers(1, CFG.vocab_size, 20)]
+    eng = _mk_engine(params, attention_backend=backend, num_pages=8,
+                     max_batch=2, max_seq_tokens=48)
+    eng.submit(EngineRequest("A", list(pA), max_new_tokens=3, priority=5))
+    head = [eng.step() for _ in range(2)]    # A is mid-prompt ...
+    eng.submit(EngineRequest("B", list(pB), max_new_tokens=4, priority=0))
+    tokens, facts = _drain(eng)              # ... when B takes its pages
+    assert eng._finished["A"].preemptions == 1
+    assert eng._finished["A"].folded_out == 0     # no token yet: mid-prompt
+    return tokens, head + facts
+
+
+@pytest.mark.parametrize("run", [_chunk_then_decode, _chunk_beside_a_rider,
+                                 _chunk_outlives_its_sharer,
+                                 _preempt_mid_prompt],
+                         ids=lambda f: f.__name__.lstrip("_"))
+def test_a_lone_prompt_chunk_costs_level_1_units_only(params, run):
+    """ISSUE 44: a prompt chunk alone in its group rides level 1 over its
+    whole context (``prefill_tokens_whole``) but for the prompt's last rows,
+    those past its split (page 8: 1 to 8 of them), which stay the cascade's
+    two states so that the first token is its bit for bit; a chunk that
+    shares a cached run with another row of the step keeps level 0 (it lies
+    past its split altogether: the group's key is the run up to it).  The
+    kernel tier serves the reference tier's tokens in each case, no step
+    makes a mask unit, and the step's facts say which tokens went which
+    way."""
+    ref, ref_facts = run(params, "reference")
+    got, facts = run(params, "kernel")
+    assert got == ref
+    assert all(f["prefill_tokens_whole"] == 0 for f in ref_facts)
+    assert [f["prefill_tokens"] for f in facts] \
+        == [f["prefill_tokens"] for f in ref_facts]
+    toks = [(f["prefill_tokens"], f["prefill_tokens_whole"]) for f in facts]
+    for f in facts:
+        # a chunk beside a sharer starts at the run's end: nothing to cut
+        assert f["mask_units"] == f["mask_full_uploads"] == 0
+        assert f["prefill_fallback_units"] <= 2
+        assert f["prefill_units"] == f["prefill_units_launched"] >= 2
+    # the steps that kept rows at level 0, as (prompt tokens, of them whole)
+    kept = [(n, whole) for n, whole in toks if whole < n]
+    lanes = [(f["decode_tokens"], f["decode_lanes_whole"]) for f in facts]
+    if run is _chunk_then_decode:
+        # 43 tokens, split 40: 16, 16, then 8 inside the span and 3 past it
+        assert [t for t in toks if t[0]] == [(16, 16), (16, 16), (11, 8)]
+        assert all(dec == whole for dec, whole in lanes)
+    elif run is _preempt_mid_prompt:
+        # B's 20 (split 16) as 16 + 4; A's 40 (split 32) ends on 8 past it
+        assert kept == [(4, 0), (8, 0)]
+        assert [t for t in toks if t[0] and t not in kept] == [(16, 16)] * 3
+        assert all(dec == whole for dec, whole in lanes)
+    elif run is _chunk_beside_a_rider:
+        # a0's and b0's last rows by themselves, then b1's 7 tokens in one
+        # step beside b0's lane: level 0 for both rows
+        assert kept == [(4, 0), (3, 0), (7, 0)]
+        assert lanes[toks.index((7, 0))] == (1, 0)
+    else:
+        # ... then 4 of b1's 7 beside b0's last lane, and 3 by themselves
+        assert kept == [(4, 0), (3, 0), (4, 0), (3, 0)]
+        assert [t for t in toks if t[0] and t not in kept] == [(4, 4)] * 4
+        assert [lanes[i] for i, t in enumerate(toks) if t in kept] \
+            == [(0, 0), (0, 0), (1, 0), (0, 0)]
+
+
+def test_back_to_back_schedules_on_one_rung_agree_with_fresh_engines(
+        params, chunks_at_level0):
     """A long chunked prompt, then two short prompts, through ONE engine
     on the same rungs: the second wave's tokens and mask units are what a
     fresh engine serves for it (nothing of the larger step's plans, bitmap
-    rows or schedule arrays is left in the smaller step's)."""
+    rows or schedule arrays is left in the smaller step's).  With the
+    chunks kept under level 0's masks, so that there are bitmap rows to
+    leave behind."""
     rng = np.random.default_rng(53)
     waves = [[[int(t) for t in rng.integers(1, CFG.vocab_size, n)]
               for n in wave] for wave in ((45,), (9, 14))]
@@ -740,6 +857,8 @@ def test_back_to_back_schedules_on_one_rung_agree_with_fresh_engines(params):
     assert got == want and len(got) == 2
     assert [f["mask_units"] for f in facts] == \
         [f["mask_units"] for f in fresh]
+    assert sum(f["mask_units"] for f in larger) > 0
+    assert sum(f["mask_units"] for f in facts) > 0
     # the second wave met a rung the first had used for a larger step
 
     def cells(fs):
@@ -759,9 +878,13 @@ def test_lowered_step_is_the_parents_at_every_rung(params):
     eng = _mk_engine(params, attention_backend="kernel", max_seq_tokens=64)
     ec = eng.config
     for rung in ec.rungs():
+        # a chunk inside its span beside a rider of it: level 0's mask cuts
         plans = ek.build_engine_work_units(
-            [ek.SchedSeg(row0=0, n=rung, pages=tuple(range(1, 9)), split=48,
-                         kv_after=8 + rung, decoding=False, slot=0,
+            [ek.SchedSeg(row0=0, n=rung - 1, pages=tuple(range(1, 9)),
+                         split=48, kv_after=7 + rung, decoding=False, slot=0,
+                         group=0),
+             ek.SchedSeg(row0=rung - 1, n=1, pages=(*range(1, 7), 9, 10),
+                         split=48, kv_after=50, decoding=True, slot=1,
                          group=0)], rung=rung, geom=eng._geom)
         assert plans["stats"]["mask_units"] > 0
         B, ppr = ec.max_batch, eng._ppr
@@ -785,9 +908,22 @@ def test_lowered_step_is_the_parents_at_every_rung(params):
                               ek.plans_on_device(kplans),
                               eng.caches).as_text()
         assert new == old, rung
+        # a lone chunk's plan (level 1 whole, no mask unit) is other VALUES
+        # for the same program
+        lone = ek.build_engine_work_units(
+            [ek.SchedSeg(row0=0, n=rung, pages=tuple(range(1, 9)), split=48,
+                         kv_after=8 + rung, decoding=False, slot=0,
+                         group=0)], rung=rung, geom=eng._geom)
+        assert lone["stats"]["mask_units"] == 0
+        assert lone["stats"]["prefill_tokens_whole"] \
+            == rung - max(8 + rung - 48, 0)      # the rows inside the span
+        assert eng._step.lower(
+            params, *sched_dev, ek.plans_on_device(jax.device_put(
+                ek.plans_to_host(lone, eng._geom.mask_unit_cap(rung)))),
+            eng.caches).as_text() == old, rung
 
 
-def test_handover_at_the_cells_statics(params):
+def test_handover_at_the_cells_statics(params, level0_kept):
     """At the benchmark cells' engine statics (page 16, 2,048 tokens a
     request, 64 slots, a prefill budget of 512: 2,048 units a plan, a
     33.5 MB bitmap) a step hands over about a megabyte in two buffers,
@@ -800,7 +936,7 @@ def test_handover_at_the_cells_statics(params):
                      max_seq_tokens=2048, share=False)
     assert {eng._geom.prefill_unit_cap(r) for r in eng.config.rungs()} \
         == {2048}
-    cuts = handover.cut._cache_size()
+    handover.cut.clear_cache()     # (this test's other run left its program)
     rng = np.random.default_rng(59)
     for i, n in enumerate((40, 23)):
         eng.submit(EngineRequest(
@@ -808,14 +944,14 @@ def test_handover_at_the_cells_statics(params):
             max_new_tokens=2))
     tokens, facts = _drain(eng)
     assert all(len(t) == 2 for t in tokens.values())
-    assert facts[0]["mask_units"] > 0 and len(facts) > 1
+    assert (facts[0]["mask_units"] > 0) == level0_kept and len(facts) > 1
     for f in facts:
         assert set(_HANDOVER_FACTS) <= set(f)
         assert f["plans_lowered"] == 1 and f["upload_transfers"] == 2
         assert f["mask_full_uploads"] == 0
         assert 512 < f["upload_kib"] < 1536
     assert eng.num_traces == len(eng._rung_traced) == 1
-    assert handover.cut._cache_size() == cuts + 1
+    assert handover.cut._cache_size() == 1
 
 
 # ---------------------------------------------------------------------------
