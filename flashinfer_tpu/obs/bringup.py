@@ -90,6 +90,7 @@ DRIVER_FOR = {
     "_ssd_chunk_kernel": "mamba",
     "_mla_decode_kernel": "mla_decode",
     "_stream_kernel": "sparse_mla_stream",
+    "_block_sparse_paged_kernel": "block_sparse_paged",
     "_gather_gmm_rowcache_kernel": "gather_gmm_rowcache",
     "_decode_split_kernel_fused_heads": "decode_split",
     "_fp4_decode_kernel": "fp4_decode",
@@ -623,6 +624,33 @@ def _drv_sparse_mla_stream(tactic=None):
         q, window, mask, jnp.int32(615), d_v=d_v, sm_scale=D ** -0.5))
 
 
+def _drv_block_sparse_paged(tactic=None):
+    import jax
+    import jax.numpy as jnp
+
+    from flashinfer_tpu.ops import block_sparse_paged as bsp
+
+    # two tiles of 8 query tokens x 16 heads a K/V head, each reading a
+    # union of 5 blocks (4 pages of 16 rows a block) of a 32-block request
+    # through its page list: the K|V buffer split at 128 lanes, the bias
+    # sliced at multiples of 512, the bf16 cast of the probabilities
+    hkv, group, d, ps, per, blocks, tile, chunk = 2, 16, 128, 16, 4, 32, 8, 8
+    ka, kb = _keys(2)
+    q = jax.random.normal(ka, (hkv, 2, tile * group, d), jnp.bfloat16)
+    pool = jax.random.normal(kb, (blocks * per + 1, hkv, ps, 2 * d),
+                             jnp.bfloat16)
+    table = jnp.arange(1, blocks * per + 1, dtype=jnp.int32)[None]
+    pos = 1900 + jnp.arange(2 * tile).reshape(2, tile)
+    mask = jnp.zeros((2 * tile, hkv, blocks), bool).at[
+        :, :, jnp.asarray([0, 7, 19, 28, 29])].set(True)
+    union, count, hit = bsp.tile_unions(
+        mask, jnp.ones((2 * tile,), bool), tile=tile, cap=chunk)
+    bias = bsp.selection_bias(union, hit, pos, block_size=ps * per)
+    jax.block_until_ready(bsp.block_sparse_paged_attention(
+        q, pool, table, jnp.zeros((hkv, 2), jnp.int32), union, count, bias,
+        block_pages=per, chunk_blocks=chunk, sm_scale=d ** -0.5))
+
+
 def _drv_gather_gmm_rowcache(tactic=None):
     import jax
     import jax.numpy as jnp
@@ -852,6 +880,7 @@ DRIVERS: Dict[str, Callable] = {
     "mamba": _drv_mamba,
     "mla_decode": _drv_mla_decode,
     "sparse_mla_stream": _drv_sparse_mla_stream,
+    "block_sparse_paged": _drv_block_sparse_paged,
     "gather_gmm_rowcache": _drv_gather_gmm_rowcache,
     "gmm": _drv_gmm,
     "paged_decode": _drv_paged_decode,

@@ -48,12 +48,12 @@ def _healthy():
 def test_ladder_covers_every_registry_entry():
     rungs = bringup.build_ladder("v5e")
     assert bringup.coverage_problems(rungs) == []
-    # the three registries each contribute: 20 mosaic_risks + 4
+    # the three registries each contribute: 22 mosaic_risks + 4
     # planners + 7 knob bindings
     kinds = {}
     for r in rungs:
         kinds[r["kind"]] = kinds.get(r["kind"], 0) + 1
-    assert kinds == {"mosaic_risk": 20, "planner": 4, "knob": 7}
+    assert kinds == {"mosaic_risk": 22, "planner": 4, "knob": 7}
     # riskiest construct class first: every strided-lane rung precedes
     # every lane-slice rung precedes every cast rung
     order = [r["rule"] for r in rungs if r["kind"] == "mosaic_risk"]

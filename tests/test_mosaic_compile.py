@@ -548,3 +548,66 @@ def test_afmoe_engine_step(chip):
     mem = compiled.memory_analysis()
     # four layers' weights, an eighth of the ends, both pools of four layers
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 4e9
+
+
+def test_minicpm_sala_engine_step(chip):
+    """The donated step of a sparse layer and a lightning layer (published
+    layers 9 and 10) at MiniCPM-SALA's widths and the cell's engine sizes
+    (requests of 65,536 tokens: page tables ``[8, 4096]``) on the largest
+    rung: the paged kernel's two launches a sparse layer (the lanes' tiles
+    and a unit's, inside the units' loop) read the page pool in place
+    through scalar-prefetched block lists, the lightning units are matrix
+    products, and every part lies under its name."""
+    from flashinfer_tpu.models.minicpm_sala import (
+        MiniCPMSalaConfig, init_minicpm_sala_params)
+    from flashinfer_tpu.serve import (EngineConfig, SamplingConfig,
+                                      ServingEngine, recurrent)
+
+    mp = pytest.MonkeyPatch()
+    _as_on_a_v5e(mp)
+    try:
+        mcfg = MiniCPMSalaConfig(
+            mixer_types=MiniCPMSalaConfig().mixer_types[9:11],
+            layer_index_published=(9, 10))
+        assert mcfg.layer_kinds() == ("latent", "recurrent")
+        ecfg = EngineConfig.from_knobs(
+            mcfg, num_pages=32784, max_seq_tokens=65536, max_batch=8,
+            prefill_budget_tokens=1016, page_size=16, ladder=(8, 512, 1024),
+            attention_backend="kernel", kv_offload="off",
+            spill_policy="recompute",
+            sampling=SamplingConfig(temperature=0.8, top_k=40, top_p=0.95))
+        params = jax.eval_shape(
+            lambda: init_minicpm_sala_params(jax.random.PRNGKey(0), mcfg))
+        eng = ServingEngine(mcfg, params, dataclasses.replace(
+            ecfg, num_pages=2))   # shapes only: no 32,784-page pool here
+        assert not eng._kernel_backend and eng._ppr == 4096
+        rung, chunk = 1024, 1016
+        B, ppr = ecfg.max_batch, eng._ppr
+        mplan, _ = recurrent.build_plan(
+            [(0, chunk, 0, True)], tokens=chunk, rung=rung, max_batch=B,
+            chunk=mcfg.scan_chunk)
+        caches = [
+            jax.eval_shape(lambda: mcfg.new_latent_pages(ecfg.num_pages, 16)),
+            jax.eval_shape(lambda: mcfg.new_recurrent_state(B))]
+        host = (params, *(np.zeros(rung, np.int32),) * 5,
+                *(np.zeros((B, ppr), np.int32),) * 2,
+                *(np.zeros(rung, np.int32),) * 2,
+                *(np.zeros(B, np.int32),) * 2, {}, caches, mplan)
+        compiled = eng._step.lower(*_like(chip, host)).compile()
+    finally:
+        mp.undo()
+    hlo = compiled.as_text()
+    kernels = re.findall(
+        r"%block_sparse_paged_attn(?:\.\d+)? = .*custom-call\(.*", hlo)
+    assert len(kernels) == 2
+    for k in kernels:
+        assert re.search(r'op_name="jit\(_body\)/(\S+/)?attn\.core/', k)
+        assert "attn.index" not in k and "attn.select" not in k
+    for scope in ("embed", "ssm.in_proj", "ssm.scan", "ssm.out", "attn.qkv",
+                  "attn.kv_write", "attn.core", "attn.index", "attn.select",
+                  "attn.out", "mlp", "head", "sample"):
+        assert re.search(rf'op_name="jit\(_body\)/(\S+/)?{scope}/', hlo), scope
+    mem = compiled.memory_analysis()
+    # two layers' weights, both ends, the page pool of one sparse layer
+    # (1.1 GB) and 8 slots; a unit's scores, mask and bias are temporaries
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 4e9
