@@ -45,7 +45,7 @@ rows at a time against the keys it can see, so a 16k sequence fits.
 ``precision="int8"`` is for the control only: every linear (not the router,
 which the configuration states in float32) has its weight rounded to 8-bit
 codes per output channel and its input per row.  ``precision=
-"float32-nowindow"`` is the other control, run by hand: the sliding layers
+"float32-nowindow"`` is the other control (``--control``): the sliding layers
 attend the whole context.
 """
 

@@ -52,9 +52,9 @@ ROWS_NUMBER = "latent_rel_rms"
 UNITS_HELD = 6
 # The control is the reference with every linear in int8 (not the router, the
 # index head weights or the scores, which the configuration states in
-# float32).  The other control, selection off ("float32-dense"), is run by
-# hand (PERF.md section 2).
-CONTROL_PRECISION = "int8"
+# float32).  The other control is selection off ("float32-dense": plain
+# causal attention).  Both run through ``--control <name>``.
+CONTROLS = ("int8", "float32-dense")
 # Limits.  Each stands between the largest reading of sound runs and the
 # reading of the control, both on the chip at the cell's own sizes (my chip
 # runs, PR 35; PERF.md section 2).
@@ -72,7 +72,7 @@ CONTROL_PRECISION = "int8"
 # output, which is as large as the embedding it is added to.  The int8
 # control reads 0.1998 (per unit 0.0130, 0.1460, 0.1614, 0.1744, 0.1861,
 # 0.1998; seed 3500000002, whose sound reading is 0.0996): twice the swaps.
-# Selection OFF (the reference with plain causal attention, run by hand in
+# Selection OFF (the reference with plain causal attention, then run by hand in
 # the control's place) reads 0.9104 (seed 3500000003).  The limit is the
 # geometric middle of the largest sound reading and the int8 control's.
 #

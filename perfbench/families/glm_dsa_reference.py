@@ -49,7 +49,7 @@ One sequence at a time, no cache, no batching.  ``precision="int8"`` is for
 the control only: every linear (not the router, the index head weights or the
 scores, which the configuration states in float32) has its weight rounded to
 8-bit codes per output channel and its input per row.
-``precision="float32-dense"`` is the other control, run by hand: selection
+``precision="float32-dense"`` is the other control (``--control``): selection
 off, plain causal attention over every cached position.
 """
 

@@ -54,7 +54,7 @@ ROWS_NUMBER = "state_rel_rms"
 UNITS_HELD = 10
 # The control is the reference with every linear in int8 (not the router and
 # not the state, which the configuration states in float32).
-CONTROL_PRECISION = "int8"
+CONTROLS = ("int8",)
 # Limits.  Each stands between the largest reading of sound runs and the
 # reading of the control, both on the chip at the cell's own sizes (my chip
 # runs, PR 28; PERF.md section 2).

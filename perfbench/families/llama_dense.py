@@ -30,8 +30,8 @@ TINY = {"hidden_size": 256, "intermediate_size": 512, "num_hidden_layers": 2,
 ROWS_NUMBER = "kv_rel_rms"
 UNITS_HELD = 4
 # The control is the reference computed in int8, the step below the
-# configurations' bf16, put in the program's place.
-CONTROL_PRECISION = "int8"
+# configurations' bf16, put in the program's place (``--control int8``).
+CONTROLS = ("int8",)
 # Limits.  Each stands between the largest reading of sound runs and the
 # smallest of the control, both read on the chip at the cells' own sizes;
 # PERF.md section 2 lists every reading.

@@ -54,7 +54,7 @@ pass), one sequence at a time inside it, no cache, no batching.  ``precision="in
 the control only: every linear has its weight rounded to 8-bit codes per
 output channel and its input per row (not the states, scores and softmaxes,
 which the configuration states in float32).  ``precision="float32-dense"`` is
-the other control, run by hand: the sparse layers attend every causal
+the other control (``--control``): the sparse layers attend every causal
 position (selection off).
 """
 

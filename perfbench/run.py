@@ -43,8 +43,11 @@ import traffic  # noqa: E402
 
 TRACE_STEPS = 5          # steady steps wrapped in the profiler
 TRACE_AT = 0.4           # ... starting at this share of the window
-SAMPLE_IN_FLIGHT = 4     # requests whose cache rows are compared
-SAMPLE_FINISHED = 4      # further requests whose tokens are compared
+# The check's sample: requests in flight whose cache rows are compared, and
+# further finished requests whose tokens are.  A family whose reference is
+# slow over its cell's contexts gives its own ``SAMPLE``, so that a whole run
+# ends well inside its time limit (PERF.md section 2).
+SAMPLE = (4, 4)
 
 
 def say(msg):
@@ -143,11 +146,11 @@ def warm_rungs(eng, ecfg, vocab, rng, make_request, cache):
     return seen
 
 
-def pick_samples(driver, rng, state_rows, ended):
-    """What the check compares: requests in flight at the window's close
-    with their cache rows (the longest among them), and finished requests of
-    the window with their served tokens (the longest among them); the rest
-    drawn from the seed."""
+def pick_samples(driver, rng, state_rows, ended, sample=SAMPLE):
+    """What the check compares: ``sample[0]`` requests in flight at the
+    window's close with their cache rows (the longest among them), and
+    ``sample[1]`` finished requests of the window with their served tokens
+    (the longest among them); the rest drawn from the seed."""
     def pick(cands, size, n):
         cands = sorted(cands, key=size, reverse=True)
         if len(cands) <= n:
@@ -159,7 +162,7 @@ def pick_samples(driver, rng, state_rows, ended):
     samples = []
     flying = [r for r in driver.live.values()
               if r.state == "running" and r.kv_len > 0]
-    for req in pick(flying, lambda r: r.kv_len, SAMPLE_IN_FLIGHT):
+    for req in pick(flying, lambda r: r.kv_len, sample[0]):
         rec = driver.records[req.rid]
         seq = rec["prompt"] + list(req.out_tokens)
         n, plen, k = req.kv_len, len(rec["prompt"]), len(req.out_tokens)
@@ -175,7 +178,7 @@ def pick_samples(driver, rng, state_rows, ended):
             "served": list(req.out_tokens)})
     done = [r for r in ended if r["tokens"] and not r.get("refused")]
     for rec in pick(done, lambda r: len(r["prompt"]) + len(r["tokens"]),
-                    SAMPLE_FINISHED):
+                    sample[1]):
         plen, k = len(rec["prompt"]), len(rec["tokens"])
         samples.append({
             "rid": rec["rid"],
@@ -212,16 +215,28 @@ def main(argv=None):
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--tiny", action="store_true",
                     help="CPU rehearsal sizes; never a result")
-    ap.add_argument("--control", type=int, choices=(0, 1), default=0,
-                    help="also read the check's numbers for the control "
-                         "(the family's reference a precision lower); "
-                         "not used by the driver")
+    ap.add_argument("--control", default="",
+                    help="also read the check's numbers for a control, the "
+                         "family's reference in the program's place: a name "
+                         "of the family's CONTROLS (int8; the family's "
+                         "mechanism switched off), several with commas; 1 "
+                         "is the first, int8; not used by the driver")
+    ap.add_argument("--keep-gaps", default="",
+                    help="write every sampled token's gap, rank and labels, "
+                         "and each control's, to this JSON file")
     ap.add_argument("--keep-trace", default="",
                     help="copy the profiler's files into this directory")
     args = ap.parse_args(argv)
 
     cell, config, spec, e2e, per_layer = load_cell(args.workload)
     fam = family.load(config)
+    import check
+
+    try:
+        check.controls_of(fam, args.control)
+    except ValueError as e:
+        print(f"perfbench: {e}", file=sys.stderr)
+        return 2
     if args.tiny:
         config, spec = apply_tiny(config, spec, fam)
     if args.trace:
@@ -268,8 +283,6 @@ def main(argv=None):
     say(f"device {device}; jax {jax.__version__}; compile cache at "
         f"{env.xla_cache_dir()}; cell {cell['name']} seed {args.seed} "
         f"window {args.seconds:g} s trace {args.trace}")
-
-    import check
 
     # ---- set-up -----------------------------------------------------------
     t0 = time.perf_counter()
@@ -405,21 +418,31 @@ def main(argv=None):
     # ---- check (outside the window, after the program's state is freed) ---
     samples = pick_samples(driver, np.random.default_rng([args.seed, 0xC4EC]),
                            lambda req, n: fam.state_rows(eng, req, n),
-                           ended)
+                           ended, getattr(fam, "SAMPLE", SAMPLE))
     bad_tokens = sum(not 0 <= t < vocab
                      for r in driver.records.values() for t in r["tokens"])
     eng.caches = None
     del eng, params, driver.eng
     gc.collect()
     t0 = time.perf_counter()
+    kept = {"workload": cell["name"], "seed": args.seed,
+            "seconds": args.seconds} if args.keep_gaps else None
     numbers, ctl = check.compare(samples, fam, config, args.seed,
                                  top_k=config["sampling"]["top_k"],
-                                 control=bool(args.control), say=say)
+                                 control=args.control,
+                                 sampling=config["sampling"], kept=kept,
+                                 say=say)
     numbers.update(tokens_outside_vocab=bad_tokens, **in_window)
     limit_of = check.limits(fam)
     correct = check.verdict(numbers, limit_of, say)
     if ctl is not None:
         say("control numbers: " + json.dumps(ctl))
+        check.control_verdict(ctl, limit_of, say)
+    if kept is not None:
+        kept["numbers"] = numbers
+        path = ROOT / args.keep_gaps
+        path.parent.mkdir(parents=True, exist_ok=True)
+        path.write_text(json.dumps(kept))
     say(f"check took {time.perf_counter() - t0:.1f} s (not in setup_s)")
 
     # ---- the result line ---------------------------------------------------

@@ -25,7 +25,7 @@ TINY = {"hidden_size": 128, "intermediate_size": 384, "num_hidden_layers": 3,
 
 ROWS_NUMBER = "packed_rel_rms"
 UNITS_HELD = 3
-CONTROL_PRECISION = "int8"
+CONTROLS = ("int8",)
 # The toy's own: set for the CPU rehearsal at TINY widths, never read on a chip.
 LIMITS = {"packed_rel_rms": 0.02, "token_below_topk": 0.5,
           "samples_without_logits": 0}

@@ -20,6 +20,12 @@ def _config():
         return json.load(f)
 
 
+def fam_limits():
+    import family
+
+    return family.load(_config()).LIMITS
+
+
 def test_the_family_is_whole_and_the_file_holds_the_cut():
     import family
 
@@ -129,7 +135,8 @@ def test_the_cell_rehearses_to_the_end(tmp_path):
     p = subprocess.run(
         [sys.executable, str(PERFBENCH / "run.py"), "--workload",
          "trinity-mini.longctx", "--seed", str(2**31 + 11), "--seconds", "2",
-         "--trace", "1", "--tiny"],
+         "--trace", "1", "--tiny", "--control", "float32-nowindow",
+         "--keep-gaps", str(tmp_path / "gaps.json")],
         capture_output=True, text=True, timeout=900, cwd=ROOT,
         env={**os.environ, "JAX_PLATFORMS": "cpu",
              "JAX_COMPILATION_CACHE_DIR": str(tmp_path / ".xla_cache")})
@@ -146,6 +153,26 @@ def test_the_cell_rehearses_to_the_end(tmp_path):
     # the layers after it read 0.07-0.08, else 0.01-0.015
     assert len(per_unit) == 4 and all(0 < x < 0.2 for x in per_unit)
     assert list(would["compared"])[0] == "kv_rel_rms"
+    assert set(would["compared"]) == set(fam_limits()) | {
+        "rows_not_finite", "tokens_outside_vocab", "traces_in_window",
+        "compiles_in_window"}
+    # the window-off control by its name: the first layer's rows do not
+    # depend on the window, the later ones read far over the program's, and
+    # every sampled token's gap is kept, the control's drawn tokens' too
+    ctl = json.loads(next(ln for ln in out if "control numbers: " in ln)
+                     .split("control numbers: ")[1])["float32-nowindow"]
+    assert ctl["kv_rel_rms"] > 2 * would["compared"]["kv_rel_rms"]["value"]
+    assert any("control float32-nowindow: would read correct false" in ln
+               for ln in out)
+    with open(tmp_path / "gaps.json") as f:
+        kept = json.load(f)
+    served = next(ln for ln in out if " served tokens of " in ln)
+    assert f"check: {len(kept['tokens']['gap'])} served tokens" in served
+    assert max(kept["tokens"]["gap"]) \
+        == would["compared"]["token_below_topk"]["value"]
+    assert kept["controls"]["float32-nowindow"]["numbers"] == ctl
+    assert len(kept["controls"]["float32-nowindow"]["tokens"]["gap"]) \
+        == len(kept["tokens"]["gap"])
     assert any("router choices (token x layer)" in ln for ln in out)
     m = would["metrics"]
     # 4 of the toy's 8 experts are held; the random selection bias tilts it

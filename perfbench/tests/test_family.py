@@ -127,7 +127,8 @@ def test_a_second_family_arrives_as_new_files(tmp_path):
     # the control (the toy's reference in int8) reads well above the program
     ctl = json.loads(next(ln for ln in out if "control numbers: " in ln)
                      .split("control numbers: ")[1])
-    assert ctl["packed_rel_rms"] > 3 * compared["packed_rel_rms"]["value"]
+    assert ctl["int8"]["packed_rel_rms"] \
+        > 3 * compared["packed_rel_rms"]["value"]
     # the CPU has no device trace: the roofline metric reads nothing, the
     # cell's other metric is there
     assert any("toy.dense_roofline_share: nothing to read" in ln

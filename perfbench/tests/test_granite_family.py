@@ -34,7 +34,8 @@ def test_the_cell_rehearses_to_the_end(tmp_path):
     assert list(compared)[0] == "state_rel_rms"
     ctl = json.loads(next(ln for ln in out if "control numbers: " in ln)
                      .split("control numbers: ")[1])
-    assert ctl["state_rel_rms"] > 1.5 * compared["state_rel_rms"]["value"]
+    assert ctl["int8"]["state_rel_rms"] \
+        > 1.5 * compared["state_rel_rms"]["value"]
     assert any("router choices (token x layer)" in ln for ln in out)
     # the counters of the new layers are read; the CPU has no device trace
     m = would["metrics"]

@@ -102,3 +102,51 @@ def test_a_broken_timed_path_is_not_correct():
     assert any("token_below_topk" in ln and "NOT OK" in ln for ln in lines)
     assert any("kv_rel_rms" in ln and " ok" in ln for ln in lines)
     assert p.returncode == 1
+
+
+class _Flying:
+    def __init__(self, rid, kv_len, served):
+        self.rid, self.kv_len, self.state = rid, kv_len, "running"
+        self.out_tokens, self.preemptions = [7] * served, 0
+
+
+class _Driver:
+    """Six requests in flight, ``f0`` the longest, at a window's close."""
+
+    def __init__(self):
+        self.live = {f"f{i}": _Flying(f"f{i}", 40 - i + 2, 3)
+                     for i in range(6)}
+        self.records = {rid: {"prompt": [1] * (r.kv_len - 2)}
+                        for rid, r in self.live.items()}
+
+
+@pytest.mark.parametrize("sample", [run.SAMPLE, (2, 1), (1, 1), (8, 8)])
+def test_the_checks_sample_is_as_many_as_asked_with_the_longest(sample):
+    import numpy as np
+
+    ended = [{"rid": f"e{i}", "prompt": [1] * (30 - i), "tokens": [7, 7]}
+             for i in range(5)] + [{"rid": "none", "prompt": [1] * 99,
+                                    "tokens": []}]
+    got = run.pick_samples(_Driver(), np.random.default_rng(3),
+                           lambda req, n: {"k": n}, ended, sample)
+    flying = [s for s in got if s["prog_rows"] is not None]
+    done = [s for s in got if s["prog_rows"] is None]
+    assert len(flying) == min(sample[0], 6) and len(done) == min(sample[1], 5)
+    assert flying[0]["rid"] == "f0" and done[0]["rid"] == "e0"
+    assert len({s["rid"] for s in got}) == len(got)
+    for s in flying:   # the rows the engine holds: prompt + served - 1
+        assert len(s["tokens"]) == s["prog_rows"]["k"]
+        assert s["logit_rows"][0] == len(s["tokens"]) - len(s["served"])
+
+
+def test_only_the_long_context_family_takes_a_smaller_sample():
+    """``minicpm_sala``'s reference over 16k-65k rows a request would not end
+    inside a run's time limit over the harness's 4 + 4 (PERF.md section 2)."""
+    assert run.SAMPLE == (4, 4)
+    with open(ROOT / "BENCHMARK.json") as f:
+        bench = json.load(f)
+    for c in bench["configs"]:
+        with open(ROOT / c["file"]) as f:
+            fam = family.load(json.load(f))
+        want = (2, 1) if fam.__name__ == "families.minicpm_sala" else None
+        assert getattr(fam, "SAMPLE", None) == want, fam.__name__
