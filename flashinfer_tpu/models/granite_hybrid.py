@@ -32,13 +32,15 @@ in float32):
 Mamba-2 under continuous batching: the flat batch of one step holds, per
 scheduled request, one contiguous segment (``serve/recurrent.py``).  The
 convolution reads, for the first ``d_conv - 1`` rows of a segment, the
-slot's tail; one-token segments (decode lanes) advance all slots' ``S`` at
-once under a mask; longer segments are cut into work units of at most
-``scan_chunk`` rows that one ``fori_loop`` with a dynamic bound runs in
-order, each in the chunked (SSD) form, carrying ``S`` from unit to unit of a
-segment, loading the slot at its first unit (zero for a request (re)admitted
-since its last step) and writing it back at its last.  Shapes depend on the
-rung alone: one trace per rung.
+slot's tail.  The recurrence is one Pallas launch a layer
+(``ops/ssm_slot_kernel.py``) over the step's ITEMS, the slots' array aliased
+to its output: a one-token segment (a decode lane) is one item, the one-row
+update; a longer segment is cut into work units of at most ``scan_chunk``
+rows, each an item in the chunked (SSD) form, ``S`` carried in VMEM from unit
+to unit of a segment, loaded at its first unit (zero for a request
+(re)admitted since its last step) and written back after its last.  A slot
+the step does not schedule is not touched.  Shapes depend on the rung
+alone: one trace per rung.
 """
 
 from __future__ import annotations
@@ -111,13 +113,15 @@ class GraniteHybridConfig:
 
     def new_recurrent_state(self, slots: int):
         """(convolution tail ``[slots, d_conv - 1, conv_dim]``, ``S``
-        ``[slots, heads x d_head x d_state]``) of one Mamba layer, zero.
-        ``S`` is kept flat: the step updates it in place and never lays it
-        out anew (``_mamba_mixer``)."""
+        ``[slots, heads x d_head, d_state]``) of one Mamba layer, zero.
+        ``S`` has the axes the scan's kernel blocks it by (``_mamba_mixer``):
+        on the chip a flat ``[slots, heads x d_head x d_state]`` array is
+        tiled eight slots to a tile, and opening its axes would lay every
+        slot out anew, twice a layer."""
         return (jnp.zeros((slots, self.mamba_d_conv - 1, self.conv_dim),
                           self.dtype),
-                jnp.zeros((slots, self.mamba_n_heads * self.mamba_d_head
-                           * self.mamba_d_state), self.state_dtype))
+                jnp.zeros((slots, self.mamba_n_heads * self.mamba_d_head,
+                           self.mamba_d_state), self.state_dtype))
 
     def engine_embed(self, params, tokens):
         e = params["embed"][tokens].astype(jnp.float32)
@@ -253,12 +257,13 @@ def _attention_mixer(cfg, x, layer, cache, ctx):
 
 def _mamba_mixer(cfg, x, layer, cache, ctx):
     from flashinfer_tpu.norm import rmsnorm
+    from flashinfer_tpu.ops.ssm_slot_kernel import ssm_slot_update
 
     conv_state, ssm_state = cache
     t = x.shape[0]
     nh, dh, ds, g = (cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state,
                      cfg.mamba_n_groups)
-    rep, inner, taps = nh // g, cfg.mamba_inner, cfg.mamba_d_conv - 1
+    inner, taps = cfg.mamba_inner, cfg.mamba_d_conv - 1
     seg = ctx.plan["seg"]
     row0, n_seg, fresh = seg[:, 0], seg[:, 1], seg[:, 2] > 0
     f32 = jnp.float32
@@ -297,91 +302,16 @@ def _mamba_mixer(cfg, x, layer, cache, ctx):
 
     with jax.named_scope("ssm.scan"):
         xs, bm, cm = jnp.split(act, [inner, inner + g * ds], -1)
-        xs = xs.reshape(t, g, rep, dh)
-        bm = bm.reshape(t, g, ds)
-        cm = cm.reshape(t, g, ds)
-        a_head = -jnp.exp(layer["A_log"].astype(f32)).reshape(g, rep)
-        delta = jax.nn.softplus(
-            dt.astype(f32) + layer["dt_bias"].astype(f32)).reshape(t, g, rep)
-        q = min(cfg.scan_chunk, t)
-        y = jnp.zeros((t + q, g, rep, dh), f32)
-
-        # The slots' array goes through both passes FLAT, [slots, heads x
-        # d_head x d_state], and feeds no matmul: given its axes, the
-        # compiler lays the whole array out to suit the loop's
-        # contractions, two copies of every slot a layer (an off-chip
-        # compile shows them).  Axes are opened on one slot's state at a
-        # time and, in the elementwise pass, by a reshape that is a bitcast.
-
-        # one-token segments: every slot at once, under a mask
-        one = n_seg == 1
-        rows = jnp.clip(row0, 0, t - 1)
-        s0 = jnp.where(fresh[:, None], 0, ssm_state.astype(f32)).reshape(
-            -1, nh, dh, ds)
-        d1 = delta[rows].reshape(-1, nh)                     # [B, heads]
-        b1 = jnp.repeat(bm[rows].astype(f32), rep, 1)        # [B, heads, ds]
-        c1 = jnp.repeat(cm[rows].astype(f32), rep, 1)
-        x1 = xs[rows].astype(f32).reshape(-1, nh, dh)
-        s1 = jnp.exp(d1 * a_head.reshape(nh))[..., None, None] * s0 \
-            + (d1[..., None] * x1)[..., None] * b1[:, :, None, :]
-        # one elementwise pass over the slots, in place under donation; the
-        # lanes' outputs are read from its result (a second consumer of
-        # `s1` would keep a copy of every slot alive)
-        ssm = jnp.where(one[:, None, None, None], s1, s0)
-        y1 = jnp.sum(ssm * c1[:, :, None, :], -1)
-        ssm = ssm.reshape(ssm_state.shape)
-        y = y.at[jnp.where(one, rows, t + q)].set(
-            y1.reshape(-1, g, rep, dh), mode="drop")
-
-        # longer segments: work units of at most q rows, in order
-        work = ctx.plan["work"]
-        xs_p, bm_p, cm_p, dl_p = (
-            jnp.pad(v, ((0, q),) + ((0, 0),) * (v.ndim - 1))
-            for v in (xs, bm, cm, delta))
-        lower = jnp.tril(jnp.ones((q, q), bool))
-
-        def unit(w, carry):
-            s_prev, y, ssm = carry
-            r0, n, slot, first, last, fresh_w = (work[w, i] for i in range(6))
-            s_slot = jax.lax.dynamic_index_in_dim(ssm, slot, 0, False)
-            s_in = jnp.where(first > 0,
-                             jnp.where(fresh_w > 0, 0, s_slot), s_prev)
-            s_g = s_in.reshape(g, rep, dh, ds)
-            xc = jax.lax.dynamic_slice_in_dim(xs_p, r0, q).astype(f32)
-            bc = jax.lax.dynamic_slice_in_dim(bm_p, r0, q).astype(f32)
-            cc = jax.lax.dynamic_slice_in_dim(cm_p, r0, q).astype(f32)
-            live = jnp.arange(q) < n
-            dc = jnp.where(live[:, None, None],
-                           jax.lax.dynamic_slice_in_dim(dl_p, r0, q), 0.0)
-            acum = jnp.cumsum(dc * a_head, 0)                # [q, g, rep]
-            # within the unit: scores[i, j] = (C_i . B_j) exp(acum_i -
-            # acum_j) D_j on the causal triangle, masked inside the exponent
-            decay = jnp.exp(jnp.where(
-                lower[:, :, None, None],
-                acum[:, None] - acum[None, :], -jnp.inf))    # [q, q, g, rep]
-            cb = jnp.einsum("igs,jgs->ijg", cc, bc)
-            scores = cb[..., None] * decay * dc[None]
-            y_c = jnp.einsum("ijgr,jgrd->igrd", scores, xc) \
-                + jnp.exp(acum)[..., None] * jnp.einsum(
-                    "igs,grds->igrd", cc, s_g)
-            # the state after the unit's n rows (rows past n decay by 1
-            # and add nothing)
-            wj = jnp.exp(acum[-1][None] - acum) * dc
-            s_out = (jnp.exp(acum[-1])[..., None, None] * s_g + jnp.einsum(
-                "jgrd,jgs->grds", wj[..., None] * xc, bc)).reshape(s_in.shape)
-            y_old = jax.lax.dynamic_slice_in_dim(y, r0, q)
-            y = jax.lax.dynamic_update_slice_in_dim(
-                y, jnp.where(live[:, None, None, None], y_c, y_old), r0, 0)
-            ssm = jax.lax.dynamic_update_index_in_dim(
-                ssm, jnp.where(last > 0, s_out, s_slot), slot, 0)
-            return s_out, y, ssm
-
-        _, y, ssm = jax.lax.fori_loop(
-            0, ctx.plan["n_work"], unit,
-            (jnp.zeros(ssm.shape[1:], f32), y, ssm))
-        ssm_state = ssm.astype(ssm_state.dtype)
-        y = y[:t] + layer["D"].astype(f32).reshape(g, rep)[..., None] \
-            * xs.astype(f32)
+        a_head = -jnp.exp(layer["A_log"].astype(f32))
+        delta = jax.nn.softplus(dt.astype(f32) + layer["dt_bias"].astype(f32))
+        # one launch advances the scheduled slots in place and touches no
+        # other slot (the module's docstring; ops/ssm_slot_kernel.py)
+        y, ssm_state = ssm_slot_update(
+            ssm_state, seg, ctx.plan["work"], ctx.plan["n_work"], xs, bm, cm,
+            delta, a_head, heads=nh, d_head=dh, d_state=ds, groups=g,
+            chunk=cfg.scan_chunk)
+        y = y.reshape(t, nh, dh) + layer["D"].astype(f32)[:, None] \
+            * xs.reshape(t, nh, dh).astype(f32)
         gated = (y.reshape(t, inner)
                  * jax.nn.silu(z.astype(f32))).astype(cfg.dtype)
         gated = rmsnorm(gated, layer["ssm_norm"], cfg.rms_eps)

@@ -16,7 +16,12 @@ scratch across the sequential chunk sweep:
   forms come from identity contractions (no lane reshape in Mosaic);
 - ``scores[i,j] = (C_i . B_j) exp(acum_i - acum_j) dt_j`` on the causal
   triangle, masked INSIDE the exponent (-inf -> 0) so the upper triangle
-  stays finite without clamping real causal entries.
+  stays finite without clamping real causal entries.  The rule and the
+  identity contraction are :func:`causal_scores` and :func:`transposed`,
+  written once here: ``_ssd_chunk_kernel`` calls them for the library's
+  batch form, and the serving engine's slot-addressed launch
+  (``ops/ssm_slot_kernel.py``, granite's Mamba-2 layers) for a unit of a
+  scheduled segment.
 
 Validated against ``mamba_chunk_scan_combined`` in interpret mode;
 opt-in (``backend="pallas"``) until hardware-banked.
@@ -48,6 +53,43 @@ def eligible(x, B) -> bool:
     )
 
 
+def transposed(cols):
+    """``[Q, n] -> [n, Q]`` by an identity contraction (no lane reshape in
+    Mosaic).  HIGHEST precision: the columns are LOG-DECAY EXPONENTS or
+    step sizes -- the default bf16 MXU pass rounds |acum|~128 by up to ~0.5
+    absolute, i.e. e^0.5 ~ 65% after the exp (2026-07-31 hw tier: 6% of SSD
+    outputs off by up to 2.9).  [Q, n] x [Q, Q] is n*Q*Q FLOPs -- free."""
+    Q = cols.shape[0]
+    eye = (
+        jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+        == jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    ).astype(jnp.float32)
+    return jax.lax.dot_general(
+        cols, eye, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
+    )
+
+
+def causal_scores(cb, acum_col, acum_row, dt_row):
+    """``scores[i, j] = (C_i . B_j) exp(acum_i - acum_j) dt_j`` on the causal
+    triangle, 0 above it.  ``cb [Q, Q]``; ``acum_col [Q, 1]``; ``acum_row``,
+    ``dt_row [1, Q]`` (what :func:`transposed` gives).  The mask goes INSIDE
+    the exponent (-inf -> exp 0): the upper triangle stays finite without
+    clamping real causal entries (dt can be negative with
+    dt_softplus=False, making some causal exponents positive).  The one
+    copy of this rule: ``_ssd_chunk_kernel`` here and the serving engine's
+    slot kernel (``ops/ssm_slot_kernel.py``) both call it."""
+    Q = cb.shape[0]
+    causal = (
+        jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+        >= jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    )
+    decay = jnp.exp(jnp.where(
+        causal, acum_col - jnp.broadcast_to(acum_row, (Q, Q)), -jnp.inf))
+    return decay * cb * jnp.broadcast_to(dt_row, (Q, Q))
+
+
 def _ssd_chunk_kernel(
     x_ref,  # [Q, dim] input dtype
     b_ref,  # [Q, ds] (grouped: block index h // rep)
@@ -73,39 +115,11 @@ def _ssd_chunk_kernel(
     acum = scal_ref[...][:, 0:1]  # [Q, 1]
     dt = scal_ref[...][:, 1:2]
 
-    eye = (
-        jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
-        == jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    ).astype(jnp.float32)
-
-    def row(colvec):  # [Q, 1] -> [Q, Q] broadcast of the transposed vector
-        # HIGHEST precision: this dot carries LOG-DECAY EXPONENTS — the
-        # default bf16 MXU pass rounds |acum|~128 by up to ~0.5 absolute,
-        # i.e. e^0.5 ~ 65% after the exp (2026-07-31 hw tier: 6% of SSD
-        # outputs off by up to 2.9).  [Q,1]x[Q,Q] is Q*Q FLOPs — free.
-        r = jax.lax.dot_general(
-            colvec, eye, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST,
-        )  # [1, Q]
-        return jnp.broadcast_to(r, (Q, Q))
-
-    causal_b = (
-        jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
-        >= jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    )
-    # decay[i, j] = exp(acum_i - acum_j) on the causal triangle; masking
-    # INSIDE the exponent (-inf -> exp 0) keeps the upper triangle finite
-    # without clamping real causal entries (dt can be negative with
-    # dt_softplus=False, making some causal exponents positive)
-    decay = jnp.exp(
-        jnp.where(causal_b, acum - row(acum), -jnp.inf)
-    )
     cb = jax.lax.dot_general(
         cf, bf, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )  # [Q(i), Q(j)] = C_i . B_j
-    scores = decay * cb * row(dt)
+    scores = causal_scores(cb, acum, transposed(acum), transposed(dt))
 
     s0 = s_ref[...]
     # y = scores @ x + exp(acum) * C @ S0^T

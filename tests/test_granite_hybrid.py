@@ -445,6 +445,101 @@ def test_the_scan_respects_segments_and_slots():
                                    rtol=1e-4, atol=1e-6)
 
 
+def _scan_alone(state, segs, x, b, c, delta, a_head):
+    """The recurrence a token at a time, float32, one scheduled segment at
+    a time (numpy; shares nothing with the kernel): ``{slot: S}`` and
+    ``y`` with zero rows where no segment lies.  ``b`` / ``c`` ``[t, groups,
+    d_state]``."""
+    nh, (g, ds) = a_head.shape[0], b.shape[1:]
+    b, c = (np.repeat(v, nh // g, 1)[:, :, None] for v in (b, c))
+    y = np.zeros(x.shape, np.float32)
+    out = {}
+    for row0, n, slot, fresh in segs:
+        s = np.zeros((nh, x.shape[1] // nh, ds), np.float32) if fresh \
+            else state[slot].reshape(nh, -1, ds).copy()
+        for r in range(row0, row0 + n):
+            s = np.exp(delta[r] * a_head)[:, None, None] * s + (
+                delta[r][:, None] * x[r].reshape(nh, -1))[..., None] * b[r]
+            y[r] = (s * c[r]).sum(-1).reshape(-1)
+        out[slot] = s.reshape(state.shape[1:])
+    return y, out
+
+
+_MIXED = [(0, 7, 3, False), (7, 1, 0, False), (8, 5, 1, False)]
+
+
+@pytest.mark.parametrize("segs, groups", [
+    pytest.param([(3, 1, 1, False), (4, 6, 3, False)], 1,
+                 id="unnamed_slots"),
+    pytest.param([(0, 1, 0, True), (1, 9, 2, True)], 1,
+                 id="fresh_lane_and_fresh_units"),
+    pytest.param([(0, 1, 4, False), (1, 1, 0, True), (2, 1, 2, False)], 1,
+                 id="lanes_only_n_work_0"),
+    pytest.param([(0, 24, 2, False)], 1, id="one_long_segment"),
+    pytest.param([(2, 8, 1, False), (10, 12, 4, True)], 1, id="units_only"),
+    pytest.param(_MIXED, 1, id="short_last_unit"),
+    pytest.param(_MIXED, 2, id="two_groups"),
+    pytest.param([], 1, id="nothing_scheduled"),
+])
+def test_the_slot_kernel_touches_what_the_step_schedules(segs, groups):
+    """``ops/ssm_slot_kernel.py`` at 8 heads x 16 x 16 a B/C group, units of
+    4 rows, 5 slots, in interpret mode: every scheduled slot and every row
+    of a segment is what the recurrence gives a token at a time; a ``fresh``
+    segment starts from zero whatever the slot held (NaN here); a slot no
+    item names is bit for bit what it was; rows of no segment, those past
+    the step's tokens among them, are zero."""
+    from flashinfer_tpu.ops.ssm_slot_kernel import ssm_slot_update
+    from flashinfer_tpu.serve import recurrent
+
+    nh, dh, ds, slots, t, chunk = 8 * groups, 16, 16, 5, 32, 4
+    total = sum(n for _, n, _, _ in segs)
+    rng = np.random.default_rng(len(segs) + total)
+    state = rng.standard_normal((slots, nh * dh, ds)).astype(np.float32)
+    for _, _, slot, fresh in segs:
+        if fresh:
+            state[slot] = np.nan
+    x = rng.standard_normal((t, nh * dh)).astype(np.float32)
+    b, c = rng.standard_normal((2, t, groups, ds)).astype(np.float32)
+    delta = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), (t, nh))
+                   ).astype(np.float32)
+    a_head = -rng.uniform(1, 16, nh).astype(np.float32)
+    plan, _ = recurrent.build_plan(segs, tokens=total, rung=t,
+                                   max_batch=slots, chunk=chunk)
+    y, new = ssm_slot_update(
+        jnp.asarray(state), *(jnp.asarray(plan[k]) for k in
+                              ("seg", "work", "n_work")),
+        *map(jnp.asarray, (x, b.reshape(t, -1), c.reshape(t, -1), delta,
+                           a_head)),
+        heads=nh, d_head=dh, d_state=ds, groups=groups, chunk=chunk)
+    want_y, want = _scan_alone(state, segs, x, b, c, delta, a_head)
+    np.testing.assert_allclose(y, want_y, rtol=2e-5, atol=2e-5)
+    held = np.zeros(t, bool)
+    for row0, n, _, _ in segs:
+        held[row0:row0 + n] = True
+    assert not np.asarray(y)[~held].any()
+    assert int(plan["n_work"]) == sum(-(-n // chunk) for _, n, _, _ in segs
+                                      if n > 1)
+    for slot in range(slots):
+        if slot in want:
+            np.testing.assert_allclose(new[slot], want[slot], rtol=2e-5,
+                                       atol=2e-6)
+        else:
+            np.testing.assert_array_equal(new[slot], state[slot])
+
+
+def test_the_slot_kernel_refuses_a_block_across_groups():
+    """A grid step's heads share one row of B and C, so a block lies inside
+    one group; where no whole 128-column tile fits in a group the launch
+    raises and does not read another group's rows."""
+    from flashinfer_tpu.ops.ssm_slot_kernel import head_block
+
+    assert head_block(128, 64, 128, 128) == 16     # granite-4.0-h-small
+    assert head_block(128, 64, 128, 16) == 16      # eight groups
+    assert head_block(8, 16, 16, 8) == 8           # tiny: all the heads
+    with pytest.raises(ValueError, match="inside a B/C group"):
+        head_block(8, 16, 16, 4)
+
+
 def test_the_plan_of_a_step():
     from flashinfer_tpu.serve import recurrent
 

@@ -16,6 +16,7 @@ to it cannot be read back without a chip.
 """
 
 import dataclasses
+import functools
 import re
 
 import jax
@@ -348,12 +349,46 @@ def test_moe_gmm_at_granite_widths(chip, compiled_branch, k, n):
                      compiled.as_text())
 
 
-def test_granite_hybrid_engine_step(chip):
+def _whole_state_copies(hlo):
+    """The compiled text's instructions that write a whole ``[64 slots,
+    128 x 64, 128]`` float32 state array other than the scan's kernel."""
+    return [ln.strip()[:160] for ln in hlo.splitlines()
+            if re.search(r"= f32\[64,(8192,128|1048576)\]\S* "
+                         r"(copy|fusion|dynamic-update-slice|select)\(", ln)]
+
+
+@pytest.mark.parametrize("rung", [64, 640])
+def test_ssm_slot_update_at_granite_widths(chip, compiled_branch, rung):
+    """The slot-addressed scan kernel alone (``ops/ssm_slot_kernel.py``: the
+    lanes' one-row path and the units' chunked path in one launch) at
+    granite-4.0-h-small's widths, 64 slots, units of ``min(128, rung)``
+    rows: Mosaic takes it, the state is updated in place."""
+    from flashinfer_tpu.ops.ssm_slot_kernel import ssm_slot_update
+    from flashinfer_tpu.serve import recurrent
+
+    nh, dh, ds, slots = 128, 64, 128, 64
+    compiled = jax.jit(
+        functools.partial(ssm_slot_update, heads=nh, d_head=dh, d_state=ds,
+                          groups=1, chunk=128), donate_argnums=0).lower(
+        _sds(chip, (slots, nh * dh, ds), "float32"),
+        _sds(chip, (slots, 3), "int32"),
+        _sds(chip, (recurrent.work_units(rung, slots, 128), 6), "int32"),
+        _sds(chip, (), "int32"), _sds(chip, (rung, nh * dh), "bfloat16"),
+        _sds(chip, (rung, ds), "bfloat16"), _sds(chip, (rung, ds), "bfloat16"),
+        _sds(chip, (rung, nh), "float32"), _sds(chip, (nh,), "float32")
+    ).compile()
+    hlo = compiled.as_text()
+    assert re.search(r"%ssm_slot_update(\.\d+)? = .*custom-call\(", hlo)
+    assert "input_output_alias" in hlo.splitlines()[0]
+    assert not _whole_state_copies(hlo)
+
+
+@pytest.fixture(scope="module")
+def granite_step(chip):
     """The donated step of a Mamba-2 layer and an attention layer with
     their expert layers at granite-4.0-h-small's widths (36 of 72 experts
-    held, half the vocabulary), on the largest rung: the attention kernels
-    without rotation, the grouped matmuls, the scan's loop, and every part
-    under its name."""
+    held, half the vocabulary), on the largest rung, compiled once for the
+    tests that read it."""
     from flashinfer_tpu.models.granite_hybrid import (
         GraniteHybridConfig, init_granite_hybrid_params)
     from flashinfer_tpu.serve import (EngineConfig, SamplingConfig,
@@ -398,9 +433,17 @@ def test_granite_hybrid_engine_step(chip):
         compiled = lowered.compile()
     finally:
         mp.undo()
+    return compiled
+
+
+def test_granite_hybrid_engine_step(granite_step):
+    """The attention kernels without rotation, the grouped matmuls, the
+    scan's one launch, and every part under its name."""
+    compiled = granite_step
     hlo = compiled.as_text()
     for kernel in ("fused_paged_prefill_l0", "fused_paged_prefill_l1",
-                   "paged_decode_attention_split", "moe_gmm"):
+                   "paged_decode_attention_split", "moe_gmm",
+                   "ssm_slot_update"):
         assert re.search(rf"%{kernel}(\.\d+)? = .*custom-call\(", hlo), kernel
     for scope in ("embed", "ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.out",
                   "moe.route", "moe.experts", "moe.shared", "attn.qkv",
@@ -412,6 +455,12 @@ def test_granite_hybrid_engine_step(chip):
     # embedding, 64 slots and 512 pages: 2.1 GB; well under the chip's 16
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 6e9
 
+
+def test_the_granite_step_copies_no_whole_state(granite_step):
+    """The slots' array goes into the scan's kernel and comes out of it as
+    the engine holds it: no instruction of the compiled step writes all 64
+    slots (a copy, a fusion, a select or an update of the array's shape)."""
+    assert not _whole_state_copies(granite_step.as_text())
 
 
 @pytest.mark.parametrize("k, n", [(6144, 4096), (2048, 6144)])
