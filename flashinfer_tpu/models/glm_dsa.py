@@ -302,22 +302,26 @@ def _attend_block(cfg, q, q_i, w_i, pos, win_i, win_l, last=None):
     ``win_i`` ``[K, Di]`` and ``win_l`` ``[K, W]`` the request's cached index
     keys and latent rows by position.  Float32 ``[Q, H, ckv]``.
 
-    One selection, two forms of the same softmax over it
+    One selection rule, two forms of the same softmax over it
     (``ops/sparse_mla.py``): a unit of many rows, which gives ``last`` (the
     position of its last live row), streams the window once under the
-    selection as a mask; one row (a decode lane) gathers its list's rows."""
+    selection as a mask, and a mask needs the ``index_topk``-th score of each
+    row only: a search, no sort; one row (a decode lane) gathers its list's
+    rows, so it sorts for the list."""
     from flashinfer_tpu.ops.sparse_mla import (
-        index_scores, select_topk, selection_mask, sparse_mla_attend,
-        sparse_mla_stream)
+        index_scores, kth_score, mask_from_kth, select_topk,
+        sparse_mla_attend, sparse_mla_stream)
 
     with jax.named_scope("attn.index"):
         scores = index_scores(q_i, w_i, win_i, pos)
+    if last is not None:
+        with jax.named_scope("attn.select"):
+            kth = kth_score(scores, cfg.index_topk)
+        return sparse_mla_stream(
+            q, win_l, mask_from_kth(scores, kth, cfg.index_topk), last,
+            d_v=cfg.kv_lora_rank, sm_scale=cfg.attention_scale)
     with jax.named_scope("attn.select"):
         idx, valid = select_topk(scores, cfg.index_topk)
-    if last is not None:
-        return sparse_mla_stream(
-            q, win_l, selection_mask(scores, idx, valid), last,
-            d_v=cfg.kv_lora_rank, sm_scale=cfg.attention_scale)
     return sparse_mla_attend(q, win_l[idx], valid, d_v=cfg.kv_lora_rank,
                              sm_scale=cfg.attention_scale)
 
@@ -360,7 +364,7 @@ def _sparse_core(cfg, q, q_i, w_i, lat, kidx, ctx):
 
     # a unit's window follows its request's context: a quarter, a half or
     # the whole of the pages a request can hold, the least that covers the
-    # unit's last position (score, sort and gather grow with the window)
+    # unit's last position (score, search and gather grow with the window)
     ps, most = lat.shape[1], table.shape[1]
     widths = sorted({-(-most // d) for d in (4, 2, 1)})
 

@@ -5,19 +5,19 @@ selected rows only, in two forms that compute the same softmax.
 
 - :func:`index_scores`: ``I[q, s] = sum_j w[q, j] relu(q_I[q, j] . k_I[s])``
   in float32, masked outside ``s <= pos[q]``.  Plain XLA.
-- :func:`select_topk`: the ``k`` highest of a row of masked scores, exactly
-  (``lax.top_k``: a sort, no approximation), and which of them are real (a
-  query with fewer than ``k`` cached tokens keeps them all).  Plain XLA.
+- :func:`select_topk`: the ``k`` highest of a row of masked scores as a LIST
+  (``lax.top_k``, which the TPU runs as a sort of the whole row), for a query
+  that gathers; :func:`kth_score`: the ``k``-th score alone, by a search.
 - :func:`sparse_mla_attend`, the gathered form (plain XLA): softmax over
   each query token's own gathered rows, the value being the rows' leading
   ``d_v`` columns (the absorbed form: one latent row is key and value of
   every head).  Right where a query brings few MXU rows: a decode lane, and
   ``run_sparse``.
 - :func:`selection_mask` and :func:`sparse_mla_stream`, the streamed form (a
-  Pallas kernel, ``name="sparse_mla_stream"``): the same list as a mask over
-  the window's positions, and a flash loop that reads a request's window ONCE
-  per tile of query tokens under that mask.  Right for a unit of many query
-  rows of one request: no two queries share a list, so a gather moves
+  Pallas kernel, ``name="sparse_mla_stream"``): the same selection as a mask
+  over the window's positions, and a flash loop that reads a request's window
+  ONCE per tile of query tokens under that mask.  Right for a unit of many
+  query rows of one request: no two queries share a list, so a gather moves
   ``queries x k`` rows one at a time, where all heads of all the unit's
   tokens share the window's rows on the MXU.
 
@@ -56,9 +56,9 @@ def index_scores(q_i, w_i, k_i, q_pos):
 
 def select_topk(scores, k: int):
     """The positions of the ``k`` highest scores of each row (ties to the
-    lower position), and which of them are real: ``(idx [..., k] int32,
-    valid [..., k])``.  A row with fewer than ``k`` unmasked scores selects
-    them all; the rest of its list is not valid."""
+    lower position) and which of them are real, ``(idx [..., k] int32, valid
+    [..., k])``, by a sort of the row: for a query that gathers its list.  A
+    row with fewer than ``k`` unmasked scores selects them all, the rest not."""
     vals, idx = jax.lax.top_k(scores, min(k, scores.shape[-1]))
     return idx.astype(jnp.int32), vals > _MASKED
 
@@ -113,19 +113,19 @@ def selection_mask(scores, idx, valid):
     """The selection of :func:`select_topk` as a mask over the positions it
     chose from: ``scores`` ``[..., S]``, ``idx`` / ``valid`` ``[..., k]`` ->
     bool ``[..., S]`` with exactly the selected positions set.  From the
-    list only its last entry is read, for the ``k``-th score; the rule is
-    then applied to the scores themselves, so the order the sort left ties
-    in does not matter: a position is selected iff its score is above the
-    ``k``-th, or equal to it and among the first ``k - (count above)`` such
-    positions.  A row whose list is not full (its last entry not valid)
-    keeps every unmasked position."""
+    list only its last entry is read, for the ``k``-th score; the rule
+    (:func:`mask_from_kth`) is then applied to the scores themselves, so the
+    order the sort left ties in does not matter: a position is selected iff
+    its score is above the ``k``-th, or equal to it and among the first
+    ``k - (count above)`` such positions.  A row whose list is not full (its
+    last entry not valid) keeps every unmasked position.
+
+    A caller with no use for the list (a unit that streams its window) gets
+    the same mask from :func:`kth_score` and the rule, without the sort."""
     k = idx.shape[-1]
     kth = jnp.where(valid[..., -1:],
                     jnp.take_along_axis(scores, idx[..., -1:], -1), _MASKED)
-    above = scores > kth
-    tie = (scores == kth) & (scores > _MASKED)
-    room = (k - jnp.sum(above, -1, keepdims=True)).astype(jnp.float32)
-    return above | (tie & (_running_count(tie) <= room))
+    return mask_from_kth(scores, kth, k)
 
 
 def _stream_kernel(last_ref, q_ref, w_ref, bias_ref, o_ref,
@@ -227,3 +227,42 @@ def sparse_mla_stream(q, window, mask, last, *, d_v: int, sm_scale: float):
         interpret=use_interpret(),
     )(last, q, window, bias)
     return out.reshape(-1, heads, d_v)[:n_q]
+
+
+# score rows a grid step of the search holds in VMEM.  A grid step costs ~7 us
+# however few rows it holds (33 dependent halvings), so more rows a step is
+# fewer of them: 8 / 16 / 32 rows read 0.114 / 0.061 / 0.037 ms a unit of 128
+# rows at a window of 4,160 and 0.191 / 0.124 / 0.091 at 16,640, the sort 0.24
+# and 1.28 (one v5e; PERF.md section 6, PR 49)
+_SEARCH_ROWS = 32
+
+
+def kth_score(scores, k: int):
+    """The ``k``-th highest score of each row, exactly and without a sort:
+    ``scores`` ``[Q, S]`` float32 (``-inf`` where masked) -> ``[Q, 1]``, for
+    :func:`mask_from_kth`.  The search of ``ops/sampling_kernels.py
+    top_k_thresholds``: :data:`_SEARCH_ROWS` rows at a time resident in VMEM,
+    33 halvings of the interval of their order-preserving int32 keys, one HBM
+    read of the scores.  A row with fewer than ``k`` unmasked scores gives
+    its least one (which keeps them all, as ``-inf`` would), a row with none
+    ``+inf`` (which keeps nothing, as there is nothing to keep); scores at or
+    under ``-1e20`` count as masked."""
+    from flashinfer_tpu.ops.sampling_kernels import top_k_thresholds
+
+    return top_k_thresholds(
+        scores, jnp.full((scores.shape[0],), k, jnp.float32),
+        block_rows=_SEARCH_ROWS)[:, None]
+
+
+def mask_from_kth(scores, kth, k: int):
+    """THE selection rule: ``scores`` ``[..., S]``, ``kth`` ``[..., 1]`` the
+    ``k``-th highest score of each row (from a list's last entry or from
+    :func:`kth_score`) -> bool ``[..., S]``: set where the score is above
+    ``kth``, or equal to it, unmasked, and among the first ``k - (count
+    above)`` such positions (ties go to the lower position, as a stable sort
+    leaves them).  Float comparisons only, so ``0.0`` and ``-0.0`` tie and
+    any ``kth`` under every unmasked score keeps them all."""
+    above = scores > kth
+    tie = (scores == kth) & (scores > _MASKED)
+    room = (k - jnp.sum(above, -1, keepdims=True)).astype(jnp.float32)
+    return above | (tie & (_running_count(tie) <= room))

@@ -341,6 +341,61 @@ def test_a_unit_streams_what_it_would_gather(case, pos, live, ties):
     np.testing.assert_allclose(got[:live], want[:live], atol=5e-6)
 
 
+_SEARCH_K = 64
+_SEARCH_CASES = {   # name: (values, unmasked positions of each of 16 rows, k)
+    "random": ("normal", range(200, 216), _SEARCH_K),
+    "ties_straddle_the_kth": ("halves", range(300, 316), _SEARCH_K),
+    "only_zeros_of_both_signs": ("zeros", [416] * 16, _SEARCH_K),
+    "zeros_of_both_signs_at_the_kth": ("zeros_between", [416] * 16, _SEARCH_K),
+    "fewer_than_k": ("normal", range(1, 17), _SEARCH_K),
+    "exactly_k": ("halves", [_SEARCH_K] * 16, _SEARCH_K),
+    "k_plus_one": ("halves", [_SEARCH_K + 1] * 16, _SEARCH_K),
+    "some_rows_all_masked": ("normal", [0, 0, 100, 0] * 4, _SEARCH_K),
+    "k_over_the_width": ("halves", range(400, 416), 2048),
+}
+
+
+def _search_scores(values, unmasked, width):
+    """Float32 ``[rows, width]`` of the named kind, ``-inf`` from each row's
+    ``unmasked`` count on: ``halves`` lie on a grid of halves (exact ties),
+    ``zeros`` are ``0.0`` / ``-0.0`` at random, ``zeros_between`` holds ~40
+    ones a row, then such zeros where the ``k``-th falls, then minus ones."""
+    shape = (len(unmasked), width)
+    k_normal, k_signs = jax.random.split(jax.random.PRNGKey(49))
+    normal = jax.random.normal(k_normal, shape)
+    zeros = jnp.where(jax.random.bernoulli(k_signs, 0.5, shape), 0.0, -0.0)
+    sc = {"normal": normal, "halves": jnp.round(2 * normal) / 2,
+          "zeros": zeros,
+          "zeros_between": jnp.where(normal > 1.3, 1.0, jnp.where(
+              normal < -1.3, -1.0, zeros))}[values]
+    return jnp.where(jnp.arange(width) < jnp.asarray(unmasked)[:, None], sc,
+                     -jnp.inf)
+
+
+@pytest.mark.parametrize("width", [416, 384])   # 4,160 scaled down; whole tiles
+@pytest.mark.parametrize("values, unmasked, k", _SEARCH_CASES.values(),
+                         ids=list(_SEARCH_CASES))
+def test_a_units_search_selects_what_the_sort_would(values, unmasked, k,
+                                                    width):
+    """A unit's mask, from the ``k``-th score found by search
+    (``kth_score``, the bisection kernel in interpret mode) under the one
+    rule, is bit for bit the mask from ``select_topk``'s sorted list: exact
+    float32 ties at the ``k``-th score cut at the same position, ``0.0`` and
+    ``-0.0`` one class, every unmasked position kept while a row has no more
+    than ``k``, nothing of a row that has none (a unit's padding); at a
+    width of 3.25 lane tiles, which the search pads, and at one of 3."""
+    from flashinfer_tpu.ops.sparse_mla import (
+        kth_score, mask_from_kth, select_topk, selection_mask)
+
+    scores = _search_scores(values, unmasked, width)
+    want = np.asarray(selection_mask(scores, *select_topk(scores, k)))
+    got = np.asarray(mask_from_kth(scores, kth_score(scores, k), k))
+    np.testing.assert_array_equal(got, want)
+    unmasked = np.asarray(scores > -jnp.inf).sum(-1)
+    np.testing.assert_array_equal(got.sum(-1), np.minimum(unmasked, k))
+    assert not got[np.asarray(scores) == -np.inf].any()
+
+
 def test_units_stream_through_the_engine(params, monkeypatch):
     """``test_prefill_then_decode_matches_the_reference`` again with windows
     of several blocks (a block cut to 16 positions here, so a 64-position

@@ -485,12 +485,14 @@ def test_moe_gmm_at_glm_widths(chip, compiled_branch, k, n):
 def test_glm_sparse_unit(chip, compiled_branch, k):
     """One unit of GLM-5's sparse attention at the cell's shapes (128 query
     rows against a window of a quarter, a half or all of a request's 16,640
-    positions: index scores, the exact top-2,048, the list as a mask, the
-    window streamed once under it by the ``sparse_mla_stream`` kernel) fits
-    the chip beside the cell's 10.9 GB of weights and pages.  The kernel is
-    a custom call of ``attn.core`` itself, outside the two scopes nested in
-    it, so ``step.index_ms_per_step`` and ``step.select_ms_per_step`` keep
-    reading what they read."""
+    positions: index scores, the exact 2,048th score of each row by search,
+    the selection as a mask, the window streamed once under it by the
+    ``sparse_mla_stream`` kernel) fits the chip beside the cell's 10.9 GB of
+    weights and pages.  The search is a Mosaic kernel under ``attn.select``
+    and no sort is left in the unit; the stream is a custom call of
+    ``attn.core`` itself, outside the two scopes nested in it, so
+    ``step.index_ms_per_step`` and ``step.select_ms_per_step`` keep reading
+    what they read."""
     from flashinfer_tpu.models.glm_dsa import GlmDsaConfig, _attend_block
 
     mcfg = GlmDsaConfig()
@@ -508,12 +510,19 @@ def test_glm_sparse_unit(chip, compiled_branch, k):
     hlo = compiled.as_text()
     for scope in ("attn.index", "attn.select"):
         assert f"/{scope}/" in hlo, scope
+    assert not re.search(r"\) sort\(", hlo)      # "%sort = (...) sort(" before
+    mosaic = [line for line in hlo.splitlines()
+              if 'custom_call_target="tpu_custom_call"' in line]
+    search = [line for line in mosaic if "/attn.select/" in line]
+    assert len(search) == 1 and len(mosaic) == 2
+    assert re.search(
+        r'op_name="jit\(unit\)/(\S+/)?attn\.core/attn\.select/', search[0])
     kernel = re.findall(r"%sparse_mla_stream(?:\.\d+)? = .*custom-call\(.*", hlo)
     assert len(kernel) == 1
     assert re.search(r'op_name="jit\(unit\)/(\S+/)?attn\.core/', kernel[0])
     assert "attn.index" not in kernel[0] and "attn.select" not in kernel[0]
-    # the scores, the sort's operands and the mask, each [128, k] wide: no
-    # [128, 2048, 640] block of gathered rows (335 MB and its two readers)
+    # the scores, the keys of the search and the mask, each [128, k] wide:
+    # no [128, 2048, 640] block of gathered rows (335 MB and its two readers)
     assert compiled.memory_analysis().temp_size_in_bytes < 1e8
 
 
